@@ -30,6 +30,98 @@ def add_registry_flag(
     )
 
 
+def add_model_override_flag(parser: argparse.ArgumentParser) -> None:
+    """``--model-override KEY=VALUE`` (repeatable): the one way every
+    model-taking CLI (trainer, serve, infer) cuts a named config — e.g.
+    ``--model llama3-8b --model-override n_layers=2`` is the published
+    widths at a depth one chip holds."""
+    parser.add_argument("--model-override", action="append", default=[],
+                        metavar="KEY=VALUE",
+                        help="override a model-config field (repeatable), "
+                             "e.g. --model-override n_layers=4; ints/"
+                             "floats parsed, anything else kept as string")
+
+
+def parse_model_overrides(items: list[str]) -> dict:
+    overrides = {}
+    for item in items:
+        key, sep, raw = item.partition("=")
+        if not sep or not key:
+            raise SystemExit(f"--model-override {item!r}: expected KEY=VALUE")
+        low = raw.lower()
+        if low in ("true", "false"):
+            # A string "false" would be truthy in a bool field — parse
+            # booleans explicitly.
+            val = low == "true"
+        else:
+            try:
+                val = int(raw)
+            except ValueError:
+                try:
+                    val = float(raw)
+                except ValueError:
+                    val = raw
+        overrides[key] = val
+    return overrides
+
+
+# The persistent compilation cache of a checkout: ONE fixed path (the
+# path is part of the cache key, so a directory that moves never hits),
+# shared by every chip-owning entry point so the trainer, the packer and
+# the server of one chain — and the next run — reuse each other's
+# programs. JAX_COMPILATION_CACHE_DIR, when set, wins and nothing is set
+# in code.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def init_jax(platform: str = "") -> None:
+    """The one place a chip-owning entry point (oim-trainer, oim-serve,
+    oim-infer, bench.py) configures JAX before its first backend touch:
+    ``platform`` (the ``--platform`` flag) overrides whatever
+    JAX_PLATFORMS the environment exported — an explicit choice never
+    falls back to another backend — and the compilation cache lands in
+    JAX_COMPILATION_CACHE_DIR or, unset, in the checkout's
+    ``.jax_cache/``."""
+    import jax
+
+    if platform:
+        jax.config.update("jax_platforms", platform)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+
+
+def restore_checkpoint_params(directory: str, mcfg, verb: str):
+    """(params, step) of the latest trainer checkpoint for oim-serve /
+    oim-infer: params only — serving needs no optimizer state in HBM. No
+    checkpoint is a refusal, never a random init."""
+    from oim_tpu.train.checkpoint import restore_llama_params
+
+    try:
+        return restore_llama_params(directory, mcfg)
+    except FileNotFoundError:
+        raise SystemExit(
+            f"no checkpoint found in {directory!r} "
+            f"(refusing to {verb} from random init)") from None
+
+
+def device_memory() -> dict:
+    """Log fields for the allocator's view of every local device, in
+    device order — ``bytes_in_use`` shows a lopsided placement,
+    ``peak_bytes_in_use`` what the process needed at most. Empty on a
+    backend that keeps no allocator stats (the CPU)."""
+    import jax
+
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if not all(stats):
+        return {}
+    return {
+        key: [int(s[key]) for s in stats]
+        for key in ("bytes_in_use", "peak_bytes_in_use")
+    }
+
+
 def add_common_flags(parser: argparse.ArgumentParser) -> None:
     parser.add_argument(
         "--log-level",

@@ -16,9 +16,16 @@ import argparse
 
 import numpy as np
 
-from oim_tpu.cli.common import add_common_flags, setup_logging
+from oim_tpu.cli.common import (
+    add_common_flags,
+    add_model_override_flag,
+    init_jax,
+    parse_model_overrides,
+    restore_checkpoint_params,
+    setup_logging,
+)
 from oim_tpu.common.logging import from_context
-from oim_tpu.train import TrainConfig, Trainer
+from oim_tpu.train import TrainConfig
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -26,6 +33,7 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--checkpoint-dir", required=True)
     parser.add_argument("--model", default="llama-tiny",
                         choices=("llama-tiny", "llama-tiny-moe", "llama3-8b"))
+    add_model_override_flag(parser)
     parser.add_argument("--prompt", default="",
                         help="comma-separated token ids; repeat the flag-"
                              "value with ';' between rows for a batch")
@@ -35,24 +43,24 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--max-seq", type=int, default=0,
                         help="cache length (default: prompt + n-new)")
     parser.add_argument("--platform", default="",
-                        help="force a jax platform (e.g. cpu)")
+                        help="jax platform, overriding JAX_PLATFORMS "
+                             "(tpu | cpu)")
     add_common_flags(parser)
     args = parser.parse_args(argv)
     setup_logging(args)
     log = from_context()
 
-    if args.platform:
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", args.platform)
+    init_jax(args.platform)
 
     import jax
     import jax.numpy as jnp
 
     from oim_tpu.models import generate as gen
 
-    cfg = TrainConfig(model=args.model, checkpoint_dir=args.checkpoint_dir)
-    mcfg = cfg.model_config()
+    mcfg = TrainConfig(
+        model=args.model,
+        model_overrides=parse_model_overrides(args.model_override),
+    ).model_config()
     if args.prompt:
         rows = [
             [int(t) for t in row.split(",") if t.strip()]
@@ -71,17 +79,12 @@ def main(argv: list[str] | None = None) -> int:
             jax.random.PRNGKey(args.seed), (1, 8), 0, mcfg.vocab, jnp.int32
         )
 
-    trainer = Trainer(cfg)
-    step = trainer.init_or_resume()
-    if step == 0:
-        raise SystemExit(
-            f"no checkpoint found in {args.checkpoint_dir!r} "
-            "(refusing to sample from random init)"
-        )
+    params, step = restore_checkpoint_params(
+        args.checkpoint_dir, mcfg, "sample")
     log.info("restored", step=step, model=args.model)
 
     out = gen.generate(
-        trainer.state.params, prompt, args.n_new, mcfg,
+        params, prompt, args.n_new, mcfg,
         temperature=args.temperature, rng=jax.random.PRNGKey(args.seed),
         max_seq=args.max_seq or None,
     )

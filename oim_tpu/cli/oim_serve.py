@@ -37,9 +37,14 @@ import threading
 
 from oim_tpu.cli.common import (
     add_common_flags,
+    add_model_override_flag,
     add_observability_flags,
     add_registry_flag,
+    device_memory,
+    init_jax,
     load_tls_flags,
+    parse_model_overrides,
+    restore_checkpoint_params,
     setup_logging,
     start_observability,
     start_telemetry_row,
@@ -54,21 +59,20 @@ def _load_params(args, log):
     Returns (params, model_cfg, feeder) — feeder is None in
     checkpoint-dir mode and otherwise shared with the draft loader, so
     two weights volumes ride one control-plane connection."""
-    from oim_tpu.train import TrainConfig, Trainer
+    from oim_tpu.train import TrainConfig
 
+    # The trainer's own --model-override: the served depth is the depth
+    # that was trained (a mismatch with the weights is an error below,
+    # never a truncation).
+    mcfg = TrainConfig(
+        model=args.model,
+        model_overrides=parse_model_overrides(args.model_override),
+    ).model_config()
     if args.checkpoint_dir:
-        cfg = TrainConfig(
-            model=args.model, checkpoint_dir=args.checkpoint_dir)
-        mcfg = cfg.model_config()
-        trainer = Trainer(cfg)
-        step = trainer.init_or_resume()
-        if step == 0:
-            raise SystemExit(
-                f"no checkpoint found in {args.checkpoint_dir!r} "
-                "(refusing to serve random init)"
-            )
-        params = trainer.state.params
-        log.info("restored checkpoint", step=step, model=args.model)
+        params, step = restore_checkpoint_params(
+            args.checkpoint_dir, mcfg, "serve")
+        log.info("restored checkpoint", step=step, model=args.model,
+                 n_layers=mcfg.n_layers)
         if args.pack_to:
             from oim_tpu.serve.weights import save_packed
 
@@ -78,7 +82,6 @@ def _load_params(args, log):
 
     # Packed-blob modes need the model config to shape the KV cache; the
     # blob itself carries only the param tree.
-    mcfg = TrainConfig(model=args.model).model_config()
     feeder = _make_feeder(args)
     from oim_tpu.serve.weights import (
         publish_weights,
@@ -94,8 +97,22 @@ def _load_params(args, log):
         for peer in args.prestage:
             _prestage_peer(feeder, request, peer, log)
     params = restore_weights(feeder, args.weights_volume)
-    log.info("restored weights volume", volume=args.weights_volume)
+    _check_depth(params, mcfg, args.weights_volume)
+    log.info("restored weights volume", volume=args.weights_volume,
+             n_layers=mcfg.n_layers)
     return params, mcfg, feeder
+
+
+def _check_depth(params, mcfg, volume: str) -> None:
+    """The stacked layer leaves carry the depth the weights were packed
+    at; the configured depth must equal it (decoding N layers out of a
+    deeper stack would be a silent truncation)."""
+    depth = params["layers"]["wq"].shape[0]
+    if depth != mcfg.n_layers:
+        raise SystemExit(
+            f"weights volume {volume!r} holds {depth} layers, the "
+            f"configured model has n_layers={mcfg.n_layers}; pass the "
+            "trainer's --model-override n_layers=N")
 
 
 def _load_draft_params(args, log, feeder=None):
@@ -104,22 +121,15 @@ def _load_draft_params(args, log, feeder=None):
     target weights — a SECOND content-addressed volume, published once,
     prestaged to the same peers, O(1) cache-hit boots on every warmed
     replica."""
-    from oim_tpu.train import TrainConfig, Trainer
+    from oim_tpu.train import TrainConfig
 
     mcfg = TrainConfig(model=args.draft_model).model_config()
     if args.draft_checkpoint_dir:
-        cfg = TrainConfig(model=args.draft_model,
-                          checkpoint_dir=args.draft_checkpoint_dir)
-        trainer = Trainer(cfg)
-        step = trainer.init_or_resume()
-        if step == 0:
-            raise SystemExit(
-                f"no draft checkpoint found in "
-                f"{args.draft_checkpoint_dir!r} "
-                "(refusing to speculate from random init)")
+        params, step = restore_checkpoint_params(
+            args.draft_checkpoint_dir, mcfg, "speculate")
         log.info("restored draft checkpoint", step=step,
                  model=args.draft_model)
-        return trainer.state.params, mcfg
+        return params, mcfg
 
     if feeder is None:  # target came from a checkpoint dir
         feeder = _make_feeder(args)
@@ -200,6 +210,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     parser.add_argument("--model", default="llama-tiny",
                         choices=("llama-tiny", "llama-tiny-moe", "llama3-8b"))
+    add_model_override_flag(parser)
     parser.add_argument("--checkpoint-dir", default="",
                         help="restore a trainer checkpoint in process")
     parser.add_argument(
@@ -401,7 +412,9 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--drain-timeout", type=float, default=60.0,
                         help="graceful-drain budget on shutdown")
     parser.add_argument("--platform", default="",
-                        help="force a jax platform (e.g. cpu)")
+                        help="jax platform, overriding JAX_PLATFORMS "
+                             "(tpu | cpu); explicit, so a missing chip "
+                             "is an error and never a CPU fallback")
     add_common_flags(parser)
     add_observability_flags(parser)
     args = parser.parse_args(argv)
@@ -452,10 +465,7 @@ def main(argv: list[str] | None = None) -> int:
             "--role prefill exports KV chains and needs a control "
             "plane (--backend or --registry + --controller-id), not "
             "--checkpoint-dir")
-    if args.platform:
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", args.platform)
+    init_jax(args.platform)
     obs = start_observability(args, "oim-serve")
 
     from oim_tpu.serve import ServeEngine, ServeService, serve_server
@@ -510,7 +520,8 @@ def main(argv: list[str] | None = None) -> int:
         tls=load_tls_flags(args))
     log.info(
         "oim-serve serving", endpoint=args.endpoint, addr=server.addr,
-        model=args.model, max_batch=args.max_batch, max_seq=args.max_seq,
+        model=args.model, n_layers=mcfg.n_layers, max_batch=args.max_batch,
+        max_seq=args.max_seq, shard=args.shard, **device_memory(),
     )
 
     registration = None
@@ -605,6 +616,7 @@ def main(argv: list[str] | None = None) -> int:
         members.stop(deregister=True)
     server.stop()
     obs.stop()
+    log.info("stopped", **device_memory())
     return 0
 
 

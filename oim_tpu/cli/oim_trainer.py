@@ -18,9 +18,13 @@ import argparse
 
 from oim_tpu.cli.common import (
     add_common_flags,
+    add_model_override_flag,
     add_observability_flags,
     add_registry_flag,
+    device_memory,
+    init_jax,
     load_tls_flags,
+    parse_model_overrides,
     setup_logging,
     start_observability,
 )
@@ -65,11 +69,7 @@ def main(argv: list[str] | None = None) -> int:
                              "chunks) per device — bubble shrinks to "
                              "(P-1)/(v*M+P-1); needs --pipeline-schedule "
                              "1f1b and n_layers %% (pipe*v) == 0")
-    parser.add_argument("--model-override", action="append", default=[],
-                        metavar="KEY=VALUE",
-                        help="override a model-config field (repeatable), "
-                             "e.g. --model-override n_layers=4; ints/"
-                             "floats parsed, anything else kept as string")
+    add_model_override_flag(parser)
     parser.add_argument("--remat", action="store_true",
                         help="recompute activations in the backward pass "
                              "(fit bigger models/batches in HBM)")
@@ -169,8 +169,10 @@ def main(argv: list[str] | None = None) -> int:
     )
     parser.add_argument(
         "--platform", default="",
-        help="force a jax platform (e.g. 'cpu' for a virtual multi-device "
-             "mesh via --xla_force_host_platform_device_count)",
+        help="jax platform, overriding JAX_PLATFORMS: 'tpu' on a chip "
+             "host (a missing chip is then an error, never a CPU "
+             "fallback), 'cpu' for a virtual multi-device mesh via "
+             "--xla_force_host_platform_device_count",
     )
     add_common_flags(parser)
     add_observability_flags(parser)
@@ -187,10 +189,7 @@ def main(argv: list[str] | None = None) -> int:
             args.registry, tls=load_tls_flags(args))
     log = from_context()
 
-    if args.platform:
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", args.platform)
+    init_jax(args.platform)
 
     if args.smoke:
         import jax
@@ -202,26 +201,6 @@ def main(argv: list[str] | None = None) -> int:
         args.log_every = 1
         if not args.mesh:
             args.mesh = f"data={min(args.batch_size, len(jax.devices()))}"
-
-    overrides = {}
-    for item in args.model_override:
-        key, sep, raw = item.partition("=")
-        if not sep or not key:
-            raise SystemExit(f"--model-override {item!r}: expected KEY=VALUE")
-        low = raw.lower()
-        if low in ("true", "false"):
-            # A string "false" would be truthy in a bool field — parse
-            # booleans explicitly.
-            val = low == "true"
-        else:
-            try:
-                val = int(raw)
-            except ValueError:
-                try:
-                    val = float(raw)
-                except ValueError:
-                    val = raw
-        overrides[key] = val
 
     cfg = TrainConfig(
         model=args.model,
@@ -246,7 +225,7 @@ def main(argv: list[str] | None = None) -> int:
         checkpoint_every=args.checkpoint_every,
         eval_every=args.eval_every,
         eval_steps=args.eval_steps,
-        model_overrides=overrides,
+        model_overrides=parse_model_overrides(args.model_override),
     )
 
     data = None
@@ -344,7 +323,7 @@ def main(argv: list[str] | None = None) -> int:
     try:
         with profile_trace(args.profile):
             loss = trainer.run(steps=args.steps, data=data, eval_data=eval_data)
-        log.info("done", final_loss=round(loss, 4))
+        log.info("done", final_loss=round(loss, 4), **device_memory())
     finally:
         obs.stop()
     return 0

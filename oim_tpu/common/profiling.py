@@ -21,28 +21,14 @@ from oim_tpu.common.logging import from_context
 
 @contextlib.contextmanager
 def profile_trace(trace_dir: str | None):
-    """jax.profiler.trace wrapper; no-op when trace_dir is falsy, and
-    degrades to a warning (not a crash) on backends that can't profile —
-    remote-execution tunnels may not support the profiler service."""
+    """jax.profiler.trace wrapper; no-op when trace_dir is falsy. A
+    profiler that cannot start or finalize raises: a run asked to trace
+    that silently traced nothing is worse than one that stops."""
     if not trace_dir:
         yield
         return
     import jax
 
-    log = from_context()
-    try:
-        ctx = jax.profiler.trace(trace_dir)
-        ctx.__enter__()
-    except Exception as err:  # pragma: no cover - backend-dependent
-        log.error("profiler unavailable; continuing without trace",
-                  error=str(err))
+    from_context().info("profiling", dir=trace_dir)
+    with jax.profiler.trace(trace_dir):
         yield
-        return
-    log.info("profiling", dir=trace_dir)
-    try:
-        yield
-    finally:
-        try:
-            ctx.__exit__(None, None, None)
-        except Exception as err:  # pragma: no cover - backend-dependent
-            log.error("profiler trace finalize failed", error=str(err))

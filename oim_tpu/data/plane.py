@@ -295,8 +295,8 @@ def slice_runs(
 
 
 class PlacementNotLowerable(ValueError):
-    """The placement's slices exceed MAX_RUNS runs; callers fall back to
-    whole-array staging."""
+    """The placement's slices exceed MAX_RUNS runs (or, on a TPU, int32
+    byte indexing); callers fall back to whole-array staging."""
 
 
 class _Cancelled(Exception):
@@ -420,8 +420,8 @@ def iter_view_chunks(
 # Transient device-byte accounting for the most recent stage_source call:
 # the peak this model claims (preallocated buffers + up to two in-flight
 # chunks per concurrently-staging group — the H2D double buffer) is what
-# the memory-bound CPU test asserts, and the ring-2 TPU test checks the
-# same bound against device.memory_stats() for real.
+# the memory-bound CPU test asserts, and chip_smoke.py's stage phase checks
+# the same bound against device.memory_stats() for real.
 LAST_STAGE_PEAK = 0
 # Max shard groups observed staging simultaneously during the most recent
 # stage_source call — the concurrency the parallel pipeline achieved.
@@ -451,8 +451,9 @@ def _default_workers() -> int:
 
 
 # Buffers beyond int32 indexing land chunks under a scoped enable_x64 so
-# the dynamic_update_slice offset can be int64 (a >2 GiB shard is exactly
-# the case the donated-buffer design exists for). Patchable for tests.
+# the dynamic_update_slice offset can be int64 — on backends whose
+# compiler takes it (stage_source refuses such a view on a TPU up front).
+# Patchable for tests.
 _X64_THRESHOLD = (1 << 31) - 1
 
 
@@ -468,23 +469,13 @@ def _updater(x64: bool):
     return upd
 
 
-def _enable_x64():
-    """jax.enable_x64 moved between jax versions (removed from the top
-    level in 0.4.x); resolve the scoped context manager wherever it
-    lives."""
-    import jax
-
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(True)
-
-
 def _update(buf, dchunk, off):
     """Dispatch one donated dynamic_update_slice of ``dchunk`` into
     ``buf`` at byte offset ``off`` (int64 path past int32 indexing)."""
     if buf.size > _X64_THRESHOLD:
-        with _enable_x64():
+        import jax
+
+        with jax.enable_x64(True):
             return _updater(True)(buf, dchunk, np.int64(off))
     return _updater(False)(buf, dchunk, np.int32(off))
 
@@ -506,13 +497,12 @@ def _device_empty(nbytes: int, device):
 
 
 def _fence(dchunks) -> None:
-    """Portable completion fence for in-flight device_put results: fetch a
-    byte. Remote-execution backends can return from block_until_ready
-    before the copy consumed the host buffer (BASELINE.md caveat), so this
-    is the only fence that proves the pinned source buffer is reusable."""
-    for dc in dchunks:
-        if dc.size:
-            np.asarray(dc[:1])
+    """Completion fence for in-flight device_put results: once the copy
+    is ready on the device it has consumed its host buffer, so the
+    pinned source is reusable."""
+    import jax
+
+    jax.block_until_ready(dchunks)
 
 
 class _StageControl:
@@ -613,8 +603,7 @@ def _stage_view(src, runs, devices, chunk_bytes, ctl, group):
     while chunk N's donated update dispatches, with NO per-chunk blocking
     — the pinned source of an in-flight copy is fenced only when its slot
     comes up for reuse (every other chunk) and once at the end of the
-    group, so a remote-execution dispatch round-trip is paid per slot
-    turnover instead of per chunk.
+    group, so the host blocks per slot turnover instead of per chunk.
 
     Returns {device: uint8 buffer} or None on abort (buffers freed).
     """
@@ -782,6 +771,15 @@ def stage_source(
                 f"placement of {shape} over {sharding} exceeds "
                 f"{MAX_RUNS} runs per slice"
             )
+        view_bytes = sum(n for _, n in lr[0])
+        if devs[0].platform == "tpu" and view_bytes > _X64_THRESHOLD:
+            # The TPU compiler refuses a dynamic-update-slice whose
+            # indices need 64 bits (tests/test_chip_compile.py pins the
+            # refusal), so a byte view past int32 cannot land chunk by
+            # chunk there; the whole-read path device_puts it in one go.
+            raise PlacementNotLowerable(
+                f"a {view_bytes}-byte device slice is past "
+                "int32 indexing on a TPU")
         lowered.append((devs, lr[0], lr[1]))
     ctl = _StageControl(progress)
     n_workers = max(1, min(len(lowered),

@@ -75,7 +75,10 @@ def _bind(lib) -> None:
 
 
 def build(force: bool = False) -> bool:
-    """Build libstaging.so via make; returns success."""
+    """Build libstaging.so via make; returns success. The .so is a build
+    product git does not carry, so every fresh checkout builds it here;
+    a failed build is logged with the compiler's output (the caller then
+    runs on the io_uring / readinto path — slower, never silent)."""
     if _LIB_PATH.exists() and not force:
         return True
     try:
@@ -83,9 +86,15 @@ def build(force: bool = False) -> bool:
             ["make", "-C", str(_NATIVE_DIR)],
             check=True, capture_output=True, timeout=120,
         )
-        return _LIB_PATH.exists()
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as err:
+        from oim_tpu.common.logging import from_context
+
+        output = getattr(err, "stderr", b"") or b""
+        from_context().warning(
+            "native staging engine build failed", error=repr(err),
+            output=output.decode(errors="replace")[-2000:])
         return False
+    return _LIB_PATH.exists()
 
 
 def native_lib(autobuild: bool = False):

@@ -25,6 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from oim_tpu.common.logging import from_context
+
 NEG_INF = -1e30
 
 
@@ -531,10 +533,10 @@ def _flash_plan(q, k) -> tuple[int, int] | None:
     """(block_q, block_k) when the pallas kernels apply to these shapes on
     this backend, else None — THE dispatch rule, shared by every entry
     point so they cannot drift apart."""
-    if jax.default_backend() != "tpu":
-        return None
     tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
-    if tq % 128 or tk % 128 or d % 128 or q.shape[2] % k.shape[2]:
+    if (jax.default_backend() != "tpu" or tq % 128 or tk % 128 or d % 128
+            or q.shape[2] % k.shape[2]):
+        _log_dispatch("jnp_reference", q, k)
         return None
 
     def pick(t):
@@ -546,7 +548,18 @@ def _flash_plan(q, k) -> tuple[int, int] | None:
                 return b
         return 128
 
-    return pick(tq), pick(tk)
+    plan = pick(tq), pick(tk)
+    _log_dispatch("pallas_flash", q, k, block_q=plan[0], block_k=plan[1])
+    return plan
+
+
+def _log_dispatch(kernel: str, q, k, **fields) -> None:
+    """One line per TRACE (the plan runs under jit, so never per step):
+    which implementation a program took is otherwise invisible — a shape
+    that silently misses the kernel costs the whole attention speed."""
+    from_context().info(
+        "attention dispatch", kernel=kernel, backend=jax.default_backend(),
+        q=tuple(q.shape), k=tuple(k.shape), **fields)
 
 
 def attention_with_lse(q, k, v, causal: bool = True, scale: float | None = None):

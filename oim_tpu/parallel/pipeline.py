@@ -156,7 +156,7 @@ def make_pipelined_apply(
     attention (parallel/ring.py) and collectives over ``seq_axis`` directly
     — PP x SP x DP in one program.
     """
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if batch_axes is None:

@@ -892,7 +892,7 @@ def make_1f1b_value_and_grad(
     scale should pre-permute storage instead (the schedule layout is a
     placement decision, like any sharding).
     """
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if batch_axes is None:
@@ -996,7 +996,7 @@ def verify_sharded_head_contract(
     per ``head_specs`` must have their ``axis`` dimension divisible by
     the axis size).
     """
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     head_params, hb, tgt = make_tiny_inputs(jax.random.PRNGKey(17))

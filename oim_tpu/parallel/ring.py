@@ -288,7 +288,7 @@ def make_sequence_parallel_attention(
     the output mapped back, so callers keep natural sequence order and
     RoPE applied before this call stays correct.
     """
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if batch_axes is None:

@@ -564,7 +564,11 @@ class ServeEngine:
         # chain, which only the admitting request ever knew.
         self._hot_chains: collections.OrderedDict[str, tuple] = \
             collections.OrderedDict()
-        self.params = jax.tree.map(jnp.asarray, params)
+        # A sharded replica commits host leaves straight to their mesh
+        # slices below; landing them whole on the first device first
+        # would need the full model in ONE member's HBM.
+        self.params = params if self.shard > 1 else jax.tree.map(
+            jnp.asarray, params)
         # +1 physical page: id 0 is the reserved scratch/null page every
         # unmapped table entry points at (see init_page_pool).
         self._cache = gen.init_page_pool(

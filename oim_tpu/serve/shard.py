@@ -144,7 +144,7 @@ def wrap_forward(shard: int, body, cache_arg: int):
     tree paths, so tracers are fine."""
     from jax.sharding import PartitionSpec as P
 
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = tp_mesh(shard)
     pool = pool_specs()
@@ -172,7 +172,7 @@ def _probe_program(shard: int):
     from jax.sharding import PartitionSpec as P
 
     from oim_tpu.parallel import collectives
-    from oim_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = tp_mesh(shard)
     prog = jax.jit(shard_map(

@@ -47,11 +47,10 @@ def _dtype_name(dtype) -> str:
     return name
 
 
-def pack_params(params: Any) -> bytes:
-    """Serialize a params pytree: magic + uint64 header length + JSON
-    manifest (tree paths, dtypes, shapes, offsets) + raw leaf bytes.
-    Deterministic for a given tree, so identical checkpoints pack to
-    identical bytes and content-address to one stage-cache entry."""
+def _pack_parts(params: Any):
+    """(preamble bytes, [leaf byte views]) of the packed form: magic +
+    uint64 header length + JSON manifest (tree paths, dtypes, shapes,
+    offsets), then the raw leaf bytes in manifest order."""
     import jax
 
     leaves_with_paths, treedef = jax.tree_util.tree_flatten_with_path(params)
@@ -59,31 +58,32 @@ def pack_params(params: Any) -> bytes:
     blobs = []
     offset = 0
     for path, leaf in leaves_with_paths:
-        arr = np.asarray(leaf)
-        raw = np.ascontiguousarray(arr)
+        arr = np.ascontiguousarray(np.asarray(leaf))
         manifest.append({
             "path": jax.tree_util.keystr(path),
             "dtype": _dtype_name(arr.dtype),
             "shape": list(arr.shape),
             "offset": offset,
-            "bytes": int(raw.nbytes),
+            "bytes": int(arr.nbytes),
         })
-        blobs.append(raw)
-        offset += raw.nbytes
+        # A uint8 view, not memoryview(arr): the buffer protocol has no
+        # format for bfloat16 (the dtype every real-width leaf has).
+        blobs.append(arr.reshape(-1).view(np.uint8))
+        offset += arr.nbytes
     header = json.dumps({
         "leaves": manifest,
         "treedef": str(treedef),
         "total_bytes": offset,
     }, sort_keys=True).encode()
-    out = bytearray()
-    out += _MAGIC
-    out += struct.pack("<Q", len(header))
-    out += header
-    for raw in blobs:
-        # memoryview, not the array itself: bytearray += ndarray is
-        # elementwise add, not concatenation.
-        out += memoryview(raw).cast("B")
-    return bytes(out)
+    return _MAGIC + struct.pack("<Q", len(header)) + header, blobs
+
+
+def pack_params(params: Any) -> bytes:
+    """Serialize a params pytree to bytes. Deterministic for a given
+    tree, so identical checkpoints pack to identical bytes and
+    content-address to one stage-cache entry."""
+    preamble, blobs = _pack_parts(params)
+    return preamble + b"".join(memoryview(raw) for raw in blobs)
 
 
 def unpack_params(buf) -> dict:
@@ -127,10 +127,14 @@ def _insert(tree: dict, keystr: str, leaf) -> None:
 def save_packed(params: Any, path: str) -> int:
     """Pack ``params`` to ``path``; returns the byte size. The file is
     the volume SOURCE — publish it with :func:`publish_weights`."""
-    blob = pack_params(params)
+    preamble, blobs = _pack_parts(params)
     with open(path, "wb") as f:
-        f.write(blob)
-    return len(blob)
+        # Leaf by leaf: an 8B-class tree is never built a second (or
+        # third) time in host RAM just to reach the disk.
+        f.write(preamble)
+        for raw in blobs:
+            f.write(memoryview(raw))
+    return len(preamble) + sum(raw.nbytes for raw in blobs)
 
 
 def weights_request(volume_id: str, path: str, total_bytes: int):
@@ -155,8 +159,13 @@ def publish_weights(feeder, volume_id: str, path: str,
 
     request = weights_request(volume_id, path, os.path.getsize(path))
     pub = feeder.publish(request, timeout=timeout)
+    from oim_tpu.data import staging
+
+    # read_path: which engine read the source in THIS process (native /
+    # io_uring / readinto; "none" = staged remotely or a cache hit).
     from_context().info(
-        "published weights volume", volume=volume_id, bytes=pub.bytes)
+        "published weights volume", volume=volume_id, bytes=pub.bytes,
+        read_path=staging.read_path())
     return pub
 
 

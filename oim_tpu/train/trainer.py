@@ -76,11 +76,16 @@ PEAK_HBM_BW = {
 
 
 def _lookup_peak(table: dict[str, float]) -> float:
+    """The chip's published peak. A device the table does not know is an
+    error, not a 0.0 default: a utilization figured against no peak
+    reads as a measurement."""
     kind = jax.devices()[0].device_kind.lower()
     for key, val in table.items():
         if key in kind:
             return val
-    return 0.0
+    raise KeyError(
+        f"no published peak for device kind {kind!r} "
+        f"(known: {sorted(table)})")
 
 
 def peak_flops_per_device() -> float:
@@ -165,7 +170,44 @@ def _llama_attn_fn(cfg: TrainConfig, mesh):
             mesh, kind=cfg.seq_parallel, axis="seq", causal=True
         )
         return lambda q, k, v, causal=True: sp(q, k, v)
+    if mesh.size > 1 and cfg.rules != "pipe":
+        # (The pipeline runs its stages — attention included — inside
+        # its own shard_map already.)
+        return _per_device_attention(cfg, mesh)
     return None  # model default (pallas flash / reference)
+
+
+def _per_device_attention(cfg: TrainConfig, mesh):
+    """The model's default attention under shard_map: XLA's SPMD pass
+    cannot partition a Pallas (Mosaic) kernel, so on a multi-device mesh
+    the jitted step refuses to compile on a TPU unless each device is
+    handed its own slice — the batch over the rules' batch axes and,
+    under tensor parallelism, whole GQA groups of heads over "model"."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from oim_tpu.ops.attention import attention
+    from oim_tpu.parallel.sharding import HEAD
+
+    rules = RULES[cfg.rules]
+
+    def present(axes):
+        # Rules name axes a mesh may hold at size 1 (or not at all).
+        axes = axes if isinstance(axes, tuple) else (axes,) if axes else ()
+        return tuple(a for a in axes if mesh.shape.get(a, 1) > 1) or None
+
+    mcfg = cfg.model_config()
+    heads = present(rules.axis_for(HEAD))
+    if heads:
+        width = int(np.prod([mesh.shape[a] for a in heads]))
+        if mcfg.n_heads % width or mcfg.n_kv_heads % width:
+            heads = None  # a split would cut through a GQA group
+    spec = P(present(rules.axis_for(BATCH)), None, heads, None)
+    local = shard_map(
+        lambda q, k, v: attention(q, k, v, True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
+    return lambda q, k, v, causal=True: local(q, k, v)
 
 
 def _follow_param_shardings(abstract_tree, params_abstract, p_shardings, replicated):
@@ -669,7 +711,10 @@ class Trainer:
                         "consumed"
                     ) from None
         fps = flops_per_step(cfg)
-        peak = peak_flops_per_device() * self.mesh.size
+        # MFU is a device metric: figured on a TPU only (a CPU run has
+        # no peak to divide by and logs no mfu).
+        peak = (peak_flops_per_device() * self.mesh.size
+                if jax.default_backend() == "tpu" else None)
         last_loss = float("nan")
         t_prev = time.monotonic()
         last_logged = start_step
@@ -708,8 +753,10 @@ class Trainer:
                 M.TRAIN_STEP_SECONDS.set(dt)
                 M.TRAIN_EXAMPLES_PER_SEC.set(cfg.batch_size / dt)
                 M.FEED_WAIT_SECONDS.set(feed_wait / n_steps)
-                mfu = fps / dt / peak if peak else 0.0
-                M.TRAIN_MFU.set(mfu)
+                device_stats = {}
+                if peak:
+                    device_stats["mfu"] = round(fps / dt / peak, 4)
+                    M.TRAIN_MFU.set(device_stats["mfu"])
                 extra_stats = {}
                 for k, v in stats.items():
                     if k in ("loss", "grad_norm"):
@@ -721,9 +768,9 @@ class Trainer:
                 log.info(
                     "step", step=i + 1, loss=round(last_loss, 4),
                     grad_norm=round(float(stats["grad_norm"]), 4),
-                    step_s=round(dt, 4), mfu=round(mfu, 4),
+                    step_s=round(dt, 4),
                     feed_wait_s=round(feed_wait / n_steps, 4),
-                    **extra_stats,
+                    **device_stats, **extra_stats,
                 )
                 feed_wait = 0.0
             if eval_every and (i + 1) % eval_every == 0:

@@ -1,0 +1,251 @@
+"""The main path's programs COMPILED for a TPU v5e that is described, not
+attached: the chip's own compiler refuses here what it would refuse on
+the machine — a kernel slice off the tiling, a program over HBM, a
+Mosaic call XLA is asked to partition — at no chip time. Shapes are
+chip_smoke.py's (llama3-8b widths, its depths, its --max-batch/--max-seq),
+so the smoke's first chip call is never spent on a compile error.
+
+A compile that passes is not a run: nothing here says a result is right
+or fast. Everything that touches the topology lives in fixtures of THIS
+file (one xdist worker loads the TPU library; nothing at import time).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+import chip_smoke
+from oim_tpu.models import generate as gen
+from oim_tpu.models import llama
+from oim_tpu.ops.attention import _flash_plan, attention
+from oim_tpu.train import TrainConfig
+
+ONE = chip_smoke.ONE_CHIP
+FOUR = chip_smoke.FOUR_CHIPS
+PAGE = 16  # oim-serve's default --prefix-block == --kv-page-tokens
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch, no_compile_cache):
+    """Code that asks jax.default_backend() still sees the CPU here and
+    would take its CPU branch; steer the attention dispatch from the
+    test, not through an option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def shaped(tree, sharding):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def model_config(n_layers: int) -> llama.Config:
+    return TrainConfig(
+        model=ONE["model"], model_overrides={"n_layers": n_layers},
+    ).model_config()
+
+
+def test_widths_are_the_published_ones():
+    cfg = model_config(ONE["n_layers"])
+    assert dataclasses.replace(cfg, n_layers=32) == llama.LLAMA3_8B
+    assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.mlp_dim,
+            cfg.vocab) == (4096, 32, 8, 128, 14336, ONE["vocab"])
+
+
+@pytest.mark.parametrize("seq,block", [(2048, 1024), (1536, 512), (640, 128)])
+def test_flash_fwd_bwd(topo, as_tpu, seq, block):
+    """The Pallas flash kernels, forward and backward, at the model's
+    head geometry and each block size the plan can pick."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg = llama.LLAMA3_8B
+    q = jax.ShapeDtypeStruct((1, seq, cfg.n_heads, cfg.head_dim),
+                             jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, seq, cfg.n_kv_heads, cfg.head_dim),
+                              jnp.bfloat16, sharding=chip)
+    assert _flash_plan(q, kv) == (block, block)
+
+    def loss(q, k, v):
+        return attention(q, k, v, True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def serve_shapes(cfg, sharding):
+    n_pages = ONE["max_batch"] * ONE["max_seq"] // PAGE + 1
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(
+        lambda: gen.init_page_pool(cfg, n_pages, PAGE)), sharding)
+    return params, pool
+
+
+def step_operands(sharding):
+    b, blocks = ONE["max_batch"], ONE["max_seq"] // PAGE
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (s((b,), jnp.int32), s((b,), jnp.int32),
+            s((b,) + key.shape, key.dtype), s((b,), jnp.float32),
+            s((b, blocks), jnp.int32))
+
+
+def test_decode_step(topo, as_tpu):
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg = model_config(ONE["n_layers"])
+    step, _ = _target_programs(cfg, PAGE, ONE["max_seq"])
+    params, pool = serve_shapes(cfg, chip)
+    mem = step.lower(params, pool, *step_operands(chip)
+                     ).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.parametrize("bucket", [16, ONE["max_seq"]])
+def test_prefill_bucket(topo, as_tpu, bucket):
+    """The smallest bucket and the full --max-seq one (f32 scores and
+    full-sequence logits materialize there)."""
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg = model_config(ONE["n_layers"])
+    _, prefill = _target_programs(cfg, PAGE, ONE["max_seq"])
+    params, pool = serve_shapes(cfg, chip)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    prefill.lower(
+        params, pool, s((1, bucket), jnp.int32), s((), jnp.int32),
+        s((ONE["max_seq"] // PAGE,), jnp.int32), s((), jnp.int32),
+        s(key.shape, key.dtype), s((), jnp.float32)).compile()
+
+
+def compile_train_step(topo, rules, axes, n_layers, batch):
+    from oim_tpu.parallel.sharding import BATCH, logical_sharding
+    from oim_tpu.train.state import make_optimizer
+    from oim_tpu.train.trainer import RULES, make_train_step
+
+    n = int(np.prod([size for _, size in axes]))
+    mesh = Mesh(np.asarray(topo.devices[:n]).reshape(
+        [size for _, size in axes]), tuple(name for name, _ in axes))
+    cfg = TrainConfig(model=ONE["model"], rules=rules, batch_size=batch,
+                      seq_len=ONE["seq"],
+                      model_overrides={"n_layers": n_layers})
+    step, shardings, init_fn, _ = make_train_step(cfg, mesh, make_optimizer())
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)), shardings)
+    tokens = jax.ShapeDtypeStruct(
+        (batch, ONE["seq"] + 1), jnp.int32,
+        sharding=logical_sharding(mesh, RULES[rules], (BATCH, None)))
+    return step.lower(state, {"tokens": tokens}).compile()
+
+
+def test_train_step_one_chip(topo, as_tpu):
+    """The smoke's trainer step fits one chip with the kernel in it, and
+    the chunked loss keeps the [B, T, vocab] logits out of the program."""
+    compiled = compile_train_step(
+        topo, "dp", [("data", 1)], ONE["n_layers"], ONE["batch"])
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    logits = f"[{ONE['batch']},{ONE['seq']},{ONE['vocab']}]"
+    assert logits not in text.replace(" ", "")
+
+
+def test_train_step_fsdp_four_chips(topo, as_tpu):
+    """--rules fsdp over four chips: XLA cannot partition a Mosaic kernel,
+    so the step compiles only with attention under shard_map."""
+    compiled = compile_train_step(
+        topo, "fsdp", [("data", 1), ("fsdp", 4)], FOUR["deep_layers"],
+        FOUR["batch"])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_shard4(topo, as_tpu, monkeypatch):
+    """One --shard 4 decode step on a mesh of the described devices."""
+    from jax.sharding import PartitionSpec as P
+
+    from oim_tpu.serve import shard as shardlib
+    from oim_tpu.serve.engine import _target_programs
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("tp",))
+    monkeypatch.setattr(shardlib, "tp_mesh", lambda n: mesh)
+    cfg = model_config(ONE["n_layers"])
+    _target_programs.cache_clear()
+    try:
+        step, _ = _target_programs(cfg, PAGE, ONE["max_seq"], 4)
+        n_pages = ONE["max_batch"] * ONE["max_seq"] // PAGE + 1
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(
+                    mesh, shardlib.leaf_spec(path[-1].key))),
+            jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)))
+        pool = {
+            k: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(
+                    mesh, shardlib.pool_specs()[k]))
+            for k, s in jax.eval_shape(
+                lambda: gen.init_page_pool(cfg, n_pages, PAGE)).items()}
+        text = step.lower(
+            params, pool, *step_operands(NamedSharding(mesh, P()))
+        ).compile().as_text()
+    finally:
+        _target_programs.cache_clear()  # never leak the described mesh
+    assert "all-reduce" in text
+
+
+def test_byte_buffer_past_int32_is_refused(topo, no_compile_cache):
+    """Why plane.stage_source sends a >2 GiB byte view on a TPU to the
+    whole-read path: the chip's compiler refuses the chunk-landing
+    dynamic-update-slice once its indices need 64 bits. If this ever
+    compiles, the refusal in stage_source can go."""
+    from oim_tpu.data import plane
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    buf = jax.ShapeDtypeStruct((3 << 30,), jnp.uint8, sharding=chip)
+    chunk = jax.ShapeDtypeStruct((64 << 20,), jnp.uint8, sharding=chip)
+    with jax.enable_x64(True):
+        off = jax.ShapeDtypeStruct((), jnp.int64, sharding=chip)
+        with pytest.raises(Exception, match="exceed 32-bits"):
+            plane._updater(True).lower(buf, chunk, off).compile()
+    # Under int32 the same program is fine.
+    small = jax.ShapeDtypeStruct((1 << 30,), jnp.uint8, sharding=chip)
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+    plane._updater(False).lower(small, chunk, off).compile()

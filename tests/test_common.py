@@ -3,6 +3,7 @@ path_test.go, pci_test.go, server_test.go, cmdmonitor_test.go and pkg/log
 tests)."""
 
 import io
+import os
 import subprocess
 import sys
 import time
@@ -307,3 +308,48 @@ class TestDependencyManifest:
             "pyproject.toml pins drifted from the running environment "
             f"(update the manifest to the verified set): {drift}"
         )
+
+
+class TestChipOwnership:
+    """A chip belongs to one process at a time, so everything that runs
+    NEXT TO a chip-owning trainer or server must stay off JAX entirely:
+    the daemons (a `--backend malloc` controller serves MapVolume and
+    ReadVolume windows from host buffers) and chip_smoke.py itself, whose
+    children hold the chip in turn."""
+
+    @pytest.mark.parametrize("module", [
+        "oim_tpu.cli.oim_registry", "oim_tpu.cli.oim_controller",
+        "oim_tpu.cli.oim_router", "oim_tpu.cli.oim_monitor",
+        "oim_tpu.cli.oim_autoscaler", "chip_smoke",
+    ])
+    def test_import_stays_off_jax(self, module):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        out = subprocess.run(
+            [sys.executable, "-c",
+             f"import sys; import {module}; print('jax' in sys.modules)"],
+            cwd=root, capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip() == "False", f"{module} imports jax"
+
+    def test_malloc_controller_data_path_stays_off_jax(self, tmp_path):
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        script = f"""
+import sys
+import numpy as np
+from oim_tpu.controller import MallocBackend
+from oim_tpu.controller.controller import ControllerService
+from oim_tpu.feeder import Feeder
+from oim_tpu.spec import pb
+np.save({str(tmp_path / 'v.npy')!r}, np.arange(1000, dtype=np.int32))
+feeder = Feeder(controller=ControllerService(MallocBackend()))
+req = pb.MapVolumeRequest(volume_id="v")
+req.file.path, req.file.format = {str(tmp_path / 'v.npy')!r}, "npy"
+assert feeder.publish(req, timeout=30).bytes == 4000
+assert feeder.fetch_window("v", 0, 1024, timeout=10)[1] == 4000
+print('jax' in sys.modules)
+"""
+        out = subprocess.run(
+            [sys.executable, "-c", script], cwd=root, capture_output=True,
+            text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == "False"

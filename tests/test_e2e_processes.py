@@ -37,6 +37,9 @@ def child_env() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"  # children never touch the real chip
+    # These tests READ the children's INFO lines ("done", "step"); the
+    # suite-wide OIM_LOG_LEVEL=error (conftest.py) would silence them.
+    env["OIM_LOG_LEVEL"] = "info"
     return env
 
 
@@ -66,6 +69,11 @@ class Cluster:
             "--endpoint", f"tcp://127.0.0.1:{self.registry_port}",
             "--ca", f"{certs}/ca.crt", "--key", f"{certs}/component.registry",
         )
+        # The controller dials a registry that already answers: a first
+        # connect that is refused puts its channel into gRPC's reconnect
+        # backoff (1 s, growing), and on a loaded box registration then
+        # lands past wait_ready's deadline.
+        self.wait_registry()
         self._spawn(
             "controller", "oim_tpu.cli.oim_controller",
             "--endpoint", f"tcp://127.0.0.1:{self.controller_port}",
@@ -94,7 +102,20 @@ class Cluster:
         channel = secure_channel(f"127.0.0.1:{self.registry_port}", tls)
         return RegistryStub(channel)
 
-    def wait_ready(self, timeout: float = 30.0) -> None:
+    def wait_registry(self, timeout: float = 60.0) -> None:
+        """The registry answers. A FRESH channel per probe: one channel
+        reused across refused connects sits out the reconnect backoff."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            try:
+                self.admin_stub().GetValues(
+                    pb.GetValuesRequest(path=""), timeout=2)
+                return
+            except Exception:
+                time.sleep(0.1)
+        raise TimeoutError("registry never answered")
+
+    def wait_ready(self, timeout: float = 60.0) -> None:
         """Registry answers AND the controller has self-registered."""
         stub = self.admin_stub()
         deadline = time.monotonic() + timeout

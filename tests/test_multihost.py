@@ -36,6 +36,10 @@ def child_env(devices: int = 0) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
+    # These tests READ the children's INFO lines ("distributed", "step",
+    # "resumed"); the suite-wide OIM_LOG_LEVEL=error (conftest.py) would
+    # silence them.
+    env["OIM_LOG_LEVEL"] = "info"
     if devices:
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     return env
@@ -68,6 +72,10 @@ class TwoHostCluster:
             "--endpoint", f"tcp://127.0.0.1:{self.registry_port}",
             "--ca", f"{certs}/ca.crt", "--key", f"{certs}/component.registry",
         )
+        # Controllers dial a registry that already answers (a refused
+        # first connect parks their channel in gRPC's reconnect backoff
+        # and, on a loaded box, registration past wait_ready's deadline).
+        self.wait_registry()
         for i, port in enumerate(self.controller_ports):
             self._spawn(
                 f"controller-{i}", "oim_tpu.cli.oim_controller",
@@ -97,6 +105,19 @@ class TwoHostCluster:
         )
         return RegistryStub(
             secure_channel(f"127.0.0.1:{self.registry_port}", tls))
+
+    def wait_registry(self, timeout: float = 60.0) -> None:
+        """A FRESH channel per probe: one channel reused across refused
+        connects sits out the reconnect backoff."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            try:
+                self.admin_stub().GetValues(
+                    pb.GetValuesRequest(path=""), timeout=2)
+                return
+            except Exception:
+                time.sleep(0.1)
+        raise TimeoutError("registry never answered")
 
     def wait_ready(self, timeout: float = 120.0) -> None:
         # Generous: the full suite can run this module on a machine already
@@ -377,9 +398,23 @@ class TestDistributedCheckpointResume:
         except Exception:
             return None
 
+    # The checkpointing pair is launched for PAIR_STEPS and killed early.
+    # --steps is also the LR schedule's horizon, so the uninterrupted
+    # control runs the SAME horizon and is read at the target step: a
+    # control launched with --steps <target> decays its LR faster and
+    # only agrees with the pair while resumed_from == 2 — whenever the
+    # SIGKILL raced a later save (a loaded box), it left the trajectory.
+    PAIR_STEPS = 50
+
     @staticmethod
     def _final_loss(out: str) -> float:
         m = re.findall(r"final_loss: ([0-9.]+)", out)
+        assert m, out[-2000:]
+        return float(m[-1])
+
+    @staticmethod
+    def _loss_at(out: str, step: int) -> float:
+        m = re.findall(rf"step \| step: {step} loss: ([0-9.]+)", out)
         assert m, out[-2000:]
         return float(m[-1])
 
@@ -399,8 +434,8 @@ class TestDistributedCheckpointResume:
         # A distinct volume id: the conflicting-republish guard would
         # (rightly) reject the sibling test's "mh-ckpt" with a different
         # source file on the shared module cluster.
-        pair = self._spawn_pair(cluster, path, steps=50, ckpt_dir=ckpt,
-                                checkpoint_every=2,
+        pair = self._spawn_pair(cluster, path, steps=self.PAIR_STEPS,
+                                ckpt_dir=ckpt, checkpoint_every=2,
                                 volume="mh-ckpt-elastic")
         deadline = time.monotonic() + 420
         committed = None
@@ -422,14 +457,15 @@ class TestDistributedCheckpointResume:
         resumed_from = self._committed_step(ckpt) or committed
         target = resumed_from + 1
 
-        # Control: uninterrupted 2-rank run to the same target.
-        control = self._spawn_pair(cluster, path, steps=target,
+        # Control: uninterrupted 2-rank run on the pair's own schedule,
+        # read at the target step.
+        control = self._spawn_pair(cluster, path, steps=self.PAIR_STEPS,
                                    volume="mh-ckpt-elastic")
         control_losses = []
         for i, proc in enumerate(control):
             out, _ = proc.communicate(timeout=600)
             assert proc.returncode == 0, f"control rank {i}:\n{out[-4000:]}"
-            control_losses.append(self._final_loss(out))
+            control_losses.append(self._loss_at(out, target))
 
         # Phase 2: ONE process, HALF the mesh (data=4), resumes the
         # 2-rank checkpoint and trains one more step.
@@ -469,8 +505,8 @@ class TestDistributedCheckpointResume:
         # Checkpointing pair, launched for MORE steps than we let it run:
         # wait for orbax to commit step 2 under jax.distributed, then
         # SIGKILL both ranks mid-training.
-        pair = self._spawn_pair(cluster, path, steps=50, ckpt_dir=ckpt,
-                                checkpoint_every=2)
+        pair = self._spawn_pair(cluster, path, steps=self.PAIR_STEPS,
+                                ckpt_dir=ckpt, checkpoint_every=2)
         deadline = time.monotonic() + 420
         committed = None
         while time.monotonic() < deadline:
@@ -497,14 +533,15 @@ class TestDistributedCheckpointResume:
         resumed_from = self._committed_step(ckpt) or committed
         target = resumed_from + 1
 
-        # Control: an UNINTERRUPTED run to the same target step, no
-        # checkpointing — the trajectory the resumed pair must continue.
-        control = self._spawn_pair(cluster, path, steps=target)
+        # Control: an UNINTERRUPTED run on the pair's own schedule, no
+        # checkpointing, read at the target step — the trajectory the
+        # resumed pair must continue.
+        control = self._spawn_pair(cluster, path, steps=self.PAIR_STEPS)
         control_losses = []
         for i, proc in enumerate(control):
             out, _ = proc.communicate(timeout=600)
             assert proc.returncode == 0, f"control rank {i}:\n{out[-4000:]}"
-            control_losses.append(self._final_loss(out))
+            control_losses.append(self._loss_at(out, target))
         assert control_losses[0] == control_losses[1]
 
         # Restart both ranks (fresh rendezvous, re-formed mesh).

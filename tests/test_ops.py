@@ -394,7 +394,7 @@ class TestVocabParallelCE:
     def _sharded_fn(self, n=4):
         import functools
 
-        from oim_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from oim_tpu.ops.losses import vocab_parallel_cross_entropy

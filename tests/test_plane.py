@@ -211,8 +211,8 @@ class TestStageSource:
         volume). With the parallel pipeline, transients scale with the
         pool width (2 chunks per in-flight group): the plane's accounting
         asserts peak <= physical placement + 2 * chunk * workers — the
-        knob that bounds transient memory on a tight chip; the ring-2
-        twin checks device.memory_stats() for real on TPU."""
+        knob that bounds transient memory on a tight chip; chip_smoke.py's
+        stage phase checks device.memory_stats() for real on TPU."""
         volume_bytes = 1 << 20
         budget = int(1.5 * volume_bytes)  # old path needed 2x > budget
         chunk = 64 << 10

@@ -256,6 +256,26 @@ class TestWeights:
             assert a.dtype == b.dtype and a.shape == b.shape
             np.testing.assert_array_equal(a, b)
 
+    def test_bfloat16_leaves_pack_and_save(self, tmp_path):
+        """The real-width dtype (llama.Config.dtype): the buffer protocol
+        has no bfloat16 format, so packing must go through a byte view —
+        the tiny f32 fixture never showed it (found on the chip, PR 22).
+        save_packed streams leaf by leaf and must equal pack_params."""
+        import jax.numpy as jnp
+
+        params = {"layers": {"wq": jnp.full((2, 3, 4), 1.5, jnp.bfloat16)},
+                  "norm": jnp.arange(4, dtype=jnp.float32)}
+        blob = pack_params(params)
+        path = tmp_path / "bf16.oimw"
+        assert save_packed(params, str(path)) == len(blob)
+        assert path.read_bytes() == blob
+        tree = unpack_params(blob)
+        assert tree["layers"]["wq"].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(tree["layers"]["wq"], np.float32),
+            np.full((2, 3, 4), 1.5, np.float32))
+        np.testing.assert_array_equal(tree["norm"], np.arange(4.0))
+
     def test_unpack_is_zero_copy_over_arrays(self, model):
         params, _ = model
         buf = np.frombuffer(pack_params(params), np.uint8)
