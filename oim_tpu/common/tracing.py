@@ -17,12 +17,15 @@ Dapper-style (Sigelman et al., 2010) without new dependencies:
   ``oim_rpc_latency_seconds{method,code}`` / ``oim_rpc_total{method,code}``
   (common/metrics.py) and bind ``trace_id`` into the context logger so log
   lines and spans cross-reference.
-* Spans export as Chrome trace-event JSON — loads in Perfetto or
-  ``chrome://tracing`` next to a ``jax.profiler`` device trace. With a
-  ``--trace-dir`` the recorder streams events as they finish (crash-safe:
-  the JSON array is intentionally left unterminated, which Perfetto
-  accepts), and the metrics server serves the ring buffer at
-  ``GET /debug/spans``.
+* Spans export as Chrome trace-event JSON (wall clock) — loads in Perfetto
+  or ``chrome://tracing``. With a ``--trace-dir`` the recorder streams
+  events as they finish (crash-safe: the JSON array is intentionally left
+  unterminated, which Perfetto accepts), and the metrics server serves the
+  ring buffer at ``GET /debug/spans``.
+* Under a ``jax.profiler`` session every live span is ALSO a host event
+  ``oim.<name>`` in the profiler's own trace, on the profiler's clock next
+  to the device operations (``annotate``); with no session it costs a
+  fraction of a microsecond and records nothing.
 """
 
 from __future__ import annotations
@@ -32,6 +35,7 @@ import contextvars
 import json
 import os
 import secrets
+import sys
 import threading
 import time
 from typing import Any, Iterator, NamedTuple, Sequence
@@ -186,8 +190,6 @@ class SpanRecorder:
         self._file_lock = threading.Lock()
         self._file = None
         self._last_flush = 0.0
-        self._dropped = 0
-        self._sampled_out = 0
 
     # -- tail-sampling policy ---------------------------------------------
 
@@ -224,11 +226,7 @@ class SpanRecorder:
                 else:
                     self._spans[self._next] = span
                     self._next = (self._next + 1) % self.capacity
-                    self._dropped += 1
-        if self.trace_dir:
-            if not self.keep_for_export(span):
-                self._sampled_out += 1
-                return
+        if self.trace_dir and self.keep_for_export(span):
             with self._file_lock:
                 self._write_event(span.to_event(self.pid))
 
@@ -321,10 +319,30 @@ def trace_id() -> str:
     return span.trace_id if span is not None else ""
 
 
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotate(name: str):
+    """Context manager that puts ``oim.<name>`` around the block into the
+    ``jax.profiler`` trace, on the profiler's clock, as a host event of
+    this thread. Nothing else: no ring record, no ids, and no keyword
+    arguments (the profiler appends them to the event's name, which would
+    split one phase into many names for every reader). Inert when no
+    profiler session runs, and one shared no-op in a process that has not
+    imported JAX: this module never imports it (``oim_registry`` and
+    ``oim_controller --backend malloc`` must not)."""
+    profiler = sys.modules.get("jax.profiler")
+    make = getattr(profiler, "TraceAnnotation", None)  # None: mid-import
+    return _NO_ANNOTATION if make is None else make("oim." + name)
+
+
 @contextlib.contextmanager
 def start_span(name: str, parent: SpanContext | None = None,
                **attrs: Any) -> Iterator[Span]:
-    """Record ``name`` as a span around the block.
+    """Record ``name`` as a span around the block, and ``annotate`` it for
+    the life of the span. (``record_phase`` and the client interceptor's
+    callback-closed span are known only after the fact or end on another
+    thread: those stay in the ring alone.)
 
     Parent resolution: an explicit ``parent`` (e.g. extracted from request
     metadata) wins; otherwise the context's current span; otherwise a new
@@ -341,7 +359,8 @@ def start_span(name: str, parent: SpanContext | None = None,
     span = Span(name, ctx, parent_id, attrs)
     token = _current.set(span)
     try:
-        yield span
+        with annotate(name):
+            yield span
     finally:
         _current.reset(token)
         span.finish()

@@ -1023,7 +1023,8 @@ class ServeEngine:
                     while (not self._pending and not self._cmds
                            and not any(s is not None for s in self._slots)
                            and not (self._stopping or self._draining)):
-                        self._work.wait()
+                        with tracing.annotate("serve.wait"):
+                            self._work.wait()
                     if self._stopping or self._draining:
                         self._fail_pending_locked("drained")
                     stop_now = self._stopping
@@ -1037,7 +1038,8 @@ class ServeEngine:
                     self._fail_cmds()
                     return
                 self._service_cmds()
-                self._admit()
+                with tracing.annotate("serve.admit"):
+                    self._admit()
                 if any(s is not None for s in self._slots):
                     self._decode_once()
         except Exception as err:  # noqa: BLE001 - the loop IS the process
@@ -1347,16 +1349,17 @@ class ServeEngine:
         """Pull the device-resident step operands back into the host
         mirrors (writable copies) before an admission mutates a row; the
         next decode step re-uploads the merged state once."""
-        if self._spec_keys_dev is not None:
-            self._spec_keys = np.array(self._spec_keys_dev)
-            self._spec_keys_dev = None
-        if self._dev is None:
-            return
-        d_tokens, d_pos, d_keys, _ = self._dev
-        self._tokens = np.array(d_tokens)
-        self._pos = np.array(d_pos)
-        self._keys = np.array(d_keys)
-        self._dev = None
+        with tracing.annotate("serve.sync"):
+            if self._spec_keys_dev is not None:
+                self._spec_keys = np.array(self._spec_keys_dev)
+                self._spec_keys_dev = None
+            if self._dev is None:
+                return
+            d_tokens, d_pos, d_keys, _ = self._dev
+            self._tokens = np.array(d_tokens)
+            self._pos = np.array(d_pos)
+            self._keys = np.array(d_keys)
+            self._dev = None
 
     def _admit(self) -> None:
         """Insert queued requests into free slots (prefill between decode
@@ -1385,43 +1388,44 @@ class ServeEngine:
                 continue
             n = len(req.prompt)
             m, shared = 0, []
-            if self._prefix is not None:
-                chain = prefixhash.usable_hashes(
-                    req.prompt, self.prefix_block)
-                if chain:
-                    with self._lock:
-                        self._hot_chains[chain[-1]] = tuple(chain)
-                        self._hot_chains.move_to_end(chain[-1])
-                        while len(self._hot_chains) > \
-                                self.ADVERTISE_PREFIXES * 4:
-                            self._hot_chains.popitem(last=False)
-                m = self._prefix.match(chain)
-                if m:
-                    got = self._prefix.gather(chain[:m])
-                    if got is None:
-                        m = 0  # a link evicted between match and gather
-                    else:
-                        shared = got
-                        # Pin the shared pages NOW: once referenced,
-                        # no eviction (LRU or pressure valve) can free
-                        # them out from under this admission.
-                        self._pagepool.ref(shared)
-                # Tier walk for the unmatched tail: host-RAM blocks
-                # re-stage H2D (promotion), then the fleet tier may
-                # extend further with peer-exported blocks; both leave
-                # pinned HBM pages behind, exactly like a store hit.
-                if m < len(chain):
-                    m = self._promote_tail(chain, m, shared)
-                if self._kv_fetch is not None and m < len(chain):
-                    m = self._adopt_peer(chain, m, shared, req)
-            if not self._map_slot(req, free, n, m, shared):
-                return  # still the queue head; retried next loop pass
-            # The draft half of the slot, best-effort: a request whose
-            # draft pages can't be mapped (draft pool pressure, valve
-            # closed) decodes plainly in the same batch instead of
-            # waiting — target pages are the admission contract, draft
-            # pages only an accelerator.
-            spec_row = self._map_draft_slot(req, free, n)
+            with tracing.annotate("serve.map"):
+                if self._prefix is not None:
+                    chain = prefixhash.usable_hashes(
+                        req.prompt, self.prefix_block)
+                    if chain:
+                        with self._lock:
+                            self._hot_chains[chain[-1]] = tuple(chain)
+                            self._hot_chains.move_to_end(chain[-1])
+                            while len(self._hot_chains) > \
+                                    self.ADVERTISE_PREFIXES * 4:
+                                self._hot_chains.popitem(last=False)
+                    m = self._prefix.match(chain)
+                    if m:
+                        got = self._prefix.gather(chain[:m])
+                        if got is None:
+                            m = 0  # a link evicted between match and gather
+                        else:
+                            shared = got
+                            # Pin the shared pages NOW: once referenced,
+                            # no eviction (LRU or pressure valve) can free
+                            # them out from under this admission.
+                            self._pagepool.ref(shared)
+                    # Tier walk for the unmatched tail: host-RAM blocks
+                    # re-stage H2D (promotion), then the fleet tier may
+                    # extend further with peer-exported blocks; both leave
+                    # pinned HBM pages behind, exactly like a store hit.
+                    if m < len(chain):
+                        m = self._promote_tail(chain, m, shared)
+                    if self._kv_fetch is not None and m < len(chain):
+                        m = self._adopt_peer(chain, m, shared, req)
+                if not self._map_slot(req, free, n, m, shared):
+                    return  # still the queue head; retried next loop pass
+                # The draft half of the slot, best-effort: a request whose
+                # draft pages can't be mapped (draft pool pressure, valve
+                # closed) decodes plainly in the same batch instead of
+                # waiting — target pages are the admission contract, draft
+                # pages only an accelerator.
+                spec_row = self._map_draft_slot(req, free, n)
             with self._lock:
                 self._pending.popleft()
                 M.SERVE_QUEUE_DEPTH.set(len(self._pending))
@@ -1784,16 +1788,17 @@ class ServeEngine:
         (spec_mask pins their accepted count to 0), so mixed
         spec/non-spec batches stay lockstep."""
         jnp = self._jnp
-        if self._dev is None:
-            self._dev = (
-                jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                jnp.asarray(self._keys), jnp.asarray(self._temps))
-        if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
-        if self._draft_tables_dev is None:
-            self._draft_tables_dev = jnp.asarray(self._draft_tables)
-        if self._spec_keys_dev is None:
-            self._spec_keys_dev = jnp.asarray(self._spec_keys)
+        with tracing.annotate("serve.upload"):
+            if self._dev is None:
+                self._dev = (
+                    jnp.asarray(self._tokens), jnp.asarray(self._pos),
+                    jnp.asarray(self._keys), jnp.asarray(self._temps))
+            if self._tables_dev is None:
+                self._tables_dev = jnp.asarray(self._tables)
+            if self._draft_tables_dev is None:
+                self._draft_tables_dev = jnp.asarray(self._draft_tables)
+            if self._spec_keys_dev is None:
+                self._spec_keys_dev = jnp.asarray(self._spec_keys)
         d_tokens, d_pos, d_keys, d_temps = self._dev
         with self._lock:
             live = [(i, r) for i, r in enumerate(self._slots)
@@ -1804,17 +1809,19 @@ class ServeEngine:
         if self._spec_mask_dev is None:
             self._spec_mask_dev = jnp.asarray(
                 np.array(spec_rows, dtype=bool))
-        draft_toks, draft_logits, self._draft_cache, \
-            self._spec_keys_dev = self._propose(
-                self._draft_params, self._draft_cache, d_tokens, d_pos,
-                self._spec_keys_dev, d_temps, self._draft_tables_dev)
-        out, n_emit, tok, keys, self._cache, pos = self._verify(
-            self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
-            self._tables_dev, draft_toks, draft_logits,
-            self._spec_mask_dev)
+        with tracing.annotate("serve.dispatch"):
+            draft_toks, draft_logits, self._draft_cache, \
+                self._spec_keys_dev = self._propose(
+                    self._draft_params, self._draft_cache, d_tokens, d_pos,
+                    self._spec_keys_dev, d_temps, self._draft_tables_dev)
+            out, n_emit, tok, keys, self._cache, pos = self._verify(
+                self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
+                self._tables_dev, draft_toks, draft_logits,
+                self._spec_mask_dev)
         self._dev = (tok, pos, keys, d_temps)
-        out = np.asarray(out)  # forces the round; the per-round fetch
-        n_emit = np.asarray(n_emit)
+        with tracing.annotate("serve.fetch"):
+            out = np.asarray(out)  # forces the round; the per-round fetch
+            n_emit = np.asarray(n_emit)
         self._target_steps += 1
         self._spec_rounds += 1
         if self.shard > 1:
@@ -1854,26 +1861,29 @@ class ServeEngine:
             for i, _ in live:
                 if spec_rows[i]:
                     self._release_draft(i)
-        for i, req in live:
-            if req.cancelled.is_set():
-                self._release_slot(i, req)
-                with self._lock:
-                    self._slots[i] = None
-                events.emit(events.SLOT_EVICTED,
-                            trace_id=self._trace_id(req), slot=i,
-                            reason="cancelled", tokens=req.emitted)
-                self._occupancy()
-                self._finish(req, "cancelled")
-                continue
-            # The device advanced past every token of the round; the
-            # host emits only what the request's budget admits and
-            # stops at the first EOS — a truncated row retires, so its
-            # stale device row is rewritten at the next admission.
-            count = min(int(n_emit[i]), req.max_new - req.emitted)
-            for t in out[i, :count]:
-                self._emit(req, int(t))
-                if self._retire_if_done(i, req, int(t)):
-                    break
+        with tracing.annotate("serve.emit"):
+            for i, req in live:
+                if req.cancelled.is_set():
+                    self._release_slot(i, req)
+                    with self._lock:
+                        self._slots[i] = None
+                    events.emit(events.SLOT_EVICTED,
+                                trace_id=self._trace_id(req), slot=i,
+                                reason="cancelled", tokens=req.emitted)
+                    self._occupancy()
+                    self._finish(req, "cancelled")
+                    continue
+                # The device advanced past every token of the round; the
+                # host emits only what the request's budget admits and
+                # stops at the first EOS — a truncated row retires, so its
+                # stale device row is rewritten at the next admission.
+                count = min(int(n_emit[i]), req.max_new - req.emitted)
+                for t in out[i, :count]:
+                    self._emit(req, int(t))
+                    if self._retire_if_done(i, req, int(t)):
+                        break
+            # The round's operands die under the name, as in _plain_once.
+            del d_tokens, d_pos, d_keys, draft_toks, draft_logits
 
     def _plain_once(self) -> None:
         """One lockstep decode step over every resident slot; idle rows
@@ -1889,33 +1899,40 @@ class ServeEngine:
         the difference between replicas that scale and replicas that
         serialize."""
         jnp = self._jnp
-        if self._dev is None:
-            self._dev = (
-                jnp.asarray(self._tokens), jnp.asarray(self._pos),
-                jnp.asarray(self._keys), jnp.asarray(self._temps))
-        if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
+        with tracing.annotate("serve.upload"):
+            if self._dev is None:
+                self._dev = (
+                    jnp.asarray(self._tokens), jnp.asarray(self._pos),
+                    jnp.asarray(self._keys), jnp.asarray(self._temps))
+            if self._tables_dev is None:
+                self._tables_dev = jnp.asarray(self._tables)
         d_tokens, d_pos, d_keys, d_temps = self._dev
-        tok, self._cache, keys, pos = self._step(
-            self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
-            self._tables_dev)
+        with tracing.annotate("serve.dispatch"):
+            tok, self._cache, keys, pos = self._step(
+                self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
+                self._tables_dev)
         self._dev = (tok, pos, keys, d_temps)
-        tok = np.asarray(tok)  # forces the step; the only per-step fetch
+        with tracing.annotate("serve.fetch"):
+            tok = np.asarray(tok)  # forces the step; the only per-step fetch
         self._target_steps += 1
         with self._lock:
             live = [(i, r) for i, r in enumerate(self._slots) if r is not None]
         if self.shard > 1:
             self._observe_ici(live)
-        for i, req in live:
-            if req.cancelled.is_set():
-                self._release_slot(i, req)
-                with self._lock:
-                    self._slots[i] = None
-                events.emit(events.SLOT_EVICTED,
-                            trace_id=self._trace_id(req), slot=i,
-                            reason="cancelled", tokens=req.emitted)
-                self._occupancy()
-                self._finish(req, "cancelled")
-                continue
-            self._emit(req, int(tok[i]))
-            self._retire_if_done(i, req, int(tok[i]))
+        with tracing.annotate("serve.emit"):
+            for i, req in live:
+                if req.cancelled.is_set():
+                    self._release_slot(i, req)
+                    with self._lock:
+                        self._slots[i] = None
+                    events.emit(events.SLOT_EVICTED,
+                                trace_id=self._trace_id(req), slot=i,
+                                reason="cancelled", tokens=req.emitted)
+                    self._occupancy()
+                    self._finish(req, "cancelled")
+                    continue
+                self._emit(req, int(tok[i]))
+                self._retire_if_done(i, req, int(tok[i]))
+            # The round's operands die here, under the name, and not at the
+            # frame's exit: three [B] device arrays cost 1-3 ms to release.
+            del d_tokens, d_pos, d_keys

@@ -727,10 +727,6 @@ class Trainer:
         feed_wait = 0.0
         for i in range(start_step, steps):
             batch = pending
-            # The control-plane span (common/tracing.py) complements the
-            # jax.profiler annotation: the device trace shows XLA time, the
-            # oim trace shows the host-side dispatch + feed wait next to
-            # the publish/window spans that fed this step.
             with tracing.start_span("train.step", step=i + 1), \
                     jax.profiler.StepTraceAnnotation("train", step_num=i + 1):
                 self.state, stats = self.step_fn(self.state, batch)

@@ -8,7 +8,10 @@ from __future__ import annotations
 
 import io
 import json
+import os
 import re
+import subprocess
+import sys
 import urllib.request
 
 import grpc
@@ -129,6 +132,124 @@ class TestSpans:
             str(tmp_path), str(tmp_path / "merged.json"))
         assert json.loads((tmp_path / "merged.json").read_text())[
             "traceEvents"] == merged
+
+
+# -- the bridge onto the profiler's clock ------------------------------------
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class TestProfilerBridge:
+    """``annotate`` / ``start_span`` put ``oim.<name>`` into the
+    ``jax.profiler`` trace without this module ever importing JAX, and
+    without changing what the ring, the file and /debug/spans hold."""
+
+    @pytest.mark.parametrize("body", [
+        "with tracing.start_span('x', slot=3): pass",
+        "with tracing.annotate('y'): pass",
+        "tracing.record_phase('serve.decode', 0.0, 1.0)",
+        "assert tracing.annotate('y') is tracing.annotate('z')",
+        "from oim_tpu.common import server, tlsutil",
+    ], ids=["start_span", "annotate", "record_phase", "one-shared-no-op",
+            "rpc-plumbing"])
+    def test_never_imports_jax(self, body):
+        """In a fresh interpreter (this one has JAX from conftest): what
+        ``oim_registry`` and ``oim_controller --backend malloc`` rely on."""
+        code = ("import sys\nfrom oim_tpu.common import tracing\n"
+                f"{body}\n"
+                "bad = [m for m in sys.modules if m == 'jax' "
+                "or m.startswith(('jax.', 'jaxlib'))]\n"
+                "assert not bad, bad\n"
+                "assert len(tracing.recorder().spans()) <= 1\n")
+        done = subprocess.run(
+            [sys.executable, "-c", code], cwd=_REPO, capture_output=True,
+            text=True, timeout=60,
+            env={**os.environ, "PYTHONPATH": _REPO})
+        assert done.returncode == 0, done.stderr
+
+    @pytest.fixture(scope="class")
+    def profiled(self, tmp_path_factory):
+        """One CPU ``jax.profiler`` session around a span with attributes,
+        a bare annotation inside it, a span on another thread and a span
+        whose body raises; the host plane as ``benchmarks/reduce.py`` loads
+        it, and what the ring got meanwhile."""
+        import threading
+
+        import jax
+
+        from benchmarks import reduce
+
+        rec = tracing.configure("bridge-test")
+        trace_dir = str(tmp_path_factory.mktemp("profile"))
+        jax.profiler.start_trace(trace_dir)
+        try:
+            with tracing.start_span("bridge.outer", slot=3, volume="v"):
+                with tracing.annotate("bridge.inner"):
+                    jax.numpy.ones((8, 8)).sum().block_until_ready()
+                tracing.record_phase("bridge.phase", 0.0, 1.0)
+            def on_another_thread():
+                with tracing.start_span("bridge.thread"):
+                    pass
+
+            worker = threading.Thread(target=on_another_thread)
+            worker.start()
+            worker.join(10)
+            with pytest.raises(KeyError):
+                with tracing.start_span("bridge.raises"):
+                    raise KeyError("x")
+        finally:
+            jax.profiler.stop_trace()
+        with tracing.start_span("bridge.after"):  # no session: inert
+            pass
+        planes = reduce.load(reduce.find_xplane(trace_dir))["planes"]
+        host = [((i, j), name, start, start + dur)  # (i, j): one thread
+                for i, plane in enumerate(planes)
+                for j, line in enumerate(plane["lines"])
+                for name, start, dur in line["events"]]
+        yield {"host": host, "ring": rec.spans(), "events": rec.to_events()}
+        tracing.configure("oim")
+
+    @pytest.mark.parametrize("name", [
+        "oim.bridge.outer", "oim.bridge.inner", "oim.bridge.thread",
+        "oim.bridge.raises"])
+    def test_live_spans_and_annotations_are_in_the_profile(self, profiled, name):
+        # exactly once, under its plain name: attributes are NOT appended
+        # (the profiler would write them as #k=v#, one name per value)
+        assert [n for _, n, _, _ in profiled["host"]].count(name) == 1
+
+    @pytest.mark.parametrize("name", [
+        "oim.bridge.phase", "oim.bridge.after", "bridge.outer"])
+    def test_what_is_not_in_the_profile(self, profiled, name):
+        """A phase recorded after the fact has no live scope to annotate;
+        with no session the annotation is inert; no name goes unprefixed."""
+        assert name not in [n for _, n, _, _ in profiled["host"]]
+
+    def test_profile_nests_as_the_code_does_and_keeps_threads_apart(
+            self, profiled):
+        at = {n: (line, s, e) for line, n, s, e in profiled["host"]}
+        outer, inner = at["oim.bridge.outer"], at["oim.bridge.inner"]
+        assert outer[0] == inner[0]
+        assert outer[1] <= inner[1] <= inner[2] <= outer[2]
+        assert at["oim.bridge.thread"][0] != outer[0]
+        assert at["oim.bridge.raises"][1] >= outer[2]
+
+    def test_ring_and_debug_spans_are_unchanged_by_the_bridge(self, profiled):
+        ring = {s.name: s for s in profiled["ring"]}
+        assert list(ring) == [  # the bare annotation left no record
+            "bridge.phase", "bridge.outer", "bridge.thread", "bridge.raises",
+            "bridge.after"]
+        assert ring["bridge.outer"].attrs == {"slot": 3, "volume": "v"}
+        assert ring["bridge.phase"].parent_id == ring["bridge.outer"].span_id
+        assert ring["bridge.phase"].duration == 1.0
+        assert ring["bridge.outer"].duration > 0
+        event = next(e for e in profiled["events"]
+                     if e["name"] == "bridge.outer")
+        assert set(event) == {"name", "cat", "ph", "ts", "dur", "pid", "tid",
+                              "args"}
+        assert event["args"]["slot"] == 3 and event["cat"] == "oim"
+        assert not any(e["name"].startswith("oim.")
+                       for e in profiled["events"])
 
 
 # -- telemetry interceptors over real gRPC ---------------------------------
