@@ -549,6 +549,7 @@ def phase_serve(run: Run, blob: str, n_layers: int,
              requests=len(answers), streams_finished=len(answers),
              new_tokens=[len(a["tokens"]) for a in answers],
              prompt_lens=list(run.sizes["prompt_lens"]),
+             decode_attention=serving.get("decode_attention"),
              bytes_in_use_after_load=memory_of(serving, "bytes_in_use"),
              peak_bytes_in_use=memory_of(stopped))
 
@@ -646,6 +647,7 @@ def phase_shard(run: Run, blob: str) -> None:
         results[shard] = [a["tokens"] for a in answers]
         run.emit(name, t, shard=shard, n_layers=s["n_layers"],
                  tokens=results[shard],
+                 decode_attention=serving.get("decode_attention"),
                  bytes_in_use_after_load=memory_of(serving, "bytes_in_use"),
                  peak_bytes_in_use=memory_of(stopped))
     t = time.monotonic()

@@ -28,6 +28,7 @@ from jax import lax
 
 from oim_tpu.models.llama import Config, _ffn
 from oim_tpu.ops.norms import rmsnorm
+from oim_tpu.ops.paged_attention import cache_attention, paged_attention
 from oim_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -96,38 +97,6 @@ def init_cache(cfg: Config, batch: int, max_seq: int):
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
-def _cache_attention(q, ck, cv, pos, cfg: Config):
-    """q [B,T,H,hd] over the full cache [B,S,kvh,hd], masked to positions
-    <= pos+t (unwritten cache slots mask out with everything else).
-    ``pos`` is a scalar (every row at the same depth — prefill/solo
-    decode) or a [B] vector (the serving batch, where mid-flight
-    admission puts every slot at its own depth).
-
-    GQA rides a grouped einsum against the kv-head cache directly — no
-    head-expanded copy of the cache, no f32 materialization of K (the
-    einsum accumulates in f32 from bf16 operands, the same numerics as the
-    training path's mha_reference)."""
-    B, T, H, hd = q.shape
-    S = ck.shape[1]
-    g = H // cfg.n_kv_heads
-    qg = q.reshape(B, T, cfg.n_kv_heads, g, hd)
-    scores = jnp.einsum(
-        "btkgd,bskd->bkgts", qg, ck, preferred_element_type=jnp.float32
-    ) * (hd ** -0.5)
-    pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
-    mask = (pos_b[:, None] + jnp.arange(T))[:, :, None] \
-        >= jnp.arange(S)[None, None, :]  # [B,T,S]
-    scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    # Probs drop to the cache dtype (what the flash kernels do) so the V
-    # side also avoids an f32 copy of the cache; accumulation stays f32.
-    out = jnp.einsum(
-        "bkgts,bskd->btkgd", probs.astype(cv.dtype), cv,
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(B, T, H, hd).astype(q.dtype)
-
-
 def cached_forward(params, tokens, cache, pos, cfg: Config,
                    axis: str | None = None):
     """Forward ``tokens`` [B,T] occupying absolute positions pos..pos+T-1.
@@ -161,7 +130,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
         k = apply_rope(k, cos, sin, positions)
         ck = lax.dynamic_update_slice_in_dim(ck, k, pos, axis=1)
         cv = lax.dynamic_update_slice_in_dim(cv, v, pos, axis=1)
-        attn = _cache_attention(q, ck, cv, pos, cfg)
+        attn = cache_attention(q, ck, cv, pos)
         x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
         h = rmsnorm(x, layer["mlp_norm"])
         ffn, _ = _ffn(h, layer, cfg)
@@ -182,20 +151,39 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
 # stops being a per-slot [max_seq] reservation — short and long prompts
 # share one pool, and a cached prompt prefix is SHARED by pointing two
 # slots' tables at the same physical pages (vLLM's paged-attention idea
-# re-expressed on this repo's primitives). The two engine operations —
+# re-expressed on this repo's primitives). The engine's operations —
 # insert a new request's prefill into a slot mid-flight, advance the
-# whole batch one token with per-row positions — become scatter (write
-# this step's K/V through the table) + gather (materialize the slot's
-# logical cache from the table) around the SAME ``_cache_attention`` the
-# solo path uses, so there is still exactly one attention implementation
-# to keep correct.
+# whole batch one token with per-row positions, verify a draft's
+# candidates — are ONE layer loop (``_forward_paged``) that carries the
+# pool, scatters this call's K/V through the table in place, and attends
+# through ``ops.paged_attention.paged_attention``. That function has two
+# paths and one rule (``_paged_plan``: shapes and backend only, no
+# option):
 #
-# Why byte-identity to solo generate() survives paging: the gathered
-# logical cache holds exactly the values the dense cache held at every
-# position the causal mask admits, and masked positions (unwritten pads,
-# stale bytes in a freshly mapped page) contribute EXACT zeros through
-# the softmax (-inf score -> 0 probability -> 0 * finite = 0), so the
-# attention sums are term-for-term identical.
+# * the REFERENCE path, everywhere and for every T: gather the slot's
+#   logical cache from the table, then the SAME ``cache_attention`` the
+#   solo path uses. The gathered logical cache holds exactly the values
+#   the dense cache held at every position the causal mask admits, and
+#   masked positions (unwritten pads, stale bytes in a freshly mapped
+#   page) contribute EXACT zeros through the softmax (-inf score -> 0
+#   probability -> 0 * finite = 0), so the attention sums are
+#   term-for-term identical: served tokens are BYTE-IDENTICAL to solo
+#   generate(). Tier-1 (CPU) runs and guards this path: tests/test_spec.py,
+#   the engine's paged-vs-solo identity tests.
+# * the KERNEL path, a decode step (T == 1) on a TPU when head_dim and
+#   the page fit the tiling: a Pallas kernel reads each row's live pages
+#   where they lie, with an online softmax (bf16 K/V and probabilities,
+#   f32 scores, statistics and accumulators, every live position
+#   attended; only the order of the f32 sums differs from the reference).
+#   It is NOT byte-identical to solo generate(): it is held to the
+#   benchmark's limits against the float32 reference on the chip
+#   (benchmarks/configs/*.json) and to the reference path in interpret
+#   mode (tests/test_ops.py); tests/test_chip_compile.py holds the
+#   compiled programs to "no copy, restack or gather of the pool".
+#
+# The program logs ``attention dispatch kernel=pallas_paged|jnp_gather``
+# once per trace, and ``ServeEngine.stats()["decode_attention"]`` shows
+# the same word on the replica's serve/<id> row.
 
 
 def init_page_pool(cfg: Config, n_pages: int, page_tokens: int):
@@ -207,6 +195,51 @@ def init_page_pool(cfg: Config, n_pages: int, page_tokens: int):
     shape = (cfg.n_layers, n_pages, page_tokens,
              cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _forward_paged(params, tokens, pool, tables, pos, phys, off,
+                   cfg: Config, axis: str | None):
+    """The one layer loop of the three serving programs: forward
+    ``tokens`` [B, T] at absolute positions pos[b] + t (``pos`` a scalar
+    or [B]), writing position (b, t)'s K/V at pool[l, phys[b, t],
+    off[b, t]] (an out-of-range ``phys`` DROPS the write) and attending
+    through ``tables`` [B, n_blocks]. Returns (hidden [B, T, D] after the
+    final norm, updated pool).
+
+    The pool is part of the scan's CARRY, with the layer index beside it:
+    each layer scatters its rows into pool[l] and reads pool[l] where it
+    lies. As the scan's xs/ys the pool was sliced per layer and restacked
+    every call — two copies of the whole pool a program, whatever the
+    callers' donation. Carried, the caller's donated buffer is the one
+    the scatter updates in place (tests/test_chip_compile.py holds the
+    compiled programs to it)."""
+    B, T = tokens.shape
+    S = tables.shape[1] * pool["k"].shape[2]
+    cfg = _no_drop(cfg)
+    params = jax.tree.map(jnp.asarray, params)
+    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
+    positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
+    x = params["embed"][tokens].astype(cfg.dtype)
+
+    def body(carry, layer):
+        x, pk, pv, l = carry  # pk, pv: [L, n_pages, page, kvh, hd]
+        h = rmsnorm(x, layer["attn_norm"])
+        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
+        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        pk = pk.at[l, phys, off].set(k, mode="drop")
+        pv = pv.at[l, phys, off].set(v, mode="drop")
+        attn = paged_attention(q, pk, pv, l, tables, pos)
+        x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
+        h = rmsnorm(x, layer["mlp_norm"])
+        ffn, _ = _ffn(h, layer, cfg)
+        return (x + _reduce(ffn, axis), pk, pv, l + 1), None
+
+    (x, pk, pv, _), _ = lax.scan(
+        body, (x, pool["k"], pool["v"], jnp.int32(0)), params["layers"])
+    return rmsnorm(x, params["final_norm"]), {"k": pk, "v": pv}
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
@@ -239,47 +272,22 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     owns (its tail and decode blocks), which is the copy-on-write
     contract the prefix store relies on.
     """
-    B, T = tokens.shape  # B == 1: admission is per-slot
+    T = tokens.shape[1]  # tokens [1, T]: admission is per-slot
     nb = page_table.shape[0]
     S = nb * page_tokens
     n_pages = pool["k"].shape[1]
-    cfg = _no_drop(cfg)
-    params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
-    positions = jnp.broadcast_to(start + jnp.arange(T), (B, T))
     logical = start + jnp.arange(T)
     blk = jnp.minimum(logical // page_tokens, nb - 1)
     keep = (jnp.arange(T) < n_tokens) & (logical < S)
-    # Out-of-range physical index + mode="drop": pad K/V never lands.
+    # Out-of-range physical index: pad K/V never lands.
     phys = jnp.where(keep, page_table[blk], n_pages)
-    off = logical % page_tokens
-    x = params["embed"][tokens].astype(cfg.dtype)
-
-    def body(x, inp):
-        layer, pk, pv = inp  # [n_pages, page, kvh, hd]
-        h = rmsnorm(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        pk = pk.at[phys, off].set(k[0], mode="drop")
-        pv = pv.at[phys, off].set(v[0], mode="drop")
-        # Gather-by-page-table: the slot's logical [S] cache view.
-        ck = pk[page_table].reshape(1, S, cfg.n_kv_heads, cfg.head_dim)
-        cv = pv[page_table].reshape(1, S, cfg.n_kv_heads, cfg.head_dim)
-        attn = _cache_attention(q, ck, cv, start, cfg)
-        x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
-        h = rmsnorm(x, layer["mlp_norm"])
-        ffn, _ = _ffn(h, layer, cfg)
-        return x + _reduce(ffn, axis), (pk, pv)
-
-    x, (pk, pv) = lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))
-    x = rmsnorm(x, params["final_norm"])
+    x, pool = _forward_paged(
+        params, tokens, pool, page_table[None], start, phys[None],
+        (logical % page_tokens)[None], cfg, axis)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     last = lax.dynamic_index_in_dim(
         logits[0], n_tokens - 1, axis=0, keepdims=False)
-    return last, {"k": pk, "v": pv}
+    return last, pool
 
 
 def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
@@ -291,7 +299,7 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
 
     Mid-flight admission leaves every slot at its own depth, so the K/V
     write is a per-row scatter at (table[b, pos // page], pos % page)
-    and the attention mask is per-row (_cache_attention takes the [B]
+    and the attention is per-row (``paged_attention`` takes the [B]
     position vector directly). Idle slots decode a garbage row the
     engine discards; their page tables are all-zero, so their writes
     land in scratch page 0, never in a page a live request owns. A live
@@ -301,44 +309,19 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
     """
     B = tokens.shape[0]
     nb = page_tables.shape[1]
-    S = nb * page_tokens
-    cfg = _no_drop(cfg)
-    params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
-    positions = pos[:, None]  # [B, 1]
-    x = params["embed"][tokens[:, None]].astype(cfg.dtype)
-    rows = jnp.arange(B)
     # Positions past the table (an idle row's clamped position, or a
     # draft model speculating past a request's final position) write
     # scratch page 0 — never the clamped LAST page, which a live row
     # may own. In-range positions of an idle row land in scratch via
     # its all-zero table either way.
     blk = jnp.minimum(pos // page_tokens, nb - 1)
-    phys = jnp.where(pos < S, page_tables[rows, blk], 0)  # [B]
-    off = pos % page_tokens
-
-    def body(x, inp):
-        layer, pk, pv = inp  # [n_pages, page, kvh, hd]
-        h = rmsnorm(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(B, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, 1, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        pk = pk.at[phys, off].set(k[:, 0])
-        pv = pv.at[phys, off].set(v[:, 0])
-        ck = pk[page_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        cv = pv[page_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        attn = _cache_attention(q, ck, cv, pos, cfg)
-        x = x + _reduce(attn.reshape(B, 1, cfg.q_dim) @ layer["wo"], axis)
-        h = rmsnorm(x, layer["mlp_norm"])
-        ffn, _ = _ffn(h, layer, cfg)
-        return x + _reduce(ffn, axis), (pk, pv)
-
-    x, (pk, pv) = lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))
-    x = rmsnorm(x, params["final_norm"])
+    phys = jnp.where(pos < nb * page_tokens,
+                     page_tables[jnp.arange(B), blk], 0)  # [B]
+    x, pool = _forward_paged(
+        params, tokens[:, None], pool, page_tables, pos, phys[:, None],
+        (pos % page_tokens)[:, None], cfg, axis)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits[:, 0], {"k": pk, "v": pv}
+    return logits[:, 0], pool
 
 
 def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
@@ -371,42 +354,17 @@ def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
     attention byte-identical)."""
     B, T = tokens.shape
     nb = page_tables.shape[1]
-    S = nb * page_tokens
     n_pages = pool["k"].shape[1]
-    cfg = _no_drop(cfg)
-    params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
     positions = pos[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    rows = jnp.arange(B)[:, None]
     blk = jnp.minimum(positions // page_tokens, nb - 1)
-    # Out-of-range physical index + mode="drop": past-the-table K/V
-    # never lands (same stance as prefill_into_pages' pad positions).
-    phys = jnp.where(positions < S, page_tables[rows, blk], n_pages)
-    off = positions % page_tokens
-    x = params["embed"][tokens].astype(cfg.dtype)
-
-    def body(x, inp):
-        layer, pk, pv = inp  # [n_pages, page, kvh, hd]
-        h = rmsnorm(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        pk = pk.at[phys, off].set(k, mode="drop")
-        pv = pv.at[phys, off].set(v, mode="drop")
-        ck = pk[page_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        cv = pv[page_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        attn = _cache_attention(q, ck, cv, pos, cfg)
-        x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
-        h = rmsnorm(x, layer["mlp_norm"])
-        ffn, _ = _ffn(h, layer, cfg)
-        return x + _reduce(ffn, axis), (pk, pv)
-
-    x, (pk, pv) = lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))
-    x = rmsnorm(x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": pk, "v": pv}
+    # Out-of-range physical index: past-the-table K/V never lands (same
+    # stance as prefill_into_pages' pad positions).
+    phys = jnp.where(positions < nb * page_tokens,
+                     page_tables[jnp.arange(B)[:, None], blk], n_pages)
+    x, pool = _forward_paged(
+        params, tokens, pool, page_tables, pos, phys,
+        positions % page_tokens, cfg, axis)
+    return (x @ params["lm_head"]).astype(jnp.float32), pool
 
 
 def generate(params, prompt, n_new: int, cfg: Config,

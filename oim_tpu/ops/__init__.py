@@ -2,9 +2,11 @@
 
 Kernel policy (pallas_guide.md): write pallas only where XLA's own fusion
 leaves bandwidth on the table — blockwise attention is the one op where the
-O(T^2) intermediate must never exist. Elementwise chains (rmsnorm, rope,
-swiglu, losses) are written in plain jnp and left to XLA to fuse into the
-neighbouring matmuls.
+O(T^2) intermediate must never exist, and decode attention over the serving
+page pool (paged_attention.py) the one where a gather of every slot's whole
+logical cache must not. Elementwise chains (rmsnorm, rope, swiglu, losses)
+are written in plain jnp and left to XLA to fuse into the neighbouring
+matmuls.
 """
 
 from oim_tpu.ops.attention import attention, flash_attention, mha_reference

@@ -604,6 +604,22 @@ class ServeEngine:
         # already compiled.
         self._step, self._prefill = _target_programs(
             cfg, page, max_seq, self.shard)
+        # Which attention the decode program takes — the dispatch rule
+        # of ops/paged_attention.py on this engine's (member-local)
+        # shapes, the word the program logs when it is traced. stats()
+        # carries it, so a replica that silently missed the kernel can
+        # be told from its serve/<id> row.
+        from oim_tpu.ops import paged_attention
+
+        lcfg = gen.shard_config(cfg, self.shard)
+        pool_k = self._cache["k"]
+        self.decode_attention = paged_attention.kernel_name(
+            jax.ShapeDtypeStruct(
+                (max_batch, 1, lcfg.n_heads, cfg.head_dim), cfg.dtype),
+            jax.ShapeDtypeStruct(
+                pool_k.shape[:3] + (lcfg.n_kv_heads, cfg.head_dim),
+                pool_k.dtype),
+            jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32))
 
         # -- speculative decoding (serve/spec.py): draft propose K
         # tokens through its OWN small page pool (K lockstep decode
@@ -855,6 +871,9 @@ class ServeEngine:
                 # routers ignore it, new routers split requests across
                 # tiers (missing/malformed reads back as "mixed").
                 "role": self.role,
+                # A build fact, not a load: "pallas_paged" or
+                # "jnp_gather" (see __init__).
+                "decode_attention": self.decode_attention,
             }
             if self.role == "prefill":
                 # A COLD prefill replica must still advertise its block

@@ -13,6 +13,10 @@ file (one xdist worker loads the TPU library; nothing at import time).
 from __future__ import annotations
 
 import dataclasses
+import json
+import math
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,8 +116,7 @@ def serve_shapes(cfg, sharding):
     return params, pool
 
 
-def step_operands(sharding):
-    b, blocks = ONE["max_batch"], ONE["max_seq"] // PAGE
+def step_operands(sharding, b=ONE["max_batch"], seq=ONE["max_seq"]):
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
 
     def s(shape, dtype):
@@ -121,7 +124,18 @@ def step_operands(sharding):
 
     return (s((b,), jnp.int32), s((b,), jnp.int32),
             s((b,) + key.shape, key.dtype), s((b,), jnp.float32),
-            s((b, blocks), jnp.int32))
+            s((b, seq // PAGE), jnp.int32))
+
+
+def prefill_operands(sharding, bucket, seq=ONE["max_seq"]):
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (s((1, bucket), jnp.int32), s((), jnp.int32),
+            s((seq // PAGE,), jnp.int32), s((), jnp.int32),
+            s(key.shape, key.dtype), s((), jnp.float32))
 
 
 def test_decode_step(topo, as_tpu):
@@ -146,15 +160,124 @@ def test_prefill_bucket(topo, as_tpu, bucket):
     cfg = model_config(ONE["n_layers"])
     _, prefill = _target_programs(cfg, PAGE, ONE["max_seq"])
     params, pool = serve_shapes(cfg, chip)
-    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prefill.lower(params, pool, *prefill_operands(chip, bucket)).compile()
+
+
+# -- the page pool is read and written where it lies ------------------------
+# The benchmark's two serving cells at their own sizes (benchmarks/configs).
+
+CELLS = {"chat": ("mistral-7b", 1024), "batch": ("mixtral-8x7b", 512)}
+MOVES = ("copy", "copy-start", "dynamic-update-slice", "gather")
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+
+
+def moves_of(text: str, shape, tail=()):
+    """Instructions of the optimized HLO (fused bodies too) that copy,
+    restack or gather an array of ``shape``'s element count — whatever
+    way the compiler folded its leading dims — and, with ``tail``, of
+    those trailing dims."""
+    count, found = math.prod(shape), []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(3) not in MOVES:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(2)):
+            dims = tuple(int(d) for d in dims.split(","))
+            if math.prod(dims) == count and dims[len(dims) - len(tail):] \
+                    == tuple(tail):
+                found.append((m.group(3), m.group(1), dims))
+    return found
+
+
+def test_moves_of_reads_the_hlo():
+    text = """
+  %copy.94 = bf16[14,2561,16,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%p)
+  %dynamic-update-slice.3 = bf16[573664,8,128]{2,1,0} dynamic-update-slice(%a, %b, %c)
+  ROOT %gather.1 = bf16[32,256,16,8,128]{4,3,2,1,0} gather(%a, %b)
+  %copy.1 = bf16[32768,4096]{1,0} copy(%embed)
+  %bitcast.2 = bf16[14,2561,128,128]{3,2,1,0} bitcast(%fusion.176)
+  %fusion.9 = bf16[573664,8,128]{2,1,0} fusion(%a), kind=kCustom"""
+    assert [m[1] for m in moves_of(text, (14, 2561, 16, 8, 128))] == [
+        "copy.94", "dynamic-update-slice.3"]
+    assert [m[1] for m in moves_of(text, (32, 4096, 8, 128), (8, 128))] == [
+        "gather.1"]
+
+
+def cell_shapes(cell, sharding):
+    from benchmarks import common
+
+    name, bucket = CELLS[cell]
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    model = common.model_dict(config, "serve")
+    cfg = common.program_config(model)
+    n_pages = config["serve"]["kv_pool_tokens"] // PAGE + 1
+    b, seq = config["serve"]["max_batch"], model["max_seq"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(
+        lambda: gen.init_page_pool(cfg, n_pages, PAGE)), sharding)
+    return cfg, params, pool, b, seq, bucket
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_decode_reads_live_pages_in_place(topo, as_tpu, cell):
+    """The decode program of each serving cell: the Pallas kernel is in
+    it, and nothing copies, restacks or gathers an array of the pool's
+    shape or of [B, S, kvh, hd]."""
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg, params, pool, b, seq, _ = cell_shapes(cell, chip)
+    step, _ = _target_programs(cfg, PAGE, seq)
+    compiled = step.lower(
+        params, pool, *step_operands(chip, b, seq)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not moves_of(text, pool["k"].shape)
+    tail = (cfg.n_kv_heads, cfg.head_dim)
+    assert not moves_of(text, (b, seq) + tail, tail)
+    # No gathered view, no f32 scores over all S: what is left is small.
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cell_prefill_carries_the_pool(topo, as_tpu, cell):
+    """The cell's commonest prefill bucket: the pool is scattered into in
+    place (its gather of ONE slot's table stays until flash prefill over
+    pages, ROADMAP S2)."""
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg, params, pool, _, seq, bucket = cell_shapes(cell, chip)
+    _, prefill = _target_programs(cfg, PAGE, seq)
+    text = prefill.lower(
+        params, pool, *prefill_operands(chip, bucket, seq)
+    ).compile().as_text()
+    assert not moves_of(text, pool["k"].shape)
+
+
+def test_verify_carries_the_pool(topo, as_tpu):
+    """The speculative verify program (T = K + 1 > 1: the gather path on
+    the carried pool) on the chat cell's target and pool."""
+    from oim_tpu.serve.engine import _spec_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg, params, pool, b, seq, _ = cell_shapes("chat", chip)
+    k = 3
+    dcfg = dataclasses.replace(cfg, n_layers=1)
+    _, _, verify = _spec_programs(cfg, dcfg, PAGE, seq, k)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    prefill.lower(
-        params, pool, s((1, bucket), jnp.int32), s((), jnp.int32),
-        s((ONE["max_seq"] // PAGE,), jnp.int32), s((), jnp.int32),
-        s(key.shape, key.dtype), s((), jnp.float32)).compile()
+    text = verify.lower(
+        params, pool, *step_operands(chip, b, seq), s((b, k), jnp.int32),
+        s((b, k, cfg.vocab), jnp.float32), s((b,), jnp.bool_),
+    ).compile().as_text()
+    assert not moves_of(text, pool["k"].shape)
 
 
 def compile_train_step(topo, rules, axes, n_layers, batch):
@@ -199,7 +322,12 @@ def test_train_step_fsdp_four_chips(topo, as_tpu):
 
 
 def test_decode_step_shard4(topo, as_tpu, monkeypatch):
-    """One --shard 4 decode step on a mesh of the described devices."""
+    """One --shard 4 decode step on a mesh of the described devices, at
+    the chat cell's 32 slots x 4096 positions (the smoke's pool of a
+    few MB the compiler parks in VMEM and back, which is no finding). The
+    member's
+    view (kv heads 8 / 4 = 2) takes the kernel inside the shard_map and
+    moves neither its slice of the pool nor a gathered view."""
     from jax.sharding import PartitionSpec as P
 
     from oim_tpu.serve import shard as shardlib
@@ -208,10 +336,11 @@ def test_decode_step_shard4(topo, as_tpu, monkeypatch):
     mesh = Mesh(np.asarray(topo.devices[:4]), ("tp",))
     monkeypatch.setattr(shardlib, "tp_mesh", lambda n: mesh)
     cfg = model_config(ONE["n_layers"])
+    b, seq = 32, 4096
+    n_pages = b * seq // PAGE + 1
     _target_programs.cache_clear()
     try:
-        step, _ = _target_programs(cfg, PAGE, ONE["max_seq"], 4)
-        n_pages = ONE["max_batch"] * ONE["max_seq"] // PAGE + 1
+        step, _ = _target_programs(cfg, PAGE, seq, 4)
         params = jax.tree_util.tree_map_with_path(
             lambda path, s: jax.ShapeDtypeStruct(
                 s.shape, s.dtype, sharding=NamedSharding(
@@ -224,11 +353,15 @@ def test_decode_step_shard4(topo, as_tpu, monkeypatch):
             for k, s in jax.eval_shape(
                 lambda: gen.init_page_pool(cfg, n_pages, PAGE)).items()}
         text = step.lower(
-            params, pool, *step_operands(NamedSharding(mesh, P()))
+            params, pool, *step_operands(NamedSharding(mesh, P()), b, seq)
         ).compile().as_text()
     finally:
         _target_programs.cache_clear()  # never leak the described mesh
     assert "all-reduce" in text
+    assert "tpu_custom_call" in text
+    tail = (gen.shard_config(cfg, 4).n_kv_heads, cfg.head_dim)
+    assert not moves_of(text, (cfg.n_layers, n_pages, PAGE) + tail)
+    assert not moves_of(text, (b, seq) + tail, tail)
 
 
 def test_byte_buffer_past_int32_is_refused(topo, no_compile_cache):

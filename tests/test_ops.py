@@ -514,3 +514,90 @@ class TestZLoss:
         np.testing.assert_allclose(
             float(total) - float(term), float(ce), rtol=1e-5)
         assert float(term) > 0
+
+
+# -- paged decode attention (ops/paged_attention.py) ------------------------
+
+PAGE = 16
+N_BLOCKS = 8  # S = 128 positions a row
+S_PAGED = PAGE * N_BLOCKS
+
+
+def _paged_case(name):
+    """(tables [B, nb], pos [B]) of one scenario; page ids >= 1 are
+    mapped, 0 is the scratch page."""
+    full = np.arange(1, N_BLOCKS + 1, dtype=np.int32)
+
+    def mapped(first, n):
+        row = np.zeros(N_BLOCKS, np.int32)
+        row[:n] = np.arange(first, first + n)
+        return row
+
+    if name == "ragged":  # a first position, both sides of a page edge, S - 1
+        return (np.stack([mapped(1, 1), mapped(2, 1), mapped(3, 2), full + 4]),
+                np.array([1, PAGE - 1, PAGE, S_PAGED - 1], np.int32))
+    if name == "idle":  # all-zero tables at a stale and at the clamped pos
+        return (np.stack([mapped(1, 3), mapped(0, 0), mapped(4, 5),
+                          mapped(0, 0)]),
+                np.array([40, 77, 70, S_PAGED], np.int32))
+    if name == "past_table":  # pos >= S: every position of the table live
+        return (np.stack([full, full + 8]),
+                np.array([S_PAGED, S_PAGED + 5], np.int32))
+    if name == "shared":  # two rows on the same three prefix pages
+        a, b = mapped(1, 5), mapped(1, 5)
+        a[3:5], b[3:5] = [9, 10], [11, 12]
+        return np.stack([a, b]), np.array([70, 55], np.int32)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("kvh", [8, 2])  # 2: the --shard 4 member's view
+@pytest.mark.parametrize(
+    "case", ["ragged", "idle", "past_table", "shared"])
+def test_paged_decode_kernel_matches_gather(case, kvh):
+    """The Pallas kernel in interpret mode against gather +
+    cache_attention on one pool. The kernel's pool has NaN wherever no
+    live position lies (unmapped pages, the scratch page, past ``pos`` in
+    a mapped page); the reference's has zeros there."""
+    from oim_tpu.ops.paged_attention import _paged_decode, gather_attention
+
+    tables, pos = _paged_case(case)
+    B, g, hd, L, n_pages = len(pos), 4, 128, 2, 24
+    rng = np.random.RandomState(len(case) + kvh)
+    q = jnp.asarray(rng.randn(B, kvh * g, hd), jnp.bfloat16)
+    shape = (L, n_pages, PAGE, kvh, hd)
+    k, v = rng.randn(*shape), rng.randn(*shape)
+    live = np.zeros((n_pages, PAGE), bool)
+    for row, p in zip(tables, pos):
+        if row[0]:
+            n = min(p + 1, S_PAGED)
+            live[row[np.arange(n) // PAGE], np.arange(n) % PAGE] = True
+    assert not live[0].any() and live.any()
+    clean = {n: jnp.asarray(np.where(live[None, :, :, None, None], x, 0.0),
+                            jnp.bfloat16) for n, x in (("k", k), ("v", v))}
+    dirty = {n: jnp.asarray(np.where(live[None, :, :, None, None], x, np.nan),
+                            jnp.bfloat16) for n, x in (("k", k), ("v", v))}
+    layer = jnp.int32(1)
+    want = gather_attention(q[:, None], clean["k"], clean["v"], layer,
+                            jnp.asarray(tables), jnp.asarray(pos))[:, 0]
+    got = _paged_decode(q, dirty["k"], dirty["v"], layer,
+                        jnp.asarray(tables), jnp.asarray(pos), pages=2,
+                        interpret=True)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    idle = tables[:, 0] == 0
+    assert np.isfinite(got).all()
+    assert not got[idle].any()  # an idle row reads nothing
+    # bf16 outputs of O(1): two roundings of the probabilities apart.
+    np.testing.assert_allclose(got[~idle], want[~idle], atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("backend,t,want", [
+    ("cpu", 1, None), ("tpu", 1, 16), ("tpu", 4, None)])
+def test_paged_dispatch_reads_shapes_and_backend_only(
+        monkeypatch, backend, t, want):
+    from oim_tpu.ops.paged_attention import _paged_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct((4, t, 32, 128), jnp.bfloat16)
+    pk = jax.ShapeDtypeStruct((2, 9, PAGE, 8, 128), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+    assert _paged_plan(q, pk, tables) == want

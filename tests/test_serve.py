@@ -12,8 +12,10 @@ restore provably re-reads NOTHING from source), then 16+ concurrent
 streaming requests admitted mid-flight, each byte-identical to solo.
 """
 
+import dataclasses
 import threading
 import time
+import unittest.mock
 
 import grpc
 import numpy as np
@@ -88,6 +90,24 @@ def engine(model):
 
 
 class TestEngineInvariants:
+    def test_stats_say_which_attention_the_decode_program_takes(
+            self, engine, model):
+        """The dispatch word of ops/paged_attention.py rides stats() (the
+        serve/<id> row): on the CPU the reference path, and the kernel
+        where the rule finds its shapes on a TPU. No option selects it."""
+        assert engine.stats()["decode_attention"] == "jnp_gather"
+        params, cfg = model  # head_dim 16: off the kernel's tiling
+        wide = dataclasses.replace(cfg, head_dim=128)
+        with unittest.mock.patch.object(
+                jax, "default_backend", lambda: "tpu"):
+            for c, want in ((cfg, "jnp_gather"), (wide, "pallas_paged")):
+                eng = ServeEngine(llama.init(jax.random.PRNGKey(0), c), c,
+                                  max_batch=2, max_seq=64, queue_depth=8)
+                try:
+                    assert eng.decode_attention == want
+                finally:
+                    eng.stop(drain=False, timeout=30)
+
     def test_midflight_admission_byte_identical(self, model):
         """More requests than slots, mixed greedy/sampled, mixed lengths:
         every admission happens against a batch mid-decode, and every
