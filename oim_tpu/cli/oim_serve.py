@@ -31,6 +31,7 @@ close as "drained", new ones get UNAVAILABLE.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import signal
 import threading
@@ -64,10 +65,16 @@ def _load_params(args, log):
     # The trainer's own --model-override: the served depth is the depth
     # that was trained (a mismatch with the weights is an error below,
     # never a truncation).
-    mcfg = TrainConfig(
-        model=args.model,
-        model_overrides=parse_model_overrides(args.model_override),
-    ).model_config()
+    overrides = parse_model_overrides(args.model_override)
+    if args.model == "joyai-llm-flash":
+        # Served only: the trainer has no name for a block it cannot
+        # train yet (param_logical_axes refuses latent attention).
+        from oim_tpu.models import llama
+
+        mcfg = dataclasses.replace(llama.JOYAI_LLM_FLASH, **overrides)
+    else:
+        mcfg = TrainConfig(
+            model=args.model, model_overrides=overrides).model_config()
     if args.checkpoint_dir:
         params, step = restore_checkpoint_params(
             args.checkpoint_dir, mcfg, "serve")
@@ -107,7 +114,9 @@ def _check_depth(params, mcfg, volume: str) -> None:
     """The stacked layer leaves carry the depth the weights were packed
     at; the configured depth must equal it (decoding N layers out of a
     deeper stack would be a silent truncation)."""
-    depth = params["layers"]["wq"].shape[0]
+    from oim_tpu.models.llama import layer_groups
+
+    depth = sum(g["attn_norm"].shape[0] for g in layer_groups(params))
     if depth != mcfg.n_layers:
         raise SystemExit(
             f"weights volume {volume!r} holds {depth} layers, the "
@@ -209,7 +218,8 @@ def main(argv: list[str] | None = None) -> int:
         help="listen endpoint (tcp:// or unix://)",
     )
     parser.add_argument("--model", default="llama-tiny",
-                        choices=("llama-tiny", "llama-tiny-moe", "llama3-8b"))
+                        choices=("llama-tiny", "llama-tiny-moe", "llama3-8b",
+                                 "joyai-llm-flash"))
     add_model_override_flag(parser)
     parser.add_argument("--checkpoint-dir", default="",
                         help="restore a trainer checkpoint in process")
@@ -336,9 +346,10 @@ def main(argv: list[str] | None = None) -> int:
         "--prefill-chunk", type=int, default=0,
         help="chunked prefill: prefill long prompts in slices of this "
              "many tokens, one decode round over resident slots "
-             "between slices, so one long prompt never stalls the "
-             "batch's decode cadence (byte-identical — chunking only "
-             "changes dispatch order). 0 = one full-length prefill")
+             "between slices and between two admissions, so no long "
+             "prompt stalls the batch's decode cadence by more than a "
+             "slice (byte-identical — chunking only changes dispatch "
+             "order). 0 = one full-length prefill")
     parser.add_argument(
         "--window-compress", action="store_true",
         help="ask volume servers to zlib-compress ReadVolume window "

@@ -752,9 +752,11 @@ SERVE_PREFILL_HANDOFFS = DEFAULT.counter(
     labelnames=("outcome",))
 SERVE_PREFILL_CHUNK_SECONDS = DEFAULT.histogram(
     "oim_serve_prefill_chunk_seconds",
-    "one --prefill-chunk slice of a long prompt's prefill (device-sync "
-    "included) — the bound on how long a resident stream's decode "
-    "cadence can stall behind prompt work between interleaved steps",
+    "one --prefill-chunk slice of a long prompt's prefill, from its "
+    "dispatch to the host's first news of it (with residents: the fetch "
+    "of the decode round dispatched behind it; the last slice's own "
+    "token) — the bound on how long a resident stream's decode cadence "
+    "can stall behind prompt work between interleaved steps",
     buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
              0.5, 1.0, 2.5))
 # Request router (oim_tpu/router: least-loaded LB over serve replicas).

@@ -5,8 +5,9 @@ New scope relative to the reference (a storage control plane has no
 inference path); this completes the model-family API so a checkpoint
 trained by oim-trainer is directly servable. TPU-first shape:
 
-- The cache is a pair of [L, B, S, kv_heads, head_dim] arrays scanned in
-  lockstep with the stacked layer params — one trace per layer regardless
+- The cache is one [L, B, S, ...] array a leaf of ``Config.cache_leaves``
+  (K and V by kv head for GQA, one latent vector a position for latent
+  attention) scanned in lockstep with the stacked layer params — one trace per layer regardless
   of depth, like the training path.
 - Decode attends over the FULL fixed-size cache with a position mask
   (static shapes; no growing arrays inside jit). Prefill and decode are the
@@ -26,10 +27,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oim_tpu.models.llama import Config, _ffn
+from oim_tpu.models.llama import Config, _block, layer_groups
+from oim_tpu.ops import latent_attention
 from oim_tpu.ops.norms import rmsnorm
 from oim_tpu.ops.paged_attention import cache_attention, paged_attention
-from oim_tpu.ops.rope import apply_rope, rope_frequencies
+from oim_tpu.ops.rope import rope_frequencies
 
 
 def _reduce(x, axis: str | None):
@@ -64,6 +66,11 @@ def shard_config(cfg: Config, n: int) -> Config:
         raise ValueError(f"shard count must be >= 1, got {n}")
     if n == 1:
         return cfg
+    if cfg.kv_lora_rank:
+        raise ValueError(
+            "tensor-parallel decode does not support latent attention yet "
+            "(one latent is shared by all heads: the cache cannot split by "
+            f"head; kv_lora_rank={cfg.kv_lora_rank})")
     if cfg.n_experts:
         raise ValueError(
             "tensor-parallel decode does not support MoE configs yet "
@@ -81,8 +88,8 @@ def _no_drop(cfg: Config) -> Config:
     and caps expert capacity, but a decode step has so few tokens that the
     cap would route trained tokens to nothing. A capacity factor of
     n_experts/top_k makes capacity == n_tokens — mathematically no drop."""
-    if not cfg.n_experts:
-        return cfg
+    if not cfg.n_experts or cfg.moe_dispatch == "ragged":
+        return cfg  # dense, or dropless by construction
     import dataclasses
 
     factor = cfg.n_experts / cfg.moe_top_k
@@ -92,9 +99,37 @@ def _no_drop(cfg: Config) -> Config:
 
 
 def init_cache(cfg: Config, batch: int, max_seq: int):
-    """Zeroed KV cache: {"k","v"} of [L, B, max_seq, kv_heads, head_dim]."""
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    """Zeroed dense cache: one [L, B, max_seq, ...] array a leaf of
+    ``cfg.cache_leaves`` ({"k","v"} [.., kv_heads, head_dim] for GQA,
+    {"kv"} [.., kv_lora_rank + qk_rope_head_dim] for latent attention)."""
+    return {name: jnp.zeros((cfg.n_layers, batch, max_seq) + tail, cfg.dtype)
+            for name, tail in cfg.cache_leaves.items()}
+
+
+def _scan_groups(body, carry, params, cfg: Config, xs=None):
+    """``lax.scan`` of the block over each stacked layer group in turn
+    (models/llama.py LAYER_GROUPS); ``xs`` are per-layer arrays [L, ...]
+    scanned beside the layers. Returns (carry, stacked ys)."""
+    from oim_tpu.models import moe
+
+    ys, at = [], 0
+    for group in layer_groups(params):
+        n = jax.tree.leaves(group)[0].shape[0]
+        part = None if xs is None else jax.tree.map(
+            lambda a: a[at:at + n], xs)
+        # A dropless expert group's expert leaves stay whole in the scan
+        # (moe.keep_stacked: a slice handed to a grouped product is a copy).
+        sliced, whole = (moe.keep_stacked(group)
+                         if cfg.moe_dispatch == "ragged" else (group, {}))
+
+        def step(carry, inp, whole=whole):
+            layer, part, i = inp
+            return body(carry, (moe.at_layer(layer, whole, i), part))
+
+        carry, y = lax.scan(step, carry, (sliced, part, jnp.arange(n)))
+        ys.append(y)
+        at += n
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
 
 
 def cached_forward(params, tokens, cache, pos, cfg: Config,
@@ -109,43 +144,46 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     reassemble the projections (see :func:`_reduce`).
     """
     B, T = tokens.shape
-    S = cache["k"].shape[2]
+    S = jax.tree.leaves(cache)[0].shape[2]
     cfg = _no_drop(cfg)
     # Host-numpy weight trees (a freshly restored checkpoint) must work:
     # numpy arrays can't be indexed by traced token ids inside the decode
     # scan, so lift everything to jax arrays first (no-op when already on
     # device).
     params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
+    cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
     positions = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
     x = params["embed"][tokens].astype(cfg.dtype)
 
-    def body(x, inp):
-        layer, ck, cv = inp
-        h = rmsnorm(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        ck = lax.dynamic_update_slice_in_dim(ck, k, pos, axis=1)
-        cv = lax.dynamic_update_slice_in_dim(cv, v, pos, axis=1)
-        attn = cache_attention(q, ck, cv, pos)
-        x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
-        h = rmsnorm(x, layer["mlp_norm"])
-        ffn, _ = _ffn(h, layer, cfg)
-        return x + _reduce(ffn, axis), (ck, cv)
+    def attend(c, q, *new):
+        if cfg.kv_lora_rank:
+            latent, wkv_b = new
+            kv = lax.dynamic_update_slice_in_dim(c["kv"], latent, pos, axis=1)
+            return latent_attention.full_attention(
+                q, kv, wkv_b, cfg.latent, pos), {"kv": kv}
+        k, v = new
+        ck = lax.dynamic_update_slice_in_dim(c["k"], k, pos, axis=1)
+        cv = lax.dynamic_update_slice_in_dim(c["v"], v, pos, axis=1)
+        return cache_attention(q, ck, cv, pos), {"k": ck, "v": cv}
 
-    x, (ck, cv) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    def body(x, inp):
+        layer, c = inp
+        x, _, c = _block(x, layer, cfg, cos, sin, positions, attend, c,
+                         lambda y: _reduce(y, axis))
+        return x, c
+
+    x, cache = _scan_groups(body, x, params, cfg, cache)
     x = rmsnorm(x, params["final_norm"])
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": ck, "v": cv}
+    return logits, cache
 
 
 # -- serving entry points (oim_tpu/serve: continuous batching) ------------
 #
-# The serving engine's KV storage is PAGED: one pool of fixed-size pages
-# {"k","v"} [L, n_pages, page_tokens, kv_heads, head_dim] shared by every
+# The serving engine's KV storage is PAGED: one pool of fixed-size pages,
+# an array [L, n_pages, page_tokens, ...] a leaf of ``Config.cache_leaves``
+# ({"k","v"} [.., kv_heads, head_dim] for GQA; for latent attention ONE
+# array {"kv"} [.., kv_lora_rank + qk_rope_head_dim]), shared by every
 # live request, addressed through per-slot page tables (logical position
 # s of slot b lives at pool[:, table[b, s // page], s % page]). Capacity
 # stops being a per-slot [max_seq] reservation — short and long prompts
@@ -187,24 +225,39 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
 
 
 def init_page_pool(cfg: Config, n_pages: int, page_tokens: int):
-    """Zeroed page pool: {"k","v"} of [L, n_pages, page_tokens, kv_heads,
-    head_dim]. Physical page 0 is the engine's scratch/null page: every
-    unmapped page-table entry points at it, and idle decode rows write
-    their discarded K/V into it — its content is garbage by design and
-    is only ever read through the causal mask's exact-zero branch."""
-    shape = (cfg.n_layers, n_pages, page_tokens,
-             cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    """Zeroed page pool: one [L, n_pages, page_tokens, ...] array a leaf
+    of ``cfg.cache_leaves`` — {"k","v"} [.., kv_heads, head_dim] for GQA,
+    ONE array {"kv"} [.., kv_lora_rank + qk_rope_head_dim] for latent
+    attention (no head axis, no separate value). Physical page 0 is the
+    engine's scratch/null page: every unmapped page-table entry points at
+    it, and idle decode rows write their discarded K/V into it — its
+    content is garbage by design and is only ever read through the
+    causal mask's exact-zero branch."""
+    return {name: jnp.zeros((cfg.n_layers, n_pages, page_tokens) + tail,
+                            cfg.dtype)
+            for name, tail in cfg.cache_leaves.items()}
+
+
+def page_bytes(cfg: Config, page_tokens: int) -> int:
+    """Device bytes of one page over all layers and all leaves of the
+    pool: the unit of the engine's pool and prefix-store accounting."""
+    import math
+
+    per_position = sum(math.prod(t) for t in cfg.cache_leaves.values())
+    return (cfg.n_layers * page_tokens * per_position
+            * jnp.dtype(cfg.dtype).itemsize)
 
 
 def _forward_paged(params, tokens, pool, tables, pos, phys, off,
                    cfg: Config, axis: str | None):
     """The one layer loop of the three serving programs: forward
     ``tokens`` [B, T] at absolute positions pos[b] + t (``pos`` a scalar
-    or [B]), writing position (b, t)'s K/V at pool[l, phys[b, t],
+    or [B]), writing position (b, t)'s cache entry at pool[l, phys[b, t],
     off[b, t]] (an out-of-range ``phys`` DROPS the write) and attending
     through ``tables`` [B, n_blocks]. Returns (hidden [B, T, D] after the
-    final norm, updated pool).
+    final norm, updated pool, load): ``load`` [2] f32 is the mean over the
+    expert layers of [experts that got a row, rows of the fullest expert
+    over the mean] (zeros without a dropless expert layer).
 
     The pool is part of the scan's CARRY, with the layer index beside it:
     each layer scatters its rows into pool[l] and reads pool[l] where it
@@ -212,34 +265,41 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     every call — two copies of the whole pool a program, whatever the
     callers' donation. Carried, the caller's donated buffer is the one
     the scatter updates in place (tests/test_chip_compile.py holds the
-    compiled programs to it)."""
+    compiled programs to it). The layer index runs on through the layer
+    groups (an expert model's leading dense layers, then the rest)."""
     B, T = tokens.shape
-    S = tables.shape[1] * pool["k"].shape[2]
+    page = jax.tree.leaves(pool)[0].shape[2]
+    S = tables.shape[1] * page
     cfg = _no_drop(cfg)
     params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.head_dim, S, cfg.rope_theta)
+    cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
     positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
     x = params["embed"][tokens].astype(cfg.dtype)
 
-    def body(carry, layer):
-        x, pk, pv, l = carry  # pk, pv: [L, n_pages, page, kvh, hd]
-        h = rmsnorm(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        pk = pk.at[l, phys, off].set(k, mode="drop")
-        pv = pv.at[l, phys, off].set(v, mode="drop")
-        attn = paged_attention(q, pk, pv, l, tables, pos)
-        x = x + _reduce(attn.reshape(B, T, cfg.q_dim) @ layer["wo"], axis)
-        h = rmsnorm(x, layer["mlp_norm"])
-        ffn, _ = _ffn(h, layer, cfg)
-        return (x + _reduce(ffn, axis), pk, pv, l + 1), None
+    def body(carry, inp):
+        x, pool, l = carry  # pool leaves: [L, n_pages, page, ...]
 
-    (x, pk, pv, _), _ = lax.scan(
-        body, (x, pool["k"], pool["v"], jnp.int32(0)), params["layers"])
-    return rmsnorm(x, params["final_norm"]), {"k": pk, "v": pv}
+        def attend(pool, q, *new):
+            if cfg.kv_lora_rank:
+                latent, wkv_b = new
+                kv = pool["kv"].at[l, phys, off].set(latent, mode="drop")
+                return latent_attention.paged_attention(
+                    q, kv, l, tables, pos, wkv_b, cfg.latent), {"kv": kv}
+            k, v = new
+            pk = pool["k"].at[l, phys, off].set(k, mode="drop")
+            pv = pool["v"].at[l, phys, off].set(v, mode="drop")
+            return (paged_attention(q, pk, pv, l, tables, pos),
+                    {"k": pk, "v": pv})
+
+        x, aux, pool = _block(x, inp[0], cfg, cos, sin, positions, attend,
+                              pool, lambda y: _reduce(y, axis), load=True)
+        return (x, pool, l + 1), aux[2:]
+
+    (x, pool, _), load = _scan_groups(
+        body, (x, pool, jnp.int32(0)), params, cfg)
+    n_moe = max(cfg.n_layers - cfg.n_dense_layers, 1) if cfg.n_experts else 1
+    return (rmsnorm(x, params["final_norm"]), pool,
+            jnp.sum(load, axis=0) / n_moe)
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
@@ -275,27 +335,30 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     T = tokens.shape[1]  # tokens [1, T]: admission is per-slot
     nb = page_table.shape[0]
     S = nb * page_tokens
-    n_pages = pool["k"].shape[1]
+    n_pages = jax.tree.leaves(pool)[0].shape[1]
     logical = start + jnp.arange(T)
     blk = jnp.minimum(logical // page_tokens, nb - 1)
     keep = (jnp.arange(T) < n_tokens) & (logical < S)
     # Out-of-range physical index: pad K/V never lands.
     phys = jnp.where(keep, page_table[blk], n_pages)
-    x, pool = _forward_paged(
+    x, pool, _ = _forward_paged(
         params, tokens, pool, page_table[None], start, phys[None],
         (logical % page_tokens)[None], cfg, axis)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    last = lax.dynamic_index_in_dim(
-        logits[0], n_tokens - 1, axis=0, keepdims=False)
-    return last, pool
+    # The last real row is taken BEFORE the head: one row of logits is
+    # kept, so one row is computed (at 129 280 rows of vocabulary a
+    # 2048-token chunk's float32 logits would be 1 GB for nothing).
+    last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
+    return (last @ params["lm_head"]).astype(jnp.float32)[0], pool
 
 
 def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
-                page_tokens: int, axis: str | None = None):
+                page_tokens: int, axis: str | None = None,
+                with_load: bool = False):
     """One lockstep decode step over the whole slot batch: ``tokens`` [B]
     int32 (each slot's previous token) at absolute positions ``pos`` [B],
     written and attended through ``page_tables`` [B, n_blocks]. Returns
-    (logits [B, vocab] f32, updated pool).
+    (logits [B, vocab] f32, updated pool) and, ``with_load``, the expert
+    layers' load (see ``_forward_paged``).
 
     Mid-flight admission leaves every slot at its own depth, so the K/V
     write is a per-row scatter at (table[b, pos // page], pos % page)
@@ -317,10 +380,12 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
     blk = jnp.minimum(pos // page_tokens, nb - 1)
     phys = jnp.where(pos < nb * page_tokens,
                      page_tables[jnp.arange(B), blk], 0)  # [B]
-    x, pool = _forward_paged(
+    x, pool, load = _forward_paged(
         params, tokens[:, None], pool, page_tables, pos, phys[:, None],
         (pos % page_tokens)[:, None], cfg, axis)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
+    if with_load:
+        return logits[:, 0], pool, load
     return logits[:, 0], pool
 
 
@@ -354,14 +419,14 @@ def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
     attention byte-identical)."""
     B, T = tokens.shape
     nb = page_tables.shape[1]
-    n_pages = pool["k"].shape[1]
+    n_pages = jax.tree.leaves(pool)[0].shape[1]
     positions = pos[:, None] + jnp.arange(T)[None, :]  # [B, T]
     blk = jnp.minimum(positions // page_tokens, nb - 1)
     # Out-of-range physical index: past-the-table K/V never lands (same
     # stance as prefill_into_pages' pad positions).
     phys = jnp.where(positions < nb * page_tokens,
                      page_tables[jnp.arange(B)[:, None], blk], n_pages)
-    x, pool = _forward_paged(
+    x, pool, _ = _forward_paged(
         params, tokens, pool, page_tables, pos, phys,
         positions % page_tokens, cfg, axis)
     return (x @ params["lm_head"]).astype(jnp.float32), pool

@@ -56,6 +56,30 @@ class Config:
     # dense dispatch); see models/moe.py MoEConfig.dispatch and the
     # BASELINE.md r4 measurement row.
     moe_dispatch: str = "gather"
+    # The DeepSeek-V3 family's FFN, under its published keys: the first
+    # ``first_k_dense_replace`` layers keep the dense FFN (width mlp_dim),
+    # the rest route over experts of width ``moe_intermediate_size`` (0:
+    # mlp_dim) with ``scoring_func`` "softmax" or "sigmoid", the chosen
+    # experts' weights scaled by ``routed_scaling_factor``, beside
+    # ``n_shared_experts`` shared experts every token takes. Sigmoid
+    # scoring is dropless: it needs moe_dispatch="ragged" (models/moe.py).
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Latent attention (MLA), under its published keys; kv_lora_rank > 0
+    # selects it. Queries and keys are ``qk_nope_head_dim`` dims from
+    # low-rank projections (with an RMSNorm inside each) plus
+    # ``qk_rope_head_dim`` rotated dims, the key's shared by all heads;
+    # values are ``v_head_dim`` wide. What a position caches is one vector
+    # of kv_lora_rank + qk_rope_head_dim for all heads
+    # (ops/latent_attention.py); n_kv_heads and head_dim are unused.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # Rematerialize each layer's activations in the backward pass
     # (jax.checkpoint around the scan body): ~1/3 more FLOPs for O(1)-layer
     # activation memory — what makes 8B-class configs at long context fit
@@ -84,6 +108,19 @@ class Config:
     # matches GPipe bit-for-bit (asserted by test_pipeline_moe).
     z_loss: float = 0.0
 
+    def __post_init__(self):
+        if self.kv_lora_rank and not (
+                self.q_lora_rank and self.qk_nope_head_dim
+                and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if self.n_experts and self.scoring_func == "sigmoid" \
+                and self.moe_dispatch != "ragged":
+            raise ValueError(
+                "scoring_func='sigmoid' routes dropless: it needs "
+                f"moe_dispatch='ragged', got {self.moe_dispatch!r}")
+
     @property
     def moe(self):
         from oim_tpu.models.moe import MoEConfig
@@ -93,18 +130,82 @@ class Config:
             top_k=self.moe_top_k,
             capacity_factor=self.moe_capacity_factor,
             dispatch=self.moe_dispatch,
+            scoring=self.scoring_func,
+            routed_scale=self.routed_scaling_factor,
+            n_shared=self.n_shared_experts,
         )
 
     @property
+    def expert_dim(self) -> int:
+        return self.moe_intermediate_size or self.mlp_dim
+
+    @property
+    def n_dense_layers(self) -> int:
+        """Leading layers that keep the dense FFN in an expert model."""
+        if not self.n_experts:
+            return 0
+        return min(self.first_k_dense_replace, self.n_layers)
+
+    @property
+    def latent(self):
+        """The latent attention's sizes, or None for GQA."""
+        if not self.kv_lora_rank:
+            return None
+        from oim_tpu.ops.latent_attention import Dims
+
+        return Dims(heads=self.n_heads, rank=self.kv_lora_rank,
+                    nope=self.qk_nope_head_dim, rope=self.qk_rope_head_dim,
+                    v=self.v_head_dim)
+
+    @property
+    def rope_dim(self) -> int:
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
     def q_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.n_heads * (self.qk_nope_head_dim
+                                   + self.qk_rope_head_dim)
+        return self.n_heads * self.head_dim
+
+    @property
+    def o_dim(self) -> int:
+        """Width of the attention's output before ``wo``."""
+        if self.kv_lora_rank:
+            return self.n_heads * self.v_head_dim
         return self.n_heads * self.head_dim
 
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
 
+    @property
+    def cache_leaves(self) -> dict:
+        """What a position keeps in a layer's cache: {leaf: trailing
+        shape}. The page pool, the dense cache, the host tier and the
+        exported volumes are all built over these leaves."""
+        if self.kv_lora_rank:
+            return {"kv": (self.latent.width,)}
+        return {"k": (self.n_kv_heads, self.head_dim),
+                "v": (self.n_kv_heads, self.head_dim)}
+
 
 LLAMA3_8B = Config(vocab_chunk=16384)  # 128k-vocab logits never materialize
+
+
+# JoyAI-LLM-Flash (48B-A2.7B) as published: latent attention, a leading
+# dense layer, then 256 sigmoid-routed experts top-8 beside a shared one.
+# https://huggingface.co/jdopensource/JoyAI-LLM-Flash/blob/main/config.json
+# (n_group = topk_group = 1: no group is masked; the one multi-token-
+# prediction module is not part of the served forward.) One v5e chip holds
+# 5 of the 40 layers: --model-override n_layers=5.
+JOYAI_LLM_FLASH = Config(
+    vocab=129280, dim=2048, n_layers=40, n_heads=32, n_kv_heads=32,
+    head_dim=64, mlp_dim=7168, max_seq=131072, rope_theta=32e6,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, n_experts=256, moe_top_k=8,
+    moe_intermediate_size=768, n_shared_experts=1, first_k_dense_replace=1,
+    scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
 
 
 def tiny(vocab: int = 256, dim: int = 64, n_layers: int = 2,
@@ -117,29 +218,58 @@ def tiny(vocab: int = 256, dim: int = 64, n_layers: int = 2,
     )
 
 
+def tiny_latent(vocab: int = 512, n_layers: int = 3, dtype=jnp.float32) -> Config:
+    """The DeepSeek-V3 family's block at test scale: latent attention, a
+    leading dense layer, then sigmoid-routed experts beside a shared one."""
+    return Config(
+        vocab=vocab, dim=64, n_layers=n_layers, n_heads=4, n_kv_heads=4,
+        head_dim=16, mlp_dim=192, max_seq=512, rope_theta=32e6, dtype=dtype,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, n_experts=16, moe_top_k=4,
+        moe_intermediate_size=32, n_shared_experts=1, first_k_dense_replace=1,
+        scoring_func="sigmoid", routed_scaling_factor=2.5,
+        moe_dispatch="ragged")
+
+
 def _dense(rng, shape, dtype, scale=None):
     if scale is None:
         scale = shape[-2] ** -0.5  # fan-in of the contraction dim
     return (jax.random.normal(rng, shape) * scale).astype(dtype)
 
 
-def init(rng, cfg: Config = LLAMA3_8B):
-    L, D = cfg.n_layers, cfg.dim
+def _init_group(rng, cfg: Config, L: int, experts: bool):
+    """One stacked group of ``L`` layers: attention leaves by the
+    configuration's attention, FFN leaves dense or experts."""
+    D = cfg.dim
     ks = jax.random.split(rng, 10)
     fan = D**-0.5
     layers = {
         "attn_norm": jnp.ones((L, D), jnp.float32),
-        "wq": _dense(ks[1], (L, D, cfg.q_dim), cfg.dtype, fan),
-        "wk": _dense(ks[2], (L, D, cfg.kv_dim), cfg.dtype, fan),
-        "wv": _dense(ks[3], (L, D, cfg.kv_dim), cfg.dtype, fan),
-        "wo": _dense(ks[4], (L, cfg.q_dim, D), cfg.dtype, cfg.q_dim**-0.5),
+        "wo": _dense(ks[4], (L, cfg.o_dim, D), cfg.dtype, cfg.o_dim**-0.5),
         "mlp_norm": jnp.ones((L, D), jnp.float32),
     }
-    if cfg.n_experts:
+    if cfg.kv_lora_rank:
+        m = cfg.latent
+        layers.update(
+            wq_a=_dense(ks[1], (L, D, cfg.q_lora_rank), cfg.dtype, fan),
+            q_norm=jnp.ones((L, cfg.q_lora_rank), jnp.float32),
+            wq_b=_dense(ks[2], (L, cfg.q_lora_rank, cfg.q_dim), cfg.dtype),
+            wkv_a=_dense(ks[3], (L, D, m.rank + m.rope), cfg.dtype, fan),
+            kv_norm=jnp.ones((L, m.rank), jnp.float32),
+            wkv_b=_dense(ks[9], (L, m.rank, m.heads * (m.nope + m.v)),
+                         cfg.dtype),
+        )
+    else:
+        layers.update(
+            wq=_dense(ks[1], (L, D, cfg.q_dim), cfg.dtype, fan),
+            wk=_dense(ks[2], (L, D, cfg.kv_dim), cfg.dtype, fan),
+            wv=_dense(ks[3], (L, D, cfg.kv_dim), cfg.dtype, fan),
+        )
+    if experts:
         from oim_tpu.models import moe
 
         layers["moe"] = moe.init(
-            ks[5], D, cfg.mlp_dim, cfg.moe, cfg.dtype, n_layers=L
+            ks[5], D, cfg.expert_dim, cfg.moe, cfg.dtype, n_layers=L
         )
     else:
         layers.update(
@@ -148,15 +278,42 @@ def init(rng, cfg: Config = LLAMA3_8B):
             w_down=_dense(ks[7], (L, cfg.mlp_dim, D), cfg.dtype,
                           cfg.mlp_dim**-0.5),
         )
-    return {
+    return layers
+
+
+# The stacked layer groups of a parameter tree, in the order they run:
+# the leading dense layers of an expert model (``first_k_dense_replace``),
+# where it has any, then the homogeneous rest. Each is one ``lax.scan``.
+LAYER_GROUPS = ("dense_layers", "layers")
+
+
+def layer_groups(params) -> list:
+    return [params[g] for g in LAYER_GROUPS if g in params]
+
+
+def init(rng, cfg: Config = LLAMA3_8B):
+    D = cfg.dim
+    ks = jax.random.split(rng, 10)
+    fan = D**-0.5
+    lead = cfg.n_dense_layers
+    params = {
         "embed": _dense(ks[0], (cfg.vocab, D), cfg.dtype, scale=0.02),
-        "layers": layers,
+        "layers": _init_group(rng, cfg, cfg.n_layers - lead,
+                              bool(cfg.n_experts)),
         "final_norm": jnp.ones((D,), jnp.float32),
         "lm_head": _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan),
     }
+    if lead:
+        params["dense_layers"] = _init_group(ks[9], cfg, lead, False)
+    return params
 
 
 def param_logical_axes(cfg: Config = LLAMA3_8B):
+    if cfg.kv_lora_rank or cfg.n_dense_layers or cfg.n_shared_experts:
+        raise ValueError(
+            "no sharding rules yet for latent attention, leading dense "
+            "layers or shared experts: this block is served on one chip "
+            "and not trained (ROADMAP.md, Reach)")
     layers = {
         "attn_norm": (LAYER, None),
         "wq": (LAYER, EMBED, HEAD),
@@ -206,35 +363,87 @@ def _remat_policy(cfg: Config):
     return getattr(jax.checkpoint_policies, name)
 
 
-def _ffn(h, layer, cfg: Config):
+def _ffn(h, layer, cfg: Config, load: bool = False):
     """FFN half of a block on the pre-normed activations; returns
     (out, aux) — aux is the f32 vector [load_balance_loss,
     dropped_token_fraction] (zeros for the dense FFN): one uniform aux
     shape lets every schedule's masked accumulator carry the MoE
-    telemetry without special cases. Shared by the training path
-    (_layer) and the KV-cached decode path (models/generate.py)."""
-    if cfg.n_experts:
+    telemetry without special cases. With ``load`` (the serving programs)
+    it is moe.apply's four-vector. Which FFN a layer has is read from its
+    own leaves: an expert model's leading dense layers carry none of
+    ``moe``."""
+    if "moe" in layer:
         from oim_tpu.models import moe
 
-        return moe.apply(layer["moe"], h, cfg.moe, with_stats=True)
+        return moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
+                         with_load=load)
     gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-    return gated @ layer["w_down"], jnp.zeros((2,), jnp.float32)
+    return gated @ layer["w_down"], jnp.zeros((4 if load else 2,),
+                                               jnp.float32)
+
+
+def _same(x):
+    return x
+
+
+def _block(x, layer, cfg: Config, cos, sin, positions, attend, cache=None,
+           reduce=_same, load: bool = False):
+    """THE decoder block, for every program: the full-sequence forward
+    (``_layer``), the dense-cache decode and the three paged serving
+    programs (models/generate.py) differ only in ``attend``, which is
+    handed this call's projections and whatever ``cache`` the caller
+    threads, and returns (attention output [B, T, H, v], cache):
+
+    - GQA: ``attend(cache, q, k, v)``, q/k rotated;
+    - latent (cfg.kv_lora_rank): ``attend(cache, q, latent, wkv_b)`` with
+      q [B, T, H, nope + rope] rotated in its rope dims and ``latent``
+      [B, T, rank + rope] = [RMSNorm(c_kv) | rotated k_r], exactly what a
+      position's cache entry is.
+
+    ``reduce`` sums the two row-split projections over a tensor-parallel
+    axis. Returns (x, aux, cache)."""
+    B, T, _ = x.shape
+    h = rmsnorm(x, layer["attn_norm"])
+    if cfg.kv_lora_rank:
+        m = cfg.latent
+        q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"]) @ layer["wq_b"]
+             ).reshape(B, T, m.heads, m.nope + m.rope)
+        q = jnp.concatenate(
+            [q[..., :m.nope],
+             apply_rope(q[..., m.nope:], cos, sin, positions)], axis=-1)
+        ckv = h @ layer["wkv_a"]
+        k_r = apply_rope(ckv[..., None, m.rank:], cos, sin, positions)
+        latent = m.entry(rmsnorm(ckv[..., :m.rank], layer["kv_norm"]),
+                         k_r[..., 0, :])
+        attn, cache = attend(cache, q, latent, layer["wkv_b"])
+    else:
+        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
+        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        attn, cache = attend(cache, q, k, v)
+    x = x + reduce(attn.reshape(B, T, cfg.o_dim) @ layer["wo"])
+    h = rmsnorm(x, layer["mlp_norm"])
+    ffn, aux = _ffn(h, layer, cfg, load)
+    return x + reduce(ffn), aux, cache
 
 
 def _layer(x, layer, cfg: Config, cos, sin, attn_fn: AttentionFn):
-    """Returns (x, aux_loss); aux is 0 for dense FFN layers."""
-    B, T, D = x.shape
-    h = rmsnorm(x, layer["attn_norm"])
-    q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-    k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    attn = attn_fn(q, k, v, causal=True)
-    x = x + attn.reshape(B, T, cfg.q_dim) @ layer["wo"]
-    h = rmsnorm(x, layer["mlp_norm"])
-    ffn, aux = _ffn(h, layer, cfg)
-    return x + ffn, aux
+    """The block over a whole sequence, no cache. Returns (x, aux_loss);
+    aux is 0 for dense FFN layers."""
+    if cfg.kv_lora_rank:
+        from oim_tpu.ops import latent_attention
+
+        def attend(_, q, latent, wkv_b):
+            return latent_attention.full_attention(
+                q, latent, wkv_b, cfg.latent), None
+    else:
+        def attend(_, q, k, v):
+            return attn_fn(q, k, v, causal=True), None
+
+    x, aux, _ = _block(x, layer, cfg, cos, sin, None, attend)
+    return x, aux
 
 
 def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
@@ -244,7 +453,7 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
     if attn_fn is None:
         attn_fn = default_attention
     T = tokens.shape[1]
-    cos, sin = rope_frequencies(cfg.head_dim, T, cfg.rope_theta)
+    cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
     x = params["embed"][tokens].astype(cfg.dtype)
 
     def body(x, layer):
@@ -255,8 +464,11 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
         # prevent_cse=False: unnecessary (and costly) inside a scan body.
         body = jax.checkpoint(
             body, prevent_cse=False, policy=_remat_policy(cfg))
-    x, aux = lax.scan(body, x, params["layers"])
-    return rmsnorm(x, params["final_norm"]), jnp.sum(aux, axis=0)
+    aux = jnp.zeros((2,), jnp.float32)
+    for group in layer_groups(params):
+        x, group_aux = lax.scan(body, x, group)
+        aux = aux + jnp.sum(group_aux, axis=0)
+    return rmsnorm(x, params["final_norm"]), aux
 
 
 def apply(params, tokens, cfg: Config = LLAMA3_8B,
@@ -742,17 +954,25 @@ def make_1f1b_loss(mesh, cfg: Config, n_microbatches: int,
 
 def _param_counts(cfg: Config, experts: int) -> int:
     L, D = cfg.n_layers, cfg.dim
+    dense = 3 * D * cfg.mlp_dim
     if cfg.n_experts:
-        # Router always sees every expert; expert weights count ``experts``.
-        ffn = D * cfg.n_experts + 3 * experts * D * cfg.mlp_dim
+        # Router always sees every expert; expert weights count ``experts``
+        # routed experts and every shared one.
+        ffn = (D * cfg.n_experts
+               + 3 * (experts + cfg.n_shared_experts) * D * cfg.expert_dim
+               + (cfg.n_experts if cfg.scoring_func == "sigmoid" else 0))
     else:
-        ffn = 3 * D * cfg.mlp_dim
-    per_layer = (
-        2 * D  # norms
-        + D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.q_dim * D
-        + ffn
-    )
-    return cfg.vocab * D + L * per_layer + D + D * cfg.vocab
+        ffn = dense
+    if cfg.kv_lora_rank:
+        m = cfg.latent
+        attn = (D * cfg.q_lora_rank + cfg.q_lora_rank
+                + cfg.q_lora_rank * cfg.q_dim + D * (m.rank + m.rope) + m.rank
+                + m.rank * m.heads * (m.nope + m.v) + cfg.o_dim * D)
+    else:
+        attn = D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.q_dim * D
+    lead = cfg.n_dense_layers
+    layers = L * (2 * D + attn) + lead * dense + (L - lead) * ffn
+    return cfg.vocab * D + layers + D + D * cfg.vocab
 
 
 def num_params(cfg: Config = LLAMA3_8B) -> int:

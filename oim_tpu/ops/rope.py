@@ -41,3 +41,13 @@ def apply_rope(x, cos, sin, positions=None):
     out1 = xf1 * cos_t - xf2 * sin_t
     out2 = xf2 * cos_t + xf1 * sin_t
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+
+
+def interleaved_to_split_half(n: int):
+    """Column order that turns an interleaved rope layout of ``n`` dims
+    into the split-half one: [0, 2, 4, ..., 1, 3, 5, ...]. A projection
+    whose rope OUTPUT columns are permuted this way (on the query and on
+    the key alike) may be rotated split-half by ``apply_rope``: the pairs
+    and their frequencies are the same, and the dot product of a rotated
+    query with a rotated key does not depend on the order of the dims."""
+    return jnp.concatenate([jnp.arange(0, n, 2), jnp.arange(1, n, 2)])

@@ -182,6 +182,12 @@ class GenHandle:
         }
 
 
+def _counts_expert_load(cfg: Config) -> bool:
+    """Whether the decode program reports the expert layers' load: the
+    dropless dispatch is the one that counts it (models/moe.py)."""
+    return bool(cfg.n_experts) and cfg.moe_dispatch == "ragged"
+
+
 @functools.lru_cache(maxsize=64)
 def _target_programs(cfg: Config, page: int, max_seq: int,
                      shard: int = 1):
@@ -226,13 +232,14 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
                 p, t, n, c, tb, st, lcfg, page, axis="tp"), cache_arg=2)
     else:
         def _decode(p, t, c, tb, ps):
-            return gen.decode_step(p, t, c, tb, ps, cfg, page)
+            return gen.decode_step(p, t, c, tb, ps, cfg, page,
+                                   with_load=_counts_expert_load(cfg))
 
         def _prefill_fwd(p, t, n, c, tb, st):
             return gen.prefill_into_pages(p, t, n, c, tb, st, cfg, page)
 
     def step(params, cache, tokens, pos, keys, temps, tables):
-        logits, cache = _decode(params, tokens, cache, tables, pos)
+        logits, cache, *load = _decode(params, tokens, cache, tables, pos)
         split = jax.vmap(jax.random.split)(keys)  # [B, 2, key]
         carry, subs = split[:, 0], split[:, 1]
         # Sampling matches generate() bit-for-bit per row: each slot
@@ -255,7 +262,10 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
         # clamped to max_seq so they can't drift without bound (a live
         # row retires before its position could reach the clamp, so
         # the clamp never alters a real request's numerics).
-        return tok, cache, carry, jnp.minimum(pos + 1, max_seq)
+        # A dropless expert model's step also returns its expert load
+        # [experts that got a row, fullest over mean], a fifth output the
+        # engine fetches with the tokens; other models' steps are as ever.
+        return (tok, cache, carry, jnp.minimum(pos + 1, max_seq), *load)
 
     def prefill(params, cache, tokens, n_tokens, table, start, key,
                 temp):
@@ -445,6 +455,16 @@ class ServeEngine:
         # devices. Validate the geometry NOW — indivisible head counts
         # and missing devices are config typos, not runtime surprises.
         self.shard = max(int(shard), 1)
+        if cfg.kv_lora_rank and draft_params is not None:
+            raise ValueError(
+                "speculative decoding does not support latent attention "
+                "yet (the verify program and a draft pool over a latent "
+                "cache are untested)")
+        if cfg.kv_lora_rank and str(role) != "mixed":
+            raise ValueError(
+                f"role {role!r} does not support latent attention yet "
+                "(the prefill-to-decode handoff is tested over K/V pages "
+                "only); serve it as role='mixed'")
         self.member_hbm_budget = max(int(member_hbm_budget), 0)
         if self.shard > 1:
             from oim_tpu.serve import shard as shardlib
@@ -510,9 +530,7 @@ class ServeEngine:
                 f"kv_pool_tokens ({pool_tokens}) is smaller than one "
                 f"{self.page_tokens}-token page")
         n_pages = pool_tokens // self.page_tokens
-        page_bytes = (2 * cfg.n_layers * self.page_tokens
-                      * cfg.n_kv_heads * cfg.head_dim
-                      * np.dtype(cfg.dtype).itemsize)
+        page_bytes = gen.page_bytes(cfg, self.page_tokens)
         self._pagepool = PagePool(n_pages, self.page_tokens, page_bytes)
         # Per-member HBM budget: a member holds 1/shard of the split
         # weight leaves, the replicated leaves whole, and 1/shard of
@@ -593,7 +611,7 @@ class ServeEngine:
             self._cache = jax.device_put(
                 self._cache,
                 {k: NamedSharding(mesh, s)
-                 for k, s in shardlib.pool_specs().items()})
+                 for k, s in shardlib.pool_specs(self._cache).items()})
         page = self.page_tokens
         # Jitted programs are SHARED across engine instances of one
         # geometry (_target_programs / _spec_programs below): jit
@@ -609,17 +627,26 @@ class ServeEngine:
         # shapes, the word the program logs when it is traced. stats()
         # carries it, so a replica that silently missed the kernel can
         # be told from its serve/<id> row.
-        from oim_tpu.ops import paged_attention
+        from oim_tpu.ops import latent_attention, paged_attention
 
-        lcfg = gen.shard_config(cfg, self.shard)
-        pool_k = self._cache["k"]
-        self.decode_attention = paged_attention.kernel_name(
-            jax.ShapeDtypeStruct(
-                (max_batch, 1, lcfg.n_heads, cfg.head_dim), cfg.dtype),
-            jax.ShapeDtypeStruct(
-                pool_k.shape[:3] + (lcfg.n_kv_heads, cfg.head_dim),
-                pool_k.dtype),
-            jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32))
+        self.cache_kind = "latent" if cfg.kv_lora_rank else "gqa"
+        if cfg.kv_lora_rank:
+            self.decode_attention = latent_attention.kernel_name(1)
+        else:
+            lcfg = gen.shard_config(cfg, self.shard)
+            pool_k = self._cache["k"]
+            self.decode_attention = paged_attention.kernel_name(
+                jax.ShapeDtypeStruct(
+                    (max_batch, 1, lcfg.n_heads, cfg.head_dim), cfg.dtype),
+                jax.ShapeDtypeStruct(
+                    pool_k.shape[:3] + (lcfg.n_kv_heads, cfg.head_dim),
+                    pool_k.dtype),
+                jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32))
+        # Expert load of a dropless expert model's decode steps, summed:
+        # [steps counted, experts that got a row (mean over the expert
+        # layers), rows of the fullest expert over the mean]. stats()
+        # shows the sums; a reader takes the difference of two snapshots.
+        self._expert_load = np.zeros(3, np.float64)
 
         # -- speculative decoding (serve/spec.py): draft propose K
         # tokens through its OWN small page pool (K lockstep decode
@@ -638,9 +665,7 @@ class ServeEngine:
                 raise ValueError(
                     f"spec_pool_tokens ({draft_pool_tokens}) is smaller "
                     f"than one {self.page_tokens}-token page")
-            draft_page_bytes = (2 * dcfg.n_layers * self.page_tokens
-                                * dcfg.n_kv_heads * dcfg.head_dim
-                                * np.dtype(dcfg.dtype).itemsize)
+            draft_page_bytes = gen.page_bytes(dcfg, self.page_tokens)
             n_draft_pages = draft_pool_tokens // self.page_tokens
             self._draft_pagepool = PagePool(
                 n_draft_pages, self.page_tokens, draft_page_bytes,
@@ -874,7 +899,17 @@ class ServeEngine:
                 # A build fact, not a load: "pallas_paged" or
                 # "jnp_gather" (see __init__).
                 "decode_attention": self.decode_attention,
+                # Pages in use, and the kind of cache they hold ("gqa": K
+                # and V by head; "latent": one vector a position). Flat
+                # scalars: readers of the serve/<id> row take no nesting.
+                "cache_kind": self.cache_kind,
+                "kv_pages_used": self._pagepool.used_pages,
             }
+            if _counts_expert_load(self.cfg):
+                steps, touched, fullest = self._expert_load
+                snap.update(expert_load_steps=int(steps),
+                            experts_touched_sum=float(touched),
+                            expert_load_max_over_mean_sum=float(fullest))
             if self.role == "prefill":
                 # A COLD prefill replica must still advertise its block
                 # size: the router's split gate compares prompt length
@@ -1179,8 +1214,7 @@ class ServeEngine:
         """PrefixStore demote hook: D2H the evicting store-only page
         into the host tier (engine thread — every store mutation path
         runs here, which is what makes the device read legal)."""
-        k, v = page_kv(self._cache, page)
-        self._host_tier.put(key, k, v)
+        self._host_tier.put(key, *page_kv(self._cache, page))
 
     def _alloc_one(self) -> int | None:
         """One fresh page for a promotion/adoption, shedding cold
@@ -1215,7 +1249,7 @@ class ServeEngine:
             page = self._alloc_one()
             if page is None:
                 break
-            self._cache = stage_page(self._cache, page, got[0], got[1])
+            self._cache = stage_page(self._cache, page, *got)
             self._host_tier.pop(chain[m])
             self._install_block(chain[m], page, shared)
             m += 1
@@ -1241,8 +1275,8 @@ class ServeEngine:
                         trace_id=self._trace_id(req),
                         matched_blocks=m, chain_blocks=len(chain))
             return m
-        keys, pages, ks, vs = [], [], [], []
-        for key, (k, v) in fetched:
+        keys, pages, blocks = [], [], []
+        for key, block in fetched:
             if m + len(keys) >= len(chain) or key != chain[m + len(keys)]:
                 break  # only a consecutive continuation may adopt
             page = self._alloc_one()
@@ -1250,14 +1284,13 @@ class ServeEngine:
                 break
             keys.append(key)
             pages.append(page)
-            ks.append(k)
-            vs.append(v)
+            blocks.append(block)
         if not keys:
             return m
         try:
             # One batched scatter for the whole adopted run — per-page
             # dispatch overhead would eat the prefill this path saves.
-            self._cache = stage_pages(self._cache, pages, ks, vs)
+            self._cache = stage_pages(self._cache, pages, blocks)
         except Exception as err:  # noqa: BLE001 - e.g. peer shape skew
             self._pagepool.unref(pages)
             events.emit(events.KV_FETCH_FALLBACK,
@@ -1382,7 +1415,8 @@ class ServeEngine:
 
     def _admit(self) -> None:
         """Insert queued requests into free slots (prefill between decode
-        steps: new work overlaps residents' decoding at step granularity).
+        steps: new work overlaps residents' decoding at step granularity;
+        with ``prefill_chunk`` one request a call, see the end).
         Admission reserves the request's pages first; an exhausted pool
         leaves the request AT THE HEAD of the queue (FIFO preserved) and
         returns — retirements free pages, the next loop pass retries.
@@ -1471,6 +1505,12 @@ class ServeEngine:
             self._occupancy()
             self._emit(req, tok)
             self._retire_if_done(free, req, tok)
+            if self.prefill_chunk:
+                # One admission a loop pass: the residents get a decode
+                # round between this prompt's last slice and the next
+                # prompt's first, as they do between two slices of one
+                # prompt. No token waits for more than one slice.
+                return
 
     def _map_slot(self, req: _Request, slot: int, n: int,
                   m: int, shared: list[int]) -> bool:
@@ -1612,7 +1652,12 @@ class ServeEngine:
         """The prompt tail in --prefill-chunk token slices, one decode
         round over the RESIDENT slots between slices — admission never
         stalls a long prompt behind the batch, and the batch's decode
-        cadence never stalls behind a long prompt. Byte-identical to
+        cadence never stalls behind a long prompt. No slice but the last
+        is waited for: the round is dispatched behind the slice in flight
+        and the next slice behind the round (``_decode_once``'s
+        ``queue_behind``), so the device runs slice, round, slice back to
+        back while the host fetches and emits, and a resident's gap across
+        a slice is the device's time alone. Byte-identical to
         one full prefill: every slice runs the SAME compiled program
         over the same pages at shifted ``start`` (attention math is
         position-indexed, not dispatch-indexed), and every slice gets
@@ -1641,29 +1686,42 @@ class ServeEngine:
             self._draft_tables_dev = None
         table_dev = jnp.asarray(table_row)
         key0 = self._jax.random.PRNGKey(req.seed)
-        tok = key = None
-        with tracing.start_span(
-                "serve.prefill", parent=req.trace_ctx, slot=slot,
-                prompt_tokens=n, chunk_tokens=chunk,
-                chunks=-(-len(tail) // chunk)):
-            for off in range(0, len(tail), chunk):
-                piece = tail[off:off + chunk]
-                padded = np.zeros((1, self._bucket(len(piece))), np.int32)
-                padded[0, :len(piece)] = piece
-                t0 = time.monotonic()
+
+        def dispatch(off: int):
+            """One slice on its way: (token, RNG carry, when)."""
+            piece = tail[off:off + chunk]
+            padded = np.zeros((1, self._bucket(len(piece))), np.int32)
+            padded[0, :len(piece)] = piece
+            since = time.monotonic()
+            with tracing.annotate("serve.prefill_chunk"):
                 tok, self._cache, key = self._prefill(
                     self.params, self._cache, jnp.asarray(padded),
                     jnp.int32(len(piece)), table_dev,
                     jnp.int32(P + off), key0,
                     jnp.float32(req.temperature))
-                tok = int(tok)  # device sync: the slice is DONE here
-                M.SERVE_PREFILL_CHUNK_SECONDS.observe(
-                    time.monotonic() - t0, self._trace_id(req))
-                if off + chunk < len(tail):
-                    with self._lock:
-                        resident = any(r is not None for r in self._slots)
-                    if resident:
-                        self._decode_once()
+            return tok, key, since
+
+        def landed(since: float) -> None:
+            M.SERVE_PREFILL_CHUNK_SECONDS.observe(
+                time.monotonic() - since, self._trace_id(req))
+
+        with tracing.start_span(
+                "serve.prefill", parent=req.trace_ctx, slot=slot,
+                prompt_tokens=n, chunk_tokens=chunk,
+                chunks=-(-len(tail) // chunk)):
+            tok, key, since = dispatch(0)
+            for off in range(chunk, len(tail), chunk):
+                nxt: list = []
+                with self._lock:
+                    resident = any(r is not None for r in self._slots)
+                if resident:
+                    self._decode_once(
+                        queue_behind=lambda off=off: nxt.append(dispatch(off)))
+                tok.block_until_ready()  # behind a round: done already
+                landed(since)
+                tok, key, since = nxt[0] if nxt else dispatch(off)
+            tok = int(tok)  # device sync: the prompt is in the pages HERE
+            landed(since)
         self._tables[slot, :] = table_row
         self._tables_dev = None
         if draft_row is not None:
@@ -1759,12 +1817,17 @@ class ServeEngine:
         M.SERVE_PREFILL_HANDOFFS.labels(
             outcome="exported" if volume_id else "export_failed").inc()
 
-    def _decode_once(self) -> None:
+    def _decode_once(self, queue_behind=None) -> None:
         """One decode round over every resident slot: a speculative
         draft-propose / target-verify round when a draft model is
         configured, the valve is open and any live slot holds a draft
         cache; one plain lockstep decode step otherwise (a closed
-        valve's plain rounds tick the re-probe cooldown)."""
+        valve's plain rounds tick the re-probe cooldown).
+
+        ``queue_behind`` is called between the round's dispatch and the
+        fetch that waits for it: what it dispatches runs on the device
+        right behind the round, while the host fetches and emits (a
+        chunked prefill's next slice: ``_prefill_chunked``)."""
         # Chaos lever: an armed fault here wedges the engine — the run
         # loop's catch-all fails every request and stops admissions (a
         # crashed-but-still-listening replica).
@@ -1776,13 +1839,13 @@ class ServeEngine:
                         r is not None and self._spec_row[i]
                         for i, r in enumerate(self._slots))
                 if any_spec:
-                    self._spec_once()
+                    self._spec_once(queue_behind)
                     return
             elif self._valve.tick_plain():
                 from_context().info(
                     "speculation re-probing after cooldown",
                     reprobe_rounds=self._valve.reprobe_rounds)
-        self._plain_once()
+        self._plain_once(queue_behind)
 
     def _observe_ici(self, live) -> None:
         """One ICI-allreduce observation per target dispatch (sharded
@@ -1797,7 +1860,7 @@ class ServeEngine:
             shardlib.time_allreduce(self.shard),
             self._trace_id(live[0][1]) if live else "")
 
-    def _spec_once(self) -> None:
+    def _spec_once(self, queue_behind=None) -> None:
         """One speculative round: the draft proposes K tokens per row
         (K fused decode steps over its own page pool), the target
         verifies all K in ONE multi-token forward, and each live row
@@ -1904,7 +1967,7 @@ class ServeEngine:
             # The round's operands die under the name, as in _plain_once.
             del d_tokens, d_pos, d_keys, draft_toks, draft_logits
 
-    def _plain_once(self) -> None:
+    def _plain_once(self, queue_behind=None) -> None:
         """One lockstep decode step over every resident slot; idle rows
         compute a discarded garbage token.
 
@@ -1927,12 +1990,20 @@ class ServeEngine:
                 self._tables_dev = jnp.asarray(self._tables)
         d_tokens, d_pos, d_keys, d_temps = self._dev
         with tracing.annotate("serve.dispatch"):
-            tok, self._cache, keys, pos = self._step(
+            tok, self._cache, keys, pos, *load = self._step(
                 self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
                 self._tables_dev)
         self._dev = (tok, pos, keys, d_temps)
+        if queue_behind is not None:
+            queue_behind()
         with tracing.annotate("serve.fetch"):
-            tok = np.asarray(tok)  # forces the step; the only per-step fetch
+            # Forces the step; the only per-step fetch (a dropless expert
+            # model's two load numbers ride the same round trip).
+            if load:
+                tok, load = self._jax.device_get((tok, load[0]))
+                self._expert_load += (1.0, *load)
+            else:
+                tok = np.asarray(tok)
         self._target_steps += 1
         with self._lock:
             live = [(i, r) for i, r in enumerate(self._slots) if r is not None]

@@ -9,7 +9,7 @@ of the tier lattice:
     hbm (PagePool page, zero-copy shareable)
       |  demote: D2H copy on eviction of a store-only page
       v
-    host (numpy K/V block in this LRU, bounded by --kv-host-bytes)
+    host (numpy block in this LRU, bounded by --kv-host-bytes)
       |  promote: H2D re-stage into a freshly allocated page on a hit
       v
     volume (serve/kvvolume.py: content-addressed blob on a controller)
@@ -43,16 +43,17 @@ from oim_tpu.common import metrics as M
 
 
 class _HostBlock:
-    """One demoted block: K and V for ``page_tokens`` positions of one
-    chain hash, as host numpy arrays [L, page_tokens, kv_heads, hd]."""
+    """One demoted block: what ``page_tokens`` positions of one chain hash
+    keep in the cache, as one host numpy array [L, page_tokens, ...] a
+    leaf of the pool, in the order of the pool's sorted leaf names ((k, v)
+    for GQA, (kv,) for a latent pool)."""
 
-    __slots__ = ("key", "k", "v", "nbytes")
+    __slots__ = ("key", "leaves", "nbytes")
 
-    def __init__(self, key: str, k: np.ndarray, v: np.ndarray):
+    def __init__(self, key: str, leaves: tuple):
         self.key = key
-        self.k = k
-        self.v = v
-        self.nbytes = int(k.nbytes + v.nbytes)
+        self.leaves = leaves
+        self.nbytes = int(sum(a.nbytes for a in leaves))
 
 
 class HostTier:
@@ -72,12 +73,12 @@ class HostTier:
             M.KVTIER_HOST_PAGES.set(0)
             M.KVTIER_HOST_BYTES.set(0)
 
-    def put(self, key: str, k: np.ndarray, v: np.ndarray) -> bool:
+    def put(self, key: str, *leaves: np.ndarray) -> bool:
         """Admit one demoted block (MRU), LRU-evicting to fit. False
         when the tier is disabled or the block alone exceeds the
         budget (the chain is simply dropped, as pre-tier eviction
         always did)."""
-        block = _HostBlock(key, k, v)
+        block = _HostBlock(key, leaves)
         with self._lock:
             if block.nbytes > self.capacity_bytes:
                 return False
@@ -96,14 +97,14 @@ class HostTier:
             M.KVTIER_DEMOTIONS.inc()
         return True
 
-    def get(self, key: str) -> tuple[np.ndarray, np.ndarray] | None:
-        """The block's (k, v), MRU-touched; None when absent."""
+    def get(self, key: str) -> tuple | None:
+        """The block's leaves, MRU-touched; None when absent."""
         with self._lock:
             block = self._blocks.get(key)
             if block is None:
                 return None
             self._blocks.move_to_end(key)
-            return block.k, block.v
+            return block.leaves
 
     def pop(self, key: str, promoted: bool = True) -> bool:
         """Remove a block — the promotion's second half (the bytes are
@@ -163,75 +164,61 @@ class HostTier:
 
 # -- device <-> host block movement (engine-thread only) -----------------
 
-def page_kv(cache: dict, page: int) -> tuple[np.ndarray, np.ndarray]:
-    """D2H: one physical page's (k, v) as host arrays
-    [L, page_tokens, kv_heads, head_dim]. Reads the engine's device
-    pool, so engine-thread only (the buffers are donated to the step
-    programs between the engine's own dispatches)."""
-    return (np.asarray(cache["k"][:, page]),
-            np.asarray(cache["v"][:, page]))
+def page_kv(cache: dict, page: int) -> tuple:
+    """D2H: one physical page as host arrays [L, page_tokens, ...], one a
+    leaf of the pool in sorted-name order ((k, v) for GQA, (kv,) for a
+    latent pool). Reads the engine's device pool, so engine-thread only
+    (the buffers are donated to the step programs between the engine's
+    own dispatches)."""
+    return tuple(np.asarray(cache[name][:, page]) for name in sorted(cache))
 
 
 @functools.lru_cache(maxsize=64)
-def _stage_program(shape: tuple, dtype_name: str):
-    """H2D re-stage, jitted once per pool geometry and shared across
-    engines (the _target_programs discipline). The pool operands are
-    DONATED so writing one page never copies the whole pool — the
-    promotion's device cost is one page's H2D plus an aliased update."""
+def _stage_program(geometry: tuple):
+    """H2D re-stage of one page or of N (``pages`` a scalar or [N]),
+    jitted once per pool geometry and shared across engines (the
+    _target_programs discipline). The pool is DONATED so writing a page
+    never copies the whole pool — the promotion's device cost is the
+    pages' H2D plus an aliased update."""
     import jax
 
-    def stage(pool_k, pool_v, page, k, v):
-        return (pool_k.at[:, page].set(k),
-                pool_v.at[:, page].set(v))
+    def stage(pool, pages, blocks):
+        return {name: pool[name].at[:, pages].set(block)
+                for name, block in zip(sorted(pool), blocks)}
 
-    del shape, dtype_name  # cache keys only: geometry selects the HLO
-    return jax.jit(stage, donate_argnums=(0, 1))
+    del geometry  # cache key only: geometry selects the HLO
+    return jax.jit(stage, donate_argnums=(0,))
 
 
-def stage_page(cache: dict, page: int, k: np.ndarray,
-               v: np.ndarray) -> dict:
-    """H2D: write (k, v) into physical ``page`` of the device pool,
-    returning the NEW pool dict (the old buffers are donated, matching
-    the engine's cache-threading discipline). Engine-thread only."""
+def _geometry(cache: dict) -> tuple:
+    return tuple((name, tuple(a.shape), str(a.dtype))
+                 for name, a in sorted(cache.items()))
+
+
+def stage_page(cache: dict, page: int, *leaves: np.ndarray) -> dict:
+    """H2D: write one block's leaves (``page_kv`` order) into physical
+    ``page`` of the device pool, returning the NEW pool dict (the old
+    buffers are donated, matching the engine's cache-threading
+    discipline). Engine-thread only."""
     import jax.numpy as jnp
 
-    fn = _stage_program(tuple(cache["k"].shape), str(cache["k"].dtype))
-    new_k, new_v = fn(cache["k"], cache["v"], jnp.int32(page),
-                      jnp.asarray(k), jnp.asarray(v))
-    return {"k": new_k, "v": new_v}
+    return _stage_program(_geometry(cache))(
+        cache, jnp.int32(page), tuple(jnp.asarray(a) for a in leaves))
 
 
-@functools.lru_cache(maxsize=64)
-def _stage_many_program(n: int, shape: tuple, dtype_name: str):
-    """Batched H2D re-stage: N pages in one scatter. Compiled per
-    (chain length, pool geometry) — adoption lengths repeat, so the
-    cache stays tiny."""
-    import jax
-
-    def stage(pool_k, pool_v, pages, ks, vs):
-        return (pool_k.at[:, pages].set(ks),
-                pool_v.at[:, pages].set(vs))
-
-    del n, shape, dtype_name  # cache keys only
-    return jax.jit(stage, donate_argnums=(0, 1))
-
-
-def stage_pages(cache: dict, pages: list, ks: list, vs: list) -> dict:
-    """H2D: write N blocks into N pool pages in ONE jitted scatter,
-    returning the NEW pool dict. A peer-fetch adoption stages whole
-    chains at once; per-page dispatch overhead would eat a good slice
-    of the prefill it is there to save. Engine-thread only."""
+def stage_pages(cache: dict, pages: list, blocks: list) -> dict:
+    """H2D: write N blocks (each a tuple of leaves in ``page_kv`` order)
+    into N pool pages in ONE jitted scatter, returning the NEW pool dict.
+    A peer-fetch adoption stages whole chains at once; per-page dispatch
+    overhead would eat a good slice of the prefill it is there to save.
+    Compiled per (chain length, pool geometry) — adoption lengths repeat.
+    Engine-thread only."""
     import jax.numpy as jnp
 
     if len(pages) == 1:
-        return stage_page(cache, pages[0], ks[0], vs[0])
-    fn = _stage_many_program(len(pages), tuple(cache["k"].shape),
-                             str(cache["k"].dtype))
-    # Stack along axis 1: pool layout is [L, page, tok, kvh, hd], so
-    # the scatter operand is [L, N, tok, kvh, hd].
-    new_k, new_v = fn(
-        cache["k"], cache["v"],
-        jnp.asarray(np.asarray(pages, np.int32)),
-        jnp.asarray(np.stack(ks, axis=1)),
-        jnp.asarray(np.stack(vs, axis=1)))
-    return {"k": new_k, "v": new_v}
+        return stage_page(cache, pages[0], *blocks[0])
+    # Stack along axis 1: a leaf is [L, page, tok, ...], so the scatter
+    # operand is [L, N, tok, ...].
+    return _stage_program(_geometry(cache))(
+        cache, jnp.asarray(np.asarray(pages, np.int32)),
+        tuple(jnp.asarray(np.stack(leaf, axis=1)) for leaf in zip(*blocks)))

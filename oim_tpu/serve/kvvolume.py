@@ -38,7 +38,7 @@ from oim_tpu.common import metrics as M
 from oim_tpu.common.logging import from_context
 from oim_tpu.serve.weights import _dtype_name, _leaf_dtype
 
-_MAGIC = b"OIMK0001"
+_MAGIC = b"OIMK0002"  # 0002: one shape a pool leaf ("shapes"), not k/v
 
 # Volume-id prefix for exported chains: the id is a pure function of
 # the chain (deepest hash names all of it — chain hashes are
@@ -52,13 +52,21 @@ def config_fingerprint(cfg, page_tokens: int) -> dict:
     fingerprints match hold interchangeable pages; a mismatch (other
     model, other page size) makes a fetched blob unusable and the
     unpack refuses it."""
-    return {
+    latent = bool(getattr(cfg, "kv_lora_rank", 0))
+    fp = {
         "n_layers": int(cfg.n_layers),
-        "n_kv_heads": int(cfg.n_kv_heads),
-        "head_dim": int(cfg.head_dim),
+        # The cache's kind and, per leaf, what a position keeps: a latent
+        # volume must never be adopted by a GQA replica, nor the reverse,
+        # even where the byte counts happen to agree.
+        "cache": "latent" if latent else "gqa",
+        "leaves": {name: [int(d) for d in tail]
+                   for name, tail in sorted(cfg.cache_leaves.items())},
         "dtype": _dtype_name(np.dtype(cfg.dtype)),
         "page_tokens": int(page_tokens),
     }
+    if not latent:
+        fp.update(n_kv_heads=int(cfg.n_kv_heads), head_dim=int(cfg.head_dim))
+    return fp
 
 
 def chain_volume_id(hashes: Sequence[str]) -> str:
@@ -72,10 +80,11 @@ def chain_volume_id(hashes: Sequence[str]) -> str:
 
 def pack_chain(hashes: Sequence[str], blocks, block: int,
                fingerprint: dict) -> bytes:
-    """Serialize a chain's blocks — ``blocks[i]`` is the (k, v) host
-    arrays for ``hashes[i]`` — into one self-describing blob: magic +
-    uint64 header length + sorted-keys JSON manifest + raw K/V bytes
-    per block in chain order. Deterministic for a given chain, so
+    """Serialize a chain's blocks — ``blocks[i]`` is the tuple of host
+    arrays for ``hashes[i]``, one a leaf of the pool (``kvtier.page_kv``:
+    (k, v) for GQA, (kv,) for a latent pool) — into one self-describing
+    blob: magic + uint64 header length + sorted-keys JSON manifest + raw
+    bytes per block in chain order, leaf after leaf. Deterministic for a given chain, so
     identical prefixes pack to identical bytes on every replica and
     content-address to one stage-cache entry."""
     if len(blocks) != len(hashes):
@@ -84,31 +93,30 @@ def pack_chain(hashes: Sequence[str], blocks, block: int,
             f"{len(blocks)} blocks")
     if not hashes:
         raise ValueError("refusing to pack an empty chain")
-    k0, v0 = blocks[0]
-    k0, v0 = np.ascontiguousarray(k0), np.ascontiguousarray(v0)
+    first = [np.ascontiguousarray(a) for a in blocks[0]]
+    shapes = [a.shape for a in first]
+    block_bytes = int(sum(a.nbytes for a in first))
     header = json.dumps({
         "chain": list(hashes),
         "block": int(block),
         "fingerprint": fingerprint,
-        "k_shape": list(k0.shape),
-        "v_shape": list(v0.shape),
-        "dtype": _dtype_name(k0.dtype),
-        "block_bytes": int(k0.nbytes + v0.nbytes),
-        "total_bytes": int((k0.nbytes + v0.nbytes) * len(blocks)),
+        "shapes": [list(shape) for shape in shapes],
+        "dtype": _dtype_name(first[0].dtype),
+        "block_bytes": block_bytes,
+        "total_bytes": block_bytes * len(blocks),
     }, sort_keys=True).encode()
     out = bytearray()
     out += _MAGIC
     out += struct.pack("<Q", len(header))
     out += header
-    for k, v in blocks:
-        k = np.ascontiguousarray(k)
-        v = np.ascontiguousarray(v)
-        if k.shape != k0.shape or v.shape != v0.shape:
+    for leaves in blocks:
+        leaves = [np.ascontiguousarray(a) for a in leaves]
+        if [a.shape for a in leaves] != shapes:
             raise ValueError("ragged chain blocks cannot pack")
-        # memoryview, not the array: bytearray += ndarray is
-        # elementwise add, not concatenation (weights.py discipline).
-        out += memoryview(k).cast("B")
-        out += memoryview(v).cast("B")
+        for a in leaves:
+            # memoryview, not the array: bytearray += ndarray is
+            # elementwise add, not concatenation (weights.py discipline).
+            out += memoryview(a).cast("B")
     return bytes(out)
 
 
@@ -139,18 +147,16 @@ def unpack_chain(buf, fingerprint: dict | None = None):
             f"truncated KV-chain blob: {len(data) - base} payload "
             f"bytes, manifest claims {header['total_bytes']}")
     dtype = _leaf_dtype(header["dtype"])
-    k_shape = tuple(header["k_shape"])
-    v_shape = tuple(header["v_shape"])
-    k_bytes = int(np.prod(k_shape)) * dtype.itemsize
-    v_bytes = int(np.prod(v_shape)) * dtype.itemsize
+    shapes = [tuple(shape) for shape in header["shapes"]]
     blocks = []
     off = base
     for _ in header["chain"]:
-        k = data[off:off + k_bytes].view(dtype).reshape(k_shape)
-        off += k_bytes
-        v = data[off:off + v_bytes].view(dtype).reshape(v_shape)
-        off += v_bytes
-        blocks.append((k, v))
+        leaves = []
+        for shape in shapes:
+            nbytes = int(np.prod(shape)) * dtype.itemsize
+            leaves.append(data[off:off + nbytes].view(dtype).reshape(shape))
+            off += nbytes
+        blocks.append(tuple(leaves))
     return list(header["chain"]), blocks, int(header["block"])
 
 

@@ -1,8 +1,10 @@
 """Host-side accounting for the paged KV cache: page ids, refcounts,
 and the free list.
 
-The device arrays — {"k","v"} of [L, n_pages, page_tokens, kv_heads,
-head_dim], created by ``models/generate.py init_page_pool`` — belong to
+The device arrays — one [L, n_pages, page_tokens, ...] array a leaf of the
+model's ``Config.cache_leaves`` ({"k","v"} [.., kv_heads, head_dim] for
+GQA, {"kv"} [.., 640] for latent attention), created by
+``models/generate.py init_page_pool`` — belong to
 the engine and flow through its jitted step programs. This class owns
 everything the HOST must know about them: which physical pages are
 free, how many references each allocated page holds (a live slot's page
@@ -35,8 +37,8 @@ class PagePool:
     """Thread-safe page-id allocator over ``n_pages`` usable pages
     (physical ids 1..n_pages; 0 is the reserved scratch page).
 
-    ``page_bytes`` is the device footprint of one page's K+V across all
-    layers — the unit the prefix store's byte budget is charged in.
+    ``page_bytes`` is the device footprint of one page across all layers
+    and all leaves of the pool (``generate.page_bytes``) — the unit the prefix store's byte budget is charged in.
     """
 
     def __init__(self, n_pages: int, page_tokens: int, page_bytes: int = 0,

@@ -75,14 +75,15 @@ def param_specs(params):
         lambda path, _: leaf_spec(path[-1].key), params)
 
 
-def pool_specs():
-    """Page-pool spec: K/V [L, n_pages, page_tokens, n_kv_heads, hd]
-    shard the KV-head axis — pages live whole on every member, each
-    member holding its own heads' slice of every page."""
+def pool_specs(leaves=("k", "v")):
+    """Page-pool spec, one a leaf of the pool: a GQA leaf [L, n_pages,
+    page_tokens, n_kv_heads, hd] shards the KV-head axis — pages live
+    whole on every member, each member holding its own heads' slice of
+    every page. (A latent pool has no head axis to split: the engine
+    refuses it under shard > 1, generate.shard_config.)"""
     from jax.sharding import PartitionSpec as P
 
-    spec = P(None, None, None, "tp", None)
-    return {"k": spec, "v": spec}
+    return {name: P(None, None, None, "tp", None) for name in leaves}
 
 
 @functools.lru_cache(maxsize=8)

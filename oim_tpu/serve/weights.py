@@ -112,16 +112,52 @@ def unpack_params(buf) -> dict:
     return tree
 
 
+# Leaves a checkpoint may carry and serving never reads, by the name of any
+# key on their path: the multi-token-prediction module of the DeepSeek-V3
+# family (``num_nextn_predict_layers``). The main model's forward is whole
+# without it, and serve/spec.py drafts from a separate model only.
+IGNORED_KEYS = ("mtp", "nextn")
+
+
 def _insert(tree: dict, keystr: str, leaf) -> None:
     """Place a leaf at a jax.tree_util.keystr path like
-    "['layers']['wq']" — dict keys only (the llama param tree)."""
+    "['layers']['wq']" — dict keys only (the llama param tree). A leaf
+    under an ``IGNORED_KEYS`` name is dropped."""
     keys = re.findall(r"\['([^']+)'\]", keystr)
     if "".join(f"['{k}']" for k in keys) != keystr or not keys:
         raise ValueError(f"unsupported tree path {keystr!r}")
+    if any(k.startswith(IGNORED_KEYS) for k in keys):
+        return
     node = tree
     for k in keys[:-1]:
         node = node.setdefault(k, {})
     node[keys[-1]] = leaf
+
+
+def rope_split_half(params: dict, cfg) -> dict:
+    """A latent-attention tree whose rope dims are in the PUBLISHED
+    interleaved order (``rope_interleave: true``: pairs (2i, 2i+1)), in
+    the order the program rotates (split-half): the ``qk_rope_head_dim``
+    output columns of every head of ``wq_b`` and of ``wkv_a`` permuted by
+    ``ops.rope.interleaved_to_split_half``. Query and key are permuted
+    alike, so every score is unchanged (tests/test_latent.py)."""
+    from oim_tpu.ops.rope import interleaved_to_split_half
+
+    m = cfg.latent
+    perm = np.asarray(interleaved_to_split_half(m.rope))
+    head = np.concatenate([np.arange(m.nope), m.nope + perm])
+    q_cols = (np.arange(m.heads)[:, None] * (m.nope + m.rope)
+              + head[None, :]).reshape(-1)
+    kv_cols = np.concatenate([np.arange(m.rank), m.rank + perm])
+
+    def group(layers):
+        return {**layers, "wq_b": layers["wq_b"][..., q_cols],
+                "wkv_a": layers["wkv_a"][..., kv_cols]}
+
+    from oim_tpu.models.llama import LAYER_GROUPS
+
+    return {k: group(v) if k in LAYER_GROUPS else v
+            for k, v in params.items()}
 
 
 def save_packed(params: Any, path: str) -> int:

@@ -382,3 +382,78 @@ def test_byte_buffer_past_int32_is_refused(topo, no_compile_cache):
     small = jax.ShapeDtypeStruct((1 << 30,), jnp.uint8, sharding=chip)
     off = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
     plane._updater(False).lower(small, chunk, off).compile()
+
+
+# -- the latent pool of joyai-llm-flash.longctx, at its own sizes ------------
+
+
+def latent_cell(sharding):
+    from benchmarks import common
+    from benchmarks.runners import serve_family
+
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "joyai-llm-flash.json"))
+    model = serve_family.model_dict(config, "serve")
+    cfg = serve_family.program_config(model)
+    sizes = config["serve"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(lambda: gen.init_page_pool(
+        cfg, sizes["kv_pool_tokens"] // PAGE + 1, PAGE)), sharding)
+    return cfg, params, pool, sizes, model["max_seq"]
+
+
+def test_latent_widths_are_the_published_ones(topo):
+    cfg, params, pool, sizes, seq = latent_cell(
+        SingleDeviceSharding(topo.devices[0]))
+    assert dataclasses.replace(cfg, n_layers=40, max_seq=131072) \
+        == llama.JOYAI_LLM_FLASH
+    assert pool["kv"].shape == (5, 18433, 16, 640) and set(pool) == {"kv"}
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves((params, pool)))
+    assert 12.9e9 < held < 13.1e9  # 11.12 GB of weights + 1.89 GB of pool
+
+
+def test_latent_decode_updates_the_pool_in_place(topo, as_tpu):
+    """The decode program of joyai-llm-flash.longctx: nothing copies,
+    restacks or gathers an array of the pool's shape (the scatter of the
+    step's 32 entries and the blockwise reads alias the donated buffer),
+    no [B, S, width] gathered view exists, and arguments + temporaries fit
+    the chip."""
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg, params, pool, sizes, seq = latent_cell(chip)
+    b = sizes["max_batch"]
+    step, _ = _target_programs(cfg, PAGE, seq)
+    compiled = step.lower(params, pool, *step_operands(chip, b, seq)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert not moves_of(text, pool["kv"].shape)
+    assert not moves_of(text, (b, seq, 640), (640,))
+    assert mem.alias_size_in_bytes >= math.prod(pool["kv"].shape) * 2
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("bucket", [2048, 64])
+def test_latent_prefill_chunk_carries_the_pool(topo, as_tpu, bucket):
+    """A prefill chunk (the configuration's, and a last piece's small
+    bucket): the pool in place, no [H, T, S] score array (blockwise over
+    key blocks: 8.6 GB in float32 at 32 heads, 2048 x 32768), one row of
+    logits, and arguments + temporaries inside 15.75 GB."""
+    from oim_tpu.serve.engine import _target_programs
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    cfg, params, pool, sizes, seq = latent_cell(chip)
+    _, prefill = _target_programs(cfg, PAGE, seq)
+    compiled = prefill.lower(
+        params, pool, *prefill_operands(chip, bucket, seq)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert not moves_of(text, pool["kv"].shape)
+    assert f"f32[{cfg.n_heads},{bucket},{seq}]" not in text
+    assert f"f32[1,{cfg.n_heads},{bucket},{seq}]" not in text
+    if bucket != cfg.dim:  # [dim, vocab] is the head itself
+        assert f"f32[{bucket},{cfg.vocab}]" not in text  # the last row only
+        assert f"f32[1,{bucket},{cfg.vocab}]" not in text
+    assert mem.temp_size_in_bytes < 1.25 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

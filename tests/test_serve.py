@@ -134,6 +134,63 @@ class TestEngineInvariants:
         for (p, n, t, s), out in zip(reqs, outs):
             assert out == solo_tokens(params, cfg, p, n, t, s), (p, t, s)
 
+    def test_chunked_prefill_puts_a_decode_round_between_two_admissions(
+            self, model):
+        """With ``prefill_chunk`` a resident never waits for more than one
+        slice: between any two prefill dispatches (two slices of one
+        prompt, or the last slice of one admission and the first of the
+        next) the residents get a decode round. Tokens stay solo's."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=4, max_seq=64,
+                          queue_depth=8, prefill_chunk=8)
+        order = []
+        prefill, decode = eng._prefill, eng._decode_once
+        eng._prefill = lambda *a: (order.append("slice"), prefill(*a))[1]
+        eng._decode_once = lambda **kw: (order.append("round"), decode(**kw))[1]
+        try:
+            first = eng.submit([1, 2, 3], max_new=40)
+            assert wait_for(lambda: "round" in order)
+            prompts = [list(range(1, 21)), list(range(30, 42)), [5, 6, 7]]
+            # a round takes milliseconds: all three queue during one
+            handles = [eng.submit(p, max_new=4) for p in prompts]
+            outs = [h.result(timeout=120) for h in handles]
+            first.result(timeout=120)
+        finally:
+            eng.stop(timeout=30)
+        assert order.count("slice") == 1 + 3 + 2 + 1
+        assert "slice slice" not in " ".join(order)
+        for p, out in zip(prompts, outs):
+            assert out == solo_tokens(params, cfg, p, 4)
+
+    def test_chunked_prefill_keeps_the_device_a_dispatch_ahead(self, model):
+        """No slice but a prompt's last is waited for: with a resident,
+        the round is dispatched behind the slice in flight and the next
+        slice behind the round, BEFORE the round's tokens are fetched and
+        emitted — so the device never idles between them, and the host's
+        time is in no resident's gap across a slice. Tokens stay solo's."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=4, max_seq=64,
+                          queue_depth=8, prefill_chunk=8)
+        order = []
+        prefill, step, emit = eng._prefill, eng._step, eng._emit
+        eng._prefill = lambda *a: (order.append("slice"), prefill(*a))[1]
+        eng._step = lambda *a: (order.append("step"), step(*a))[1]
+        eng._emit = lambda *a: (order.append("emit"), emit(*a))[1]
+        try:
+            first = eng.submit([1, 2, 3], max_new=40)
+            assert wait_for(lambda: "step" in order)
+            del order[:]
+            prompt = list(range(1, 30))  # 4 slices: 8, 8, 8, 5
+            out = eng.submit(prompt, max_new=4).result(timeout=120)
+            first.result(timeout=120)
+        finally:
+            eng.stop(timeout=30)
+        at = [i for i, what in enumerate(order) if what == "slice"]
+        assert len(at) == 4
+        for i in at[1:]:  # each later slice: right behind a round's dispatch
+            assert order[i - 1] == "step" and order[i + 1] == "emit", order
+        assert out == solo_tokens(params, cfg, prompt, 4)
+
     def test_slot_reuse_leaks_nothing(self, model):
         """A slot's next occupant sees a zero cache: with max_batch=1
         every request reuses THE slot, and each must still match solo —

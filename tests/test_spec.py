@@ -351,6 +351,27 @@ class TestSpecEngine:
         finally:
             eng.stop(drain=False, timeout=30)
 
+    def test_chunked_prefill_behind_a_speculative_round(self, model):
+        """With ``prefill_chunk`` a long prompt's next slice is queued on
+        the device behind the residents' round — here a speculative one
+        (propose + verify) — before that round's tokens are fetched:
+        resident and newcomer both stay byte-identical to solo."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=2, max_seq=64,
+                          queue_depth=16, draft_params=params,
+                          draft_cfg=cfg, spec_tokens=3, prefill_chunk=8)
+        try:
+            resident = eng.submit([3, 1, 4], max_new=40, seed=1)
+            assert wait_for(lambda: eng.stats()["spec_proposed"] > 0)
+            prompt = [1 + i % 50 for i in range(29)]  # 4 slices: 8, 8, 8, 5
+            late = eng.submit(prompt, max_new=6, seed=2)
+            assert late.result(timeout=300) == solo_tokens(
+                params, cfg, prompt, 6, seed=2)
+            assert resident.result(timeout=300) == solo_tokens(
+                params, cfg, [3, 1, 4], 40, seed=1)
+        finally:
+            eng.stop(drain=False, timeout=30)
+
     def test_mid_batch_mixed_spec_and_plain_slots(self, model):
         """A draft pool sized for ONE request: the second concurrent
         admission gets no draft slot and decodes plainly in the same
