@@ -4,10 +4,10 @@ that reads live pages where they lie (TPU) + the gather reference
 
 The pool here is the GQA one, {"k","v"} [L, n_pages, page_tokens, kv_heads,
 head_dim] (models/generate.py ``init_page_pool``; a latent-attention model's
-one-leaf pool is read by ``ops/latent_attention.py``); logical position s of
-row b lives at pool[l, tables[b, s // page], s % page]. Both paths take the WHOLE pool
-and the layer index, never a per-layer slice: a slice of a carried buffer
-is a copy of it.
+one-leaf pool has its own decode kernel and rule in ``ops/latent_attention.py``);
+logical position s of row b lives at pool[l, tables[b, s // page], s % page].
+Both paths take the WHOLE pool and the layer index, never a per-layer slice:
+a slice of a carried buffer is a copy of it.
 
 - ``gather_attention``: materialize each row's logical [S] cache through
   its table, then ``cache_attention`` — the same function the solo dense

@@ -623,15 +623,21 @@ class ServeEngine:
         self._step, self._prefill = _target_programs(
             cfg, page, max_seq, self.shard)
         # Which attention the decode program takes — the dispatch rule
-        # of ops/paged_attention.py on this engine's (member-local)
-        # shapes, the word the program logs when it is traced. stats()
-        # carries it, so a replica that silently missed the kernel can
-        # be told from its serve/<id> row.
+        # of ops/paged_attention.py (ops/latent_attention.py for a latent
+        # cache) on this engine's (member-local) shapes, the word the
+        # program logs when it is traced. stats() carries it, so a
+        # replica that silently missed the kernel can be told from its
+        # serve/<id> row.
         from oim_tpu.ops import latent_attention, paged_attention
 
         self.cache_kind = "latent" if cfg.kv_lora_rank else "gqa"
+        tables = jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32)
         if cfg.kv_lora_rank:
-            self.decode_attention = latent_attention.kernel_name(1)
+            d = cfg.latent
+            self.decode_attention = latent_attention.kernel_name(
+                jax.ShapeDtypeStruct(
+                    (max_batch, 1, d.heads, d.nope + d.rope), cfg.dtype),
+                self._cache["kv"], tables, d)
         else:
             lcfg = gen.shard_config(cfg, self.shard)
             pool_k = self._cache["k"]
@@ -641,7 +647,7 @@ class ServeEngine:
                 jax.ShapeDtypeStruct(
                     pool_k.shape[:3] + (lcfg.n_kv_heads, cfg.head_dim),
                     pool_k.dtype),
-                jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32))
+                tables)
         # Expert load of a dropless expert model's decode steps, summed:
         # [steps counted, experts that got a row (mean over the expert
         # layers), rows of the fullest expert over the mean]. stats()

@@ -13,6 +13,7 @@ file (one xdist worker loads the TPU library; nothing at import time).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import json
 import math
 import os
@@ -27,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 import chip_smoke
 from oim_tpu.models import generate as gen
 from oim_tpu.models import llama
+from oim_tpu.ops import latent_attention
 from oim_tpu.ops.attention import _flash_plan, attention
 from oim_tpu.train import TrainConfig
 
@@ -167,6 +169,8 @@ def test_prefill_bucket(topo, as_tpu, bucket):
 # The benchmark's two serving cells at their own sizes (benchmarks/configs).
 
 CELLS = {"chat": ("mistral-7b", 1024), "batch": ("mixtral-8x7b", 512)}
+# sha256 (first 16 digits) of each cell's decode kernel, see mosaic_kernels.
+PAGED_KERNEL = {"chat": "98b1c7d9bae56d91", "batch": "da7dd844297494c1"}
 MOVES = ("copy", "copy-start", "dynamic-update-slice", "gather")
 _INSTRUCTION = re.compile(
     r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
@@ -187,6 +191,40 @@ def moves_of(text: str, shape, tail=()):
             if math.prod(dims) == count and dims[len(dims) - len(tail):] \
                     == tuple(tail):
                 found.append((m.group(3), m.group(1), dims))
+    return found
+
+
+def mosaic_kernels(text: str) -> list:
+    """(kernel function's name, the HLO line of its call, its Mosaic module
+    as text WITHOUT debug locations) of each Pallas call in an optimized
+    HLO. The serialized module carries the checkout's path and every source
+    line; without them the text is the kernel's body alone, the same in
+    any checkout until the kernel itself (or the shapes it is built for)
+    changes."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        config = json.loads(line[line.index("backend_config=") + 15:])
+        body = config["custom_call_config"].get("body")
+        if not body:
+            continue
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # stable_mosaic.*
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            attrs = module.operation.attributes
+            if "sym_name" in attrs:  # XLA's own (ragged dot) have no name
+                found.append((
+                    ir.StringAttr(attrs["sym_name"]).value, line.strip(),
+                    module.operation.get_asm(enable_debug_info=False)))
     return found
 
 
@@ -236,6 +274,13 @@ def test_cell_decode_reads_live_pages_in_place(topo, as_tpu, cell):
         params, pool, *step_operands(chip, b, seq)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # The GQA kernel as PR 27 left it (commit 518e4ec; PR 29 gave the
+    # latent pool a kernel of its own and held these two programs to their
+    # text): an edit of ops/paged_attention.py that moves this moves both
+    # cells' decode programs; measure them, then record the new text's hash.
+    assert {(name, hashlib.sha256(body.encode()).hexdigest()[:16])
+            for name, _, body in mosaic_kernels(text)} == {
+        ("_paged_kernel", PAGED_KERNEL[cell])}
     assert not moves_of(text, pool["k"].shape)
     tail = (cfg.n_kv_heads, cfg.head_dim)
     assert not moves_of(text, (b, seq) + tail, tail)
@@ -415,11 +460,14 @@ def test_latent_widths_are_the_published_ones(topo):
 
 
 def test_latent_decode_updates_the_pool_in_place(topo, as_tpu):
-    """The decode program of joyai-llm-flash.longctx: nothing copies,
-    restacks or gathers an array of the pool's shape (the scatter of the
-    step's 32 entries and the blockwise reads alias the donated buffer),
-    no [B, S, width] gathered view exists, and arguments + temporaries fit
-    the chip."""
+    """The decode program of joyai-llm-flash.longctx: the latent kernel is
+    in it (once a layer group) and reads the pool where it lies; nothing
+    copies, restacks or gathers an array of the pool's shape (the scatter
+    of the step's 32 entries aliases the donated buffer), no gathered view
+    exists ([B, S, width], or a block [B * pages, 16, width] of every
+    row's pages), no f32 [B, H, block] scores at the kernel's block, and
+    arguments + temporaries fit the chip."""
+    from benchmarks import common
     from oim_tpu.serve.engine import _target_programs
 
     chip = SingleDeviceSharding(topo.devices[0])
@@ -428,10 +476,36 @@ def test_latent_decode_updates_the_pool_in_place(topo, as_tpu):
     step, _ = _target_programs(cfg, PAGE, seq)
     compiled = step.lower(params, pool, *step_operands(chip, b, seq)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = mosaic_kernels(text)
+    assert [name for name, _, _ in kernels] == ["_latent_kernel"] * 2, \
+        "one call in the dense layer's loop, one in the expert layers'"
+    # The benchmark's readers tell operations by the text of their HLO
+    # line: latent_kernel_roofline's pattern finds the two calls and no
+    # other instruction; latent_attn_roofline's (a while whose carry is
+    # the online softmax's) finds the two loops that hold them, as it
+    # found the jax.numpy form's: the metric must not lose its operation.
+    lines = [re.sub(r"^\s*(ROOT )?", "", line) for line in text.splitlines()]
+    found = {}
+    for metric in ("latent_kernel_roofline", "latent_attn_roofline"):
+        op = re.compile(common.metric_spec(common.ROOT, metric)["args"]["op"])
+        found[metric] = [line for line in lines if op.search(line)]
+    assert sorted(found["latent_kernel_roofline"]) == sorted(
+        line for _, line, _ in kernels)
+    assert len(found["latent_attn_roofline"]) == 2
+    for line in found["latent_attn_roofline"]:  # each holds one call
+        body = re.search(r"body=(%[\w.\-]+)", line).group(1)
+        held = text[text.index(f"\n{body} "):]
+        assert held[:held.index("\n}")].count("tpu_custom_call") == 1
     assert not moves_of(text, pool["kv"].shape)
     assert not moves_of(text, (b, seq, 640), (640,))
+    # Positions a block: the kernel's, and the jax.numpy form's.
+    for block in (latent_attention.BLOCK_TOKENS, latent_attention.KEY_BLOCK):
+        assert not moves_of(
+            text, (b * block // PAGE, PAGE, 640), (PAGE, 640))
+        if block != cfg.kv_lora_rank:  # [B, H, rank] f32 is the carried sum
+            assert f"f32[{b},{cfg.n_heads},{block}]" not in text
     assert mem.alias_size_in_bytes >= math.prod(pool["kv"].shape) * 2
-    assert mem.temp_size_in_bytes < 1 << 30
+    assert mem.temp_size_in_bytes < 64 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 

@@ -205,6 +205,82 @@ def test_an_idle_row_reads_nothing_and_gets_zeros():
     assert np.array_equal(np.asarray(got), np.zeros((2, 1, 4, 16)))
 
 
+def latent_shapes(t=1, rank=512, page=16, dtype=jnp.bfloat16, nb=2048):
+    """(q, pool, tables, d) of a decode (t = 1) or prefill program, as
+    shapes: the cell's own by default."""
+    d = latent_attention.Dims(heads=32, rank=rank, nope=128, rope=64, v=128)
+    return (jax.ShapeDtypeStruct((32, t, 32, 192), dtype),
+            jax.ShapeDtypeStruct((5, 18433, page, d.width), dtype),
+            jax.ShapeDtypeStruct((32, nb), jnp.int32), d)
+
+
+@pytest.mark.parametrize("backend,kw,name,pages", [
+    ("tpu", {}, "pallas_latent", latent_attention.BLOCK_TOKENS // 16),
+    ("tpu", {"nb": 24}, "pallas_latent", 8),  # a block never past the table
+    ("tpu", {"dtype": jnp.float32, "page": 8}, "pallas_latent",
+     latent_attention.BLOCK_TOKENS // 8),
+    ("cpu", {}, "jnp_latent_absorbed", None),
+    ("tpu", {"rank": 448}, "jnp_latent_absorbed", None),  # values off the lanes
+    ("tpu", {"page": 8}, "jnp_latent_absorbed", None),  # half a bf16 tile
+    ("tpu", {"t": 4}, "jnp_latent_expanded", None),
+    ("cpu", {"t": 2048}, "jnp_latent_expanded", None),
+])
+def test_latent_dispatch_reads_shapes_and_backend_only(
+        monkeypatch, backend, kw, name, pages):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    shapes = latent_shapes(**kw)
+    assert latent_attention.kernel_name(*shapes) == name
+    assert latent_attention._latent_plan(*shapes) == pages
+
+
+def test_stats_say_which_attention_the_latent_decode_program_takes(
+        monkeypatch):
+    """``stats()["decode_attention"]`` is the rule's word on the engine's
+    own shapes: the kernel where a TPU finds the entry's value part whole
+    lanes (rank 128 here), the ``jax.numpy`` form for the toy's rank 16 and
+    on the CPU. No option selects it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for rank, want in ((128, "pallas_latent"), (16, "jnp_latent_absorbed")):
+        model = serve_family.model_dict({**CONFIG, "kv_lora_rank": rank})
+        cfg = serve_family.program_config(model)
+        eng = ServeEngine(wd.make_on_device(SEED, model), cfg, max_batch=2,
+                          max_seq=64, prefix_block=16, queue_depth=4)
+        try:
+            assert eng.stats()["decode_attention"] == want
+            assert eng.stats()["cache_kind"] == "latent"
+        finally:
+            eng.stop(drain=False, timeout=30)
+
+
+def test_the_decode_step_takes_the_kernel_where_the_rule_says_so(monkeypatch):
+    """``paged_attention`` at T = 1 under a TPU's rule, the kernel run in
+    interpret mode: the absorbed form's numbers, both absorptions around
+    the kernel included."""
+    import functools
+
+    d = latent_attention.Dims(heads=4, rank=128, nope=16, rope=8, v=16)
+    rng = np.random.default_rng(5)
+    B, nb, page = 3, 4, 16
+    pool = rng.normal(size=(2, 14, page, d.width)).astype(np.float32)
+    pool[..., d.rank + d.rope:] = 0.0
+    pool = jnp.asarray(pool)
+    wkv_b = jnp.asarray(rng.normal(size=(d.rank, 4 * 32)) * 0.1, jnp.float32)
+    tables = jnp.asarray([[3, 7, 1, 9], [0, 0, 0, 0], [12, 5, 0, 0]], jnp.int32)
+    pos = jnp.asarray([57, 3, 20], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, 1, 4, 24)), jnp.float32)
+    args = (q, pool, 1, tables, pos, wkv_b, d)
+    want = latent_attention.paged_attention(*args)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        latent_attention, "_latent_decode", functools.partial(
+            latent_attention._latent_decode, interpret=True))
+    assert latent_attention.kernel_name(q, pool, tables, d) == "pallas_latent"
+    got = latent_attention.paged_attention(*args)
+    assert not np.asarray(got[1]).any()  # the idle row
+    # float32, sums reordered: 1e-5 of values of order 1
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
 def skewed_experts(bias_scale):
     """A router that sends nearly every row's first choice to expert 3 and
     never reaches experts 12..15; ``bias_scale`` sizes the bias."""

@@ -601,3 +601,61 @@ def test_paged_dispatch_reads_shapes_and_backend_only(
     pk = jax.ShapeDtypeStruct((2, 9, PAGE, 8, 128), jnp.bfloat16)
     tables = jax.ShapeDtypeStruct((4, 256), jnp.int32)
     assert _paged_plan(q, pk, tables) == want
+
+
+# -- latent decode attention (ops/latent_attention.py) -----------------------
+
+
+def _latent_case(name):
+    if name == "all_idle":  # every table on the scratch page, stale positions
+        return (np.zeros((3, N_BLOCKS), np.int32),
+                np.array([0, 50, S_PAGED], np.int32))
+    return _paged_case(name)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize(
+    "case", ["ragged", "idle", "past_table", "shared", "all_idle"])
+@pytest.mark.parametrize("span", [None, 2])
+def test_latent_decode_kernel_matches_absorbed(case, dtype, layer, span):
+    """The Pallas kernel over the latent pool in interpret mode against the
+    ``jax.numpy`` absorbed sum (``_gathered_sum``: what ``_absorbed`` runs
+    off a TPU). Blocks of two pages: ``ragged`` ends rows mid-page and
+    mid-block, ``idle`` has idle rows between live ones, ``past_table`` a
+    row at the table's full length. The kernel's pool has NaN wherever no
+    live position lies (other layers, unmapped pages, the scratch page,
+    past ``pos`` in a mapped page); the reference's has zeros there.
+    ``span`` 2: a kernel call walks two table entries, so the calls' parts
+    are merged over several passes, rows running out at different ones."""
+    from oim_tpu.ops import latent_attention as la
+
+    d = la.Dims(heads=8, rank=128, nope=32, rope=16, v=32)
+    tables, pos = _latent_case(case)
+    B, L, n_pages = len(pos), 3, 24
+    rng = np.random.RandomState(len(case) + layer)
+    dtype = jnp.dtype(dtype)
+    qq = jnp.asarray(rng.randn(B, d.heads, d.width) * 0.5, dtype)
+    entries = rng.randn(L, n_pages, PAGE, d.width)
+    entries[..., d.rank + d.rope:] = 0.0  # the entry's pad
+    live = np.zeros((L, n_pages, PAGE), bool)
+    for row, p in zip(tables, pos):
+        if row[0]:
+            n = min(p + 1, S_PAGED)
+            live[layer, row[np.arange(n) // PAGE], np.arange(n) % PAGE] = True
+    assert not live[:, 0].any() and live.any() == (case != "all_idle")
+    clean = jnp.asarray(np.where(live[..., None], entries, 0.0), dtype)
+    dirty = jnp.asarray(np.where(live[..., None], entries, np.nan), dtype)
+    args = (jnp.int32(layer), jnp.asarray(tables), jnp.asarray(pos), d)
+    want = la._gathered_sum(qq, clean, *args)
+    got = la._latent_decode(qq, dirty, *args, pages=2, interpret=True,
+                            span=span)
+    assert got.shape == (B, d.heads, d.rank) and got.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    idle = tables[:, 0] == 0
+    assert np.isfinite(got).all()
+    assert not got[idle].any()  # an idle row reads nothing
+    # Outputs of O(1). bf16: two roundings of the probabilities apart;
+    # f32: the blocks' sums in another order.
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got[~idle], want[~idle], atol=tol, rtol=tol)
