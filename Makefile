@@ -9,7 +9,7 @@
 PY ?= python
 RUFF := $(shell command -v ruff 2>/dev/null)
 
-.PHONY: test pytest lint drift proto native tsan demo start stop clean replication-demo trace-demo bench-smoke serve-smoke router-smoke obs-smoke slo-smoke autoscale-smoke prefix-smoke paged-smoke spec-smoke kvtier-smoke disagg-smoke shard-smoke chaos chaos-smoke quorum-smoke control-plane-bench scalesim-smoke
+.PHONY: test pytest lint drift proto native tsan demo start stop clean replication-demo trace-demo chaos quorum-smoke
 
 # drift and tsan are standalone conveniences; the full pytest target
 # already runs both (SpecDrift + the TSAN stream test build in-fixture).
@@ -41,135 +41,6 @@ tsan:
 	$(MAKE) -C native tsan
 	$(PY) -m pytest tests/test_staging.py -q -k thread_sanitizer
 
-# Tiny CPU-only stage-and-train correctness loop (seconds, not minutes):
-# byte-identical staging through the parallel pipeline, cache-hit
-# republish, converging train steps, and the direct-data-path guards —
-# the remote read-back must serve >=1 window controller-direct and dial
-# each target at most once (per-window channel churn stays dead). Also
-# runs in tier-1 as tests/test_bench_smoke.py, so neither the pipeline
-# nor the window path can silently regress.
-bench-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --smoke
-
-# Tiny serving-plane correctness loop (seconds): weights published once
-# through the control plane (cache-hit republish proven), then an
-# open-loop streaming load through the continuous-batching engine over
-# real gRPC — every output byte-identical to its solo generate() run.
-# Also runs in tier-1 as tests/test_serve_smoke.py.
-serve-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke
-
-# Tiny request-router correctness loop (seconds): in-process registry +
-# 2 serve replicas heartbeating TTL-leased serve/<id> rows + oim-router;
-# every routed output byte-identical to its solo generate() run and >=1
-# request served per replica (the least-loaded pick must spread).
-# Also runs in tier-1 as tests/test_router_smoke.py.
-router-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --replicas 2
-
-# Prefix-cache acceptance loop (seconds): the serve smoke with half the
-# requests opening on one shared system prompt — hit_rate > 0, cached-
-# prefill tokens saved > 0, every output (hit and miss, greedy and
-# sampled) byte-identical to solo generate(); then 2 replicas behind a
-# router, with same-prefix requests herded to the replica holding the
-# prefix (oim_router_affinity_picks_total observed). Also runs in
-# tier-1 as tests/test_prefix_smoke.py.
-prefix-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --prefix-share 0.5
-
-# Paged-KV-cache acceptance loop (seconds): the serve smoke under a
-# bimodal short/long prompt mix with the page pool sized at HALF the
-# dense max_batch x max_seq reservation — every output byte-identical
-# to solo generate(), zero dropped requests (pool exhaustion
-# backpressures through the queue, never OOMs) — plus a deterministic
-# packing phase proving MORE live slots than dense slots of equal HBM
-# (a reverted max_seq-per-slot reservation fails the gate). Also runs
-# in tier-1 as tests/test_paged_smoke.py.
-paged-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --prompt-mix
-
-# Speculative-decoding acceptance loop (seconds): the serve smoke with
-# a self-draft proposing 4 tokens per verify round — every greedy
-# output byte-identical to solo generate(), acceptance rate > 0, more
-# than one decode token per target dispatch, zero pages left in EITHER
-# pool (target and draft) after a graceful drain, and the interleaved
-# spec-on vs spec-off inter-token comparison reported — plus a routed
-# mixed-fleet half (2 replicas, one speculating) proving byte-identity
-# through the router wherever the pick lands. Also runs in tier-1 as
-# tests/test_spec_smoke.py.
-spec-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --spec-tokens 4
-
-# Sharded-decode acceptance loop (seconds): ONE logical replica spans
-# 2 tensor-parallel members over a CPU mesh of fake XLA devices.
-# Gates: every rank's restore stages ONLY its slice of the one
-# published weights volume; a model whose weights+pool exceed one
-# member's HBM budget is REFUSED at shard=1 ("shard wider") and serves
-# byte-identically at shard=2; routed requests byte-identical to solo
-# generate() through a real router; SIGKILLing a non-rank-0 member's
-# lease flips the replica not-ready; zero-leak census on every member
-# pool; the ICI-allreduce histogram gains samples. Also runs in tier-1
-# as tests/test_shard_smoke.py.
-shard-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --shard 2
-
-# KV-tiering + fleet-prefix-sharing acceptance loop (seconds): replica
-# A exports a finished 28-block prefix chain as a content-addressed
-# KV-page volume through an in-process controller; replica B — which
-# never held the prefix — adopts the pages over the data path. Gates:
-# byte identity to solo generate() (greedy and sampled), first-token
-# p50 on a peer-hit STRICTLY better than full recompute, every trial a
-# real peer fetch, and a post-drain zero-leak census across the HBM
-# tier, the host tier (A's store demotes D2H on eviction first), and
-# the exported volume (unpublishes cleanly). Also runs in tier-1 as
-# tests/test_kvtier_smoke.py.
-kvtier-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --peer-prefix
-
-# Prefill/decode disaggregation acceptance loop (~1 min): a 2-replica
-# split fleet (one prefill-role replica chunk-prefilling and shipping
-# finished chains as content-addressed volumes, one decode-role replica
-# adopting them) vs a unified 2-mixed baseline of the same geometry,
-# under a bimodal prompt mix with long prompts in flight. Gates:
-# short-prompt first-token p99 and decode inter-token p99 hold against
-# the baseline (interleaved min-time rounds), peer-shipped first-token
-# p50 strictly beats decode-local recompute, every routed output
-# byte-identical to solo generate(), and a zero-leak census on both
-# tiers (pages, host bytes, exported volumes, pooled channels). Also
-# runs in tier-1 as tests/test_disagg_smoke.py.
-disagg-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --serve --smoke --disagg
-
-# Observability-plane acceptance loop (seconds): in-process registry +
-# 2 serve replicas + router; one trace_id traced from a /metrics
-# OpenMetrics exemplar through /debug/spans to the router_retry event it
-# caused in /debug/events, `oimctl --top` rendered for every TTL-leased
-# telemetry/<id> row, and the tracing+events overhead recorded as
-# obs_overhead_ratio. Also runs in tier-1 as tests/test_obs_smoke.py.
-obs-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --obs-smoke
-
-# Fleet-SLO-plane acceptance loop (seconds): merged fleet p99 within
-# one bucket of the pooled-observation ground truth across a replica
-# restart (counter-reset epochs), a degraded replica firing exactly one
-# TTL-leased alert/<name> row — observed arriving over a registry Watch
-# stream, resolving after heal with one fired/resolved event pair (the
-# debounce contract) — and `oimctl --autopsy` attributing >=90% of one
-# REAL routed request's wall time to named phases. Also runs in tier-1
-# as tests/test_slo_smoke.py.
-slo-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --slo-smoke
-
-# Fleet-actuator acceptance loop (seconds): an SLO alert scales a
-# one-slot fleet up through oim-autoscaler, with alert-to-ready latency
-# broken into actuate/prestage/boot (the boot proven a stage-cache HIT,
-# zero source re-reads), then a rolling weight upgrade drains stale
-# replicas one cooldown at a time under routed load — zero
-# client-visible errors, byte-identical outputs. Also runs in tier-1
-# as tests/test_autoscale_smoke.py.
-autoscale-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --autoscale
-
 # Chaos ladder (minutes): seeded, scripted fault schedules over an
 # in-process cluster sim — replica SIGKILL, black-holed channel,
 # page-pool exhaustion, registry-primary kill -> auto-promotion,
@@ -179,18 +50,11 @@ autoscale-smoke:
 # asserts CONVERGENCE: the expected heal events on /debug/events, in
 # order; zero client-visible errors where the retry contract promises
 # them; byte-identical routed outputs; zero-leak page/prefix/channel
-# censuses. Same seed -> same heal-event sequence, or a loud assert.
+# censuses. Same seed -> same heal-event sequence, or a loud assert
+# (`python -m oim_tpu.chaos --seed N` picks another). The fast rungs
+# run in tier-1 as tests/test_chaos_smoke.py.
 chaos:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --chaos
-
-# The trimmed tier-1 variant (seconds): the fast serving-tier rungs
-# plus the serve-free quorum rungs (symmetric partition -> minority
-# step-down + split-brain census 0; rolling restart -> writes resume
-# per hop, one Watch stream survives), plus the fault_overhead_ratio
-# guard that every fault point is free when unarmed. Also runs in
-# tier-1 as tests/test_chaos_smoke.py.
-chaos-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --chaos --smoke
+	env JAX_PLATFORMS=cpu $(PY) -m oim_tpu.chaos
 
 # Quorum-registry acceptance loop (seconds): 3 in-process members
 # elect a leader, a quorum-committed write is readable on a follower
@@ -201,22 +65,6 @@ chaos-smoke:
 # tier-1 as tests/test_quorum_smoke.py.
 quorum-smoke:
 	env JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_quorum_smoke.py -q
-
-# Control-plane load columns (seconds): GetValues QPS at 1k simulated
-# publishers measured poll-mode vs watch-mode on the same in-process
-# registry (gated >= 10x drop), plus a full-fleet lease-renewal sweep
-# re-publish vs batched Heartbeat.
-control-plane-bench:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --control-plane
-
-# Control-plane scale smoke (seconds): one 3-member quorum registry
-# carrying 50 LiteReplica rows (real registration/heartbeat/telemetry/
-# Watch clients, decode stubbed) with 8 Watch consumers; gates leader-
-# kill convergence, zero shed streams, and every knee-curve column.
-# The full 10/100/1000 curve runs under `make control-plane-bench`.
-# Also runs in tier-1 as tests/test_scalesim_smoke.py.
-scalesim-smoke:
-	env JAX_PLATFORMS=cpu $(PY) bench.py --control-plane --smoke
 
 demo:
 	bash scripts/demo_cluster.sh demo

@@ -399,7 +399,7 @@ class Autoscaler:
     def _track_alert_to_ready(self, alerts, ready: int,
                               now: float) -> None:
         """alert/ row first observed -> the raised target fully ready:
-        the histogram bench.py --autoscale breaks down."""
+        one observation of oim_autoscale_alert_to_ready_seconds."""
         if self._alert_t0 is not None and self._alert_spawned \
                 and ready >= self._state.target > 0:
             M.AUTOSCALE_ALERT_TO_READY.observe(now - self._alert_t0)

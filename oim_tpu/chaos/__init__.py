@@ -8,15 +8,14 @@ the heal paths CONVERGE — expected events on ``/debug/events``, in
 order, zero client-visible errors where the retry contract promises
 them, byte-identical routed outputs, zero-leak censuses.
 
-Entry points: ``make chaos`` (the full ladder), ``bench.py --chaos
---smoke`` / tests/test_chaos_smoke.py (the trimmed tier-1 rungs).
+Entry points: ``make chaos`` = ``python -m oim_tpu.chaos [--seed N]`` (the
+full ladder), tests/test_chaos_smoke.py (the trimmed tier-1 rungs).
 """
 
 from oim_tpu.chaos.ladder import (  # noqa: F401
     RUNGS,
     SMOKE_RUNGS,
     Rung,
-    fault_overhead,
     run_ladder,
 )
 from oim_tpu.chaos.sim import ClusterSim  # noqa: F401

@@ -1092,59 +1092,3 @@ def run_ladder(seed: int = DEFAULT_SEED, include_slow: bool = True,
     finally:
         backoff.use_rng(None)
     return report
-
-
-def fault_overhead(rounds: int = 6, n_requests: int = 24,
-                   max_new: int = 12) -> dict:
-    """The no-op-when-unarmed guard for the serving tier's fault
-    points: serve throughput with the REAL (unarmed) ``fire`` vs a
-    stubbed no-op, paired per round with alternating order, median of
-    the paired ratios (the obs_overhead methodology — pairing cancels
-    box drift, the median cancels one disturbed round). An unarmed
-    ``fire`` is one dict lookup, so this ratio must sit at ~1.0."""
-    from oim_tpu.common import faultinject
-    from oim_tpu.serve import ServeEngine
-
-    params, cfg = model()
-    engine = ServeEngine(params, cfg, max_batch=4, max_seq=64,
-                         queue_depth=n_requests)
-    rng = np.random.RandomState(11)
-    reqs = [rng.randint(1, cfg.vocab, size=rng.randint(2, 8)).tolist()
-            for _ in range(n_requests)]
-    real_fire = faultinject.fire
-
-    def noop_fire(point, **ctx):
-        return None
-
-    walls: dict[str, list[float]] = {"real": [], "noop": []}
-    try:
-        engine.submit([1, 2, 3], max_new=2).result(timeout=300)  # warm
-
-        def one_round() -> float:
-            t0 = time.monotonic()
-            handles = [engine.submit(p, max_new=max_new, temperature=0.0,
-                                     seed=i)
-                       for i, p in enumerate(reqs)]
-            for h in handles:
-                h.result(timeout=300)
-            return time.monotonic() - t0
-
-        for i in range(rounds):
-            order = ("real", "noop") if i % 2 == 0 else ("noop", "real")
-            for mode in order:
-                faultinject.fire = (real_fire if mode == "real"
-                                    else noop_fire)
-                walls[mode].append(one_round())
-    finally:
-        faultinject.fire = real_fire
-        engine.stop(drain=False, timeout=30)
-    ratios = sorted(noop / real
-                    for real, noop in zip(walls["real"], walls["noop"]))
-    median = ratios[len(ratios) // 2]
-    return {
-        # noop/real throughput ratio: 1.0 = the unarmed fire is free.
-        "fault_overhead_ratio": round(median, 4),
-        "fault_overhead_pair_spread": [round(ratios[0], 4),
-                                       round(ratios[-1], 4)],
-        "fault_overhead_rounds": rounds,
-    }

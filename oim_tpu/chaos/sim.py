@@ -1,10 +1,8 @@
 """In-process cluster simulator: the chaos ladder's substrate.
 
-bench.py grew the in-process cluster three times (router_cluster, the
-obs smoke, tests/test_router.py's live_cluster) — always as a one-shot
-context manager with no way to KILL anything mid-flight. This module
-factors that plumbing into a reusable fixture whose components carry
-per-component fault handles:
+The in-process cluster as a reusable fixture (tests/cluster.py boots the
+one-shot variant whose weights travel through the control plane) whose
+components carry per-component fault handles:
 
 * a **registry** — single node, or a replicated primary/standby pair
   (``registry_pair=True``) with a short auto-promotion lease, killable

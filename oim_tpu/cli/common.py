@@ -78,7 +78,7 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def init_jax(platform: str = "") -> None:
     """The one place a chip-owning entry point (oim-trainer, oim-serve,
-    oim-infer, bench.py) configures JAX before its first backend touch:
+    oim-infer) configures JAX before its first backend touch:
     ``platform`` (the ``--platform`` flag) overrides whatever
     JAX_PLATFORMS the environment exported — an explicit choice never
     falls back to another backend — and the compilation cache lands in

@@ -497,8 +497,8 @@ def top_row(row_id: str, status: str, role: str, target: str,
         if p50 == p50 or p99 == p99:
             row["commit_ms"] = (p50 * 1e3, p99 * 1e3)
     if role == "router":
-        # Per-request pick cost: the table-scan control-plane tax the
-        # 10/100/1000-row curve pins (bench.py --control-plane).
+        # Per-request pick cost: the table-scan control-plane tax,
+        # linear in table rows.
         p50, p99 = _series_quantiles(
             samples, "oim_router_pick_seconds", {})
         if p50 == p50 or p99 == p99:

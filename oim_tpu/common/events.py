@@ -159,8 +159,7 @@ class EventRecorder:
         """Record one event. ``trace_id`` defaults to the ambient span's
         (tracing.trace_id()); string attribute values are redacted. The
         emit path is a deque append under one lock — cheap enough to
-        leave on in production (bench.py records the proof as
-        ``obs_overhead_ratio``)."""
+        leave on in production."""
         if self.capacity == 0:
             return None
         if trace_id is None:

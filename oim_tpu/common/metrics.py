@@ -120,16 +120,6 @@ class _HistogramValue:
         with self._lock:
             return self._sum
 
-    def bucket_snapshot(self) -> tuple[tuple[float, ...],
-                                       tuple[int, ...], int]:
-        """(bucket upper bounds, per-bucket counts, total count) — the
-        raw data in-process quantile estimation needs (bench.py reads
-        engine-side percentiles off the live histogram without a
-        /metrics scrape; per-bucket counts are NON-cumulative, values
-        above the last bound appear only in the total)."""
-        with self._lock:
-            return tuple(self._buckets), tuple(self._counts), self._count
-
     def snapshot(self) -> dict:
         """The MERGEABLE wire snapshot (obs/merge.py format): shared
         ``le`` grid, CUMULATIVE counts with the +Inf total last, and the
@@ -482,8 +472,8 @@ REGISTRY_COMMIT_INDEX = DEFAULT.gauge(
 REGISTRY_GETVALUES = DEFAULT.counter(
     "oim_registry_getvalues_total",
     "GetValues reads served by this registry — the poll load Watch "
-    "streams exist to remove (bench.py --control-plane measures the "
-    "drop at 1k publishers)")
+    "streams exist to remove (tests/test_watch.py holds a synced "
+    "watch-mode table to zero of them)")
 WATCH_STREAMS = DEFAULT.gauge(
     "oim_watch_streams",
     "Watch streams currently attached to this registry")
@@ -494,9 +484,8 @@ WATCH_EVENTS = DEFAULT.counter(
     labelnames=("kind",))
 # Control-plane self-metrics: the paths every fleet consumer rides
 # (Watch fan-out, quorum commit, election convergence, telemetry fold,
-# router pick), instrumented so bench.py --control-plane can publish
-# the 10/100/1000-replica knee curve and oimctl --top can show where
-# the control plane bends.
+# router pick), instrumented so oimctl --top can show where the control
+# plane bends (tests/test_scalesim_smoke.py reads them at 50 rows).
 WATCH_FANOUT_SECONDS = DEFAULT.histogram(
     "oim_watch_fanout_seconds",
     "wall seconds one committed delta took to serialize (once) and "
@@ -550,8 +539,7 @@ ROUTER_PICK_SECONDS = DEFAULT.histogram(
     "oim_router_pick_seconds",
     "wall seconds one router pick spent scoring the replica table "
     "(affinity hash + least-loaded scan) — linear in table rows, the "
-    "per-request control-plane tax bench.py --control-plane curves at "
-    "10/100/1000 rows",
+    "per-request control-plane tax (`oimctl --top`, PICK column)",
     buckets=(0.000001, 0.0000025, 0.000005, 0.00001, 0.000025, 0.00005,
              0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.01))
 # Direct data path (feeder/driver.py + common/channelpool.py): windows
@@ -811,8 +799,7 @@ AUTOSCALE_REPLICAS_DESIRED = DEFAULT.gauge(
 AUTOSCALE_REPLICAS_READY = DEFAULT.gauge(
     "oim_autoscale_replicas_ready",
     "serve/ rows the autoscaler observes ready:true — desired minus "
-    "ready is the fleet's actuation lag, the gap bench.py --autoscale "
-    "times end to end")
+    "ready is the fleet's actuation lag (`oimctl --top`, FLEET banner)")
 AUTOSCALE_ACTIONS_TOTAL = DEFAULT.counter(
     "oim_autoscale_actions_total",
     "reconcile actions executed through the ReplicaLauncher, by action "
@@ -824,8 +811,8 @@ AUTOSCALE_ALERT_TO_READY = DEFAULT.histogram(
     "oim_autoscale_alert_to_ready_seconds",
     "seconds from an alert/ row first observed to every replica of the "
     "raised target heartbeating ready:true — THE number the prestaged "
-    "O(1) boot path exists to minimize (spawn/prestage/first-ready "
-    "breakdown in bench.py --autoscale)",
+    "O(1) boot path exists to minimize "
+    "(tests/test_autoscale_smoke.py observes one episode)",
     buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0))
 # Labeled RPC telemetry (common/tracing.py interceptors — the
 # go-grpc-prometheus analog; recorded by client and server vantage alike).

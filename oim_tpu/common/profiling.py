@@ -9,7 +9,7 @@ loadable trace of device compute, XLA ops, and host<->device transfers is
 the TPU analog of a Jaeger span tree.
 
 Usage: ``with profile_trace(dir):`` around the hot region, or the
-``--profile DIR`` flag on oim-trainer / bench.py. Empty dir = no-op.
+``--profile DIR`` flag on oim-trainer. Empty dir = no-op.
 """
 
 from __future__ import annotations

@@ -122,10 +122,6 @@ def native_lib(autobuild: bool = False):
         return _lib or None
 
 
-def has_native() -> bool:
-    return native_lib() is not None
-
-
 class StagingError(IOError):
     pass
 
@@ -429,7 +425,7 @@ def read_into(path: str | os.PathLike, dst: np.ndarray,
     elapsed = time.monotonic() - t0
     if fast and elapsed > 0:
         # Disk half of the staging pipeline, attributable separately from
-        # the host->HBM half (bench.py reports both).
+        # the host->HBM half.
         M.STAGE_GBPS.set(dst.size / elapsed / 1e9)
 
 

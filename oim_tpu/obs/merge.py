@@ -32,8 +32,8 @@ Folding is INCREMENTAL: ``SnapshotFold`` keeps per-grid running
 aggregates that contributors patch in and out on row change, so
 ``merged()`` is O(grids) per render instead of O(replicas) — at 1k
 telemetry rows the from-scratch fold was the ``--top --watch`` render
-knee (bench.py --control-plane records the paired before/after; the
-``oim_top_merge_seconds{mode}`` histogram times both paths).
+knee (the ``oim_top_merge_seconds{mode}`` histogram times both paths;
+tests/test_obs_merge.py holds the two folds equal).
 """
 
 from __future__ import annotations

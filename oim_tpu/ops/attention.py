@@ -541,7 +541,7 @@ def _flash_plan(q, k) -> tuple[int, int] | None:
 
     def pick(t):
         # Largest measured-good block the length divides: the r3 sweep on
-        # v5e (scripts/sweep_llama.py, BASELINE.md) ranked 1024 > 512 >> 256
+        # v5e (BASELINE.md, the July 2026 rig) ranked 1024 > 512 >> 256
         # at seq 2048 (0.6974 / 0.6916 / 0.6161 MFU).
         for b in (1024, 512, 128):
             if t % b == 0:

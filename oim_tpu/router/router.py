@@ -214,7 +214,7 @@ class RouterService:
         """(replica, was_affinity_pick) — times the one pick
         implementation: the scan is linear in table rows, so
         oim_router_pick_seconds is the per-request control-plane tax
-        bench.py --control-plane curves at 10/100/1000 rows."""
+        (`oimctl --top`, PICK column)."""
         t0 = time.monotonic()
         try:
             return self._pick_inner(exclude, prompt, prefix_len,

@@ -893,7 +893,7 @@ class ServeEngine:
                 # Decode cadence accounting: tokens emitted by decode /
                 # verify rounds over the rounds that produced them —
                 # tokens_per_target_step > 1 is speculation paying off
-                # (bench.py's headline spec column). Extra keys ride
+                # (tests/test_spec_smoke.py holds it). Extra keys ride
                 # the heartbeat row; pre-spec routers ignore them
                 # (Replica.parse reads only the fields it knows).
                 "target_steps": self._target_steps,

@@ -15,7 +15,7 @@ import ast
 from pathlib import Path
 
 MAX_LINE = 100
-ROOTS = ("oim_tpu", "tests", "scripts", "bench.py", "chip_smoke.py",
+ROOTS = ("oim_tpu", "tests", "scripts", "chip_smoke.py",
          "__graft_entry__.py")
 EXCLUDE = {"oim_tpu/spec/oim_pb2.py"}  # generated
 DEBUGGERS = ("breakpoint(", "pdb.set_trace(")  # noqa

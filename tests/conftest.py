@@ -21,8 +21,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 # suite's wall clock is dominated by XLA compiles of the same small models
 # (measured: test_train 185s -> 143s, chaos+shard smoke 90s -> 45s). Byte-
 # identity pins compare runs within one process, so they see the same
-# executable either way. Callers that want full optimization (bench.py on
-# real hardware never imports this conftest) are unaffected.
+# executable either way. What runs on the chip (benchmarks/run.py,
+# chip_smoke.py) never imports this conftest and is unaffected.
 if "xla_backend_optimization_level" not in _flags:
     _flags = (_flags + " --xla_backend_optimization_level=0").strip()
 os.environ["XLA_FLAGS"] = _flags

@@ -1,73 +1,57 @@
-"""Tier-1 wiring of `make chaos-smoke`: the trimmed chaos ladder runs
-inside the normal (non-slow) test pass — the three fast serving-tier
-rungs (replica SIGKILL -> retry-before-first-token, black-holed channel
--> pool eviction + redial, page-pool exhaustion -> backpressure-not-
-OOM) plus the serve-free quorum-registry rungs (symmetric partition ->
-minority step-down + majority election + split-brain census 0; rolling
-restart of all 3 members -> writes resume per hop with ONE Watch stream
-surviving), the KV peer-fetch rung (prefix adopted from a peer's
-exported volume, then the holder SIGKILLed mid-fetch -> recompute
-fallback, byte-identical), the prefill-replica-kill rung (the
-disaggregated prompt tier SIGKILLed mid-handoff -> router mark-failed
-+ plain routing + decode-local recompute, zero client errors,
-byte-identical) and the shard-member-kill rung (a shard-2
-replica's member lease SIGKILLed -> not-ready flip, router rotates
-with zero client errors, drain + re-prestage heals on a stage-cache
-hit staging only the member slice), each converging on its declared
-/debug/events heal signature with zero client-visible errors,
-byte-identical routed outputs, and a zero-leak census
-(bench.chaos_smoke() itself raises on any divergence). The compound
-rung, the leader-kill-under-load rung and the rest of the ladder run
-under `make chaos` / `pytest -m slow` (tests/test_chaos.py)."""
+"""The fast rungs of the chaos ladder, one a case: each builds a fresh
+in-process cluster, runs its seeded fault schedule and must CONVERGE:
+the declared heal events on ``/debug/events`` in their declared order,
+no client-visible error where the retry contract promises none,
+byte-identical routed outputs (the rung's own asserts) and a zero-leak
+page / prefix / channel census. The compound rung, leader kill under
+load and the rest run under ``make chaos`` / ``pytest -m slow``
+(tests/test_chaos.py)."""
 
-import sys
-from pathlib import Path
+import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+SERVE_FREE = {"quorum_partition", "registry_rolling_restart"}
 
 
 def teardown_module(_module):
-    # Eight rungs x several sim replicas each leave a pile of compiled
-    # executables in XLA's in-process cache; each one is live LLVM code
-    # mappings counted against the kernel's vm.max_map_count cap. Drop
-    # them so the accumulated suite stays clear of the cap (crossing it
-    # segfaults a later module's compile).
+    # Eight rungs x several sim replicas leave a pile of compiled
+    # executables in XLA's in-process cache, each live LLVM mappings
+    # against vm.max_map_count: crossing it segfaults a later compile.
     import jax
 
     jax.clear_caches()
 
 
-def test_chaos_smoke_rungs_converge_and_fault_points_are_free():
-    import bench
+HEALED = {
+    "replica_kill": ["router_mark_failed", "router_retry"],
+    "channel_blackhole": ["router_mark_failed", "router_retry"],
+    "pool_exhaustion": ["page_pool_exhausted"],
+    "quorum_partition": ["registry_election", "registry_promotion",
+                         "registry_stepdown"],
+    "registry_rolling_restart": ["registry_election", "registry_promotion"],
+    "kv_peer_fetch": ["kv_peer_fetch", "kv_fetch_fallback"],
+    "prefill_replica_kill": ["kv_peer_fetch", "router_mark_failed",
+                             "kv_fetch_fallback"],
+    "shard_member_kill": ["shard_member_lost", "shard_member_healed"],
+}
 
-    extras = bench.chaos_smoke()  # raises AssertionError on divergence
-    assert extras["chaos_rung_names"] == [
-        "replica_kill", "channel_blackhole", "pool_exhaustion",
-        "quorum_partition", "registry_rolling_restart", "kv_peer_fetch",
-        "prefill_replica_kill", "shard_member_kill"]
-    assert extras["chaos_event_signature"] == [
-        ["replica_kill", "router_mark_failed", "router_retry"],
-        ["channel_blackhole", "router_mark_failed", "router_retry"],
-        ["pool_exhaustion", "page_pool_exhausted"],
-        ["quorum_partition", "registry_election", "registry_promotion",
-         "registry_stepdown"],
-        ["registry_rolling_restart", "registry_election",
-         "registry_promotion"],
-        ["kv_peer_fetch", "kv_peer_fetch", "kv_fetch_fallback"],
-        ["prefill_replica_kill", "kv_peer_fetch", "router_mark_failed",
-         "kv_fetch_fallback"],
-        ["shard_member_kill", "shard_member_lost",
-         "shard_member_healed"],
-    ]
-    serve_free = {"quorum_partition", "registry_rolling_restart"}
-    for rung in extras["chaos_report"]:
-        if rung["name"] in serve_free:
-            # Registry-only rungs: the census still ran (it checks the
-            # channel pool), there are just no engines to audit.
-            assert "pooled_channels" in rung["census"], rung
-        else:
-            assert rung["census"]["replicas"], rung  # census actually ran
-    # The unarmed-fault-point overhead gate (>= 0.90, the
-    # obs_overhead_ratio stance) is enforced inside bench.chaos_ladder
-    # itself; here we only pin that the smoke recorded it.
-    assert "fault_overhead_ratio" in extras, extras
+
+def test_the_smoke_rungs_are_the_fast_ones():
+    from oim_tpu import chaos
+
+    assert set(chaos.SMOKE_RUNGS) == set(HEALED)
+    assert not [r.name for r in chaos.RUNGS
+                if r.slow and r.name in chaos.SMOKE_RUNGS]
+
+
+@pytest.mark.parametrize("rung", list(HEALED))
+def test_chaos_smoke_rung_converges(rung):
+    from oim_tpu import chaos
+
+    report = chaos.run_ladder(names=[rung])
+    assert report["event_signature"] == [[rung, *HEALED[rung]]]
+    census = report["rungs"][0]["census"]
+    if rung in SERVE_FREE:
+        # No engine to audit; the channel pool's census still ran.
+        assert "pooled_channels" in census
+    else:
+        assert census["replicas"], "the census audited no replica"

@@ -221,7 +221,7 @@ class TestDependencyManifest:
     (reference test/test.make:118-149). Two invariants:
 
     1. every third-party module imported anywhere in oim_tpu/ (plus
-       bench.py and __graft_entry__.py) is declared in the manifest;
+       __graft_entry__.py) is declared in the manifest;
     2. every pinned version matches the installed one — the manifest
        names the exact environment the green suite and the BASELINE.md
        perf rows were produced on.
@@ -267,7 +267,7 @@ class TestDependencyManifest:
 
         root = Path(__file__).resolve().parent.parent
         files = list((root / "oim_tpu").rglob("*.py"))
-        files += [root / "bench.py", root / "__graft_entry__.py"]
+        files.append(root / "__graft_entry__.py")
         mods: set[str] = set()
         for p in files:
             for node in ast.walk(ast.parse(p.read_text())):

@@ -1,20 +1,13 @@
-"""Tier-1 wiring of `make disagg-smoke` plus the disaggregation unit
-gates: tolerant role parsing (mixed-version routing), chunked-prefill
-byte-identity across chunk sizes, the prefill->decode handoff pinned to
-solo generate(), and the `oimctl --top` ROLE column. The heavy
-end-to-end bench itself (bench.disagg_bench) raises unless the split
-fleet held both latency gates against the unified baseline, the
-peer-shipped first token beat decode-local recompute, every routed
-output stayed byte-identical, and both tiers drained to a zero-leak
-census."""
-
-import sys
-from pathlib import Path
+"""Prefill/decode disaggregation: tolerant role parsing (mixed-version
+routing), chunked-prefill byte-identity across chunk sizes, the
+prefill -> decode handoff held to solo generate(), the `oimctl --top`
+ROLE column, and the split fleet end to end: under a bimodal mix with
+long prompts in flight the router splits every long prompt, the decode
+tier adopts the shipped chains, no routed token changes, and both tiers
+drain to a zero-leak census."""
 
 import numpy as np
 import pytest
-
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def teardown_module(_module):
@@ -219,30 +212,87 @@ def test_top_role_column_and_dash_degrade():
     assert render_top([old]).count("serve") == 1  # KIND still renders
 
 
-def test_disagg_smoke_gates():
-    """`make disagg-smoke` as a tier-1 gate: the bench raises on any
-    broken invariant; the assertions here pin the headline numbers the
-    docs quote."""
-    import bench
-
-    extras = bench.disagg_bench(smoke=True)
-    assert extras["byte_identity"] is True
-    assert extras["short_first_token_p99_ratio"] <= 1.25
-    assert extras["inter_token_p99_ratio"] <= 1.25
-    assert extras["peer_first_token_p50_ms"] \
-        < extras["local_first_token_p50_ms"]
-    assert extras["peer_speedup_x"] > 1.0
-    assert extras["handoff_splits"] > 0
-    assert extras["exported_volumes"] > 0
+N_LONG = 3
 
 
-@pytest.mark.slow
-def test_disagg_bench_full():
-    """The full-depth variant (`bench.py --serve --disagg`, 4 rounds):
-    same gates, more rounds — the numbers ROADMAP quotes."""
-    import bench
+@pytest.fixture(scope="module")
+def split_fleet():
+    """r0 the prompt tier (chunked prefill, a retired chain shipped as a
+    content-addressed volume), r1 the stream tier (adopts shipped
+    chains), behind one router; six short and three long requests, the
+    longs arriving while the shorts decode."""
+    from oim_tpu.common import metrics as M
+    from oim_tpu.serve.kvvolume import (
+        PeerPrefixFetcher,
+        config_fingerprint,
+        export_chain,
+    )
+    from tests import cluster as C
 
-    extras = bench.disagg_bench(smoke=False)
-    assert extras["short_first_token_p99_ratio"] <= 1.25
-    assert extras["inter_token_p99_ratio"] <= 1.25
-    assert extras["peer_speedup_x"] > 1.0
+    facts = {}
+    with C.cluster(replicas=2, max_seq=128, max_batch=4, prefix_block=16,
+                   engine_kwargs=[dict(role="prefill", prefill_chunk=32),
+                                  dict(role="decode")]) as sim:
+        prefill, decode = C.engines(sim)
+        feeder = sim.feeder()
+        prefill.set_handoff_export(
+            lambda eng, hashes: export_chain(eng, feeder, hashes))
+        decode.set_kv_fetch(PeerPrefixFetcher(
+            feeder, config_fingerprint(C.model()[1], 16)))
+        sim.warm()
+        split = M.SERVE_PREFILL_HANDOFFS.labels(outcome="split")
+        hit = M.SERVE_PREFIX_PEER_FETCHES.labels(outcome="hit")
+        before = split.value, hit.value
+        # Fresh tokens a long prompt: a repeated one would hit a prefix
+        # store and ship nothing.
+        longs = C.mixed_requests(11, N_LONG, prompt_len=(49, 49),
+                                 max_new=(4, 4))
+        shorts = C.mixed_requests(12, 6)
+        reqs = [r for trio in zip(shorts[::2], shorts[1::2], longs)
+                for r in trio]
+        results, errors = sim.routed_load(reqs, concurrency=5)
+        exported = prefill.exported_volumes()
+        facts.update(
+            reqs=reqs, results=results, errors=errors,
+            splits=split.value - before[0], hits=hit.value - before[1],
+            exported=len(exported),
+            solo=[C.solo(sim, *req) for req in reqs])
+
+        pools = C.drain(sim)
+        facts["left"] = {
+            engine.role: (pool["used_pages"], engine.host_stats()["entries"],
+                          engine.host_stats()["bytes"])
+            for engine, pool in zip((prefill, decode), pools)}
+        facts["after_unpublish"] = []
+        for volume_id in exported.values():
+            feeder.unpublish(volume_id)
+            try:
+                feeder.fetch_window(volume_id, 0, 16)
+                facts["after_unpublish"].append("still served")
+            except Exception as err:  # noqa: BLE001 - the test reads it
+                facts["after_unpublish"].append(str(err))
+    return facts
+
+
+def test_router_splits_every_long_prompt_and_decode_adopts_it(split_fleet):
+    assert split_fleet["splits"] == N_LONG, \
+        "a long prompt skipped the prefill tier"
+    assert split_fleet["hits"] == N_LONG, \
+        "the decode tier recomputed a shipped chain"
+    assert split_fleet["exported"] == N_LONG
+
+
+def test_disagg_smoke_routed_streams_match_solo_generate(split_fleet):
+    errors = split_fleet["errors"]
+    assert not errors, f"a client saw the split: {errors[0]!r}"
+    for req, tokens, solo in zip(split_fleet["reqs"], split_fleet["results"],
+                                 split_fleet["solo"]):
+        assert tokens == solo, f"routed {req} diverged from solo"
+
+
+def test_both_tiers_drain_and_shipped_volumes_unpublish(split_fleet):
+    assert split_fleet["left"] == {"prefill": (0, 0, 0),
+                                   "decode": (0, 0, 0)}
+    assert len(split_fleet["after_unpublish"]) == N_LONG
+    for message in split_fleet["after_unpublish"]:
+        assert "NOT_FOUND" in message

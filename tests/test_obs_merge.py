@@ -269,7 +269,7 @@ class TestIncrementalFoldEquivalence:
     def test_fleet_histogram_incremental_matches_scratch_oracle(self):
         """FleetHistogram.merged() (SnapshotFold-backed) against its
         own merged_scratch() through restart epochs and departures —
-        the pairing bench.py --control-plane times."""
+        the two sides `oim_top_merge_seconds{mode}` times."""
         rng = random.Random(13)
         fleet = merge.FleetHistogram()
         hists: dict[str, object] = {}

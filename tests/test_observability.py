@@ -561,7 +561,7 @@ class TestMetricsDrift:
         capacity dashboards graph desired-vs-ready as two unlabeled
         gauges, alert runbooks rate() the actions counter BY action,
         and the alert-to-ready histogram's buckets are the SLO ladder
-        bench.py --autoscale reports against — none may drift."""
+        an episode is read against — none may drift."""
         assert isinstance(metrics.AUTOSCALE_REPLICAS_DESIRED, Gauge)
         assert (metrics.AUTOSCALE_REPLICAS_DESIRED.name
                 == "oim_autoscale_replicas_desired")
@@ -634,7 +634,7 @@ class TestMetricsDrift:
 
     def test_control_plane_metrics_declared_and_shaped(self):
         """The control-plane self-metric names are API (ISSUE 18):
-        bench.py --control-plane curves them at 10/100/1000 replicas
+        tests/test_scalesim_smoke.py reads them at 50 lite replicas
         and oimctl --top's COMMIT/PICK columns parse them off /metrics
         scrapes — a rename or label change silently blanks both. The
         commit histogram stays labeled BY PHASE (ack/apply/total) and

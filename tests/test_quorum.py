@@ -173,6 +173,9 @@ class TestCommit:
         with Cluster() as c:
             li = c.await_leader()
             follower = c.managers[(li + 1) % 3]
+            # Elected is not yet heard of: the hint is the leader's first
+            # contact with this follower, which six workers can delay.
+            assert wait_for(lambda: follower.leader_hint() == c.addrs[li])
             with pytest.raises(NotLeader) as err:
                 follower.propose_kv("q/y", "1", 0.0)
             assert err.value.hint == c.addrs[li]
@@ -254,6 +257,18 @@ class TestFollowerReadLag:
             li = c.await_leader()
             fi = (li + 1) % 3
             follower = c.managers[fi]
+            # An elected leader does not mean THIS follower's stream is
+            # attached: the write below commits on the leader and the
+            # other follower alone, and a follower that attaches after it
+            # gets the record by resync, applied with nothing pending:
+            # no lag ever shows. Hold the window open only on a follower
+            # that is seen applying the leader's writes.
+            c.stubs[li].SetValue(pb.SetValueRequest(value=pb.Value(
+                path="q/attached", value="1", lease_seconds=60)),
+                timeout=10)
+            assert wait_for(
+                lambda: c.services[fi].db.get("q/attached") == "1"), \
+                "the follower never applied a write of the elected leader"
             real_flush = follower._flush_pending
             follower._flush_pending = lambda: None
             try:

@@ -1,22 +1,36 @@
-"""Tier-1 wiring of `make router-smoke`: an in-process registry + 2
-serve replicas + oim-router, with EVERY routed output asserted
-byte-identical to its solo generate() run by bench.router_smoke()
-itself, and at least one request served by each replica (the
-least-loaded pick must actually spread, not herd)."""
+"""Two serve replicas heartbeating ``serve/<id>`` rows behind an
+``oim-router``: the least-loaded pick spreads, and the router changes no
+token."""
 
-import sys
-from pathlib import Path
+import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+from tests import cluster as C
 
 
-def test_router_smoke_spread_and_byte_identity():
-    import bench
+@pytest.fixture(scope="module")
+def routed():
+    facts = {}
+    with C.cluster(replicas=2) as sim:
+        sim.warm()
+        before = [e.finished_total for e in C.engines(sim)]
+        facts["reqs"] = C.mixed_requests(5, 16, prompt_len=(2, 7),
+                                         max_new=(3, 8))
+        facts["results"], facts["errors"] = sim.routed_load(
+            facts["reqs"], concurrency=8)
+        facts["served"] = [e.finished_total - b
+                           for e, b in zip(C.engines(sim), before)]
+        facts["solo"] = [C.solo(sim, *req) for req in facts["reqs"]]
+    return facts
 
-    extras = bench.router_smoke(2)  # raises AssertionError on divergence
-    assert extras["router_byte_identity"] is True
-    assert extras["serve_completed"] == extras["serve_requests"]
-    assert extras["router_replicas"] == 2
-    assert all(count >= 1
-               for count in extras["router_served_per_replica"].values())
-    assert extras["serve_qps"] > 0
+
+def test_every_replica_serves(routed):
+    errors, served = routed["errors"], routed["served"]
+    assert not errors, f"{len(errors)} routed streams failed: {errors[0]!r}"
+    assert sum(served) == len(routed["reqs"])
+    assert min(served) >= 1, f"routing did not spread: {served}"
+
+
+def test_router_smoke_spread_and_byte_identity(routed):
+    for req, tokens, solo in zip(routed["reqs"], routed["results"],
+                                 routed["solo"]):
+        assert tokens == solo, f"routed {req} diverged from solo"

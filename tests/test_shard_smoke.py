@@ -1,54 +1,65 @@
-"""Tier-1 wiring of `make shard-smoke` (sharded decode: one logical
-replica spans N members, tensor-parallel over ICI), plus the engine- and
-restore-level pins the smoke's routed run builds on:
+"""Sharded decode: one logical replica spans N members, tensor-parallel
+over ICI.
 
-* bench.shard_smoke(2) itself raises unless every routed request came
-  back byte-identical to its solo generate() run, the per-member HBM
-  budget refused the model at shard=1 ("shard wider") and served it at
-  shard=2, a member-lease SIGKILL flipped the replica not-ready, every
-  member pool drained to zero, and the ICI-allreduce histogram gained
-  samples;
+* routed: a 2-member replica, whose weights and pool fit no single
+  member's HBM budget, and a solo replica behind one router; every
+  routed stream is its solo generate() run, the sharded replica serves
+  its share, the ICI-allreduce histogram gains samples and no member
+  pool keeps a page (the member-kill flip and its heal are the chaos
+  rung ``shard_member_kill``, tests/test_chaos_smoke.py);
 * the sharded restore reassembles byte-identically: concatenating every
   rank's slice along the Megatron split axes reproduces the full tree,
-  and each rank staged exactly member_weight_bytes — not the blob;
+  and each rank staged exactly member_weight_bytes, not the blob;
 * the engine's prefill/decode/spec-verify paths are byte-identical at
-  shard 1 vs 2 (greedy AND sampled — the shard_map runs the same math,
+  shard 1 vs 2 (greedy AND sampled: the shard_map runs the same math,
   just distributed);
 * the member-lease watch is what readiness folds in: a stale member
   flips stats()["ready"] false, moves the oim_serve_shard_members
   gauges, and emits exactly one lost/healed event pair per transition.
 """
 
-import sys
-from pathlib import Path
-
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+@pytest.fixture(scope="module")
+def routed():
+    import random
+
+    from oim_tpu.chaos.ladder import _reqs
+    from oim_tpu.chaos.sim import ClusterSim, model
+    from oim_tpu.common import metrics as M
+    from oim_tpu.serve.shard import member_weight_bytes
+
+    # The full weights alone exhaust this budget: weights + pool fit one
+    # member at shard 2 only.
+    budget = member_weight_bytes(model()[0], 1)
+    with ClusterSim(replicas=2, engine_kwargs=[
+            dict(shard=2, member_hbm_budget=budget), {}]) as sim:
+        sim.warm()
+        sharded = sim.replicas[0]
+        assert sharded.engine.stats()["shard_ready"] == 2
+        reqs = _reqs(random.Random(20260809), 8, max_new=(4, 8))
+        ici, done = M.SERVE_ICI_ALLREDUCE.labels().count, sharded.completed()
+        results, errors = sim.routed_load(reqs, concurrency=4)
+        yield (sim, reqs, results, errors, sharded.completed() - done,
+               M.SERVE_ICI_ALLREDUCE.labels().count - ici)
 
 
-def test_shard_smoke_gates():
-    import bench
+def test_shard_smoke_routed_streams_match_solo_generate(routed):
+    sim, reqs, results, errors, by_sharded, _ = routed
+    assert not errors, f"{len(errors)} routed streams failed: {errors[0]!r}"
+    assert sim.assert_byte_identity(reqs, results) == len(reqs)
+    assert by_sharded >= 1, "the sharded replica served none of them"
 
-    extras = bench.shard_smoke(2)  # raises AssertionError on any break
-    assert extras["serve_completed"] == extras["serve_requests"]
-    assert extras["byte_identical"] == extras["serve_requests"]
-    assert extras["hbm_refused_at_shard1"] is True
-    assert extras["hbm_serves_at_shard2"] is True
-    assert extras["member_kill_not_ready_flip"] is True
-    assert extras["shard_ready_after_kill"] == 1
-    assert extras["pages_leaked"] == 0
-    assert extras["ici_allreduce_samples"] > 0
-    # Each member staged exactly its slice of the one published volume.
-    assert extras["member_bytes_staged"] == (
-        [extras["member_weight_bytes_shard2"]] * 2)
-    assert (extras["member_weight_bytes_shard2"]
-            < extras["member_weight_bytes_shard1"])
-    # The comparison columns are REPORTED (fake-device collectives are
-    # not an interconnect); presence is what's pinned.
-    assert extras["token_p50_ms_shard1"] is not None
-    assert extras["token_p50_ms_shard2"] is not None
+
+def test_every_sharded_dispatch_times_one_allreduce(routed):
+    assert routed[5] > 0, "oim_serve_ici_allreduce_seconds never observed"
+
+
+def test_no_member_pool_keeps_a_page(routed):
+    census = routed[0].leak_census()  # raises on a leak
+    assert set(census["replicas"]) == {"r0", "r1"}
 
 
 def test_sharded_restore_reassembles_byte_identically(tmp_path):

@@ -1,29 +1,74 @@
-"""Tier-1 wiring of `make spec-smoke`: the serve smoke with speculative
-decoding (self-draft, 4 proposals per verify round) — bench.spec_smoke()
-itself raises unless every greedy output stayed byte-identical to its
-solo generate() run, the acceptance rate was > 0, speculation advanced
-more than one decode token per target dispatch, both page pools (target
-AND draft) drained to zero, and the routed mixed-fleet half (one
-speculating replica, one plain, behind the router) stayed byte-identical
-wherever the pick landed."""
+"""Speculative decoding with the target as its own draft, four
+proposals a verify round: greedy streams stay solo ``generate()``'s,
+proposals are accepted, a target dispatch yields more than one token,
+and neither pool keeps a page. Sampled streams are distribution-exact,
+not byte-identical (tests/test_spec.py holds the ratio test), so only
+greedy rows are compared here."""
 
-import sys
-from pathlib import Path
+import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+from tests import cluster as C
 
 
-def test_spec_smoke_identity_acceptance_and_leaks():
-    import bench
+def _spec():
+    """The engine's keyword arguments for a self-draft of four tokens."""
+    params, cfg = C.model()
+    return dict(draft_params=params, draft_cfg=cfg, spec_tokens=4)
 
-    extras = bench.spec_smoke(4)  # raises AssertionError on any break
-    assert extras["serve_completed"] == extras["serve_requests"]
-    assert extras["spec_accept_rate"] > 0
-    assert extras["tokens_per_target_step"] > 1
-    assert extras["kv_pages_leaked"] == 0
-    assert extras["draft_pages_leaked"] == 0
-    # The interleaved comparison is REPORTED (min-time p50 per mode);
-    # wall-clock improvement is not gated on the noisy 2-core CI box.
-    assert extras["spec_on_token_p50_ms"] is not None
-    assert extras["spec_off_token_p50_ms"] is not None
-    assert extras["router_mixed_fleet_byte_identity"] is True
+
+def _greedy_match_solo(reqs, results, solo):
+    for req, tokens, want in zip(reqs, results, solo):
+        if req[2] == 0.0:
+            assert tokens == want, f"greedy {req} diverged from solo"
+
+
+@pytest.fixture(scope="module")
+def served():
+    facts = {}
+    with C.cluster(max_batch=4, **_spec()) as sim:
+        sim.warm()
+        reqs = C.mixed_requests(42, 12)
+        results, errors = sim.routed_load(reqs, concurrency=6)
+        assert not errors, f"streams failed: {errors[0]!r}"
+        engine = C.engines(sim)[0]
+        facts.update(reqs=reqs, results=results, stats=engine.stats(),
+                     solo=[C.solo(sim, *req) for req in reqs],
+                     drained=C.drain(sim),
+                     draft_pages=engine.spec_stats()["draft_used_pages"])
+    return facts
+
+
+def test_spec_smoke_identity_and_acceptance(served):
+    assert ([len(r) for r in served["results"]]
+            == [n for _, n, _, _ in served["reqs"]])
+    _greedy_match_solo(served["reqs"], served["results"], served["solo"])
+    assert served["stats"]["spec_accept_rate"] > 0
+
+
+def test_a_target_dispatch_yields_more_than_one_token(served):
+    stats = served["stats"]
+    assert stats["decode_tokens"] > stats["target_steps"] > 0
+
+
+def test_drain_leaves_no_page_in_either_pool(served):
+    assert [pool["used_pages"] for pool in served["drained"]] == [0]
+    assert served["draft_pages"] == 0
+
+
+def test_mixed_fleet_behind_the_router():
+    """A rolling rollout's shape: one speculating replica, one plain.
+    Wherever the pick lands the greedy stream is solo's, both replicas
+    serve, and the speculating one keeps no draft page."""
+    with C.cluster(replicas=2, engine_kwargs=[_spec(), {}]) as sim:
+        sim.warm()
+        speculating, plain = C.engines(sim)
+        rounds = speculating.stats()["spec_rounds"]
+        before = [e.finished_total for e in (speculating, plain)]
+        reqs = [([11 + i, 3, 5], 6, 0.0, i) for i in range(6)]
+        results, errors = sim.routed_load(reqs, concurrency=6)
+        assert not errors, f"routed streams failed: {errors[0]!r}"
+        _greedy_match_solo(reqs, results, [C.solo(sim, *r) for r in reqs])
+        assert speculating.stats()["spec_rounds"] > rounds
+        assert min(e.finished_total - b
+                   for e, b in zip((speculating, plain), before)) >= 1
+        assert speculating.spec_stats()["draft_used_pages"] == 0

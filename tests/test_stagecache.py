@@ -382,6 +382,10 @@ class TestWarmStandby:
                 controller_address="pending",
                 registry_address=registry.addr,
                 registry_delay=0.1,
+                # The default lease is 2.5 beats = 0.25 s: one late beat
+                # under six workers and the replica is STALE when the
+                # one-shot warm (or the failover) looks for a LIVE one.
+                lease_seconds=10.0,
                 mesh_coord=MeshCoord.parse("4,5,6"),
             )
             for i in range(2)
@@ -395,10 +399,15 @@ class TestWarmStandby:
                 c.start()
             with grpc.insecure_channel(registry.addr) as ch:
                 stub = RegistryStub(ch)
+                # A beat writes <id>/address, then <id>/mesh, in two
+                # RPCs, and the one-shot warm finds its replica by the
+                # MESH rows: wait for those (an address row alone lets
+                # the publish race host-1's second write under load).
                 assert wait_for(lambda: len([
                     v for v in stub.GetValues(
-                        pb.GetValuesRequest(path="")).values
-                    if v.path.endswith("/address")]) == 2)
+                        pb.GetValuesRequest(path=""), timeout=10.0).values
+                    if v.path.endswith("/mesh")]) == 2, timeout=30), \
+                    "the two controllers never registered their mesh rows"
 
             data = np.random.RandomState(9).bytes(40_000)
             path = tmp_path / "warm.bin"
@@ -407,7 +416,8 @@ class TestWarmStandby:
                             controller_id="host-0", warm_standby=True)
             feeder.publish(_file_request(path, "vol-w"))
             # The background warm thread prestages host-1's cache.
-            assert wait_for(lambda: len(backends[1].cache) == 1, timeout=15)
+            assert wait_for(lambda: len(backends[1].cache) == 1, timeout=30), \
+                "the publish's background warm never reached host-1's cache"
             assert svcs[1].get_volume("vol-w") is None  # cache-only warm
 
             # KILL host-0; the healed window must fail over AND be served

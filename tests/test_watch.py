@@ -410,6 +410,29 @@ class TestTableWatchMode:
         finally:
             table.stop()
 
+    def test_synced_watch_table_issues_no_getvalues(self, registry):
+        """The count behind the retired `getvalues_drop_x` timing gate:
+        once its stream is synced, a watch-mode table follows every row
+        change with ZERO GetValues reads of the registry."""
+        from oim_tpu.common import metrics as M
+        from oim_tpu.router.table import ReplicaTable
+
+        _, server, stub = registry
+        put(stub, "serve/r0", self._row(), lease=60)
+        table = ReplicaTable(server.addr, interval=3600.0, watch=True)
+        table.start()
+        try:
+            assert wait_for(lambda: len(table.replicas()) == 1, timeout=10)
+            reads = M.REGISTRY_GETVALUES.value
+            for i in range(1, 4):
+                put(stub, f"serve/r{i}", self._row(f"5.6.7.{i}:9"), lease=60)
+            assert wait_for(lambda: len(table.replicas()) == 4, timeout=5), \
+                "the pushed rows never reached the table"
+            assert M.REGISTRY_GETVALUES.value == reads, \
+                "a synced watch-mode table still polls GetValues"
+        finally:
+            table.stop()
+
     def test_mark_failed_readmits_on_row_change(self, registry):
         from oim_tpu.router.table import ReplicaTable
 
@@ -460,8 +483,8 @@ class TestTableWatchMode:
 class TestSerializeOnceFanout:
     """The hub's write-path contract at scale: one committed delta is
     serialized ONCE and every attached stream's frame is the same bytes
-    object (bench.py --control-plane pairs the two modes; this pins the
-    mechanism)."""
+    object: the count behind the retired `serialize_once_x` timing
+    gate is one serialisation a committed delta."""
 
     def _hub(self, **kwargs):
         return W.WatchHub(service=None, **kwargs)
