@@ -616,6 +616,14 @@ SERVE_PREFILL_TOKENS = DEFAULT.counter(
     "copied from the prefix store (prefill skipped), compute = forwarded "
     "through the model",
     labelnames=("source",))
+SERVE_EXPERT_ROWS = DEFAULT.counter(
+    "oim_serve_expert_rows_total",
+    "rows of expert FFN work the target's programs were dispatched with, "
+    "over all expert layers, counted on the host from each program's "
+    "shapes: dropless = k x tokens a layer, padded = experts x capacity "
+    "(= experts x tokens at inference) a layer; which one a call runs is "
+    "generate._no_drop's rule on its token count",
+    labelnames=("dispatch",))
 # Paged KV cache (serve/pagepool.py): the pool every slot's page table
 # maps into; shared = pages referenced more than once (prefix sharing).
 SERVE_KV_PAGES_TOTAL = DEFAULT.gauge(

@@ -23,6 +23,8 @@ unchanged under jit with sharded params.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -83,19 +85,78 @@ def shard_config(cfg: Config, n: int) -> Config:
         cfg, n_heads=cfg.n_heads // n, n_kv_heads=cfg.n_kv_heads // n)
 
 
-def _no_drop(cfg: Config) -> Config:
-    """MoE inference must not drop tokens: training groups tokens per call
-    and caps expert capacity, but a decode step has so few tokens that the
-    cap would route trained tokens to nothing. A capacity factor of
-    n_experts/top_k makes capacity == n_tokens — mathematically no drop."""
+# Tokens in a call (B x T, static at trace time) from which a capacity-padded
+# expert configuration runs dropless at inference. One rule on shapes, no
+# option (as ``ops/paged_attention._paged_plan``). The expert FFN alone at
+# Mixtral-8x7B's widths (E 8, k 2, D 4096, F 14336, 4 layers scanned, bf16)
+# on one v5e, ms a layer (the weights' bytes / 819 GB/s are 3.44 ms, the
+# needed operations / 197 TFLOP/s 3.66 and 7.33 ms at 1024 and 2048 tokens;
+# scripts/expert_dispatch_crossing.py, PERF.md section 6, PR 31):
+#
+#   tokens     32     64     128    256    512    640    768    896    1024   2048
+#   padded     3.98   4.02   4.02   4.56   8.60   10.46  12.79  14.87  16.68  34.48
+#   dropless   4.51   5.03   6.05   9.19   10.50  9.18   11.66  10.64  12.97  18.29
+#
+# Padded computes E x N rows: bound by the weights' bytes while those rows
+# are few (86 % of that roofline to 128 tokens), and at 85-88 % of the MXU's
+# peak for the rows it computes beyond that. Dropless computes k x N rows,
+# but ``lax.ragged_dot`` reaches a third of its roofline or less at 64-256
+# rows an expert (28 % at 1024 tokens), so it wins only where padded does
+# four times its work at full speed: from 640 tokens, the first size
+# measured at which it did. A faster grouped product (a row tile,
+# ``megablox.gmm``: ROADMAP S8 (a)) moves this down.
+DROPLESS_FROM_TOKENS = 640
+
+
+def _no_drop(cfg: Config, n_tokens: int) -> Config:
+    """The one rule of expert dispatch at inference, for a call of
+    ``n_tokens`` = B x T tokens. MoE inference must not drop tokens:
+    training groups tokens per call and caps expert capacity, but an
+    inference call's cap would route trained tokens to nothing. Dense
+    configurations and dropless ones (``moe_dispatch="ragged"``) pass
+    through untouched. A capacity-padded one (``gather``, ``einsum``; its
+    router is softmax: sigmoid is dropless by configuration)
+
+    - at ``DROPLESS_FROM_TOKENS`` tokens or more runs ``dispatch="ragged"``:
+      every token through its k experts, k x N rows of expert work
+      (``moe._dropless``; ``_scan_groups`` keeps the expert leaves whole);
+    - below it keeps its dispatch with a capacity factor of
+      n_experts/top_k, which makes capacity == n_tokens (mathematically
+      no drop) at E x N rows: a decode step's few rows are bound by the
+      expert weights' stream either way, and the padded products stream
+      them nearer the roofline (the table above: 3.98 against 4.51 ms a
+      layer at 32 tokens).
+
+    Both compute the same top-k sum (``tests/test_expert_dispatch.py``)."""
     if not cfg.n_experts or cfg.moe_dispatch == "ragged":
         return cfg  # dense, or dropless by construction
     import dataclasses
 
+    if n_tokens >= DROPLESS_FROM_TOKENS:
+        return dataclasses.replace(cfg, moe_dispatch="ragged")
     factor = cfg.n_experts / cfg.moe_top_k
     if cfg.moe_capacity_factor >= factor:
         return cfg
     return dataclasses.replace(cfg, moe_capacity_factor=factor)
+
+
+@functools.lru_cache(maxsize=256)
+def expert_rows(cfg: Config, n_tokens: int) -> tuple[str, int]:
+    """(dispatch, rows of expert FFN work over all expert layers) of one
+    inference program over ``n_tokens`` tokens, from its shapes alone:
+    ("dropless", k x N a layer), ("padded", E x capacity = E x N a layer)
+    or ("", 0) for a dense configuration. What the serving engine counts
+    at each dispatch (``oim_serve_expert_rows_total``): cached, a decode
+    round asks every time."""
+    if not cfg.n_experts:
+        return "", 0
+    from oim_tpu.models import moe
+
+    run = _no_drop(cfg, n_tokens)
+    layers = cfg.n_layers - cfg.n_dense_layers
+    if run.moe_dispatch == "ragged":
+        return "dropless", layers * run.moe_top_k * n_tokens
+    return "padded", layers * run.n_experts * moe.capacity(n_tokens, run.moe)
 
 
 def init_cache(cfg: Config, batch: int, max_seq: int):
@@ -145,7 +206,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     """
     B, T = tokens.shape
     S = jax.tree.leaves(cache)[0].shape[2]
-    cfg = _no_drop(cfg)
+    cfg = _no_drop(cfg, B * T)
     # Host-numpy weight trees (a freshly restored checkpoint) must work:
     # numpy arrays can't be indexed by traced token ids inside the decode
     # scan, so lift everything to jax arrays first (no-op when already on
@@ -270,7 +331,7 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     B, T = tokens.shape
     page = jax.tree.leaves(pool)[0].shape[2]
     S = tables.shape[1] * page
-    cfg = _no_drop(cfg)
+    cfg = _no_drop(cfg, B * T)
     params = jax.tree.map(jnp.asarray, params)
     cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
     positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)

@@ -54,7 +54,8 @@ class Config:
     moe_aux_weight: float = 0.01
     # "gather" (index-based, the measured default) or "einsum" (GShard
     # dense dispatch); see models/moe.py MoEConfig.dispatch and the
-    # BASELINE.md r4 measurement row.
+    # BASELINE.md r4 measurement row. What training runs; an inference
+    # program's dispatch follows its token count (generate._no_drop).
     moe_dispatch: str = "gather"
     # The DeepSeek-V3 family's FFN, under its published keys: the first
     # ``first_k_dense_replace`` layers keep the dense FFN (width mlp_dim),

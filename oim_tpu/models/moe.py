@@ -40,8 +40,14 @@ class MoEConfig:
     #   grouped product a projection over the rows each expert got
     #   (``lax.ragged_dot``): no capacity, no [E, C, D] buffer, exactly
     #   k * N rows of expert work. What inference of a many-expert model
-    #   needs (at 256 experts the capacity-padded [E, N, D] of
-    #   ``generate._no_drop`` is 32 times the work); not differentiated.
+    #   needs (at 256 experts a capacity-padded [E, N, D] with room for
+    #   every token is 32 times the work); not differentiated.
+    # This field is what TRAINING runs. At inference ``generate._no_drop``
+    # picks the dispatch of each program from the tokens in its call: a
+    # "gather" or "einsum" configuration runs "ragged" from
+    # ``generate.DROPLESS_FROM_TOKENS`` tokens up (a long prompt's bucket) and its
+    # own dispatch with capacity = tokens below (a decode step, whose few
+    # rows the expert weights' stream bounds either way); "ragged" stays.
     dispatch: str = "gather"
     # Router scores: "softmax" over the experts (GShard / Mixtral) or
     # "sigmoid" per expert (DeepSeek-V3's ``noaux_tc`` with one group:

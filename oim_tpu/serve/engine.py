@@ -653,6 +653,11 @@ class ServeEngine:
         # layers), rows of the fullest expert over the mean]. stats()
         # shows the sums; a reader takes the difference of two snapshots.
         self._expert_load = np.zeros(3, np.float64)
+        # Rows of expert FFN work the target's programs were dispatched
+        # with, by the dispatch generate._no_drop picked for each call's
+        # token count (oim_serve_expert_rows_total; stats() shows the sums).
+        self._expert_rows = {"dropless": 0, "padded": 0}
+        self._rows_of = gen.expert_rows
 
         # -- speculative decoding (serve/spec.py): draft propose K
         # tokens through its OWN small page pool (K lockstep decode
@@ -856,6 +861,8 @@ class ServeEngine:
                 self._stopping = True
             self._work.notify()
         self._thread.join(timeout=timeout)
+        if self.cfg.n_experts:
+            from_context().info("expert rows dispatched", **self._expert_rows)
 
     @property
     def active_slots(self) -> int:
@@ -911,6 +918,10 @@ class ServeEngine:
                 "cache_kind": self.cache_kind,
                 "kv_pages_used": self._pagepool.used_pages,
             }
+            if self.cfg.n_experts:
+                snap.update(
+                    expert_rows_dropless=self._expert_rows["dropless"],
+                    expert_rows_padded=self._expert_rows["padded"])
             if _counts_expert_load(self.cfg):
                 steps, touched, fullest = self._expert_load
                 snap.update(expert_load_steps=int(steps),
@@ -1397,6 +1408,15 @@ class ServeEngine:
         req.emitted += 1
         req.out.put(int(token))
 
+    def _count_expert_rows(self, n_tokens: int) -> None:
+        """One target program over ``n_tokens`` tokens is on its way: its
+        expert rows, from its shapes (no fetch, no device work)."""
+        if not self.cfg.n_experts:
+            return
+        dispatch, rows = self._rows_of(self.cfg, n_tokens)
+        self._expert_rows[dispatch] += rows
+        M.SERVE_EXPERT_ROWS.labels(dispatch=dispatch).inc(rows)
+
     def _bucket(self, n: int) -> int:
         b = self.MIN_PREFILL_BUCKET
         while b < n:
@@ -1635,6 +1655,7 @@ class ServeEngine:
             span_attrs = {"slot": slot, "prompt_tokens": n}
             if P:
                 span_attrs["prefix_tokens"] = P
+            self._count_expert_rows(padded.shape[1])
             with tracing.start_span(
                     "serve.prefill", parent=req.trace_ctx, **span_attrs):
                 tok, self._cache, key = self._prefill(
@@ -1699,6 +1720,7 @@ class ServeEngine:
             padded = np.zeros((1, self._bucket(len(piece))), np.int32)
             padded[0, :len(piece)] = piece
             since = time.monotonic()
+            self._count_expert_rows(padded.shape[1])
             with tracing.annotate("serve.prefill_chunk"):
                 tok, self._cache, key = self._prefill(
                     self.params, self._cache, jnp.asarray(padded),
@@ -1907,6 +1929,7 @@ class ServeEngine:
                 self._tables_dev, draft_toks, draft_logits,
                 self._spec_mask_dev)
         self._dev = (tok, pos, keys, d_temps)
+        self._count_expert_rows(self.max_batch * (self.spec_tokens + 1))
         with tracing.annotate("serve.fetch"):
             out = np.asarray(out)  # forces the round; the per-round fetch
             n_emit = np.asarray(n_emit)
@@ -2000,6 +2023,7 @@ class ServeEngine:
                 self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
                 self._tables_dev)
         self._dev = (tok, pos, keys, d_temps)
+        self._count_expert_rows(self.max_batch)
         if queue_behind is not None:
             queue_behind()
         with tracing.annotate("serve.fetch"):

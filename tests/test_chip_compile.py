@@ -228,6 +228,34 @@ def mosaic_kernels(text: str) -> list:
     return found
 
 
+def program_text(text: str) -> str:
+    """An optimized HLO without what names the checkout or a source line:
+    the header's tables and every ``metadata={...}`` go, and each Pallas
+    call's serialized body gives way to its Mosaic text without debug
+    locations. What is left is the program: the same in any checkout until
+    the code it is traced from computes something else."""
+    kernels = {line: body for _, line, body in mosaic_kernels(text)}
+    out, table = [], False
+    for line in text.splitlines():
+        # The header's tables of files, functions, lines and stack frames.
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            table = True
+        elif not line:
+            table = False
+        if table:
+            continue
+        body = kernels.get(line.strip())
+        if body is not None:
+            line = line[:line.index("backend_config=")] + body
+        out.append(re.sub(r",? ?metadata=\{[^{}]*\}", "", line))
+    return "\n".join(out)
+
+
+def text_hash(text: str) -> str:
+    return hashlib.sha256(program_text(text).encode()).hexdigest()[:16]
+
+
 def test_moves_of_reads_the_hlo():
     text = """
   %copy.94 = bf16[14,2561,16,8,128]{4,3,2,1,0:T(8,128)(2,1)} copy(%p)
@@ -260,18 +288,38 @@ def cell_shapes(cell, sharding):
     return cfg, params, pool, b, seq, bucket
 
 
+_COMPILED = {}
+
+
+def serving_program(chip, cell, program):
+    """(compiled, cfg, pool, max_batch, max_seq) of one serving program of a
+    benchmark cell at the cell's own sizes: ``step``, or
+    ``prefill-<bucket>``. Compiled once a module, whichever test asks."""
+    from oim_tpu.serve.engine import _target_programs
+
+    if (cell, program) not in _COMPILED:
+        if cell == "longctx":
+            cfg, params, pool, sizes, seq = latent_cell(chip)
+            b = sizes["max_batch"]
+        else:
+            cfg, params, pool, b, seq, _ = cell_shapes(cell, chip)
+        step, prefill = _target_programs(cfg, PAGE, seq)
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(chip, b, seq))
+        else:
+            lowered = prefill.lower(params, pool, *prefill_operands(
+                chip, int(program.split("-")[1]), seq))
+        _COMPILED[cell, program] = lowered.compile(), cfg, pool, b, seq
+    return _COMPILED[cell, program]
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_decode_reads_live_pages_in_place(topo, as_tpu, cell):
     """The decode program of each serving cell: the Pallas kernel is in
     it, and nothing copies, restacks or gathers an array of the pool's
     shape or of [B, S, kvh, hd]."""
-    from oim_tpu.serve.engine import _target_programs
-
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg, params, pool, b, seq, _ = cell_shapes(cell, chip)
-    step, _ = _target_programs(cfg, PAGE, seq)
-    compiled = step.lower(
-        params, pool, *step_operands(chip, b, seq)).compile()
+    compiled, cfg, pool, b, seq = serving_program(chip, cell, "step")
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # The GQA kernel as PR 27 left it (commit 518e4ec; PR 29 gave the
@@ -293,15 +341,10 @@ def test_cell_prefill_carries_the_pool(topo, as_tpu, cell):
     """The cell's commonest prefill bucket: the pool is scattered into in
     place (its gather of ONE slot's table stays until flash prefill over
     pages, ROADMAP S2)."""
-    from oim_tpu.serve.engine import _target_programs
-
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg, params, pool, _, seq, bucket = cell_shapes(cell, chip)
-    _, prefill = _target_programs(cfg, PAGE, seq)
-    text = prefill.lower(
-        params, pool, *prefill_operands(chip, bucket, seq)
-    ).compile().as_text()
-    assert not moves_of(text, pool["k"].shape)
+    compiled, _, pool, _, _ = serving_program(
+        chip, cell, f"prefill-{CELLS[cell][1]}")
+    assert not moves_of(compiled.as_text(), pool["k"].shape)
 
 
 def test_verify_carries_the_pool(topo, as_tpu):
@@ -468,13 +511,9 @@ def test_latent_decode_updates_the_pool_in_place(topo, as_tpu):
     row's pages), no f32 [B, H, block] scores at the kernel's block, and
     arguments + temporaries fit the chip."""
     from benchmarks import common
-    from oim_tpu.serve.engine import _target_programs
 
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg, params, pool, sizes, seq = latent_cell(chip)
-    b = sizes["max_batch"]
-    step, _ = _target_programs(cfg, PAGE, seq)
-    compiled = step.lower(params, pool, *step_operands(chip, b, seq)).compile()
+    compiled, cfg, pool, b, seq = serving_program(chip, "longctx", "step")
     text, mem = compiled.as_text(), compiled.memory_analysis()
     kernels = mosaic_kernels(text)
     assert [name for name, _, _ in kernels] == ["_latent_kernel"] * 2, \
@@ -515,13 +554,9 @@ def test_latent_prefill_chunk_carries_the_pool(topo, as_tpu, bucket):
     bucket): the pool in place, no [H, T, S] score array (blockwise over
     key blocks: 8.6 GB in float32 at 32 heads, 2048 x 32768), one row of
     logits, and arguments + temporaries inside 15.75 GB."""
-    from oim_tpu.serve.engine import _target_programs
-
     chip = SingleDeviceSharding(topo.devices[0])
-    cfg, params, pool, sizes, seq = latent_cell(chip)
-    _, prefill = _target_programs(cfg, PAGE, seq)
-    compiled = prefill.lower(
-        params, pool, *prefill_operands(chip, bucket, seq)).compile()
+    compiled, cfg, pool, _, seq = serving_program(
+        chip, "longctx", f"prefill-{bucket}")
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert not moves_of(text, pool["kv"].shape)
     assert f"f32[{cfg.n_heads},{bucket},{seq}]" not in text
@@ -530,4 +565,83 @@ def test_latent_prefill_chunk_carries_the_pool(topo, as_tpu, bucket):
         assert f"f32[{bucket},{cfg.vocab}]" not in text  # the last row only
         assert f"f32[1,{bucket},{cfg.vocab}]" not in text
     assert mem.temp_size_in_bytes < 1.25 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- which expert dispatch an inference program runs (generate._no_drop) -----
+# PR 31: a capacity-padded expert configuration runs dropless in a call of
+# DROPLESS_FROM_TOKENS tokens or more. Dense and dropless-by-configuration
+# models leave the rule at its first line, and Mixtral's decode step and
+# 512-token bucket lie under the crossing: text_hash of each such program
+# of the benchmark's serving cells as the parent of PR 31 (commit 9de7509)
+# compiled it. An edit that moves
+# one of these moves that cell's program: measure the cell, then record the
+# new text's hash.
+PARENT_TEXT = {
+    ("chat", "step"): "d82ac6ba838243ad",
+    ("chat", "prefill-1024"): "274aa17ced95e47e",
+    ("batch", "step"): "b0a3a231080409c7",
+    ("batch", "prefill-512"): "bebf0b3c99dbf0f2",
+    ("longctx", "step"): "0deb004e1b62525a",
+    ("longctx", "prefill-2048"): "98b3f1a3edaead55",
+}
+def test_program_text_drops_what_names_a_checkout():
+    text = """HloModule jit_step, is_scheduled=true
+
+FileNames
+1 "/root/repo/oim_tpu/models/generate.py"
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+
+ENTRY %main (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0), metadata={op_name="p" stack_frame_id=7}
+  ROOT %add.1 = f32[4]{0} add(%p, %p), metadata={op_name="jit(step)/add" \
+source_file="/root/repo/x.py" source_line=3}
+}"""
+    got = program_text(text)
+    assert "/root/repo" not in got and "metadata" not in got
+    assert "ROOT %add.1 = f32[4]{0} add(%p, %p)" in got
+    assert got == program_text(text.replace("/root/repo", "/tmp/other")
+                               .replace("source_line=3", "source_line=99"))
+    assert got != program_text(text.replace("add(%p, %p)", "multiply(%p, %p)"))
+
+
+@pytest.mark.parametrize("cell,program", sorted(PARENT_TEXT))
+def test_programs_outside_the_choice_are_the_parents(
+        topo, as_tpu, cell, program):
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, *_ = serving_program(chip, cell, program)
+    text = compiled.as_text()
+    if cfg.moe_dispatch != "ragged":  # Mixtral under the crossing, Mistral
+        assert "ragged-dot" not in text
+    assert text_hash(text) == PARENT_TEXT[cell, program]
+
+
+@pytest.mark.parametrize("bucket", [1024, 2048])
+def test_mixtral_prefill_runs_dropless(topo, as_tpu, bucket):
+    """The batch cell's largest prefill bucket (a gap that holds one is its
+    itl_p95_ms) and the configuration's largest: three grouped
+    products a layer over k x N rows, no [E, N, F] or [E, N, D] operand,
+    and the expert leaves [L, E, D, F] go into the products whole: none is
+    copied, none sliced a layer at a time (a slice handed to a custom call
+    is a copy: PR 28's 29 ms a step)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, *_ = serving_program(chip, "batch", f"prefill-{bucket}")
+    assert bucket >= gen.DROPLESS_FROM_TOKENS and cfg.moe_dispatch == "gather"
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    L, E, D, F, k = (cfg.n_layers, cfg.n_experts, cfg.dim, cfg.mlp_dim,
+                     cfg.moe_top_k)
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
+    assert sorted(calls) == sorted(
+        [(str(k * bucket), str(F))] * 2 + [(str(k * bucket), str(D))])
+    for width in (F, D):
+        assert f"[{E},{bucket},{width}]" not in text
+    for leaf in ((L, E, D, F), (E, D, F), (L, E, F, D), (E, F, D)):
+        assert not moves_of(text, leaf)
+        shape = ",".join(str(d) for d in leaf)
+        assert not re.search(
+            rf"= bf16\[(1,)?{shape}\]\S* (dynamic-slice|copy|fusion)\(", text)
+    if bucket == 1024:  # the parent's padded form held 288 MB here
+        assert mem.temp_size_in_bytes < 288 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
