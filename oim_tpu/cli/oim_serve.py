@@ -53,6 +53,9 @@ from oim_tpu.cli.common import (
 from oim_tpu.common.logging import from_context
 
 DEFAULT_VOLUME = "weights"
+# --model names the trainer has none for: llama.py's constant of each.
+SERVED_ONLY = {"joyai-llm-flash": "JOYAI_LLM_FLASH",
+               "nemotron-3-nano-30b": "NEMOTRON_3_NANO_30B"}
 
 
 def _load_params(args, log):
@@ -66,12 +69,14 @@ def _load_params(args, log):
     # that was trained (a mismatch with the weights is an error below,
     # never a truncation).
     overrides = parse_model_overrides(args.model_override)
-    if args.model == "joyai-llm-flash":
+    if args.model in SERVED_ONLY:
         # Served only: the trainer has no name for a block it cannot
-        # train yet (param_logical_axes refuses latent attention).
+        # train yet (param_logical_axes refuses latent attention and a
+        # hybrid pattern).
         from oim_tpu.models import llama
 
-        mcfg = dataclasses.replace(llama.JOYAI_LLM_FLASH, **overrides)
+        mcfg = dataclasses.replace(
+            getattr(llama, SERVED_ONLY[args.model]), **overrides)
     else:
         mcfg = TrainConfig(
             model=args.model, model_overrides=overrides).model_config()
@@ -219,7 +224,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     parser.add_argument("--model", default="llama-tiny",
                         choices=("llama-tiny", "llama-tiny-moe", "llama3-8b",
-                                 "joyai-llm-flash"))
+                                 *SERVED_ONLY))
     add_model_override_flag(parser)
     parser.add_argument("--checkpoint-dir", default="",
                         help="restore a trainer checkpoint in process")

@@ -624,6 +624,20 @@ SERVE_EXPERT_ROWS = DEFAULT.counter(
     "(= experts x tokens at inference) a layer; which one a call runs is "
     "generate._no_drop's rule on its token count",
     labelnames=("dispatch",))
+# Recurrent state beside the pages (a hybrid's Mamba layers: models/
+# generate.py init_state_pool): a fixed size a slot, held whole.
+SERVE_STATE_BYTES = DEFAULT.gauge(
+    "oim_serve_state_bytes",
+    "device bytes of the recurrent state the replica holds beside its page "
+    "pool: max_batch slots x the Mamba layers' state a slot (0 for a model "
+    "without such layers)")
+SERVE_STATE_SLOTS_LIVE = DEFAULT.gauge(
+    "oim_serve_state_slots_live",
+    "slots whose row of the recurrent state belongs to a live request")
+SERVE_STATE_RESETS = DEFAULT.counter(
+    "oim_serve_state_resets_total",
+    "admissions that began a slot's recurrent state from zeros (one a "
+    "request: its first prompt slice)")
 # Paged KV cache (serve/pagepool.py): the pool every slot's page table
 # maps into; shared = pages referenced more than once (prefix sharing).
 SERVE_KV_PAGES_TOTAL = DEFAULT.gauge(

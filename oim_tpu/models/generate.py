@@ -29,8 +29,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oim_tpu.models.llama import Config, _block, layer_groups
-from oim_tpu.ops import latent_attention
+from oim_tpu.models.llama import (
+    Config,
+    _attn_mixer,
+    _block,
+    _expert_mixer,
+    layer_groups,
+    run_pattern,
+)
+from oim_tpu.ops import latent_attention, ssm
 from oim_tpu.ops.norms import rmsnorm
 from oim_tpu.ops.paged_attention import cache_attention, paged_attention
 from oim_tpu.ops.rope import rope_frequencies
@@ -68,6 +75,11 @@ def shard_config(cfg: Config, n: int) -> Config:
         raise ValueError(f"shard count must be >= 1, got {n}")
     if n == 1:
         return cfg
+    if cfg.hybrid_override_pattern:
+        raise ValueError(
+            "tensor-parallel decode does not support a hybrid pattern yet "
+            "(the recurrent state and the mixers have no sharding rules; "
+            f"hybrid_override_pattern={cfg.hybrid_override_pattern!r})")
     if cfg.kv_lora_rank:
         raise ValueError(
             "tensor-parallel decode does not support latent attention yet "
@@ -145,17 +157,21 @@ def expert_rows(cfg: Config, n_tokens: int) -> tuple[str, int]:
     """(dispatch, rows of expert FFN work over all expert layers) of one
     inference program over ``n_tokens`` tokens, from its shapes alone:
     ("dropless", k x N a layer), ("padded", E x capacity = E x N a layer)
-    or ("", 0) for a dense configuration. What the serving engine counts
-    at each dispatch (``oim_serve_expert_rows_total``): cached, a decode
-    round asks every time."""
+    or ("", 0) for a dense configuration. Of a held share
+    (``Config.expert_rank``) the rows of the experts held: k x N x held / E
+    a layer, what uniform routing sends them (the rows themselves are the
+    data's). What the serving engine counts at each dispatch
+    (``oim_serve_expert_rows_total``): cached, a decode round asks every
+    time."""
     if not cfg.n_experts:
         return "", 0
     from oim_tpu.models import moe
 
     run = _no_drop(cfg, n_tokens)
-    layers = cfg.n_layers - cfg.n_dense_layers
+    layers = cfg.n_expert_layers
     if run.moe_dispatch == "ragged":
-        return "dropless", layers * run.moe_top_k * n_tokens
+        return "dropless", (layers * run.moe_top_k * n_tokens
+                            * run.moe.n_held // run.n_experts)
     return "padded", layers * run.n_experts * moe.capacity(n_tokens, run.moe)
 
 
@@ -163,7 +179,14 @@ def init_cache(cfg: Config, batch: int, max_seq: int):
     """Zeroed dense cache: one [L, B, max_seq, ...] array a leaf of
     ``cfg.cache_leaves`` ({"k","v"} [.., kv_heads, head_dim] for GQA,
     {"kv"} [.., kv_lora_rank + qk_rope_head_dim] for latent attention)."""
-    return {name: jnp.zeros((cfg.n_layers, batch, max_seq) + tail, cfg.dtype)
+    if cfg.hybrid_override_pattern:
+        raise ValueError(
+            "the dense cache runs the attention-then-FFN block and holds no "
+            "recurrent state: a hybrid pattern is served through the page "
+            "pool and the state pool (ServeEngine), or run whole "
+            "(llama.apply)")
+    return {name: jnp.zeros((cfg.n_cache_layers, batch, max_seq) + tail,
+                            cfg.dtype)
             for name, tail in cfg.cache_leaves.items()}
 
 
@@ -205,7 +228,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     reassemble the projections (see :func:`_reduce`).
     """
     B, T = tokens.shape
-    S = jax.tree.leaves(cache)[0].shape[2]
+    S = _page_leaf(cache, cfg).shape[2]
     cfg = _no_drop(cfg, B * T)
     # Host-numpy weight trees (a freshly restored checkpoint) must work:
     # numpy arrays can't be indexed by traced token ids inside the decode
@@ -234,7 +257,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
         return x, c
 
     x, cache = _scan_groups(body, x, params, cfg, cache)
-    x = rmsnorm(x, params["final_norm"])
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
@@ -294,7 +317,7 @@ def init_page_pool(cfg: Config, n_pages: int, page_tokens: int):
     it, and idle decode rows write their discarded K/V into it — its
     content is garbage by design and is only ever read through the
     causal mask's exact-zero branch."""
-    return {name: jnp.zeros((cfg.n_layers, n_pages, page_tokens) + tail,
+    return {name: jnp.zeros((cfg.n_cache_layers, n_pages, page_tokens) + tail,
                             cfg.dtype)
             for name, tail in cfg.cache_leaves.items()}
 
@@ -305,12 +328,51 @@ def page_bytes(cfg: Config, page_tokens: int) -> int:
     import math
 
     per_position = sum(math.prod(t) for t in cfg.cache_leaves.values())
-    return (cfg.n_layers * page_tokens * per_position
+    return (cfg.n_cache_layers * page_tokens * per_position
             * jnp.dtype(cfg.dtype).itemsize)
 
 
+# Beside the pages a hybrid keeps RECURRENT STATE: what a slot's Mamba
+# layers hold whatever its position (``Config.state_leaves``), a row a slot
+# of the engine's batch, {"ssm": [Lm, slots, H, P, N] float32, "conv":
+# [Lm, slots, (K - 1) * conv_dim]} (ops/ssm.py says why a slot's conv
+# window is kept flat). It rides in the SAME dict as the page leaves, so the
+# serving programs donate and update it with the pool. A prefill at
+# ``start`` = 0 begins from zeros (no call zeroes a row: a retired slot's
+# state is dead where it lies), a later slice of a chunked prefill from the
+# row its predecessor left, a decode step updates the rows of live slots
+# only — a row whose first table entry is the scratch page is idle, as in
+# ``ops/paged_attention._paged_decode`` — and pad positions move nothing.
+
+
+def init_state_pool(cfg: Config, slots: int) -> dict:
+    """Zeroed recurrent state for ``slots`` slots ({} without Mamba
+    layers)."""
+    import math
+
+    n = cfg.n_of("M")
+    return {name: jnp.zeros(
+        (n, slots) + (shape if name == "ssm" else (math.prod(shape),)), dtype)
+        for name, (shape, dtype) in cfg.state_leaves.items()}
+
+
+def state_bytes(cfg: Config, slots: int = 1) -> int:
+    """Device bytes of ``slots`` slots' recurrent state over all Mamba
+    layers."""
+    import math
+
+    return slots * cfg.n_of("M") * sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize
+        for shape, dtype in cfg.state_leaves.values())
+
+
+def _page_leaf(pool, cfg: Config):
+    """One page leaf of ``pool`` (all share [L, n_pages, page_tokens])."""
+    return pool[next(iter(cfg.cache_leaves))]
+
+
 def _forward_paged(params, tokens, pool, tables, pos, phys, off,
-                   cfg: Config, axis: str | None):
+                   cfg: Config, axis: str | None, slot=None, n_tokens=None):
     """The one layer loop of the three serving programs: forward
     ``tokens`` [B, T] at absolute positions pos[b] + t (``pos`` a scalar
     or [B]), writing position (b, t)'s cache entry at pool[l, phys[b, t],
@@ -327,9 +389,14 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     callers' donation. Carried, the caller's donated buffer is the one
     the scatter updates in place (tests/test_chip_compile.py holds the
     compiled programs to it). The layer index runs on through the layer
-    groups (an expert model's leading dense layers, then the rest)."""
+    groups (an expert model's leading dense layers, then the rest).
+
+    A hybrid pattern's layers run in pattern order (``llama.run_pattern``)
+    with the recurrent state of ``pool`` beside the pages: ``slot`` None is
+    a decode step (row b of the state is batch row b's), else the one
+    slot a prefill of ``n_tokens`` real positions reads and writes."""
     B, T = tokens.shape
-    page = jax.tree.leaves(pool)[0].shape[2]
+    page = _page_leaf(pool, cfg).shape[2]
     S = tables.shape[1] * page
     cfg = _no_drop(cfg, B * T)
     params = jax.tree.map(jnp.asarray, params)
@@ -337,9 +404,8 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
     x = params["embed"][tokens].astype(cfg.dtype)
 
-    def body(carry, inp):
-        x, pool, l = carry  # pool leaves: [L, n_pages, page, ...]
-
+    def attend_at(l):
+        """Layer l's attention over the pool it is handed."""
         def attend(pool, q, *new):
             if cfg.kv_lora_rank:
                 latent, wkv_b = new
@@ -350,22 +416,90 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
             pk = pool["k"].at[l, phys, off].set(k, mode="drop")
             pv = pool["v"].at[l, phys, off].set(v, mode="drop")
             return (paged_attention(q, pk, pv, l, tables, pos),
-                    {"k": pk, "v": pv})
+                    {**pool, "k": pk, "v": pv})
+        return attend
 
-        x, aux, pool = _block(x, inp[0], cfg, cos, sin, positions, attend,
-                              pool, lambda y: _reduce(y, axis), load=True)
+    n_moe = max(cfg.n_expert_layers, 1)
+    if cfg.hybrid_override_pattern:
+        x, pool, load = _hybrid_paged(
+            params, x, pool, cfg, cos, sin, positions, attend_at,
+            tables[:, 0] != 0, slot, n_tokens, pos)
+        return rmsnorm(x, params["final_norm"], cfg.norm_eps), pool, load / n_moe
+
+    def body(carry, inp):
+        x, pool, l = carry  # pool leaves: [L, n_pages, page, ...]
+        x, aux, pool = _block(x, inp[0], cfg, cos, sin, positions,
+                              attend_at(l), pool, lambda y: _reduce(y, axis),
+                              load=True)
         return (x, pool, l + 1), aux[2:]
 
     (x, pool, _), load = _scan_groups(
         body, (x, pool, jnp.int32(0)), params, cfg)
-    n_moe = max(cfg.n_layers - cfg.n_dense_layers, 1) if cfg.n_experts else 1
-    return (rmsnorm(x, params["final_norm"]), pool,
+    return (rmsnorm(x, params["final_norm"], cfg.norm_eps), pool,
             jnp.sum(load, axis=0) / n_moe)
+
+
+def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
+                  attend_at, live, slot, n_tokens, start):
+    """``_forward_paged``'s layer loop for a hybrid pattern: (x, pool,
+    summed expert load [2]). ``pool`` carries pages and recurrent state;
+    each Mamba layer reads its rows of the state where they lie and writes
+    them back in place."""
+    m, eps = cfg.mamba, cfg.norm_eps
+    window = cfg.state_leaves["conv"][0] if m else ()  # (K - 1, conv_dim)
+
+    def mamba(carry, layer, i):
+        x, pool, load = carry
+        h = rmsnorm(x, layer["norm"], eps)
+        sp, cp = pool["ssm"], pool["conv"]
+        # The state's read and write-back stand under the mixer's scope
+        # too: the compiler fuses them with its update, and the profile
+        # names a fusion after one of its operations.
+        if slot is None:  # a decode step: every row, live rows kept
+            with jax.named_scope("ssm_step"):
+                s, c = sp[i], cp[i]
+                y, s2, c2 = ssm.step(layer, h[:, 0], s,
+                                     c.reshape((-1,) + window), m, eps)
+                s2 = jnp.where(live[:, None, None, None], s2, s)
+                c2 = jnp.where(live[:, None], c2.reshape(c.shape), c)
+                pool = {**pool, "ssm": sp.at[i].set(s2),
+                        "conv": cp.at[i].set(c2)}
+            return (x + y[:, None], pool, load)
+        # A prompt slice of one slot, from zeros at position 0.
+        with jax.named_scope("ssm_scan"):
+            s = lax.dynamic_slice(sp, (i, slot, 0, 0, 0),
+                                  (1, 1) + sp.shape[2:])[0]
+            c = lax.dynamic_slice(cp, (i, slot, 0), (1, 1, cp.shape[2]))[0]
+            s = jnp.where(start == 0, jnp.zeros_like(s), s)
+            c = jnp.where(start == 0, jnp.zeros_like(c), c)
+            y, s2, c2 = ssm.scan(layer, h, s, c.reshape((1,) + window),
+                                 n_tokens, m, eps)
+            pool = {**pool,
+                    "ssm": lax.dynamic_update_slice(
+                        sp, s2[None], (i, slot, 0, 0, 0)),
+                    "conv": lax.dynamic_update_slice(
+                        cp, c2.reshape(1, 1, -1), (i, slot, 0))}
+        return (x + y, pool, load)
+
+    def experts(carry, layer, _):
+        x, pool, load = carry
+        x, aux = _expert_mixer(x, layer, cfg, load=True)
+        return (x, pool, load + aux[2:])
+
+    def attention(carry, layer, i):
+        x, pool, load = carry
+        x, pool = _attn_mixer(x, layer, cfg, cos, sin, positions,
+                              attend_at(i), pool)
+        return (x, pool, load)
+
+    return run_pattern(
+        params, cfg, (x, pool, jnp.zeros((2,), jnp.float32)),
+        {"M": mamba, "E": experts, "*": attention})
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
                        start, cfg: Config, page_tokens: int,
-                       axis: str | None = None):
+                       axis: str | None = None, slot=0):
     """Prefill ``tokens`` [1, T] (first ``n_tokens`` real, rest pad — the
     engine buckets prompt lengths so one compiled program serves many)
     through the slot's ``page_table`` [n_blocks] into the page pool,
@@ -392,11 +526,15 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     SHARED page immutable — a slot may only write pages it privately
     owns (its tail and decode blocks), which is the copy-on-write
     contract the prefix store relies on.
+
+    ``slot`` is the engine slot being filled: the row of the recurrent
+    state a hybrid's Mamba layers carry from slice to slice (zeros at
+    ``start`` = 0); other configurations do not read it.
     """
     T = tokens.shape[1]  # tokens [1, T]: admission is per-slot
     nb = page_table.shape[0]
     S = nb * page_tokens
-    n_pages = jax.tree.leaves(pool)[0].shape[1]
+    n_pages = _page_leaf(pool, cfg).shape[1]
     logical = start + jnp.arange(T)
     blk = jnp.minimum(logical // page_tokens, nb - 1)
     keep = (jnp.arange(T) < n_tokens) & (logical < S)
@@ -404,7 +542,7 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     phys = jnp.where(keep, page_table[blk], n_pages)
     x, pool, _ = _forward_paged(
         params, tokens, pool, page_table[None], start, phys[None],
-        (logical % page_tokens)[None], cfg, axis)
+        (logical % page_tokens)[None], cfg, axis, slot, n_tokens)
     # The last real row is taken BEFORE the head: one row of logits is
     # kept, so one row is computed (at 129 280 rows of vocabulary a
     # 2048-token chunk's float32 logits would be 1 GB for nothing).
@@ -478,9 +616,13 @@ def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
     gather, and anything beyond that horizon is masked by ``pos`` with
     exact-zero softmax weight (the same argument that makes paged
     attention byte-identical)."""
+    if cfg.state_leaves:
+        raise ValueError(
+            "verify_step does not support recurrent state yet: a rejected "
+            "candidate would have to roll the state back (serve/spec.py)")
     B, T = tokens.shape
     nb = page_tables.shape[1]
-    n_pages = jax.tree.leaves(pool)[0].shape[1]
+    n_pages = _page_leaf(pool, cfg).shape[1]
     positions = pos[:, None] + jnp.arange(T)[None, :]  # [B, T]
     blk = jnp.minimum(positions // page_tokens, nb - 1)
     # Out-of-range physical index: past-the-table K/V never lands (same

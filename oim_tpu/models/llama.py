@@ -33,6 +33,13 @@ from oim_tpu.ops.rope import apply_rope, rope_frequencies
 from oim_tpu.parallel.sharding import EMBED, HEAD, KV_HEAD, LAYER, MLP, VOCAB
 
 
+# A hybrid's parameters are stacked a KIND of mixer ("M" Mamba-2, "E"
+# experts, "*" attention), whatever the order its pattern runs them in
+# (``run_pattern``).
+HYBRID_GROUPS = {"M": "mamba_layers", "E": "expert_layers",
+                 "*": "attn_layers"}
+
+
 @dataclasses.dataclass(frozen=True)
 class Config:
     vocab: int = 128256
@@ -81,6 +88,33 @@ class Config:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # RMSNorm's epsilon, every norm of the model.
+    norm_eps: float = 1e-6
+    # Whether the GQA attention rotates its queries and keys (RoPE). A
+    # hybrid whose state-space layers carry position rotates nothing.
+    attn_rope: bool = True
+    # The expert FFN's form ("silu": gated SwiGLU; "relu2": non-gated
+    # squared ReLU, models/moe.py) and the shared expert's own width (0:
+    # n_shared_experts x the routed experts' width).
+    mlp_hidden_act: str = "silu"
+    moe_shared_expert_intermediate_size: int = 0
+    # One rank's share of an expert-parallel deployment, "rank/ranks": the
+    # parameter tree holds n_experts / ranks routed experts a layer, from
+    # expert rank x that on; router, top-k and every other leaf are whole
+    # (models/moe.py MoEConfig.held). "" holds every expert.
+    expert_rank: str = ""
+    # A hybrid of mixers (the nemotron_h family's published keys): one
+    # character a layer, "M" a Mamba-2 mixer (ops/ssm.py), "E" an expert
+    # FFN, "*" GQA attention, each block ``x + mixer(norm(x))`` behind ONE
+    # norm. "" is the attention-then-FFN block of every other
+    # configuration. n_layers is the pattern's length.
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 0
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    chunk_size: int = 128
     # Rematerialize each layer's activations in the backward pass
     # (jax.checkpoint around the scan body): ~1/3 more FLOPs for O(1)-layer
     # activation memory — what makes 8B-class configs at long context fit
@@ -121,6 +155,46 @@ class Config:
             raise ValueError(
                 "scoring_func='sigmoid' routes dropless: it needs "
                 f"moe_dispatch='ragged', got {self.moe_dispatch!r}")
+        pattern = self.hybrid_override_pattern
+        if pattern:
+            if set(pattern) - set(HYBRID_GROUPS) \
+                    or len(pattern) != self.n_layers:
+                raise ValueError(
+                    f"hybrid_override_pattern {pattern!r} must be n_layers "
+                    f"({self.n_layers}) characters of {sorted(HYBRID_GROUPS)}")
+            if "M" in pattern and not (
+                    self.mamba_num_heads and self.mamba_head_dim
+                    and self.n_groups and self.ssm_state_size
+                    and self.mamba_num_heads % self.n_groups == 0):
+                raise ValueError(
+                    "a pattern with 'M' needs mamba_num_heads (a multiple of "
+                    "n_groups), mamba_head_dim, n_groups and ssm_state_size")
+            if "E" in pattern and not (
+                    self.n_experts and self.moe_dispatch == "ragged"):
+                raise ValueError(
+                    "a pattern with 'E' needs n_experts and "
+                    "moe_dispatch='ragged' (the hybrid's experts run dropless)")
+            if self.kv_lora_rank or self.first_k_dense_replace:
+                raise ValueError(
+                    "a hybrid pattern runs GQA attention and no leading "
+                    "dense layers")
+        self.experts_held  # a malformed expert_rank fails here
+
+    @property
+    def experts_held(self) -> tuple:
+        """(first, count) of the routed experts this tree holds, or ()."""
+        if not self.expert_rank:
+            return ()
+        try:
+            rank, ranks = (int(v) for v in self.expert_rank.split("/"))
+            if not 0 <= rank < ranks or self.n_experts % ranks:
+                raise ValueError
+        except ValueError:
+            raise ValueError(
+                f"expert_rank {self.expert_rank!r}: expected 'rank/ranks' "
+                f"with ranks dividing n_experts ({self.n_experts})") from None
+        count = self.n_experts // ranks
+        return (rank * count, count)
 
     @property
     def moe(self):
@@ -134,7 +208,46 @@ class Config:
             scoring=self.scoring_func,
             routed_scale=self.routed_scaling_factor,
             n_shared=self.n_shared_experts,
+            shared_dim=self.moe_shared_expert_intermediate_size,
+            act=self.mlp_hidden_act,
+            held=self.experts_held,
         )
+
+    @property
+    def mamba(self):
+        """The Mamba-2 mixer's sizes, or None without such layers."""
+        if "M" not in self.hybrid_override_pattern:
+            return None
+        from oim_tpu.ops.ssm import Dims
+
+        return Dims(heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
+                    groups=self.n_groups, state=self.ssm_state_size,
+                    conv=self.conv_kernel, chunk=self.chunk_size)
+
+    def n_of(self, kind: str) -> int:
+        """Layers of a hybrid pattern's kind ("M", "E", "*")."""
+        return self.hybrid_override_pattern.count(kind)
+
+    @property
+    def n_expert_layers(self) -> int:
+        if self.hybrid_override_pattern:
+            return self.n_of("E")
+        return self.n_layers - self.n_dense_layers if self.n_experts else 0
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Layers that keep a per-position cache: the attention layers."""
+        if self.hybrid_override_pattern:
+            return self.n_of("*")
+        return self.n_layers
+
+    @property
+    def state_leaves(self) -> dict:
+        """What a SLOT keeps in each Mamba layer, whatever its position:
+        {leaf: (shape, dtype)} (ops/ssm.py); {} without such layers. The
+        serving engine holds it beside the page pool, a row a slot."""
+        m = self.mamba
+        return m.slot_leaves(self.dtype) if m else {}
 
     @property
     def expert_dim(self) -> int:
@@ -182,9 +295,10 @@ class Config:
 
     @property
     def cache_leaves(self) -> dict:
-        """What a position keeps in a layer's cache: {leaf: trailing
-        shape}. The page pool, the dense cache, the host tier and the
-        exported volumes are all built over these leaves."""
+        """What a position keeps in an attention layer's cache
+        (``n_cache_layers`` of them): {leaf: trailing shape}. The page
+        pool, the dense cache, the host tier and the exported volumes are
+        all built over these leaves."""
         if self.kv_lora_rank:
             return {"kv": (self.latent.width,)}
         return {"k": (self.n_kv_heads, self.head_dim),
@@ -207,6 +321,43 @@ JOYAI_LLM_FLASH = Config(
     qk_rope_head_dim=64, v_head_dim=128, n_experts=256, moe_top_k=8,
     moe_intermediate_size=768, n_shared_experts=1, first_k_dense_replace=1,
     scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
+
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B (31.6B-A3.2B) as published: 23 Mamba-2
+# mixers, 23 expert layers (128 sigmoid-routed squared-ReLU experts top-6
+# beside a shared one of its own width) and 6 GQA attention layers that
+# rotate nothing, in a pattern that is not periodic; eps 1e-5.
+# https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16/blob/main/config.json
+# 63 GB in bfloat16: one v5e chip holds one rank of an 8-way expert-parallel
+# deployment at full depth (--model-override expert_rank=0/8 vocab=16384;
+# benchmarks/configs/nemotron-3-nano-30b.json).
+NEMOTRON_3_NANO_30B = Config(
+    vocab=131072, dim=2688, n_layers=52, n_heads=32, n_kv_heads=2,
+    head_dim=128, mlp_dim=1856, max_seq=262144, rope_theta=10000.0,
+    attn_rope=False, norm_eps=1e-5,
+    hybrid_override_pattern=(
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"),
+    mamba_num_heads=64, mamba_head_dim=64, n_groups=8, ssm_state_size=128,
+    conv_kernel=4, chunk_size=128, n_experts=128, moe_top_k=6,
+    moe_intermediate_size=1856, n_shared_experts=1,
+    moe_shared_expert_intermediate_size=3712, mlp_hidden_act="relu2",
+    scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
+
+
+def tiny_hybrid(vocab: int = 512, pattern: str = "MEM*EMEME", dtype=jnp.float32,
+                expert_rank: str = "") -> Config:
+    """The nemotron_h family's block at test scale, all three kinds of
+    mixer in a pattern with a scanned run and single layers."""
+    return Config(
+        vocab=vocab, dim=64, n_layers=len(pattern), n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=48, max_seq=512, dtype=dtype, attn_rope=False,
+        norm_eps=1e-5, hybrid_override_pattern=pattern, mamba_num_heads=8,
+        mamba_head_dim=8, n_groups=2, ssm_state_size=16, conv_kernel=4,
+        chunk_size=8, n_experts=16, moe_top_k=4, moe_intermediate_size=48,
+        n_shared_experts=1, moe_shared_expert_intermediate_size=96,
+        mlp_hidden_act="relu2", scoring_func="sigmoid",
+        routed_scaling_factor=2.5, moe_dispatch="ragged",
+        expert_rank=expert_rank)
 
 
 def tiny(vocab: int = 256, dim: int = 64, n_layers: int = 2,
@@ -292,6 +443,37 @@ def layer_groups(params) -> list:
     return [params[g] for g in LAYER_GROUPS if g in params]
 
 
+def _init_hybrid(rng, cfg: Config) -> dict:
+    """The three stacked groups of a hybrid, each layer ONE mixer behind
+    ONE norm."""
+    from oim_tpu.models import moe
+    from oim_tpu.ops import ssm
+
+    D = cfg.dim
+    ks = jax.random.split(rng, 7)
+    fan = D**-0.5
+    n_m, n_e, n_a = (cfg.n_of(k) for k in "ME*")
+    groups = {}
+    if n_m:
+        groups["mamba_layers"] = {
+            "norm": jnp.ones((n_m, D), jnp.float32),
+            **ssm.init(ks[0], D, cfg.mamba, cfg.dtype, n_m)}
+    if n_e:
+        groups["expert_layers"] = {
+            "norm": jnp.ones((n_e, D), jnp.float32),
+            "moe": moe.init(ks[1], D, cfg.expert_dim, cfg.moe, cfg.dtype,
+                            n_layers=n_e)}
+    if n_a:
+        groups["attn_layers"] = {
+            "norm": jnp.ones((n_a, D), jnp.float32),
+            "wq": _dense(ks[2], (n_a, D, cfg.q_dim), cfg.dtype, fan),
+            "wk": _dense(ks[3], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
+            "wv": _dense(ks[4], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
+            "wo": _dense(ks[5], (n_a, cfg.o_dim, D), cfg.dtype,
+                         cfg.o_dim**-0.5)}
+    return groups
+
+
 def init(rng, cfg: Config = LLAMA3_8B):
     D = cfg.dim
     ks = jax.random.split(rng, 10)
@@ -299,22 +481,27 @@ def init(rng, cfg: Config = LLAMA3_8B):
     lead = cfg.n_dense_layers
     params = {
         "embed": _dense(ks[0], (cfg.vocab, D), cfg.dtype, scale=0.02),
-        "layers": _init_group(rng, cfg, cfg.n_layers - lead,
-                              bool(cfg.n_experts)),
         "final_norm": jnp.ones((D,), jnp.float32),
         "lm_head": _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan),
     }
+    if cfg.hybrid_override_pattern:
+        params.update(_init_hybrid(rng, cfg))
+        return params
+    params["layers"] = _init_group(rng, cfg, cfg.n_layers - lead,
+                                   bool(cfg.n_experts))
     if lead:
         params["dense_layers"] = _init_group(ks[9], cfg, lead, False)
     return params
 
 
 def param_logical_axes(cfg: Config = LLAMA3_8B):
-    if cfg.kv_lora_rank or cfg.n_dense_layers or cfg.n_shared_experts:
+    if (cfg.kv_lora_rank or cfg.n_dense_layers or cfg.n_shared_experts
+            or cfg.hybrid_override_pattern or cfg.expert_rank):
         raise ValueError(
             "no sharding rules yet for latent attention, leading dense "
             "layers or shared experts: this block is served on one chip "
-            "and not trained (ROADMAP.md, Reach)")
+            "and not trained (ROADMAP.md, Reach); nor for a hybrid pattern's "
+            "mixers or a held share of the experts")
     layers = {
         "attn_norm": (LAYER, None),
         "wq": (LAYER, EMBED, HEAD),
@@ -403,31 +590,150 @@ def _block(x, layer, cfg: Config, cos, sin, positions, attend, cache=None,
 
     ``reduce`` sums the two row-split projections over a tensor-parallel
     axis. Returns (x, aux, cache)."""
-    B, T, _ = x.shape
-    h = rmsnorm(x, layer["attn_norm"])
+    h = rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
+    attn, cache = _attention(h, layer, cfg, cos, sin, positions, attend,
+                             cache)
+    x = x + reduce(attn @ layer["wo"])
+    h = rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
+    ffn, aux = _ffn(h, layer, cfg, load)
+    return x + reduce(ffn), aux, cache
+
+
+def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
+    """The attention of a block on the normed activations h [B, T, D], up
+    to (not with) ``wo``: (output [B, T, o_dim], cache). See ``_block``."""
+    B, T, _ = h.shape
+    eps = cfg.norm_eps
     if cfg.kv_lora_rank:
         m = cfg.latent
-        q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"]) @ layer["wq_b"]
+        q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"], eps) @ layer["wq_b"]
              ).reshape(B, T, m.heads, m.nope + m.rope)
         q = jnp.concatenate(
             [q[..., :m.nope],
              apply_rope(q[..., m.nope:], cos, sin, positions)], axis=-1)
         ckv = h @ layer["wkv_a"]
         k_r = apply_rope(ckv[..., None, m.rank:], cos, sin, positions)
-        latent = m.entry(rmsnorm(ckv[..., :m.rank], layer["kv_norm"]),
+        latent = m.entry(rmsnorm(ckv[..., :m.rank], layer["kv_norm"], eps),
                          k_r[..., 0, :])
         attn, cache = attend(cache, q, latent, layer["wkv_b"])
     else:
         q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
         k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
         v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        if cfg.attn_rope:
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
         attn, cache = attend(cache, q, k, v)
-    x = x + reduce(attn.reshape(B, T, cfg.o_dim) @ layer["wo"])
-    h = rmsnorm(x, layer["mlp_norm"])
-    ffn, aux = _ffn(h, layer, cfg, load)
-    return x + reduce(ffn), aux, cache
+    return attn.reshape(B, T, cfg.o_dim), cache
+
+
+def pattern_runs(pattern: str) -> tuple:
+    """A hybrid pattern as the runs it is executed in: ((unit, repeat),
+    ...). A unit of two kinds that repeats ("EMEMEM" = ("EM", 3)) is one
+    ``lax.scan`` over its repeats; every other layer runs alone. The
+    published 52-layer pattern is 7 scans, 2 single layers and the 6
+    attention layers: each kind's body is traced a number of times that
+    follows the attention layers' count, not the depth."""
+    runs, i = [], 0
+    while i < len(pattern):
+        unit = pattern[i:i + 2]
+        if len(set(unit)) == 2 and "*" not in unit:
+            r = 1
+            while pattern[i + 2 * r:i + 2 * r + 2] == unit:
+                r += 1
+            if r > 1:
+                runs.append((unit, r))
+                i += 2 * r
+                continue
+        runs.append((pattern[i], 1))
+        i += 1
+    return tuple(runs)
+
+
+def run_pattern(params, cfg: Config, carry, mixers: dict):
+    """Run a hybrid's layers in pattern order: ``mixers[kind](carry, layer,
+    index) -> carry`` with that kind's leaves at ``index`` (the layer's
+    place among its kind: traced inside a scanned run, a Python int
+    outside). The stacked groups are indexed where they lie and never
+    sliced for a scan (a run starts anywhere in its kind's stack); an
+    expert group's grouped-product leaves stay whole (moe.keep_stacked)."""
+    from oim_tpu.models import moe
+
+    groups = {kind: moe.keep_stacked(params[name])
+              for kind, name in HYBRID_GROUPS.items() if name in params}
+
+    def layer_at(kind, i):
+        sliced, whole = groups[kind]
+        return moe.at_layer(jax.tree.map(lambda a: a[i], sliced), whole, i)
+
+    at = dict.fromkeys(HYBRID_GROUPS, 0)
+    for unit, repeat in pattern_runs(cfg.hybrid_override_pattern):
+        def once(carry, j, base=dict(at), unit=unit):
+            for kind in unit:
+                i = base[kind] + j
+                carry = mixers[kind](carry, layer_at(kind, i), i)
+            return carry
+
+        if repeat == 1:
+            carry = once(carry, 0)
+        else:
+            carry, _ = lax.scan(lambda c, j, once=once: (once(c, j), None),
+                                carry, jnp.arange(repeat))
+        for kind in unit:
+            at[kind] += repeat
+    return carry
+
+
+def _expert_mixer(x, layer, cfg: Config, load: bool = False):
+    """A hybrid's expert layer: (x + experts(norm(x)), aux)."""
+    from oim_tpu.models import moe
+
+    h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+    out, aux = moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
+                         with_load=load)
+    return x + out, aux
+
+
+def _attn_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache):
+    """A hybrid's attention layer: (x + attention(norm(x)), cache)."""
+    h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+    attn, cache = _attention(h, layer, cfg, cos, sin, positions, attend,
+                             cache)
+    return x + attn @ layer["wo"], cache
+
+
+def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
+    """A hybrid's layers over whole sequences x [B, T, D] from an empty
+    state, no cache: (x, aux [2])."""
+    from oim_tpu.ops import ssm
+
+    B, T, _ = x.shape
+    m = cfg.mamba
+
+    def mamba(carry, layer, _):
+        x, aux = carry
+        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+        empty = {k: jnp.zeros((B,) + shape, dt)
+                 for k, (shape, dt) in cfg.state_leaves.items()}
+        y, _, _ = ssm.scan(layer, h, empty["ssm"], empty["conv"], T, m,
+                           cfg.norm_eps)
+        return x + y, aux
+
+    def experts(carry, layer, _):
+        x, aux = carry
+        x, layer_aux = _expert_mixer(x, layer, cfg)
+        return x, aux + layer_aux
+
+    def attention(carry, layer, _):
+        x, aux = carry
+        x, _ = _attn_mixer(
+            x, layer, cfg, cos, sin, None,
+            lambda _, q, k, v: (attn_fn(q, k, v, causal=True), None), None)
+        return x, aux
+
+    return run_pattern(
+        params, cfg, (x, jnp.zeros((2,), jnp.float32)),
+        {"M": mamba, "E": experts, "*": attention})
 
 
 def _layer(x, layer, cfg: Config, cos, sin, attn_fn: AttentionFn):
@@ -456,6 +762,9 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
     T = tokens.shape[1]
     cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
     x = params["embed"][tokens].astype(cfg.dtype)
+    if cfg.hybrid_override_pattern:
+        x, aux = _hybrid_hidden(params, x, cfg, cos, sin, attn_fn)
+        return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 
     def body(x, layer):
         x, aux = _layer(x, layer, cfg, cos, sin, attn_fn)
@@ -469,7 +778,7 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
     for group in layer_groups(params):
         x, group_aux = lax.scan(body, x, group)
         aux = aux + jnp.sum(group_aux, axis=0)
-    return rmsnorm(x, params["final_norm"]), aux
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def apply(params, tokens, cfg: Config = LLAMA3_8B,
@@ -748,7 +1057,7 @@ def _head_ce(cfg: Config, y, final_norm, lm_head, targets, ignore_index,
     and pipelining is exactly where HBM pressure peaks (ADVICE r2 #1).
     ``return_z_term`` (requires cfg.z_loss) additionally returns the
     reported z-loss regularizer term, matching ``loss_and_stats``."""
-    y = rmsnorm(y, final_norm)
+    y = rmsnorm(y, final_norm, cfg.norm_eps)
     if cfg.vocab_chunk:
         return chunked_softmax_cross_entropy(
             y, lm_head, targets, cfg.vocab_chunk, ignore_index,
@@ -858,7 +1167,7 @@ def make_1f1b_loss(mesh, cfg: Config, n_microbatches: int,
         layer_fn_for = lambda T: base  # noqa: E731
 
     def head_loss_fn(h, hp, tgt):
-        y = rmsnorm(h, hp["final_norm"])
+        y = rmsnorm(h, hp["final_norm"], cfg.norm_eps)
         return vocab_parallel_cross_entropy(
             y, hp["lm_head"], tgt, axis, ignore_index, reduction="sum",
             z_loss=cfg.z_loss)
@@ -959,11 +1268,22 @@ def _param_counts(cfg: Config, experts: int) -> int:
     if cfg.n_experts:
         # Router always sees every expert; expert weights count ``experts``
         # routed experts and every shared one.
+        mats = 3 if cfg.mlp_hidden_act == "silu" else 2
+        shared = (cfg.moe_shared_expert_intermediate_size
+                  or cfg.n_shared_experts * cfg.expert_dim)
         ffn = (D * cfg.n_experts
-               + 3 * (experts + cfg.n_shared_experts) * D * cfg.expert_dim
+               + mats * D * (experts * cfg.expert_dim + shared)
                + (cfg.n_experts if cfg.scoring_func == "sigmoid" else 0))
     else:
         ffn = dense
+    if cfg.hybrid_override_pattern:
+        m = cfg.mamba
+        mamba = (D * m.proj_dim + (m.conv + 1) * m.conv_dim + 3 * m.heads
+                 + m.inner + m.inner * D) if m else 0
+        attn = D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.o_dim * D
+        layers = (cfg.n_of("M") * mamba + cfg.n_of("E") * ffn
+                  + cfg.n_of("*") * attn + L * D)
+        return cfg.vocab * D + layers + D + D * cfg.vocab
     if cfg.kv_lora_rank:
         m = cfg.latent
         attn = (D * cfg.q_lora_rank + cfg.q_lora_rank
@@ -977,8 +1297,10 @@ def _param_counts(cfg: Config, experts: int) -> int:
 
 
 def num_params(cfg: Config = LLAMA3_8B) -> int:
-    """Total parameters (all experts; the memory number)."""
-    return _param_counts(cfg, cfg.n_experts)
+    """Total parameters the tree holds (all experts, or the held share of
+    them: the memory number; not the zero columns ``moe.stored_width``
+    may pad an expert leaf with)."""
+    return _param_counts(cfg, cfg.moe.n_held if cfg.n_experts else 0)
 
 
 def num_active_params(cfg: Config = LLAMA3_8B) -> int:

@@ -56,30 +56,72 @@ class MoEConfig:
     # definition: it runs under ``dispatch="ragged"`` only.
     scoring: str = "softmax"
     routed_scale: float = 1.0
-    # Shared experts: a dense SwiGLU of width n_shared * mlp_dim that
-    # every token takes beside its routed experts.
+    # Shared experts: a dense FFN of width ``shared_dim`` (0: n_shared *
+    # mlp_dim) that every token takes beside its routed experts.
     n_shared: int = 0
+    shared_dim: int = 0
+    # The experts' FFN: "silu" is the gated SwiGLU ``w_down(silu(w_gate x)
+    # * w_up x)``, "relu2" the non-gated ``w_down(relu(w_up x)^2)`` (two
+    # products an expert, no ``w_gate`` leaf).
+    act: str = "silu"
+    # One rank's share of an expert-parallel layer: ``held`` = (first,
+    # count) are the experts whose weights this tree holds. The router
+    # keeps its full width and top-k; the layer computes its own experts'
+    # part of each token's sum (and the shared expert whole) and leaves
+    # out what the absent ranks would add. () holds every expert.
+    held: tuple = ()
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held else self.n_experts
+
+
+LANES = 128
+
+
+def stored_width(mlp_dim: int) -> int:
+    """The width a dropless configuration (``dispatch="ragged"``: every
+    call of it runs the grouped product) HOLDS its routed experts' leaves
+    at: ``mlp_dim``, or the next multiple of 128 where it is wider than
+    that and not one (1856 -> 1920), the added columns of ``w_up`` / ``w_gate`` and rows of
+    ``w_down`` zero, so that they add exact zeros. A TPU keeps an array
+    whose minor dim is not whole lanes in another layout than a grouped
+    product reads, and the whole stack was then copied at every call (3.4
+    GB a decode step at 23 layers of 16 experts of 2688 x 1856, compiled
+    for a described v5e: PERF.md section 6, PR 33)."""
+    if mlp_dim <= LANES or mlp_dim % LANES == 0:
+        return mlp_dim
+    return -(-mlp_dim // LANES) * LANES
 
 
 def init(rng, dim: int, mlp_dim: int, cfg: MoEConfig, dtype, n_layers: int | None = None):
     """Expert FFN params; with n_layers, stacked [L, ...] for scan."""
     lead = () if n_layers is None else (n_layers,)
+    pad = stored_width(mlp_dim) - mlp_dim if cfg.dispatch == "ragged" else 0
+    tail = ((0, 0),) * (len(lead) + 1)
     ks = jax.random.split(rng, 4)
     # Later leaves fold their own keys in: the four draws above stay what
     # they were for every tree that has no such leaf.
     ks = list(ks) + [jax.random.fold_in(rng, i) for i in range(4, 8)]
-    e = cfg.n_experts
+    e, held = cfg.n_experts, cfg.n_held
     fan = dim**-0.5
+    gated = _gated(cfg.act)
     params = {
         "router": (jax.random.normal(ks[0], lead + (dim, e)) * fan
                    ).astype(jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], lead + (e, dim, mlp_dim)) * fan
-                   ).astype(dtype),
-        "w_up": (jax.random.normal(ks[2], lead + (e, dim, mlp_dim)) * fan
+        "w_up": (jax.random.normal(ks[2], lead + (held, dim, mlp_dim)) * fan
                  ).astype(dtype),
-        "w_down": (jax.random.normal(ks[3], lead + (e, mlp_dim, dim))
+        "w_down": (jax.random.normal(ks[3], lead + (held, mlp_dim, dim))
                    * mlp_dim**-0.5).astype(dtype),
     }
+    if gated:
+        params["w_gate"] = (jax.random.normal(
+            ks[1], lead + (held, dim, mlp_dim)) * fan).astype(dtype)
+    if pad:  # see stored_width
+        for k in EXPERT_LEAVES:
+            if k in params:
+                params[k] = jnp.pad(params[k], tail + (
+                    ((0, pad), (0, 0)) if k == "w_down" else ((0, 0), (0, pad))))
     if cfg.scoring == "sigmoid":
         # ``e_score_correction_bias``: zero in a fresh model and moved by
         # the aux-free balancing rule in training; drawn small here so
@@ -87,16 +129,33 @@ def init(rng, dim: int, mlp_dim: int, cfg: MoEConfig, dtype, n_layers: int | Non
         params["bias"] = (jax.random.normal(ks[4], lead + (e,)) * 0.01
                           ).astype(jnp.float32)
     if cfg.n_shared:
-        f = cfg.n_shared * mlp_dim
+        f = cfg.shared_dim or cfg.n_shared * mlp_dim
         params["shared"] = {
-            "w_gate": (jax.random.normal(ks[5], lead + (dim, f)) * fan
-                       ).astype(dtype),
             "w_up": (jax.random.normal(ks[6], lead + (dim, f)) * fan
                      ).astype(dtype),
             "w_down": (jax.random.normal(ks[7], lead + (f, dim))
                        * f**-0.5).astype(dtype),
         }
+        if gated:
+            params["shared"]["w_gate"] = (jax.random.normal(
+                ks[5], lead + (dim, f)) * fan).astype(dtype)
     return params
+
+
+def _gated(act: str) -> bool:
+    if act not in ("silu", "relu2"):
+        raise ValueError(f"unknown expert activation {act!r} "
+                         "(valid: 'silu' gated, 'relu2' non-gated)")
+    return act == "silu"
+
+
+def _shared_ffn(s, tokens):
+    """The shared expert on tokens [N, D]; which form is read from its
+    leaves, as ``grouped_ffn``."""
+    if "w_gate" in s:
+        return (jax.nn.silu(tokens @ s["w_gate"]) * (tokens @ s["w_up"])
+                ) @ s["w_down"]
+    return jnp.square(jax.nn.relu(tokens @ s["w_up"])) @ s["w_down"]
 
 
 def param_logical_axes(stacked: bool = False):
@@ -152,7 +211,7 @@ def keep_stacked(group: dict) -> tuple[dict, dict]:
     if "moe" not in group:
         return group, {}
     experts = group["moe"]
-    whole = {k: experts[k] for k in EXPERT_LEAVES}
+    whole = {k: experts[k] for k in EXPERT_LEAVES if k in experts}
     sliced = {**group, "moe": {k: v for k, v in experts.items()
                                if k not in EXPERT_LEAVES}}
     return sliced, whole
@@ -166,52 +225,121 @@ def at_layer(layer: dict, whole: dict, index) -> dict:
 
 
 def grouped_ffn(params, rows, group_sizes):
-    """The expert SwiGLU over ``rows`` [M, D] sorted by expert, expert e
-    owning the next ``group_sizes[e]`` of them: three grouped products.
-    With ``params["stack"]`` = (the expert leaves of ALL L layers
+    """The expert FFN over ``rows`` [M, D] sorted by expert, expert e
+    owning the next ``group_sizes[e]`` of them: three grouped products for
+    the gated SwiGLU, two for the non-gated squared ReLU (a tree without
+    ``w_gate``). Rows past the groups' sum belong to no expert held here
+    and are no part of any product. With ``params["stack"]`` = (the expert leaves of ALL L layers
     [L, E, ...], this layer's index) the products run over L * E groups, of
     which only this layer's E have rows: an empty group costs the product
     nothing, and no layer's weights are cut out of the stack."""
     if "stack" in params:
         whole, index = params["stack"]
-        n_layers, e = whole["w_gate"].shape[:2]
+        n_layers, e = whole["w_up"].shape[:2]
         group_sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * e,), group_sizes.dtype), group_sizes,
             (index * e,))
-        params = {k: whole[k].reshape((n_layers * e,) + whole[k].shape[2:])
-                  for k in EXPERT_LEAVES}
+        params = {k: v.reshape((n_layers * e,) + v.shape[2:])
+                  for k, v in whole.items()}
     with jax.named_scope("moe_gmm"):
-        gate = jax.lax.ragged_dot(rows, params["w_gate"], group_sizes)
         up = jax.lax.ragged_dot(rows, params["w_up"], group_sizes)
-        return jax.lax.ragged_dot(
-            jax.nn.silu(gate) * up, params["w_down"], group_sizes)
+        if "w_gate" in params:
+            gate = jax.lax.ragged_dot(rows, params["w_gate"], group_sizes)
+            hidden = jax.nn.silu(gate) * up
+        else:
+            hidden = jnp.square(jax.nn.relu(up))
+        return jax.lax.ragged_dot(hidden, params["w_down"], group_sizes)
+
+
+# Tokens in a call (B x T, static at trace time) up to which a HELD SHARE of
+# the experts (``MoEConfig.held``: one rank of an expert-parallel layer, a
+# few experts) runs DENSE: every held expert over every token, two batched
+# products [e, N, D] x [e, D, F], each token's sum weighed by its router
+# weights (zero for an expert it did not choose). One rule on shapes, no
+# option (as ``generate.DROPLESS_FROM_TOKENS``). Few tokens are bound by the
+# experts' bytes either way (N operations a byte, against the chip's 240),
+# nearly every held expert is touched anyway (48 tokens, top-6 of 128, 16
+# held: 14.4), and the batched product streams the weights near the
+# roofline where ``lax.ragged_dot`` over 36 live rows in 368 groups took
+# 1.0 ms a product: 47 of a 69 ms decode step on a v5e (PERF.md section 6,
+# PR 33). Above it the grouped product runs: dense would do held x N rows of
+# work for the k x N x held / E that are needed, and from 128 rows on the
+# compiler wants the stacked leaf in another layout for the batched product
+# and copies it whole (3.5 GB; tests/test_chip_compile.py holds the 64-token
+# bucket and the decode step to "no copy of an expert leaf").
+DENSE_UP_TO_TOKENS = 64
+
+
+def _dense_held(params, x, cfg: MoEConfig):
+    """``_dropless`` for a held share and few tokens: the same sum, every
+    held expert computed for every token and weighed (see
+    ``DENSE_UP_TO_TOKENS``). Returns (out, load) as ``_dropless``."""
+    b, t, d = x.shape
+    n, e = b * t, cfg.n_held
+    tokens = x.reshape(n, d)
+    with jax.named_scope("moe_route"):
+        experts, w = route(params, tokens, cfg)                    # [N, k]
+        chosen = (experts - cfg.held[0])[..., None] == jnp.arange(e)
+        weight = jnp.sum(jnp.where(chosen, w[..., None], 0.0), axis=1)  # [N, e]
+        counts = jnp.sum(chosen, axis=(0, 1))
+    leaves = params
+    if "stack" in params:  # this layer's experts, read where they lie
+        whole, index = params["stack"]
+        leaves = {k: v[index] for k, v in whole.items()}
+    with jax.named_scope("moe_gmm"):
+        up = jnp.einsum("nd,edf->enf", tokens, leaves["w_up"])
+        if "w_gate" in leaves:
+            hidden = jax.nn.silu(jnp.einsum(
+                "nd,edf->enf", tokens, leaves["w_gate"])) * up
+        else:
+            hidden = jnp.square(jax.nn.relu(up))
+        y = jnp.einsum("enf,efd->end", hidden, leaves["w_down"])
+    out = jnp.einsum("end,ne->nd", y.astype(jnp.float32), weight)
+    if cfg.n_shared:
+        out = out + _shared_ffn(params["shared"], tokens).astype(jnp.float32)
+    load = jnp.stack([
+        jnp.sum(counts > 0).astype(jnp.float32),
+        jnp.max(counts).astype(jnp.float32) * e
+        / jnp.maximum(jnp.sum(counts), 1)])
+    return out.astype(x.dtype).reshape(b, t, d), load
 
 
 def _dropless(params, x, cfg: MoEConfig):
     """x [B, T, D] -> (out, load [2] f32): every token through all k of
-    its experts. ``load`` = [experts that got a row, rows of the fullest
-    expert over the mean]: what the serving engine counts."""
+    its experts, or through those of them that are held here (``cfg.held``:
+    the others' assignments sort behind the last group and take part in no
+    product). ``load`` = [experts that got a row, rows of the fullest
+    expert over the mean], over the experts held: what the serving engine
+    counts."""
     b, t, d = x.shape
-    n, e, k = b * t, cfg.n_experts, cfg.top_k
+    n, e, k = b * t, cfg.n_held, cfg.top_k
+    if cfg.held and n <= DENSE_UP_TO_TOKENS:
+        return _dense_held(params, x, cfg)
     tokens = x.reshape(n, d)
     with jax.named_scope("moe_route"):
         experts, w = route(params, tokens, cfg)
         flat = experts.reshape(-1)                 # assignment a = token a // k
+        if cfg.held:
+            local = flat - cfg.held[0]
+            mine = (local >= 0) & (local < e)
+            flat = jnp.where(mine, local, e)       # bin e: held elsewhere
         order = jnp.argsort(flat, stable=True)     # sorted by expert
-        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        counts = jnp.zeros((e + bool(cfg.held),), jnp.int32).at[flat].add(1)[:e]
         rows = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
     y = grouped_ffn(params, rows, counts)
     # Back in assignment order, each token's k rows weighed and summed in
     # float32: a gather, no scatter-add, so the sum's order is fixed.
     y = jnp.take(y, jnp.argsort(order), axis=0).reshape(n, k, d)
+    if cfg.held:  # rows of no group are whatever the product left there
+        mine = mine.reshape(n, k)
+        y, w = jnp.where(mine[..., None], y, 0), jnp.where(mine, w, 0.0)
     out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
     if cfg.n_shared:
-        s = params["shared"]
-        out = out + ((jax.nn.silu(tokens @ s["w_gate"]) * (tokens @ s["w_up"]))
-                     @ s["w_down"]).astype(jnp.float32)
+        out = out + _shared_ffn(params["shared"], tokens).astype(jnp.float32)
+    total = jnp.maximum(jnp.sum(counts), 1) if cfg.held else n * k
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),
-        jnp.max(counts).astype(jnp.float32) * e / (n * k)])
+        jnp.max(counts).astype(jnp.float32) * e / total])
     return out.astype(x.dtype).reshape(b, t, d), load
 
 
@@ -243,11 +371,13 @@ def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
         if with_load:
             return out, jnp.concatenate([zeros, load])
         return out, (zeros if with_stats else zeros[0])
-    if cfg.scoring != "softmax" or cfg.n_shared or cfg.routed_scale != 1.0:
+    if (cfg.scoring != "softmax" or cfg.n_shared or cfg.routed_scale != 1.0
+            or cfg.held or not _gated(cfg.act)):
         raise ValueError(
-            f"MoE dispatch {cfg.dispatch!r} runs the softmax router without "
-            "shared experts only; sigmoid scoring, a routed scale and shared "
-            "experts need dispatch='ragged'")
+            f"MoE dispatch {cfg.dispatch!r} runs the softmax router over "
+            "gated experts all held here, without shared experts; sigmoid "
+            "scoring, a routed scale, shared experts, the squared-ReLU "
+            "expert and a held share need dispatch='ragged'")
     b, t, d = x.shape
     n = b * t
     e, k = cfg.n_experts, cfg.top_k

@@ -235,8 +235,9 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
             return gen.decode_step(p, t, c, tb, ps, cfg, page,
                                    with_load=_counts_expert_load(cfg))
 
-        def _prefill_fwd(p, t, n, c, tb, st):
-            return gen.prefill_into_pages(p, t, n, c, tb, st, cfg, page)
+        def _prefill_fwd(p, t, n, c, tb, st, *slot):
+            return gen.prefill_into_pages(p, t, n, c, tb, st, cfg, page,
+                                          None, *slot)
 
     def step(params, cache, tokens, pos, keys, temps, tables):
         logits, cache, *load = _decode(params, tokens, cache, tables, pos)
@@ -268,9 +269,11 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
         return (tok, cache, carry, jnp.minimum(pos + 1, max_seq), *load)
 
     def prefill(params, cache, tokens, n_tokens, table, start, key,
-                temp):
+                temp, *slot):
+        # ``slot``: the row of a hybrid's recurrent state this prompt
+        # fills (models/generate.py); no other configuration is handed it.
         last, cache = _prefill_fwd(
-            params, tokens, n_tokens, cache, table, start)
+            params, tokens, n_tokens, cache, table, start, *slot)
         carry, sub = jax.random.split(key)
         safe = jnp.where(temp > 0, temp, 1.0)
         sampled = jax.random.categorical(sub, (last / safe)[None, :])[0]
@@ -465,6 +468,26 @@ class ServeEngine:
                 f"role {role!r} does not support latent attention yet "
                 "(the prefill-to-decode handoff is tested over K/V pages "
                 "only); serve it as role='mixed'")
+        if cfg.state_leaves:
+            # Recurrent state beside the pages (a hybrid's Mamba layers):
+            # what follows cannot be right yet, one line of ROADMAP R3 each.
+            for on, what, why in (
+                    (prefix_cache_bytes > 0 and int(prefix_block) >= 1,
+                     "a prefix store (prefix_cache_bytes > 0)",
+                     "a page hit brings the attention layers' keys and "
+                     "values and no state: pass prefix_cache_bytes=0"),
+                    (int(kv_host_bytes) > 0, "a host tier (kv_host_bytes)",
+                     "it demotes the prefix store's pages"),
+                    (draft_params is not None, "speculative decoding",
+                     "a rejected draft would have to roll the state back"),
+                    (self.shard > 1, "shard > 1",
+                     "the state and the mixers have no sharding rules"),
+                    (str(role) != "mixed", f"role {role!r}",
+                     "the prefill-to-decode handoff carries pages only")):
+                if on:
+                    raise ValueError(
+                        f"{what} does not support recurrent state yet "
+                        f"({why})")
         self.member_hbm_budget = max(int(member_hbm_budget), 0)
         if self.shard > 1:
             from oim_tpu.serve import shard as shardlib
@@ -532,6 +555,11 @@ class ServeEngine:
         n_pages = pool_tokens // self.page_tokens
         page_bytes = gen.page_bytes(cfg, self.page_tokens)
         self._pagepool = PagePool(n_pages, self.page_tokens, page_bytes)
+        # A hybrid's recurrent state: a fixed size a slot, max_batch rows
+        # beside the page pool whatever the positions held (0 otherwise).
+        self.state_bytes = gen.state_bytes(cfg, max_batch)
+        self._state_resets = 0
+        M.SERVE_STATE_BYTES.set(self.state_bytes)
         # Per-member HBM budget: a member holds 1/shard of the split
         # weight leaves, the replicated leaves whole, and 1/shard of
         # every page (the pool shards with the KV heads). A model that
@@ -541,7 +569,7 @@ class ServeEngine:
             from oim_tpu.serve import shard as shardlib
 
             shardlib.check_member_budget(
-                params, self.shard, n_pages * page_bytes,
+                params, self.shard, n_pages * page_bytes + self.state_bytes,
                 self.member_hbm_budget)
         # KV tiering (serve/kvtier.py): with a --kv-host-bytes budget,
         # evicting a store-only prefix page D2H-copies its block into
@@ -591,6 +619,9 @@ class ServeEngine:
         # unmapped table entry points at (see init_page_pool).
         self._cache = gen.init_page_pool(
             cfg, n_pages + 1, self.page_tokens)
+        # The recurrent state rides in the same dict: donated to and
+        # updated in place by the same programs as the pages.
+        self._cache.update(gen.init_state_pool(cfg, max_batch))
         if self.shard > 1:
             # Commit params and pool to their mesh shardings up front:
             # each member device holds only its weight slice and its
@@ -918,6 +949,10 @@ class ServeEngine:
                 "cache_kind": self.cache_kind,
                 "kv_pages_used": self._pagepool.used_pages,
             }
+            if self.state_bytes:
+                snap.update(state_bytes=self.state_bytes,
+                            state_slots_live=active,
+                            state_resets=self._state_resets)
             if self.cfg.n_experts:
                 snap.update(
                     expert_rows_dropless=self._expert_rows["dropless"],
@@ -1053,6 +1088,11 @@ class ServeEngine:
         would have reserved in page units)."""
         s = self._pagepool.stats()
         s["dense_equiv_pages"] = self.max_batch * self.n_blocks
+        # Recurrent state beside the pages (0 without Mamba layers): its
+        # bytes are held whole from construction; a slot's row is live
+        # while a request decodes in it.
+        s["state_bytes"] = self.state_bytes
+        s["state_slots_live"] = self.active_slots if self.state_bytes else 0
         return s
 
     def spec_stats(self) -> dict:
@@ -1338,8 +1378,10 @@ class ServeEngine:
         self._occupancy()
 
     def _occupancy(self) -> None:
-        M.SERVE_SLOT_OCCUPANCY.set(
-            sum(s is not None for s in self._slots) / self.max_batch)
+        live = sum(s is not None for s in self._slots)
+        M.SERVE_SLOT_OCCUPANCY.set(live / self.max_batch)
+        if self.state_bytes:
+            M.SERVE_STATE_SLOTS_LIVE.set(live)
 
     def _finish(self, req: _Request, reason: str) -> None:
         req.finish_reason = reason
@@ -1637,6 +1679,11 @@ class ServeEngine:
         self._spec_row[slot] = False
         self._spec_mask_dev = None
 
+    def _state_row(self, slot: int) -> tuple:
+        """The prefill program's last operand: the slot's row of the
+        recurrent state, for a configuration that has any."""
+        return (self._jnp.int32(slot),) if self.state_bytes else ()
+
     def _prefill_slot(self, req: _Request, slot: int, n: int, m: int):
         """One request's prefill through slot ``slot``'s page table:
         the first ``m`` blocks are shared store pages read in place
@@ -1663,7 +1710,7 @@ class ServeEngine:
                     jnp.int32(len(tail)),
                     jnp.asarray(self._tables[slot]), jnp.int32(P),
                     self._jax.random.PRNGKey(req.seed),
-                    jnp.float32(req.temperature))
+                    jnp.float32(req.temperature), *self._state_row(slot))
                 tok = int(tok)
         if self._prefix is not None:
             if P:
@@ -1673,6 +1720,9 @@ class ServeEngine:
             else:
                 M.SERVE_PREFIX_MISSES.inc()
         M.SERVE_PREFILL_TOKENS.labels(source="compute").inc(n - P)
+        if self.state_bytes:  # the slot's state began from zeros
+            self._state_resets += 1
+            M.SERVE_STATE_RESETS.inc()
         return tok, key
 
     def _prefill_chunked(self, req: _Request, slot: int, n: int, m: int):
@@ -1712,6 +1762,7 @@ class ServeEngine:
             self._draft_tables[slot, :] = 0
             self._draft_tables_dev = None
         table_dev = jnp.asarray(table_row)
+        slot_dev = self._state_row(slot)
         key0 = self._jax.random.PRNGKey(req.seed)
 
         def dispatch(off: int):
@@ -1726,7 +1777,7 @@ class ServeEngine:
                     self.params, self._cache, jnp.asarray(padded),
                     jnp.int32(len(piece)), table_dev,
                     jnp.int32(P + off), key0,
-                    jnp.float32(req.temperature))
+                    jnp.float32(req.temperature), *slot_dev)
             return tok, key, since
 
         def landed(since: float) -> None:

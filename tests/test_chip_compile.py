@@ -645,3 +645,112 @@ def test_mixtral_prefill_runs_dropless(topo, as_tpu, bucket):
     if bucket == 1024:  # the parent's padded form held 288 MB here
         assert mem.temp_size_in_bytes < 288 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- a hybrid of mixers with recurrent state beside the pages (PR 33) --------
+# nemotron-3-nano-30b.agentbatch at the cell's own sizes: 52 layers, one rank
+# of eight, 48 slots of state (2.36 GB) beside 12 289 pages (1.21 GB).
+
+def hybrid_cell(sharding):
+    from benchmarks import common
+    from benchmarks.runners import serve_hybrid
+
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "nemotron-3-nano-30b.json"))
+    model = serve_hybrid.model_dict(config, "serve")
+    cfg = serve_hybrid.program_config(model)
+    sizes = config["serve"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(lambda: {
+        **gen.init_page_pool(cfg, sizes["kv_pool_tokens"] // PAGE + 1, PAGE),
+        **gen.init_state_pool(cfg, sizes["max_batch"])}), sharding)
+    return cfg, params, pool, sizes, model["max_seq"]
+
+
+def hybrid_program(chip, program):
+    from oim_tpu.serve.engine import _target_programs
+
+    if ("hybrid", program) not in _COMPILED:
+        cfg, params, pool, sizes, seq = hybrid_cell(chip)
+        step, prefill = _target_programs(cfg, PAGE, seq)
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(
+                chip, sizes["max_batch"], seq))
+        else:
+            lowered = prefill.lower(
+                params, pool,
+                *prefill_operands(chip, int(program.split("-")[1]), seq),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=chip))  # the slot
+        _COMPILED["hybrid", program] = lowered.compile(), cfg, pool, sizes
+    return _COMPILED["hybrid", program]
+
+
+def copies_of(text, shape):
+    return [m for m in moves_of(text, shape) if m[0].startswith("copy")]
+
+
+def test_hybrid_widths_are_the_published_ones(topo):
+    cfg, params, pool, sizes, seq = hybrid_cell(
+        SingleDeviceSharding(topo.devices[0]))
+    assert dataclasses.replace(cfg, expert_rank="", vocab=131072,
+                               max_seq=262144) == llama.NEMOTRON_3_NANO_30B
+    assert set(pool) == {"k", "v", "ssm", "conv"}
+    assert pool["k"].shape == (6, 12289, 16, 2, 128)
+    assert pool["ssm"].shape == (23, 48, 64, 64, 128) \
+        and pool["ssm"].dtype == jnp.float32
+    assert pool["conv"].shape == (23, 48, 3 * 6144)
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves((params, pool)))
+    assert 14.2e9 < held < 14.5e9  # 10.78 of weights + 2.36 of state + 1.21
+
+
+def test_hybrid_decode_updates_state_and_pool_in_place(topo, as_tpu):
+    """The decode program: the Pallas kernel in each of the six attention
+    layers (a group of 16 query heads a key/value head), state and pages
+    aliased to the donated buffers and neither copied, no copy of an expert
+    leaf (held at whole lanes: moe.stored_width), the held share's products
+    batched (no grouped product at 48 tokens), seven scanned runs, and
+    arguments + temporaries inside the chip."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = hybrid_program(chip, "step")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert [name for name, _, _ in mosaic_kernels(text)] == ["_paged_kernel"] * 6
+    for leaf in pool.values():
+        assert not copies_of(text, leaf.shape)
+    assert not copies_of(text, (23, 16, 2688, 1920))
+    assert "ragged-dot" not in text
+    assert text.count(" while(") == 7
+    state = sum(math.prod(pool[k].shape) * pool[k].dtype.itemsize
+                for k in pool)
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 128 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("bucket", [1024, 64, 32])
+def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
+    """A prefill slice (the configuration's chunk, and a short last piece):
+    the slot's rows of the state cut out and written back in place, the
+    whole state never copied, one row of logits, the expert leaves whole,
+    and arguments + temporaries inside 15.75 GB at 48 slots (what the
+    configuration's max_batch rests on). The pages of two key/value heads
+    are re-laid around the gather of the slot's table in the 1024 bucket
+    (ROADMAP S2: flash prefill over pages): held to at most the ten
+    copies compiled in PR 33, so that a change that adds one is seen."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = hybrid_program(chip, f"prefill-{bucket}")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for leaf in ("ssm", "conv"):
+        assert not copies_of(text, pool[leaf].shape)
+    assert len(copies_of(text, pool["k"].shape)) <= (10 if bucket == 1024 else 0)
+    assert not copies_of(text, (23, 16, 2688, 1920))
+    assert f"f32[{bucket},{cfg.vocab}]" not in text
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
+    if bucket > 64:  # the grouped products over k x N rows, two a layer
+        assert set(calls) == {(str(6 * bucket), "1920"), (str(6 * bucket), "2688")}
+    else:             # few tokens: every held expert over every token
+        assert not calls
+    assert mem.alias_size_in_bytes >= 3.5e9
+    assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
