@@ -624,6 +624,14 @@ SERVE_EXPERT_ROWS = DEFAULT.counter(
     "(= experts x tokens at inference) a layer; which one a call runs is "
     "generate._no_drop's rule on its token count",
     labelnames=("dispatch",))
+SERVE_EXPERT_CALLS = DEFAULT.counter(
+    "oim_serve_expert_calls_total",
+    "expert-layer calls of a held share's prefill programs by the rung "
+    "their routed products ran on (models/moe.py capacity_ladder: first "
+    "and second are batched products at a capacity an expert, whole is "
+    "the grouped product over every assignment row), tallied on the "
+    "device and fetched with each prompt's first token",
+    labelnames=("rung",))
 # Recurrent state beside the pages (a hybrid's Mamba layers: models/
 # generate.py init_state_pool): a fixed size a slot, held whole.
 SERVE_STATE_BYTES = DEFAULT.gauge(

@@ -378,9 +378,11 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     or [B]), writing position (b, t)'s cache entry at pool[l, phys[b, t],
     off[b, t]] (an out-of-range ``phys`` DROPS the write) and attending
     through ``tables`` [B, n_blocks]. Returns (hidden [B, T, D] after the
-    final norm, updated pool, load): ``load`` [2] f32 is the mean over the
+    final norm, updated pool, load): ``load`` f32 is the mean over the
     expert layers of [experts that got a row, rows of the fullest expert
-    over the mean] (zeros without a dropless expert layer).
+    over the mean] (zeros without a dropless expert layer) and, of a held
+    share of the experts, how many expert layers' routed products ran on
+    each rung (``moe.RUNG_NAMES``, ``moe.capacity_ladder``).
 
     The pool is part of the scan's CARRY, with the layer index beside it:
     each layer scatters its rows into pool[l] and reads pool[l] where it
@@ -424,7 +426,8 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
         x, pool, load = _hybrid_paged(
             params, x, pool, cfg, cos, sin, positions, attend_at,
             tables[:, 0] != 0, slot, n_tokens, pos)
-        return rmsnorm(x, params["final_norm"], cfg.norm_eps), pool, load / n_moe
+        return (rmsnorm(x, params["final_norm"], cfg.norm_eps), pool,
+                _mean_load(load, n_moe))
 
     def body(carry, inp):
         x, pool, l = carry  # pool leaves: [L, n_pages, page, ...]
@@ -436,7 +439,15 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     (x, pool, _), load = _scan_groups(
         body, (x, pool, jnp.int32(0)), params, cfg)
     return (rmsnorm(x, params["final_norm"], cfg.norm_eps), pool,
-            jnp.sum(load, axis=0) / n_moe)
+            _mean_load(jnp.sum(load, axis=0), n_moe))
+
+
+def _mean_load(load, n_moe: int):
+    """The expert layers' summed load: its two means, and a held share's
+    counts of calls a rung as they are."""
+    if load.shape[0] == 2:
+        return load / n_moe
+    return jnp.concatenate([load[:2] / n_moe, load[2:]])
 
 
 def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
@@ -492,20 +503,27 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                               attend_at(i), pool)
         return (x, pool, load)
 
+    from oim_tpu.models import moe
+
     return run_pattern(
-        params, cfg, (x, pool, jnp.zeros((2,), jnp.float32)),
+        params, cfg,
+        (x, pool, jnp.zeros((moe.load_width(cfg.moe) - 2,), jnp.float32)),
         {"M": mamba, "E": experts, "*": attention})
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
                        start, cfg: Config, page_tokens: int,
-                       axis: str | None = None, slot=0):
+                       axis: str | None = None, slot=0,
+                       with_rungs: bool = False):
     """Prefill ``tokens`` [1, T] (first ``n_tokens`` real, rest pad — the
     engine buckets prompt lengths so one compiled program serves many)
     through the slot's ``page_table`` [n_blocks] into the page pool,
     occupying logical positions [start, start + n_tokens).
 
-    Returns (last real token's logits [vocab] f32, updated pool). This is
+    Returns (last real token's logits [vocab] f32, updated pool) and,
+    ``with_rungs`` (a held share of the experts), how many expert layers'
+    routed products ran on each rung ([len(moe.RUNG_NAMES)] int32, see
+    ``_forward_paged``). This is
     BOTH prefill paths in one program: the full path is start=0 with the
     whole prompt as ``tokens``; the prefix-cache hit passes only the
     UNCACHED TAIL with ``start`` = the cached depth as a traced scalar —
@@ -540,14 +558,17 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     keep = (jnp.arange(T) < n_tokens) & (logical < S)
     # Out-of-range physical index: pad K/V never lands.
     phys = jnp.where(keep, page_table[blk], n_pages)
-    x, pool, _ = _forward_paged(
+    x, pool, load = _forward_paged(
         params, tokens, pool, page_table[None], start, phys[None],
         (logical % page_tokens)[None], cfg, axis, slot, n_tokens)
     # The last real row is taken BEFORE the head: one row of logits is
     # kept, so one row is computed (at 129 280 rows of vocabulary a
     # 2048-token chunk's float32 logits would be 1 GB for nothing).
     last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
-    return (last @ params["lm_head"]).astype(jnp.float32)[0], pool
+    logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
+    if with_rungs:
+        return logits, pool, load[2:].astype(jnp.int32)
+    return logits, pool
 
 
 def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
@@ -584,7 +605,7 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
         (pos % page_tokens)[:, None], cfg, axis)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     if with_load:
-        return logits[:, 0], pool, load
+        return logits[:, 0], pool, load[:2]
     return logits[:, 0], pool
 
 

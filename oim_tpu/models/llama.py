@@ -557,17 +557,17 @@ def _ffn(h, layer, cfg: Config, load: bool = False):
     dropped_token_fraction] (zeros for the dense FFN): one uniform aux
     shape lets every schedule's masked accumulator carry the MoE
     telemetry without special cases. With ``load`` (the serving programs)
-    it is moe.apply's four-vector. Which FFN a layer has is read from its
-    own leaves: an expert model's leading dense layers carry none of
-    ``moe``."""
-    if "moe" in layer:
-        from oim_tpu.models import moe
+    it is moe.apply's ``with_load`` vector. Which FFN a layer has is read
+    from its own leaves: an expert model's leading dense layers carry none
+    of ``moe``."""
+    from oim_tpu.models import moe
 
+    if "moe" in layer:
         return moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
                          with_load=load)
     gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-    return gated @ layer["w_down"], jnp.zeros((4 if load else 2,),
-                                               jnp.float32)
+    width = (moe.load_width(cfg.moe) if cfg.n_experts else 4) if load else 2
+    return gated @ layer["w_down"], jnp.zeros((width,), jnp.float32)
 
 
 def _same(x):

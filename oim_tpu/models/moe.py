@@ -262,11 +262,12 @@ def grouped_ffn(params, rows, group_sizes):
 # held: 14.4), and the batched product streams the weights near the
 # roofline where ``lax.ragged_dot`` over 36 live rows in 368 groups took
 # 1.0 ms a product: 47 of a 69 ms decode step on a v5e (PERF.md section 6,
-# PR 33). Above it the grouped product runs: dense would do held x N rows of
-# work for the k x N x held / E that are needed, and from 128 rows on the
-# compiler wants the stacked leaf in another layout for the batched product
-# and copies it whole (3.5 GB; tests/test_chip_compile.py holds the 64-token
-# bucket and the decode step to "no copy of an expert leaf").
+# PR 33). Above it each held expert's own rows are gathered to a capacity
+# (``capacity_ladder``, below): dense would do held x N rows of work for the
+# k x N x held / E that are needed, and from 128 rows on the compiler wants
+# the stacked leaf in another layout for this product of every token with
+# every expert and copies it whole (3.5 GB; tests/test_chip_compile.py holds
+# the 64-token bucket and the decode step to "no copy of an expert leaf").
 DENSE_UP_TO_TOKENS = 64
 
 
@@ -282,10 +283,7 @@ def _dense_held(params, x, cfg: MoEConfig):
         chosen = (experts - cfg.held[0])[..., None] == jnp.arange(e)
         weight = jnp.sum(jnp.where(chosen, w[..., None], 0.0), axis=1)  # [N, e]
         counts = jnp.sum(chosen, axis=(0, 1))
-    leaves = params
-    if "stack" in params:  # this layer's experts, read where they lie
-        whole, index = params["stack"]
-        leaves = {k: v[index] for k, v in whole.items()}
+    leaves = _layer_leaves(params)
     with jax.named_scope("moe_gmm"):
         up = jnp.einsum("nd,edf->enf", tokens, leaves["w_up"])
         if "w_gate" in leaves:
@@ -300,17 +298,148 @@ def _dense_held(params, x, cfg: MoEConfig):
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * e
-        / jnp.maximum(jnp.sum(counts), 1)])
+        / jnp.maximum(jnp.sum(counts), 1), *(0.0,) * len(RUNG_NAMES)])
     return out.astype(x.dtype).reshape(b, t, d), load
 
 
+# What a HELD SHARE's routed products are sized to above ``DENSE_UP_TO_TOKENS``
+# (one rule on shapes, no option). On a v5e ``lax.ragged_dot`` is a grouped
+# kernel of 512 x 128 x 128 tiles wherever a width is not a multiple of 256
+# (1920 = 15 x 128, 2688 = 21 x 128): 315 grid steps of 0.37 us for every
+# group that has rows, WHATEVER the rows and the groups it is handed.
+# nemotron-3-nano-30b's held share, a 1024-token slice (768 live rows of 6144
+# in 16 groups of 368, bf16), ms a product (scripts/expert_dispatch_crossing.py
+# --sweep held; PERF.md section 6, PR 35):
+#
+#   rows handed     6144    3072    1536    768
+#   368 groups      2.05    2.05    2.05    1.99
+#   16 groups       2.03    2.03    2.03    1.97   (and 1.10 ms to cut a
+#                                   layer's 330 MB of leaves from the stack)
+#
+# so neither fewer rows nor fewer groups buy anything there. Each held
+# expert's rows gathered to a capacity C and two batched products [16, C, D]
+# x [16, D, F] x [16, F, D] over the layer's leaves, read where they lie in
+# the stack, ms a layer (both products, where the grouped ones take 4.0):
+#
+#   C         64     128    256    384    512    768    1024
+#   ms        0.49   0.48   0.53   0.72   1.02   1.42   1.91
+#
+# bound by the experts' bytes (330 MB: 0.40 ms) up to the chip's 240
+# operations a byte, by the MXU beyond. Hence capacities of 256 rows an expert
+# at least, and of 5 times the rows uniform routing sends one: on the
+# agentbatch cell's own traffic a slice's fullest held expert got up to 5.3
+# times its expected 48 rows in 82 % of 4692 expert-layer calls and up to
+# 10.7 times in 98.9 % (seeded routers are skewed), so the rungs of a
+# 1024-token slice, 256 and 512, take 76-92 % and 8-23 % of the calls and the
+# grouped product behind them 0.4-1 % (nine seeds).
+MIN_CAPACITY = 256     # rows an expert: under it the experts' bytes bound
+CAPACITY_MULTIPLE = 5  # of the rows uniform routing sends an expert
+ROW_TILE = 128
+RUNG_NAMES = ("first", "second", "whole")
+
+
+def capacity_ladder(n_tokens: int, cfg: MoEConfig) -> tuple:
+    """The static capacities (rows an expert) a held share's products are
+    compiled for, of which a call takes the smallest that holds its fullest
+    expert: ``CAPACITY_MULTIPLE`` times the rows uniform routing sends an
+    expert (k x N / E) in whole row tiles, not under ``MIN_CAPACITY`` (fewer
+    rows cost the same), and twice that; neither over N (no expert gets a
+    token twice: a capacity of N always holds). Behind a last capacity
+    under N stands the grouped product over every assignment row, so any
+    routing whatever is computed in full. Every expert held: nothing to
+    cut, the grouped product alone."""
+    if not cfg.held:
+        return ()
+    expected = cfg.top_k * n_tokens / cfg.n_experts
+    first = -(-int(CAPACITY_MULTIPLE * expected) // ROW_TILE) * ROW_TILE
+    first = min(max(first, MIN_CAPACITY), n_tokens)
+    return (first, min(2 * first, n_tokens))[:1 + (first < n_tokens)]
+
+
+def load_width(cfg: MoEConfig) -> int:
+    """Entries of ``apply``'s ``with_load`` vector: [aux, dropped, experts
+    that got a row, fullest over mean] and, for a held share, the call's
+    rung (one of ``RUNG_NAMES`` set to 1; zeros in the dense form)."""
+    return 4 + (len(RUNG_NAMES) if cfg.held else 0)
+
+
+def _layer_leaves(params):
+    """This layer's expert leaves, read where they lie in the stack."""
+    if "stack" not in params:
+        return params
+    whole, index = params["stack"]
+    return {k: v[index] for k, v in whole.items()}
+
+
+def _batched_ffn(leaves, x):
+    """The expert FFN over x [e, C, D], expert e's rows in x[e]: batched
+    products [e, C, D] x [e, D, F] (which form is read from the leaves, as
+    ``grouped_ffn``)."""
+    with jax.named_scope("moe_gmm"):
+        up = jnp.einsum("ecd,edf->ecf", x, leaves["w_up"])
+        if "w_gate" in leaves:
+            hidden = jax.nn.silu(jnp.einsum(
+                "ecd,edf->ecf", x, leaves["w_gate"])) * up
+        else:
+            hidden = jnp.square(jax.nn.relu(up))
+        return jnp.einsum("ecf,efd->ecd", hidden, leaves["w_down"])
+
+
+def _held_products(params, tokens, flat, order, counts, cfg: MoEConfig):
+    """The routed products of a held share, sized to the rows held here:
+    (y [k x N, D] in assignment order, the rung taken). Sorted, expert e's
+    assignments are the ``counts[e]`` rows from ``starts[e]`` (bin e, held
+    elsewhere, sorts last). A bounded rung gathers each held expert's rows
+    into [e, C, D], C the smallest capacity of ``capacity_ladder`` that
+    holds the fullest expert (chosen on the device), and runs batched
+    products over this layer's leaves; rows past an expert's count repeat
+    token 0 and no assignment reads them. The last rung, unless a capacity
+    of N stands before it, is the grouped product over every assignment
+    row. An assignment of an absent rank reads some row of the result and
+    the caller masks it (``mine``)."""
+    e, k, rows = cfg.n_held, cfg.top_k, order.shape[0]
+    d = tokens.shape[-1]
+    back = jnp.argsort(order)  # where each assignment sorted to
+    starts = jnp.cumsum(counts) - counts
+    held = jnp.minimum(flat, e - 1)
+    rank = back - starts[held]  # an assignment's place among its expert's
+
+    def bounded(c):
+        def run():
+            with jax.named_scope("moe_route"):
+                slot = jnp.arange(c)
+                at = jnp.minimum(starts[:, None] + slot, rows - 1)
+                source = jnp.where(slot < counts[:, None], order[at] // k, 0)
+                x = jnp.take(tokens, source.reshape(-1), axis=0)
+            y = _batched_ffn(_layer_leaves(params), x.reshape(e, c, d))
+            return jnp.take(y.reshape(e * c, d),
+                            held * c + jnp.clip(rank, 0, c - 1), axis=0)
+        return run
+
+    def whole():
+        with jax.named_scope("moe_route"):
+            x = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
+        return jnp.take(grouped_ffn(params, x, counts), back, axis=0)
+
+    ladder = capacity_ladder(rows // k, cfg)
+    runs = [bounded(c) for c in ladder]
+    if not ladder or ladder[-1] < rows // k:  # a capacity of N always holds
+        runs.append(whole)
+    if len(runs) == 1:
+        return runs[0](), jnp.int32(0 if ladder else len(RUNG_NAMES) - 1)
+    rung = jnp.sum(jnp.max(counts) > jnp.asarray(ladder, jnp.int32))
+    y = jax.lax.switch(jnp.minimum(rung, len(runs) - 1), runs)
+    return y, jnp.where(rung == len(ladder), len(RUNG_NAMES) - 1, rung)
+
+
 def _dropless(params, x, cfg: MoEConfig):
-    """x [B, T, D] -> (out, load [2] f32): every token through all k of
+    """x [B, T, D] -> (out, load f32): every token through all k of
     its experts, or through those of them that are held here (``cfg.held``:
     the others' assignments sort behind the last group and take part in no
-    product). ``load`` = [experts that got a row, rows of the fullest
-    expert over the mean], over the experts held: what the serving engine
-    counts."""
+    product; few tokens run ``_dense_held``, more ``_held_products``).
+    ``load`` = [experts that got a row, rows of the fullest expert over the
+    mean], over the experts held: what the serving engine counts; a held
+    share's is followed by its rung (``load_width``)."""
     b, t, d = x.shape
     n, e, k = b * t, cfg.n_held, cfg.top_k
     if cfg.held and n <= DENSE_UP_TO_TOKENS:
@@ -325,11 +454,16 @@ def _dropless(params, x, cfg: MoEConfig):
             flat = jnp.where(mine, local, e)       # bin e: held elsewhere
         order = jnp.argsort(flat, stable=True)     # sorted by expert
         counts = jnp.zeros((e + bool(cfg.held),), jnp.int32).at[flat].add(1)[:e]
-        rows = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
-    y = grouped_ffn(params, rows, counts)
+        if not cfg.held:
+            rows = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
     # Back in assignment order, each token's k rows weighed and summed in
     # float32: a gather, no scatter-add, so the sum's order is fixed.
-    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(n, k, d)
+    if cfg.held:
+        y, rung = _held_products(params, tokens, flat, order, counts, cfg)
+    else:
+        y = grouped_ffn(params, rows, counts)
+        y = jnp.take(y, jnp.argsort(order), axis=0)
+    y = y.reshape(n, k, d)
     if cfg.held:  # rows of no group are whatever the product left there
         mine = mine.reshape(n, k)
         y, w = jnp.where(mine[..., None], y, 0), jnp.where(mine, w, 0.0)
@@ -340,6 +474,9 @@ def _dropless(params, x, cfg: MoEConfig):
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * e / total])
+    if cfg.held:
+        load = jnp.concatenate([load, jax.nn.one_hot(
+            rung, len(RUNG_NAMES), dtype=jnp.float32)])
     return out.astype(x.dtype).reshape(b, t, d), load
 
 
@@ -350,7 +487,8 @@ def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
     ``with_load`` (the serving programs): the second return is the f32
     vector [aux_loss, dropped_fraction, experts that got a row, rows of the
     fullest expert over the mean]; the capacity-padded dispatches leave
-    the last two at zero.
+    the last two at zero, and a held share adds the rung its routed
+    products ran on (``load_width``).
 
     Tokens over capacity for their chosen expert are dropped (contribute
     zero; the residual stream carries them), the standard capacity
@@ -368,7 +506,7 @@ def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
         # sigmoid router is balanced by its bias, outside the loss).
         out, load = _dropless(params, x, cfg)
         zeros = jnp.zeros((2,), jnp.float32)
-        if with_load:
+        if with_load:  # ``load_width`` entries
             return out, jnp.concatenate([zeros, load])
         return out, (zeros if with_stats else zeros[0])
     if (cfg.scoring != "softmax" or cfg.n_shared or cfg.routed_scale != 1.0

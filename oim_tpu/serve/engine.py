@@ -188,6 +188,13 @@ def _counts_expert_load(cfg: Config) -> bool:
     return bool(cfg.n_experts) and cfg.moe_dispatch == "ragged"
 
 
+def _counts_expert_rungs(cfg: Config) -> bool:
+    """Whether the prefill programs tally the rung each expert layer's
+    routed products ran on: a held share's do (models/moe.py
+    capacity_ladder)."""
+    return _counts_expert_load(cfg) and bool(cfg.experts_held)
+
+
 @functools.lru_cache(maxsize=64)
 def _target_programs(cfg: Config, page: int, max_seq: int,
                      shard: int = 1):
@@ -236,8 +243,9 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
                                    with_load=_counts_expert_load(cfg))
 
         def _prefill_fwd(p, t, n, c, tb, st, *slot):
-            return gen.prefill_into_pages(p, t, n, c, tb, st, cfg, page,
-                                          None, *slot)
+            return gen.prefill_into_pages(
+                p, t, n, c, tb, st, cfg, page, None, *slot,
+                with_rungs=_counts_expert_rungs(cfg))
 
     def step(params, cache, tokens, pos, keys, temps, tables):
         logits, cache, *load = _decode(params, tokens, cache, tables, pos)
@@ -272,14 +280,18 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
                 temp, *slot):
         # ``slot``: the row of a hybrid's recurrent state this prompt
         # fills (models/generate.py); no other configuration is handed it.
-        last, cache = _prefill_fwd(
+        # A held share's program also returns how many of its expert
+        # layers ran on each rung (moe.capacity_ladder), a fourth output the
+        # engine fetches with the prompt's first token; other models' are
+        # as ever.
+        last, cache, *rungs = _prefill_fwd(
             params, tokens, n_tokens, cache, table, start, *slot)
         carry, sub = jax.random.split(key)
         safe = jnp.where(temp > 0, temp, 1.0)
         sampled = jax.random.categorical(sub, (last / safe)[None, :])[0]
         tok = jnp.where(
             temp > 0, sampled, jnp.argmax(last)).astype(jnp.int32)
-        return tok, cache, carry
+        return (tok, cache, carry, *rungs)
 
     return (jax.jit(step, donate_argnums=(1,)),
             jax.jit(prefill, donate_argnums=(1,)))
@@ -689,6 +701,13 @@ class ServeEngine:
         # token count (oim_serve_expert_rows_total; stats() shows the sums).
         self._expert_rows = {"dropless": 0, "padded": 0}
         self._rows_of = gen.expert_rows
+        # Expert-layer calls of a held share's prefill programs by the rung
+        # their routed products ran on (moe.capacity_ladder): tallied on
+        # the device, fetched with each prompt's first token.
+        from oim_tpu.models.moe import RUNG_NAMES
+
+        self._rung_names = RUNG_NAMES
+        self._expert_rungs = np.zeros(len(RUNG_NAMES), np.int64)
 
         # -- speculative decoding (serve/spec.py): draft propose K
         # tokens through its OWN small page pool (K lockstep decode
@@ -893,7 +912,12 @@ class ServeEngine:
             self._work.notify()
         self._thread.join(timeout=timeout)
         if self.cfg.n_experts:
-            from_context().info("expert rows dispatched", **self._expert_rows)
+            rungs = self._rung_calls()
+            calls = sum(rungs.values())
+            shares = {f"{name}_share": round(n / calls, 4)
+                      for name, n in rungs.items()} if calls else {}
+            from_context().info("expert rows dispatched", **self._expert_rows,
+                                **rungs, **shares)
 
     @property
     def active_slots(self) -> int:
@@ -956,7 +980,8 @@ class ServeEngine:
             if self.cfg.n_experts:
                 snap.update(
                     expert_rows_dropless=self._expert_rows["dropless"],
-                    expert_rows_padded=self._expert_rows["padded"])
+                    expert_rows_padded=self._expert_rows["padded"],
+                    **self._rung_calls())
             if _counts_expert_load(self.cfg):
                 steps, touched, fullest = self._expert_load
                 snap.update(expert_load_steps=int(steps),
@@ -1459,6 +1484,25 @@ class ServeEngine:
         self._expert_rows[dispatch] += rows
         M.SERVE_EXPERT_ROWS.labels(dispatch=dispatch).inc(rows)
 
+    def _rung_calls(self) -> dict:
+        """stats()' and the stop line's view of ``_expert_rungs``."""
+        if not _counts_expert_rungs(self.cfg):
+            return {}
+        return {f"expert_calls_{name}_rung": int(calls) for name, calls
+                in zip(self._rung_names, self._expert_rungs)}
+
+    def _count_expert_rungs(self, tok, rungs: list) -> int:
+        """The prompt's first token, fetched; with it, in the same wait, a
+        held share's tallies of the prompt's prefill calls (``rungs``: one
+        a call, [] for any other model)."""
+        tok, rungs = self._jax.device_get((tok, rungs))
+        if rungs:
+            calls = np.sum(rungs, axis=0)
+            self._expert_rungs += calls
+            for name, n in zip(self._rung_names, calls):
+                M.SERVE_EXPERT_CALLS.labels(rung=name).inc(int(n))
+        return int(tok)
+
     def _bucket(self, n: int) -> int:
         b = self.MIN_PREFILL_BUCKET
         while b < n:
@@ -1705,13 +1749,13 @@ class ServeEngine:
             self._count_expert_rows(padded.shape[1])
             with tracing.start_span(
                     "serve.prefill", parent=req.trace_ctx, **span_attrs):
-                tok, self._cache, key = self._prefill(
+                tok, self._cache, key, *rungs = self._prefill(
                     self.params, self._cache, jnp.asarray(padded),
                     jnp.int32(len(tail)),
                     jnp.asarray(self._tables[slot]), jnp.int32(P),
                     self._jax.random.PRNGKey(req.seed),
                     jnp.float32(req.temperature), *self._state_row(slot))
-                tok = int(tok)
+                tok = self._count_expert_rungs(tok, rungs)
         if self._prefix is not None:
             if P:
                 req.prefix_tokens = P
@@ -1764,6 +1808,7 @@ class ServeEngine:
         table_dev = jnp.asarray(table_row)
         slot_dev = self._state_row(slot)
         key0 = self._jax.random.PRNGKey(req.seed)
+        rungs: list = []  # a held share's tally of each slice, on the device
 
         def dispatch(off: int):
             """One slice on its way: (token, RNG carry, when)."""
@@ -1773,11 +1818,12 @@ class ServeEngine:
             since = time.monotonic()
             self._count_expert_rows(padded.shape[1])
             with tracing.annotate("serve.prefill_chunk"):
-                tok, self._cache, key = self._prefill(
+                tok, self._cache, key, *tally = self._prefill(
                     self.params, self._cache, jnp.asarray(padded),
                     jnp.int32(len(piece)), table_dev,
                     jnp.int32(P + off), key0,
                     jnp.float32(req.temperature), *slot_dev)
+            rungs.extend(tally)
             return tok, key, since
 
         def landed(since: float) -> None:
@@ -1799,7 +1845,8 @@ class ServeEngine:
                 tok.block_until_ready()  # behind a round: done already
                 landed(since)
                 tok, key, since = nxt[0] if nxt else dispatch(off)
-            tok = int(tok)  # device sync: the prompt is in the pages HERE
+            # device sync: the prompt is in the pages HERE
+            tok = self._count_expert_rungs(tok, rungs)
             landed(since)
         self._tables[slot, :] = table_row
         self._tables_dev = None

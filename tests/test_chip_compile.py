@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 import chip_smoke
 from oim_tpu.models import generate as gen
-from oim_tpu.models import llama
+from oim_tpu.models import llama, moe
 from oim_tpu.ops import latent_attention
 from oim_tpu.ops.attention import _flash_plan, attention
 from oim_tpu.train import TrainConfig
@@ -728,7 +728,27 @@ def test_hybrid_decode_updates_state_and_pool_in_place(topo, as_tpu):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
-@pytest.mark.parametrize("bucket", [1024, 64, 32])
+def materialized(text: str, shapes) -> list:
+    """Instructions OUTSIDE fused computations that produce an array of one
+    of ``shapes`` by other means than naming it (a parameter, a tuple's
+    element, a bitcast): each is a buffer of that size written. A slice
+    inside the fusion of the product that reads it is read where it lies."""
+    wanted = {",".join(str(d) for d in shape) for shape in shapes}
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):  # a computation's header
+            fused = line.startswith(("%fused_computation", "%bitcast_fusion"))
+        m = _INSTRUCTION.match(line)
+        if fused or not m:
+            continue
+        dims = re.match(r"\w+\[([\d,]+)\]", m.group(2))
+        if dims and dims.group(1) in wanted and m.group(3) not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            found.append((m.group(3), m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("bucket", [1024, 512, 64, 32])
 def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     """A prefill slice (the configuration's chunk, and a short last piece):
     the slot's rows of the state cut out and written back in place, the
@@ -737,7 +757,13 @@ def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     configuration's max_batch rests on). The pages of two key/value heads
     are re-laid around the gather of the slot's table in the 1024 bucket
     (ROADMAP S2: flash prefill over pages): held to at most the ten
-    copies compiled in PR 33, so that a change that adds one is seen."""
+    copies compiled in PR 33, so that a change that adds one is seen.
+    The held share's products (PR 35) are compiled once a rung: batched
+    products [16, C, .] a capacity of ``moe.capacity_ladder``, which read
+    this layer's leaves where they lie in the stack (no buffer of a
+    layer's leaves is written), and LAST the grouped products over every
+    assignment row, k x N, unless a capacity of N, which always holds,
+    stands before them (the 512 bucket: a prompt's shorter last piece)."""
     chip = SingleDeviceSharding(topo.devices[0])
     compiled, cfg, pool, sizes = hybrid_program(chip, f"prefill-{bucket}")
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -745,12 +771,23 @@ def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
         assert not copies_of(text, pool[leaf].shape)
     assert len(copies_of(text, pool["k"].shape)) <= (10 if bucket == 1024 else 0)
     assert not copies_of(text, (23, 16, 2688, 1920))
+    assert not materialized(text, [(16, 2688, 1920), (16, 1920, 2688),
+                                   (1, 16, 2688, 1920), (1, 16, 1920, 2688)])
     assert f"f32[{bucket},{cfg.vocab}]" not in text
     calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
-    if bucket > 64:  # the grouped products over k x N rows, two a layer
-        assert set(calls) == {(str(6 * bucket), "1920"), (str(6 * bucket), "2688")}
+    ladder = moe.capacity_ladder(bucket, cfg.moe)
+    if bucket > 64:
+        assert ladder == (256, 512)
+        whole = {(str(6 * bucket), "1920"), (str(6 * bucket), "2688")}
+        assert set(calls) == (whole if ladder[-1] < bucket else set())
+        for capacity in ladder:  # a bounded rung: two batched products
+            for width in (1920, 2688):
+                assert re.search(rf"= bf16\[16,{capacity},{width}\]\S* "
+                                 r"convolution\(", text)
+        bodies = text.count(" conditional(")  # scanned runs + single layers
+        assert 0 < bodies <= 23 and len(calls) in (0, 2 * bodies)
     else:             # few tokens: every held expert over every token
-        assert not calls
+        assert not calls and " conditional(" not in text
     assert mem.alias_size_in_bytes >= 3.5e9
     assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -271,7 +271,7 @@ def test_stored_width_pads_whole_lanes_and_adds_exact_zeros():
 @pytest.mark.parametrize("stacked", [False, True])
 def test_a_held_share_runs_dense_at_few_tokens_and_grouped_above(
         monkeypatch, stacked):
-    """The two forms of a held share compute the same sum (float32, another
+    """The forms of a held share compute the same sum (float32, another
     order: 2e-5) and count the same load; which one runs follows the tokens
     in the call."""
     cfg = dataclasses.replace(llama.tiny_hybrid(), expert_rank="1/4").moe
@@ -291,20 +291,200 @@ def test_a_held_share_runs_dense_at_few_tokens_and_grouped_above(
     grouped, grouped_load = jax.jit(
         lambda p, x: moe.apply(p, x, cfg, with_load=True))(layer, x)
     np.testing.assert_allclose(dense, grouped, atol=2e-5)
-    np.testing.assert_allclose(dense_load, grouped_load, rtol=1e-6)
-    assert "ragged_dot" in str(jax.make_jaxpr(
-        lambda p, x: moe.apply(p, x, cfg))(layer, x))
+    np.testing.assert_allclose(dense_load[:4], grouped_load[:4], rtol=1e-6)
+    # above the crossing a call says which rung it ran on (24 tokens: one
+    # capacity, a token's worth, which always holds); the dense form ran on
+    # none
+    assert dense_load.shape == grouped_load.shape == (moe.load_width(cfg),)
+    assert not dense_load[4:].any() and list(grouped_load[4:]) == [1, 0, 0]
+
+    def traced(x):
+        return str(jax.make_jaxpr(lambda p, x: moe.apply(p, x, cfg))(layer, x))
+
+    # above the crossing each held expert's own rows are sorted out and
+    # gathered to a capacity: of few tokens a token's worth, which always
+    # holds, so no grouped product stands behind it
+    assert "argsort" in traced(x) and "ragged_dot" not in traced(x)
     monkeypatch.undo()
-    assert "ragged_dot" not in str(jax.make_jaxpr(
-        lambda p, x: moe.apply(p, x, cfg))(layer, x))
+    assert "argsort" not in traced(x)
     long = jnp.zeros((1, moe.DENSE_UP_TO_TOKENS + 1, 64))
-    assert "ragged_dot" in str(jax.make_jaxpr(
-        lambda p, x: moe.apply(p, x, cfg))(layer, long))
+    assert "argsort" in traced(long) and "ragged_dot" not in traced(long)
+    # capacities under the call's tokens: the grouped product behind them
+    monkeypatch.setattr(moe, "MIN_CAPACITY", 8)
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    monkeypatch.setattr(moe, "CAPACITY_MULTIPLE", 1)
+    assert moe.capacity_ladder(65, cfg) == (16, 32)
+    assert "ragged_dot" in traced(long) and "cond" in traced(long)
     # every expert held: the grouped form whatever the tokens
     whole_cfg = llama.tiny_hybrid().moe
     assert "ragged_dot" in str(jax.make_jaxpr(lambda x: moe.apply(
         moe.init(jax.random.PRNGKey(0), 64, 48, whole_cfg, jnp.float32), x,
         whole_cfg))(x))
+
+
+def held_layer(rank, stacked):
+    """(cfg, one layer's params as the layer loop hands them over) of a held
+    share of the tiny hybrid's 16 experts."""
+    cfg = dataclasses.replace(llama.tiny_hybrid(), expert_rank=rank).moe
+    params = moe.init(jax.random.PRNGKey(0), 64, 48, cfg, jnp.float32,
+                      n_layers=3)
+    if stacked:
+        sliced, whole = moe.keep_stacked({"moe": params})
+        return cfg, moe.at_layer(jax.tree.map(lambda a: a[1], sliced), whole,
+                                 jnp.int32(1))["moe"]
+    return cfg, jax.tree.map(lambda a: a[1], params)
+
+
+def routed_to(cfg, tokens, held_rows):
+    """A router that sends exactly the first ``held_rows`` assignments of
+    the call to the experts held here, in turn, and every other one
+    elsewhere."""
+    first, count = cfg.held
+    a = np.arange(tokens * cfg.top_k)
+    elsewhere = np.array([e for e in range(cfg.n_experts)
+                          if not first <= e < first + count])
+    experts = np.where(a < held_rows, first + a % count,
+                       elsewhere[a % len(elsewhere)])
+    w = np.random.default_rng(held_rows).uniform(0.1, 1.0, a.shape)
+
+    def route(params, x, cfg):
+        return (jnp.asarray(experts.reshape(tokens, -1), jnp.int32),
+                jnp.asarray(w.reshape(tokens, -1), jnp.float32))
+    return route
+
+
+def both_forms(monkeypatch, layer, x, cfg):
+    """(bounded out, its load, whole out): the ladder's form, and the
+    grouped product over every assignment row alone (today's ``_dropless``,
+    the ladder's last rung)."""
+    def run():
+        return jax.jit(lambda p, x: moe.apply(p, x, cfg, with_load=True))(
+            layer, x)
+    bounded, load = run()
+    with monkeypatch.context() as m:
+        m.setattr(moe, "capacity_ladder", lambda n_tokens, cfg: ())
+        whole, whole_load = run()
+    assert list(whole_load[4:]) == [0, 0, 1]
+    np.testing.assert_array_equal(load[:4], whole_load[:4])
+    return bounded, load, whole
+
+
+@pytest.fixture()
+def toy_ladder(monkeypatch):
+    """96 tokens, top-4 of 16: an expert expects 24 rows; capacities of
+    24 and 48 rows an expert (row tiles of 8), then the grouped product."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    monkeypatch.setattr(moe, "MIN_CAPACITY", 8)
+    monkeypatch.setattr(moe, "CAPACITY_MULTIPLE", 1)
+
+
+# One rank of eight holds 2 experts; the router hands them the first
+# ``held rows`` assignments in turn, so the fuller gets half, rounded up.
+HELD_ROWS = {
+    "none-held": (0, "first"), "under-the-first-rung": (40, "first"),
+    "on-the-first-rung": (48, "first"), "over-the-first-rung": (49, "second"),
+    "on-the-second-rung": (96, "second"),
+    "over-every-rung-but-the-last": (97, "whole"),
+    "every-row-held": (384, "whole")}
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("case", sorted(HELD_ROWS))
+def test_the_bounded_products_are_the_whole_product(
+        monkeypatch, toy_ladder, case, stacked):
+    """A held share's products over the smallest capacity that holds its
+    fullest expert give what the grouped product over every assignment row
+    gives (the last rung, the fallback): the same float32 terms, summed by
+    another product (2e-5, as the dense form's)."""
+    held_rows, rung = HELD_ROWS[case]
+    cfg, layer = held_layer("1/8", stacked)
+    assert moe.capacity_ladder(96, cfg) == (24, 48)
+    monkeypatch.setattr(moe, "route", routed_to(cfg, 96, held_rows))
+    x = jax.random.normal(jax.random.PRNGKey(held_rows), (1, 96, 64))
+    bounded, load, whole = both_forms(monkeypatch, layer, x, cfg)
+    assert dict(zip(moe.RUNG_NAMES, load[4:]))[rung] == 1 and load[4:].sum() == 1
+    np.testing.assert_allclose(bounded, whole, atol=2e-5)
+    if rung == "whole":  # the same program ran
+        np.testing.assert_array_equal(bounded, whole)
+    if held_rows:  # and the held experts did add something
+        shared = moe._shared_ffn(layer["shared"], x[0])
+        assert float(jnp.abs(whole[0] - shared).max()) > 1e-3
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("biased", [False, True])
+def test_the_routers_own_choices_pick_the_rung(
+        monkeypatch, toy_ladder, biased, stacked):
+    """The layer's own router: unbiased, a quarter of the assignments fall
+    to the 4 experts held of 16 and a bounded rung holds the fullest;
+    biased onto the held range, every token picks all 4 (a token's worth
+    of rows an expert, which no capacity under N holds) and the grouped
+    product runs. Either way the sum is the whole product's."""
+    cfg, layer = held_layer("1/4", stacked)
+    if biased:
+        first, count = cfg.held
+        layer = {**layer, "bias": layer["bias"].at[first:first + count].add(10.0)}
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 96, 64))
+    assert moe.capacity_ladder(96, cfg) == (24, 48)
+    bounded, load, whole = both_forms(monkeypatch, layer, x, cfg)
+    if biased:
+        assert list(load[4:]) == [0, 0, 1] and load[2] == 4
+    else:
+        assert load[6] == 0 and load[4] + load[5] == 1
+    np.testing.assert_allclose(bounded, whole, atol=2e-5)
+
+
+@pytest.mark.parametrize("held_rows", [40, 60, 300])
+def test_rows_of_no_assignment_never_reach_the_sum(
+        monkeypatch, toy_ladder, held_rows):
+    """Rows no assignment owns are whatever the products left there:
+    non-finite values planted in every one of them (a bounded rung's slots
+    past an expert's count, the grouped product's rows past the groups'
+    sum) reach no token's sum, not as a NaN times zero either."""
+    cfg, layer = held_layer("1/8", True)
+    monkeypatch.setattr(moe, "route", routed_to(cfg, 96, held_rows))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 96, 64))
+    clean, load, clean_whole = both_forms(monkeypatch, layer, x, cfg)
+    counts = jnp.asarray([-(-held_rows // 2), held_rows // 2])
+    grouped, batched = moe.grouped_ffn, moe._batched_ffn
+
+    def bad(rows):
+        return jnp.where(jnp.arange(rows) % 2 == 0, jnp.nan, jnp.inf)
+
+    def planted_grouped(params, rows, group_sizes):
+        y = grouped(params, rows, group_sizes)
+        live = jnp.arange(y.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None], y, bad(y.shape[0])[:, None])
+
+    def planted_batched(leaves, x):
+        y = batched(leaves, x)
+        live = jnp.arange(y.shape[1]) < counts[:, None]
+        return jnp.where(live[..., None], y, bad(y.shape[1])[None, :, None])
+
+    monkeypatch.setattr(moe, "grouped_ffn", planted_grouped)
+    monkeypatch.setattr(moe, "_batched_ffn", planted_batched)
+    bounded, planted_load, whole = both_forms(monkeypatch, layer, x, cfg)
+    np.testing.assert_array_equal(planted_load, load)
+    assert np.isfinite(bounded).all() and np.isfinite(whole).all()
+    np.testing.assert_array_equal(bounded, clean)
+    np.testing.assert_array_equal(whole, clean_whole)
+
+
+def test_every_expert_held_has_nothing_to_cut():
+    """``held == ()``: no capacity, the grouped product alone; and the
+    ladder a slice of the published model's share is compiled for."""
+    cfg = llama.tiny_hybrid().moe
+    assert moe.capacity_ladder(1024, cfg) == () and moe.load_width(cfg) == 4
+    share = dataclasses.replace(llama.NEMOTRON_3_NANO_30B, expert_rank="0/8").moe
+    assert moe.capacity_ladder(1024, share) == LADDER_1024
+    # a short last piece: one capacity, a token's worth, which always holds
+    assert moe.capacity_ladder(128, share) == (128,)
+    assert moe.capacity_ladder(512, share) == (256, 512)
+    assert moe.capacity_ladder(2048, share) == (512, 1024)
+    assert moe.load_width(share) == 4 + len(moe.RUNG_NAMES)
+
+
+LADDER_1024 = (256, 512)
 
 
 @pytest.mark.parametrize("tokens", [24, 160])
@@ -518,6 +698,87 @@ def test_engine_prefill_then_decode_against_the_references_full_forward(
     assert pool["state_slots_live"] == 0 and stats["cache_kind"] == "gqa"
     assert stats["expert_load_steps"] > 0
     assert 0 < stats["experts_touched_sum"] / stats["expert_load_steps"] <= 4
+
+
+@pytest.mark.parametrize("biased", [False, True])
+def test_the_engine_counts_expert_calls_by_rung(
+        monkeypatch, served, biased):
+    """A held share's prefill programs tally on the device which rung each
+    expert layer's products ran on; the engine fetches a prompt's tallies
+    in the one wait it already makes for the prompt's first token (no
+    slice is waited for), and stats() and the exported counter show the
+    sums: a bounded rung under the seeded router, the grouped product over
+    every row under a router biased onto the held range. Slices of 64
+    tokens or fewer run dense and count on no rung."""
+    from oim_tpu.common import metrics as M
+    from oim_tpu.serve.engine import _target_programs
+
+    # 128-token slices, top-4 of 16: an expert expects 32 rows
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    monkeypatch.setattr(moe, "MIN_CAPACITY", 8)
+    monkeypatch.setattr(moe, "CAPACITY_MULTIPLE", 1.5)
+    _target_programs.cache_clear()  # traced under this ladder, and dropped
+
+    model, cfg, params = served
+    cfg = dataclasses.replace(cfg, max_seq=512)
+    layers = cfg.n_expert_layers
+    assert cfg.moe.held == (0, 4) and layers == 4
+    if biased:
+        experts = params["expert_layers"]
+        params = {**params, "expert_layers": {**experts, "moe": {
+            **experts["moe"],
+            "bias": experts["moe"]["bias"].at[:, :4].add(10.0)}}}
+    engine = ServeEngine(params, cfg, max_batch=2, max_seq=512,
+                         prefix_cache_bytes=0, kv_page_tokens=PAGE,
+                         prefill_chunk=128)
+    fetches = []
+    fetch = engine._jax.device_get
+    engine._jax = type("jax", (), {"__getattr__": lambda _, k: getattr(jax, k)})()
+    engine._jax.device_get = lambda x: (fetches.append(x), fetch(x))[1]
+    def exported():
+        return {name: M.SERVE_EXPERT_CALLS.labels(rung=name).value
+                for name in moe.RUNG_NAMES}
+    at_start = exported()
+    try:
+        rng = np.random.default_rng(4)
+        assert engine.stats()["expert_calls_first_rung"] == 0
+        # 300 tokens: slices of 128, 128 and 44 (dense); 100: one of 128
+        for n, slices in ((300, 2), (100, 1)):
+            before = engine.stats()
+            engine.submit(rng.integers(0, 512, n).tolist(), max_new=3,
+                          temperature=0.0, eos=-1).result(timeout=300)
+            after = engine.stats()
+            moved = {name: after[f"expert_calls_{name}_rung"]
+                     - before[f"expert_calls_{name}_rung"]
+                     for name in moe.RUNG_NAMES}
+            assert sum(moved.values()) == layers * slices
+            assert moved["whole"] == (layers * slices if biased else 0)
+        # one fetch a prompt carried its slices' tallies with the token
+        tallies = [len(x[1]) for x in fetches
+                   if isinstance(x, tuple) and isinstance(x[1], list)]
+        assert tallies == [3, 1]
+        final = engine.stats()
+        assert {name: exported()[name] - at_start[name]
+                for name in moe.RUNG_NAMES} == {
+            name: final[f"expert_calls_{name}_rung"]
+            for name in moe.RUNG_NAMES}
+    finally:
+        engine.stop()
+        _target_programs.cache_clear()
+
+
+def test_a_model_that_holds_every_expert_counts_no_rungs():
+    cfg = llama.tiny_latent()
+    engine = ServeEngine(llama.init(jax.random.PRNGKey(0), cfg), cfg,
+                         max_batch=2, max_seq=64)
+    try:
+        engine.submit([1, 2, 3], max_new=2, temperature=0.0,
+                      eos=-1).result(timeout=300)
+        stats = engine.stats()
+        assert "expert_rows_dropless" in stats
+        assert not any(k.startswith("expert_calls_") for k in stats)
+    finally:
+        engine.stop()
 
 
 @pytest.mark.parametrize("kwargs,match", [
