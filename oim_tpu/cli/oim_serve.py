@@ -55,7 +55,8 @@ from oim_tpu.common.logging import from_context
 DEFAULT_VOLUME = "weights"
 # --model names the trainer has none for: llama.py's constant of each.
 SERVED_ONLY = {"joyai-llm-flash": "JOYAI_LLM_FLASH",
-               "nemotron-3-nano-30b": "NEMOTRON_3_NANO_30B"}
+               "nemotron-3-nano-30b": "NEMOTRON_3_NANO_30B",
+               "solar-open2-250b": "SOLAR_OPEN2_250B"}
 
 
 def _load_params(args, log):

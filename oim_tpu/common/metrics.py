@@ -632,12 +632,13 @@ SERVE_EXPERT_CALLS = DEFAULT.counter(
     "the grouped product over every assignment row), tallied on the "
     "device and fetched with each prompt's first token",
     labelnames=("rung",))
-# Recurrent state beside the pages (a hybrid's Mamba layers: models/
-# generate.py init_state_pool): a fixed size a slot, held whole.
+# Recurrent state beside the pages (a hybrid's recurrent layers, Mamba-2
+# or KDA: models/generate.py init_state_pool): a fixed size a slot, held
+# whole.
 SERVE_STATE_BYTES = DEFAULT.gauge(
     "oim_serve_state_bytes",
     "device bytes of the recurrent state the replica holds beside its page "
-    "pool: max_batch slots x the Mamba layers' state a slot (0 for a model "
+    "pool: max_batch slots x the recurrent layers' state a slot (0 for a model "
     "without such layers)")
 SERVE_STATE_SLOTS_LIVE = DEFAULT.gauge(
     "oim_serve_state_slots_live",

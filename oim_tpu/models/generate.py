@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from oim_tpu.models.llama import (
+    RECURRENT_KINDS,
     Config,
     _attn_mixer,
     _block,
@@ -37,7 +38,7 @@ from oim_tpu.models.llama import (
     layer_groups,
     run_pattern,
 )
-from oim_tpu.ops import latent_attention, ssm
+from oim_tpu.ops import latent_attention
 from oim_tpu.ops.norms import rmsnorm
 from oim_tpu.ops.paged_attention import cache_attention, paged_attention
 from oim_tpu.ops.rope import rope_frequencies
@@ -75,11 +76,11 @@ def shard_config(cfg: Config, n: int) -> Config:
         raise ValueError(f"shard count must be >= 1, got {n}")
     if n == 1:
         return cfg
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         raise ValueError(
             "tensor-parallel decode does not support a hybrid pattern yet "
             "(the recurrent state and the mixers have no sharding rules; "
-            f"hybrid_override_pattern={cfg.hybrid_override_pattern!r})")
+            f"pattern={cfg.pattern!r})")
     if cfg.kv_lora_rank:
         raise ValueError(
             "tensor-parallel decode does not support latent attention yet "
@@ -179,7 +180,7 @@ def init_cache(cfg: Config, batch: int, max_seq: int):
     """Zeroed dense cache: one [L, B, max_seq, ...] array a leaf of
     ``cfg.cache_leaves`` ({"k","v"} [.., kv_heads, head_dim] for GQA,
     {"kv"} [.., kv_lora_rank + qk_rope_head_dim] for latent attention)."""
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         raise ValueError(
             "the dense cache runs the attention-then-FFN block and holds no "
             "recurrent state: a hybrid pattern is served through the page "
@@ -332,12 +333,17 @@ def page_bytes(cfg: Config, page_tokens: int) -> int:
             * jnp.dtype(cfg.dtype).itemsize)
 
 
-# Beside the pages a hybrid keeps RECURRENT STATE: what a slot's Mamba
-# layers hold whatever its position (``Config.state_leaves``), a row a slot
-# of the engine's batch, {"ssm": [Lm, slots, H, P, N] float32, "conv":
-# [Lm, slots, (K - 1) * conv_dim]} (ops/ssm.py says why a slot's conv
-# window is kept flat). It rides in the SAME dict as the page leaves, so the
-# serving programs donate and update it with the pool. A prefill at
+# Beside the pages a hybrid keeps RECURRENT STATE: what a slot's recurrent
+# layers hold whatever its position (``Config.state_leaves``: a matrix state
+# in float32 and a conv window, a KIND of recurrent layer), a row a slot of
+# the engine's batch: a Mamba-2 model's {"ssm": [Lm, slots, H, P, N], "conv":
+# [Lm, slots, (K - 1) * conv_dim]}, a KDA model's {"kda": [Lk, slots, H, d,
+# d], "kda_conv": [Lk, slots, (K - 1) * 3 H d]} (ops/ssm.py says why a slot's
+# conv window is kept flat). A new kind of state is one more entry of
+# ``llama.RECURRENT_KINDS`` whose module has ``Dims`` (``slot_leaves``,
+# ``state_leaf``, ``window_leaf``), ``step`` and ``scan``. The leaves ride
+# in the SAME dict as the page leaves, so the serving programs donate and
+# update them with the pool. A prefill at
 # ``start`` = 0 begins from zeros (no call zeroes a row: a retired slot's
 # state is dead where it lies), a later slice of a chunked prefill from the
 # row its predecessor left, a decode step updates the rows of live slots
@@ -346,24 +352,32 @@ def page_bytes(cfg: Config, page_tokens: int) -> int:
 
 
 def init_state_pool(cfg: Config, slots: int) -> dict:
-    """Zeroed recurrent state for ``slots`` slots ({} without Mamba
-    layers)."""
+    """Zeroed recurrent state for ``slots`` slots, every kind's leaves in
+    one dict ({} without recurrent layers)."""
+    for kind, dims in cfg.recurrent.items():
+        # ``_hybrid_paged`` slices a slot's row of a [L, slots, H, a, b] leaf
+        shape, _ = cfg.state_leaves[kind][dims.state_leaf]
+        assert len(shape) == 3, (kind, shape)
+    return {name: jnp.zeros((cfg.n_of(kind), slots) + shape, dtype)
+            for kind, leaves in cfg.state_leaves.items()
+            for name, (shape, dtype) in leaves.items()}
+
+
+def state_bytes_by_kind(cfg: Config, slots: int = 1) -> dict:
+    """{kind's name ("mamba", "kda"): device bytes of ``slots`` slots'
+    recurrent state over all layers of that kind}."""
     import math
 
-    n = cfg.n_of("M")
-    return {name: jnp.zeros(
-        (n, slots) + (shape if name == "ssm" else (math.prod(shape),)), dtype)
-        for name, (shape, dtype) in cfg.state_leaves.items()}
+    return {RECURRENT_KINDS[kind].NAME: slots * cfg.n_of(kind) * sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize
+        for shape, dtype in leaves.values())
+        for kind, leaves in cfg.state_leaves.items()}
 
 
 def state_bytes(cfg: Config, slots: int = 1) -> int:
-    """Device bytes of ``slots`` slots' recurrent state over all Mamba
+    """Device bytes of ``slots`` slots' recurrent state over all recurrent
     layers."""
-    import math
-
-    return slots * cfg.n_of("M") * sum(
-        math.prod(shape) * jnp.dtype(dtype).itemsize
-        for shape, dtype in cfg.state_leaves.values())
+    return sum(state_bytes_by_kind(cfg, slots).values())
 
 
 def _page_leaf(pool, cfg: Config):
@@ -422,7 +436,7 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
         return attend
 
     n_moe = max(cfg.n_expert_layers, 1)
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         x, pool, load = _hybrid_paged(
             params, x, pool, cfg, cos, sin, positions, attend_at,
             tables[:, 0] != 0, slot, n_tokens, pos)
@@ -454,43 +468,52 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                   attend_at, live, slot, n_tokens, start):
     """``_forward_paged``'s layer loop for a hybrid pattern: (x, pool,
     summed expert load [2]). ``pool`` carries pages and recurrent state;
-    each Mamba layer reads its rows of the state where they lie and writes
-    them back in place."""
-    m, eps = cfg.mamba, cfg.norm_eps
-    window = cfg.state_leaves["conv"][0] if m else ()  # (K - 1, conv_dim)
+    each recurrent layer reads its rows of its kind's state where they lie
+    and writes them back in place."""
+    eps = cfg.norm_eps
 
-    def mamba(carry, layer, i):
-        x, pool, load = carry
-        h = rmsnorm(x, layer["norm"], eps)
-        sp, cp = pool["ssm"], pool["conv"]
-        # The state's read and write-back stand under the mixer's scope
-        # too: the compiler fuses them with its update, and the profile
-        # names a fusion after one of its operations.
-        if slot is None:  # a decode step: every row, live rows kept
-            with jax.named_scope("ssm_step"):
-                s, c = sp[i], cp[i]
-                y, s2, c2 = ssm.step(layer, h[:, 0], s,
-                                     c.reshape((-1,) + window), m, eps)
-                s2 = jnp.where(live[:, None, None, None], s2, s)
-                c2 = jnp.where(live[:, None], c2.reshape(c.shape), c)
-                pool = {**pool, "ssm": sp.at[i].set(s2),
-                        "conv": cp.at[i].set(c2)}
-            return (x + y[:, None], pool, load)
-        # A prompt slice of one slot, from zeros at position 0.
-        with jax.named_scope("ssm_scan"):
-            s = lax.dynamic_slice(sp, (i, slot, 0, 0, 0),
-                                  (1, 1) + sp.shape[2:])[0]
-            c = lax.dynamic_slice(cp, (i, slot, 0), (1, 1, cp.shape[2]))[0]
-            s = jnp.where(start == 0, jnp.zeros_like(s), s)
-            c = jnp.where(start == 0, jnp.zeros_like(c), c)
-            y, s2, c2 = ssm.scan(layer, h, s, c.reshape((1,) + window),
-                                 n_tokens, m, eps)
-            pool = {**pool,
-                    "ssm": lax.dynamic_update_slice(
-                        sp, s2[None], (i, slot, 0, 0, 0)),
-                    "conv": lax.dynamic_update_slice(
-                        cp, c2.reshape(1, 1, -1), (i, slot, 0))}
-        return (x + y, pool, load)
+    def recurrent(kind):
+        module, dims = RECURRENT_KINDS[kind], cfg.recurrent[kind]
+        state, conv = dims.state_leaf, dims.window_leaf
+        step_scope, scan_scope = module.SCOPES
+
+        def mixer(carry, layer, i):
+            x, pool, load = carry
+            h = rmsnorm(x, layer["norm"], eps)
+            sp, cp = pool[state], pool[conv]
+            # The state's read and write-back stand under the mixer's scope
+            # too: the compiler fuses them with its update, and the profile
+            # names a fusion after one of its operations.
+            if slot is None:  # a decode step: every row, live rows kept
+                with jax.named_scope(step_scope):
+                    s, c = sp[i], cp[i]
+                    y, s2, c2 = module.step(
+                        layer, h[:, 0], s, c.reshape((-1,) + dims.window),
+                        dims, eps)
+                    s2 = jnp.where(live[:, None, None, None], s2, s)
+                    c2 = jnp.where(live[:, None], c2.reshape(c.shape), c)
+                    pool = {**pool, state: sp.at[i].set(s2),
+                            conv: cp.at[i].set(c2)}
+                return (x + y[:, None], pool, load)
+            # A prompt slice of one slot, from zeros at position 0.
+            with jax.named_scope(scan_scope):
+                s = lax.dynamic_slice(sp, (i, slot, 0, 0, 0),
+                                      (1, 1) + sp.shape[2:])[0]
+                c = lax.dynamic_slice(cp, (i, slot, 0),
+                                      (1, 1, cp.shape[2]))[0]
+                s = jnp.where(start == 0, jnp.zeros_like(s), s)
+                c = jnp.where(start == 0, jnp.zeros_like(c), c)
+                y, s2, c2 = module.scan(
+                    layer, h, s, c.reshape((1,) + dims.window), n_tokens,
+                    dims, eps)
+                pool = {**pool,
+                        state: lax.dynamic_update_slice(
+                            sp, s2[None], (i, slot, 0, 0, 0)),
+                        conv: lax.dynamic_update_slice(
+                            cp, c2.reshape(1, 1, -1), (i, slot, 0))}
+            return (x + y, pool, load)
+
+        return mixer
 
     def experts(carry, layer, _):
         x, pool, load = carry
@@ -508,7 +531,8 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
     return run_pattern(
         params, cfg,
         (x, pool, jnp.zeros((moe.load_width(cfg.moe) - 2,), jnp.float32)),
-        {"M": mamba, "E": experts, "*": attention})
+        {"E": experts, "*": attention,
+         **{kind: recurrent(kind) for kind in cfg.recurrent}})
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
@@ -546,7 +570,7 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     contract the prefix store relies on.
 
     ``slot`` is the engine slot being filled: the row of the recurrent
-    state a hybrid's Mamba layers carry from slice to slice (zeros at
+    state a hybrid's recurrent layers carry from slice to slice (zeros at
     ``start`` = 0); other configurations do not read it.
     """
     T = tokens.shape[1]  # tokens [1, T]: admission is per-slot

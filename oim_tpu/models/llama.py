@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from oim_tpu.ops import kda, ssm
 from oim_tpu.ops.attention import attention as default_attention
 from oim_tpu.ops.losses import chunked_softmax_cross_entropy, softmax_cross_entropy
 from oim_tpu.ops.norms import rmsnorm
@@ -33,11 +34,15 @@ from oim_tpu.ops.rope import apply_rope, rope_frequencies
 from oim_tpu.parallel.sharding import EMBED, HEAD, KV_HEAD, LAYER, MLP, VOCAB
 
 
-# A hybrid's parameters are stacked a KIND of mixer ("M" Mamba-2, "E"
-# experts, "*" attention), whatever the order its pattern runs them in
+# A hybrid's parameters are stacked a KIND of mixer ("M" Mamba-2, "K" KDA,
+# "E" experts, "*" attention), whatever the order its pattern runs them in
 # (``run_pattern``).
-HYBRID_GROUPS = {"M": "mamba_layers", "E": "expert_layers",
-                 "*": "attn_layers"}
+HYBRID_GROUPS = {"M": "mamba_layers", "K": "kda_layers",
+                 "E": "expert_layers", "*": "attn_layers"}
+# The kinds that carry recurrent state, each with its module: ``Dims`` (the
+# mixer's sizes, what a slot keeps and under which leaves of the state
+# pool), ``step``, ``scan``, ``SCOPES`` and ``NAME``.
+RECURRENT_KINDS = {"M": ssm, "K": kda}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,6 +120,23 @@ class Config:
     ssm_state_size: int = 0
     conv_kernel: int = 4
     chunk_size: int = 128
+    # A hybrid of linear and softmax attention (the solar_open2 family's
+    # published keys): kda_num_heads > 0 selects it. Published layer i of
+    # ``n_layers`` is a mixer block then an expert block, each behind its
+    # own norm: gated GQA attention ("*") where i is in ``gqa_layers``, else
+    # a KDA mixer ("K", ops/kda.py) of ``linear_attn_config``'s num_heads,
+    # head_dim and short_conv_kernel_size; without a pattern given the one
+    # the blocks run in is derived (``pattern``: "*EKEKEKE" a period of
+    # four). ``use_gqa_gate``
+    # multiplies the attention's output, before ``wo``, by sigmoid(x W_g);
+    # ``kda_allow_neg_eigval`` lets beta range over (0, 2).
+    gqa_layers: tuple = ()
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    use_gqa_gate: bool = False
+    kda_allow_neg_eigval: bool = False
+    kda_use_full_proj: bool = False
     # Rematerialize each layer's activations in the backward pass
     # (jax.checkpoint around the scan body): ~1/3 more FLOPs for O(1)-layer
     # activation memory — what makes 8B-class configs at long context fit
@@ -155,13 +177,24 @@ class Config:
             raise ValueError(
                 "scoring_func='sigmoid' routes dropless: it needs "
                 f"moe_dispatch='ragged', got {self.moe_dispatch!r}")
-        pattern = self.hybrid_override_pattern
+        if self.kda_num_heads and not self.kda_head_dim:
+            raise ValueError("KDA layers (kda_num_heads > 0) need kda_head_dim")
+        if self.kda_use_full_proj:
+            raise ValueError(
+                "kda_use_full_proj=True (full-rank gate projections) is not "
+                "implemented: the KDA mixer's two gates are low-rank pairs")
+        given = self.hybrid_override_pattern
+        if given and (set(given) - set(HYBRID_GROUPS)
+                      or len(given) != self.n_layers):
+            raise ValueError(
+                f"hybrid_override_pattern {given!r} must be n_layers "
+                f"({self.n_layers}) characters of {sorted(HYBRID_GROUPS)}")
+        pattern = self.pattern
+        if self.use_gqa_gate and "*" not in pattern:
+            raise ValueError(
+                "use_gqa_gate gates a hybrid pattern's attention layers: "
+                "this configuration has none")
         if pattern:
-            if set(pattern) - set(HYBRID_GROUPS) \
-                    or len(pattern) != self.n_layers:
-                raise ValueError(
-                    f"hybrid_override_pattern {pattern!r} must be n_layers "
-                    f"({self.n_layers}) characters of {sorted(HYBRID_GROUPS)}")
             if "M" in pattern and not (
                     self.mamba_num_heads and self.mamba_head_dim
                     and self.n_groups and self.ssm_state_size
@@ -169,6 +202,9 @@ class Config:
                 raise ValueError(
                     "a pattern with 'M' needs mamba_num_heads (a multiple of "
                     "n_groups), mamba_head_dim, n_groups and ssm_state_size")
+            if "K" in pattern and not self.kda_num_heads:
+                raise ValueError(
+                    "a pattern with 'K' needs kda_num_heads and kda_head_dim")
             if "E" in pattern and not (
                     self.n_experts and self.moe_dispatch == "ragged"):
                 raise ValueError(
@@ -218,36 +254,64 @@ class Config:
         """The Mamba-2 mixer's sizes, or None without such layers."""
         if "M" not in self.hybrid_override_pattern:
             return None
-        from oim_tpu.ops.ssm import Dims
+        return ssm.Dims(
+            heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
+            groups=self.n_groups, state=self.ssm_state_size,
+            conv=self.conv_kernel, chunk=self.chunk_size)
 
-        return Dims(heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
-                    groups=self.n_groups, state=self.ssm_state_size,
-                    conv=self.conv_kernel, chunk=self.chunk_size)
+    @property
+    def kda(self):
+        """The KDA mixer's sizes, or None without such layers."""
+        if "K" not in self.pattern:
+            return None
+        return kda.Dims(
+            heads=self.kda_num_heads, head_dim=self.kda_head_dim,
+            conv=self.kda_conv_kernel, neg_eigval=self.kda_allow_neg_eigval)
+
+    @property
+    def pattern(self) -> str:
+        """One character a block, in the order the blocks run ("" for the
+        attention-then-FFN block): ``hybrid_override_pattern`` where given,
+        else the solar_open2 family's, two blocks a published layer."""
+        if self.hybrid_override_pattern or not self.kda_num_heads:
+            return self.hybrid_override_pattern
+        return "".join(("*" if i in self.gqa_layers else "K") + "E"
+                       for i in range(self.n_layers))
 
     def n_of(self, kind: str) -> int:
-        """Layers of a hybrid pattern's kind ("M", "E", "*")."""
-        return self.hybrid_override_pattern.count(kind)
+        """Layers of a hybrid pattern's kind (``HYBRID_GROUPS``)."""
+        return self.pattern.count(kind)
 
     @property
     def n_expert_layers(self) -> int:
-        if self.hybrid_override_pattern:
+        if self.pattern:
             return self.n_of("E")
         return self.n_layers - self.n_dense_layers if self.n_experts else 0
 
     @property
     def n_cache_layers(self) -> int:
         """Layers that keep a per-position cache: the attention layers."""
-        if self.hybrid_override_pattern:
+        if self.pattern:
             return self.n_of("*")
         return self.n_layers
 
     @property
+    def recurrent(self) -> dict:
+        """{kind: its mixer's Dims} of the pattern's kinds of recurrent
+        layer ("M": ops/ssm.py, "K": ops/kda.py); {} without any."""
+        return {kind: dims
+                for kind, dims in (("M", self.mamba), ("K", self.kda))
+                if dims is not None}
+
+    @property
     def state_leaves(self) -> dict:
-        """What a SLOT keeps in each Mamba layer, whatever its position:
-        {leaf: (shape, dtype)} (ops/ssm.py); {} without such layers. The
-        serving engine holds it beside the page pool, a row a slot."""
-        m = self.mamba
-        return m.slot_leaves(self.dtype) if m else {}
+        """What a SLOT keeps in each recurrent layer, whatever its position,
+        a kind of layer: {kind: {leaf: (shape, dtype)}}, each kind a matrix
+        state and a conv window as its ``Dims.slot_leaves`` names them; {}
+        without such layers. The serving engine holds it beside the page
+        pool, a row a slot (models/generate.py ``init_state_pool``)."""
+        return {kind: dims.slot_leaves(self.dtype)
+                for kind, dims in self.recurrent.items()}
 
     @property
     def expert_dim(self) -> int:
@@ -342,6 +406,40 @@ NEMOTRON_3_NANO_30B = Config(
     moe_intermediate_size=1856, n_shared_experts=1,
     moe_shared_expert_intermediate_size=3712, mlp_hidden_act="relu2",
     scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
+
+
+# Solar-Open2-250B (250B-A15B) as published: 48 layers, each a mixer block
+# then an expert block (320 sigmoid-routed SwiGLU experts top-8 beside a
+# shared one); the mixer is gated GQA attention that rotates nothing in
+# every fourth layer and a KDA mixer (a gated delta rule with one decay a
+# channel, ops/kda.py) in the other three; eps 1e-5.
+# https://huggingface.co/upstage/Solar-Open2-250B/blob/main/config.json
+# 500 GB in bfloat16: one v5e chip holds one rank of an 8-way expert-parallel
+# group over one period of four layers (--model-override n_layers=4
+# expert_rank=0/8 vocab=24576; benchmarks/configs/solar-open2-250b.json).
+SOLAR_OPEN2_250B = Config(
+    vocab=196608, dim=4096, n_layers=48, n_heads=64, n_kv_heads=8,
+    head_dim=128, mlp_dim=1280, max_seq=1048576, rope_theta=10000.0,
+    attn_rope=False, norm_eps=1e-5, gqa_layers=tuple(range(0, 48, 4)),
+    kda_num_heads=64, kda_head_dim=128, kda_conv_kernel=4,
+    use_gqa_gate=True, kda_allow_neg_eigval=True, n_experts=320, moe_top_k=8,
+    moe_intermediate_size=1280, n_shared_experts=1, scoring_func="sigmoid",
+    routed_scaling_factor=1.0, moe_dispatch="ragged")
+
+
+def tiny_kda(vocab: int = 512, n_layers: int = 4, dtype=jnp.float32,
+             expert_rank: str = "") -> Config:
+    """The solar_open2 family's layer at test scale: a period of one gated
+    attention layer and three KDA layers, an expert block behind each."""
+    return Config(
+        vocab=vocab, dim=64, n_layers=n_layers, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=32, max_seq=512, dtype=dtype, attn_rope=False,
+        norm_eps=1e-5, gqa_layers=tuple(range(0, n_layers, 4)),
+        kda_num_heads=4, kda_head_dim=16, kda_conv_kernel=4,
+        use_gqa_gate=True, kda_allow_neg_eigval=True, n_experts=16,
+        moe_top_k=4, moe_intermediate_size=32, n_shared_experts=1,
+        scoring_func="sigmoid", routed_scaling_factor=1.0,
+        moe_dispatch="ragged", expert_rank=expert_rank)
 
 
 def tiny_hybrid(vocab: int = 512, pattern: str = "MEM*EMEME", dtype=jnp.float32,
@@ -444,16 +542,19 @@ def layer_groups(params) -> list:
 
 
 def _init_hybrid(rng, cfg: Config) -> dict:
-    """The three stacked groups of a hybrid, each layer ONE mixer behind
-    ONE norm."""
+    """The stacked groups of a hybrid, one a kind of mixer it has, each
+    layer ONE mixer behind ONE norm."""
     from oim_tpu.models import moe
-    from oim_tpu.ops import ssm
 
     D = cfg.dim
     ks = jax.random.split(rng, 7)
     fan = D**-0.5
-    n_m, n_e, n_a = (cfg.n_of(k) for k in "ME*")
+    n_m, n_e, n_a, n_k = (cfg.n_of(k) for k in "ME*K")
     groups = {}
+    if n_k:
+        groups["kda_layers"] = {
+            "norm": jnp.ones((n_k, D), jnp.float32),
+            **kda.init(ks[6], D, cfg.kda, cfg.dtype, n_k)}
     if n_m:
         groups["mamba_layers"] = {
             "norm": jnp.ones((n_m, D), jnp.float32),
@@ -471,6 +572,10 @@ def _init_hybrid(rng, cfg: Config) -> dict:
             "wv": _dense(ks[4], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
             "wo": _dense(ks[5], (n_a, cfg.o_dim, D), cfg.dtype,
                          cfg.o_dim**-0.5)}
+        if cfg.use_gqa_gate:
+            groups["attn_layers"]["wg"] = _dense(
+                jax.random.fold_in(rng, 7), (n_a, D, cfg.o_dim), cfg.dtype,
+                fan)
     return groups
 
 
@@ -484,7 +589,7 @@ def init(rng, cfg: Config = LLAMA3_8B):
         "final_norm": jnp.ones((D,), jnp.float32),
         "lm_head": _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan),
     }
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         params.update(_init_hybrid(rng, cfg))
         return params
     params["layers"] = _init_group(rng, cfg, cfg.n_layers - lead,
@@ -496,12 +601,13 @@ def init(rng, cfg: Config = LLAMA3_8B):
 
 def param_logical_axes(cfg: Config = LLAMA3_8B):
     if (cfg.kv_lora_rank or cfg.n_dense_layers or cfg.n_shared_experts
-            or cfg.hybrid_override_pattern or cfg.expert_rank):
+            or cfg.pattern or cfg.expert_rank):
         raise ValueError(
             "no sharding rules yet for latent attention, leading dense "
             "layers or shared experts: this block is served on one chip "
             "and not trained (ROADMAP.md, Reach); nor for a hybrid pattern's "
-            "mixers or a held share of the experts")
+            "mixers (Mamba-2, KDA, gated attention) or a held share of the "
+            "experts")
     layers = {
         "attn_norm": (LAYER, None),
         "wq": (LAYER, EMBED, HEAD),
@@ -624,7 +730,10 @@ def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
         attn, cache = attend(cache, q, k, v)
-    return attn.reshape(B, T, cfg.o_dim), cache
+    attn = attn.reshape(B, T, cfg.o_dim)
+    if cfg.use_gqa_gate:  # one gate an output element, before ``wo``
+        attn = attn * jax.nn.sigmoid(h @ layer["wg"])
+    return attn, cache
 
 
 def pattern_runs(pattern: str) -> tuple:
@@ -667,7 +776,7 @@ def run_pattern(params, cfg: Config, carry, mixers: dict):
         return moe.at_layer(jax.tree.map(lambda a: a[i], sliced), whole, i)
 
     at = dict.fromkeys(HYBRID_GROUPS, 0)
-    for unit, repeat in pattern_runs(cfg.hybrid_override_pattern):
+    for unit, repeat in pattern_runs(cfg.pattern):
         def once(carry, j, base=dict(at), unit=unit):
             for kind in unit:
                 i = base[kind] + j
@@ -705,19 +814,24 @@ def _attn_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache):
 def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
     """A hybrid's layers over whole sequences x [B, T, D] from an empty
     state, no cache: (x, aux [2])."""
-    from oim_tpu.ops import ssm
-
     B, T, _ = x.shape
-    m = cfg.mamba
 
-    def mamba(carry, layer, _):
-        x, aux = carry
-        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
-        empty = {k: jnp.zeros((B,) + shape, dt)
-                 for k, (shape, dt) in cfg.state_leaves.items()}
-        y, _, _ = ssm.scan(layer, h, empty["ssm"], empty["conv"], T, m,
-                           cfg.norm_eps)
-        return x + y, aux
+    def recurrent(kind):
+        module, dims = RECURRENT_KINDS[kind], cfg.recurrent[kind]
+        leaves = dims.slot_leaves(cfg.dtype)
+        state, dt = leaves[dims.state_leaf]
+        window_dt = leaves[dims.window_leaf][1]
+
+        def mixer(carry, layer, _):
+            x, aux = carry
+            h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+            y, _, _ = module.scan(
+                layer, h, jnp.zeros((B,) + state, dt),
+                jnp.zeros((B,) + dims.window, window_dt), T, dims,
+                cfg.norm_eps)
+            return x + y, aux
+
+        return mixer
 
     def experts(carry, layer, _):
         x, aux = carry
@@ -733,7 +847,8 @@ def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
 
     return run_pattern(
         params, cfg, (x, jnp.zeros((2,), jnp.float32)),
-        {"M": mamba, "E": experts, "*": attention})
+        {"E": experts, "*": attention,
+         **{kind: recurrent(kind) for kind in cfg.recurrent}})
 
 
 def _layer(x, layer, cfg: Config, cos, sin, attn_fn: AttentionFn):
@@ -762,7 +877,7 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
     T = tokens.shape[1]
     cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
     x = params["embed"][tokens].astype(cfg.dtype)
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         x, aux = _hybrid_hidden(params, x, cfg, cos, sin, attn_fn)
         return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 
@@ -1276,13 +1391,16 @@ def _param_counts(cfg: Config, experts: int) -> int:
                + (cfg.n_experts if cfg.scoring_func == "sigmoid" else 0))
     else:
         ffn = dense
-    if cfg.hybrid_override_pattern:
+    if cfg.pattern:
         m = cfg.mamba
         mamba = (D * m.proj_dim + (m.conv + 1) * m.conv_dim + 3 * m.heads
                  + m.inner + m.inner * D) if m else 0
-        attn = D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.o_dim * D
-        layers = (cfg.n_of("M") * mamba + cfg.n_of("E") * ffn
-                  + cfg.n_of("*") * attn + L * D)
+        linear = kda.n_params(D, cfg.kda) if cfg.kda else 0
+        attn = (D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.o_dim * D
+                + (D * cfg.o_dim if cfg.use_gqa_gate else 0))
+        layers = (cfg.n_of("M") * mamba + cfg.n_of("K") * linear
+                  + cfg.n_of("E") * ffn + cfg.n_of("*") * attn
+                  + len(cfg.pattern) * D)  # a norm a block
         return cfg.vocab * D + layers + D + D * cfg.vocab
     if cfg.kv_lora_rank:
         m = cfg.latent

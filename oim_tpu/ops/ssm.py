@@ -37,6 +37,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+NAME = "mamba"  # the kind, in the engine's accounting of state bytes
+SCOPES = ("ssm_step", "ssm_scan")
+
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
@@ -46,6 +49,10 @@ class Dims:
     state: int       # N  (ssm_state_size)
     conv: int = 4    # K  (conv_kernel)
     chunk: int = 128  # (chunk_size)
+
+    # The state pool's leaves of this kind (``slot_leaves``).
+    state_leaf = "ssm"
+    window_leaf = "conv"
 
     @property
     def inner(self) -> int:
@@ -60,10 +67,16 @@ class Dims:
         """Columns of ``w_in``: z | xBC | dt."""
         return self.inner + self.conv_dim + self.heads
 
+    @property
+    def window(self) -> tuple:
+        return (self.conv - 1, self.conv_dim)
+
     def slot_leaves(self, dtype) -> dict:
-        """What a slot holds in one layer: {leaf: (shape, dtype)}."""
-        return {"ssm": ((self.heads, self.head_dim, self.state), jnp.float32),
-                "conv": ((self.conv - 1, self.conv_dim), dtype)}
+        """What a slot holds in one layer, as the pool keeps it: {leaf:
+        (shape, dtype)}, the matrix state and the window (flat)."""
+        return {self.state_leaf: ((self.heads, self.head_dim, self.state),
+                                  jnp.float32),
+                self.window_leaf: (((self.conv - 1) * self.conv_dim,), dtype)}
 
 
 def init(rng, dim: int, d: Dims, dtype, n_layers: int,
@@ -126,7 +139,7 @@ def _gated_out(y, z, layer, d: Dims, eps: float):
 def step(layer, x, ssm, conv, d: Dims, eps: float):
     """One token a row: x [B, D], ssm [B, H, P, N] float32, conv
     [B, K - 1, conv_dim] -> (out [B, D], ssm, conv)."""
-    with jax.named_scope("ssm_step"):
+    with jax.named_scope(SCOPES[0]):
         B = x.shape[0]
         z, xbc, dt = _split_proj(x @ layer["w_in"], d)
         window = jnp.concatenate([conv, xbc[:, None]], axis=1)  # [B, K, C]
@@ -155,7 +168,7 @@ def scan(layer, x, ssm, conv, n_tokens, d: Dims, eps: float):
     and window as the last real token left them (their step is 0, and the
     window handed out ends at the last real token); their outputs are
     whatever falls out and are the caller's to drop."""
-    with jax.named_scope("ssm_scan"):
+    with jax.named_scope(SCOPES[1]):
         B, T, _ = x.shape
         G, R, P, N = d.groups, d.heads // d.groups, d.head_dim, d.state
         K = d.conv

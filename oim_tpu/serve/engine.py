@@ -481,8 +481,8 @@ class ServeEngine:
                 "(the prefill-to-decode handoff is tested over K/V pages "
                 "only); serve it as role='mixed'")
         if cfg.state_leaves:
-            # Recurrent state beside the pages (a hybrid's Mamba layers):
-            # what follows cannot be right yet, one line of ROADMAP R3 each.
+            # Recurrent state beside the pages (a hybrid's Mamba-2 or KDA
+            # layers): what follows cannot be right yet, one line of ROADMAP R3 each.
             for on, what, why in (
                     (prefix_cache_bytes > 0 and int(prefix_block) >= 1,
                      "a prefix store (prefix_cache_bytes > 0)",
@@ -569,7 +569,8 @@ class ServeEngine:
         self._pagepool = PagePool(n_pages, self.page_tokens, page_bytes)
         # A hybrid's recurrent state: a fixed size a slot, max_batch rows
         # beside the page pool whatever the positions held (0 otherwise).
-        self.state_bytes = gen.state_bytes(cfg, max_batch)
+        self.state_bytes_by_kind = gen.state_bytes_by_kind(cfg, max_batch)
+        self.state_bytes = sum(self.state_bytes_by_kind.values())
         self._state_resets = 0
         M.SERVE_STATE_BYTES.set(self.state_bytes)
         # Per-member HBM budget: a member holds 1/shard of the split
@@ -918,6 +919,12 @@ class ServeEngine:
                       for name, n in rungs.items()} if calls else {}
             from_context().info("expert rows dispatched", **self._expert_rows,
                                 **rungs, **shares)
+        if self.state_bytes:
+            from_context().info(
+                "recurrent state held", state_bytes=self.state_bytes,
+                resets=self._state_resets, **{
+                    f"{kind}_bytes": n
+                    for kind, n in self.state_bytes_by_kind.items()})
 
     @property
     def active_slots(self) -> int:
@@ -1113,10 +1120,12 @@ class ServeEngine:
         would have reserved in page units)."""
         s = self._pagepool.stats()
         s["dense_equiv_pages"] = self.max_batch * self.n_blocks
-        # Recurrent state beside the pages (0 without Mamba layers): its
-        # bytes are held whole from construction; a slot's row is live
-        # while a request decodes in it.
+        # Recurrent state beside the pages (0 without recurrent layers):
+        # its bytes are held whole from construction, a kind of layer
+        # ("mamba", "kda"); a slot's row is live while a request decodes
+        # in it.
         s["state_bytes"] = self.state_bytes
+        s["state_bytes_by_kind"] = dict(self.state_bytes_by_kind)
         s["state_slots_live"] = self.active_slots if self.state_bytes else 0
         return s
 

@@ -791,3 +791,142 @@ def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     assert mem.alias_size_in_bytes >= 3.5e9
     assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- a second kind of recurrent state: KDA beside gated attention (PR 37) ------
+# solar-open2-250b.agentbatch64 at the cell's own sizes: one period of four
+# layers, one rank of eight, 64 slots of state (0.83 GB) beside 32 769 pages
+# (2.15 GB) and 6.63 GB of weights.
+
+def kda_cell(sharding):
+    from benchmarks import common
+    from benchmarks.runners import serve_kda
+
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "solar-open2-250b.json"))
+    model = serve_kda.model_dict(config, "serve")
+    cfg = serve_kda.program_config(model)
+    sizes = config["serve"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(lambda: {
+        **gen.init_page_pool(cfg, sizes["kv_pool_tokens"] // PAGE + 1, PAGE),
+        **gen.init_state_pool(cfg, sizes["max_batch"])}), sharding)
+    return cfg, params, pool, sizes, model["max_seq"]
+
+
+def kda_program(chip, program):
+    from oim_tpu.serve.engine import _target_programs
+
+    if ("kda", program) not in _COMPILED:
+        cfg, params, pool, sizes, seq = kda_cell(chip)
+        step, prefill = _target_programs(cfg, PAGE, seq)
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(
+                chip, sizes["max_batch"], seq))
+        else:
+            lowered = prefill.lower(
+                params, pool,
+                *prefill_operands(chip, int(program.split("-")[1]), seq),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=chip))  # the slot
+        _COMPILED["kda", program] = lowered.compile(), cfg, pool, sizes
+    return _COMPILED["kda", program]
+
+
+def test_kda_widths_are_the_published_ones(topo):
+    cfg, params, pool, sizes, seq = kda_cell(
+        SingleDeviceSharding(topo.devices[0]))
+    assert dataclasses.replace(
+        cfg, n_layers=48, gqa_layers=tuple(range(0, 48, 4)), expert_rank="",
+        vocab=196608, max_seq=1048576) == llama.SOLAR_OPEN2_250B
+    assert set(pool) == {"k", "v", "kda", "kda_conv"}
+    assert pool["k"].shape == (1, 32769, 16, 8, 128)
+    assert pool["kda"].shape == (3, 64, 64, 128, 128) \
+        and pool["kda"].dtype == jnp.float32
+    assert pool["kda_conv"].shape == (3, 64, 3 * 24576)
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(pool))
+    assert 6.62e9 < weights < 6.64e9       # 3.308 B parameters, 8 K in float32
+    assert 2.97e9 < held < 2.99e9          # 0.83 of state + 2.15 of pages
+
+
+def test_kda_decode_updates_state_and_pool_in_place(topo, as_tpu):
+    """The decode program at 64 slots: the Pallas kernel in the one
+    attention layer (a group of 8 query heads a key/value head, 64 heads),
+    state and pages aliased to the donated buffers and neither copied, no
+    copy of an expert leaf (1280 is ten whole lanes), the held share's three
+    products batched (no grouped product at 64 tokens), the period's three
+    KDA + expert blocks one scanned run, and arguments + temporaries inside
+    the chip."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = kda_program(chip, "step")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert [name for name, _, _ in mosaic_kernels(text)] == ["_paged_kernel"]
+    for name, leaf in pool.items():
+        moved = copies_of(text, leaf.shape)
+        if name == "kda_conv":
+            # The 28 MB of windows are updated in the chip's nearer memory
+            # (layout S(1)) and moved out once, asynchronously: a
+            # copy-start / copy-done pair, no copy the step waits on.
+            moved = [m for m in moved if m[0] != "copy-start"]
+        assert not moved
+    assert not copies_of(text, (4, 40, 4096, 1280))
+    assert "ragged-dot" not in text
+    assert text.count(" while(") == 1
+    state = sum(math.prod(pool[k].shape) * pool[k].dtype.itemsize
+                for k in pool)
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 128 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("bucket", [1024, 64])
+def test_kda_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
+    """A prefill slice (the configuration's chunk, and a short last piece):
+    the slot's rows of the state cut out and written back in place, the
+    whole state never copied, one row of logits, the expert leaves whole,
+    the pairwise decay of the chunked delta rule ([chunks, heads, C, C, d]
+    float32: 0.5 GB at 1024 tokens) inside the temporaries, and arguments +
+    temporaries inside 15.75 GB at 64 slots (what the configuration's
+    max_batch rests on; the 1024 bucket's largest temporary is the one
+    attention layer's scores, f32[64, 1024, 8192] = 2.15 GB: ROADMAP S2)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = kda_program(chip, f"prefill-{bucket}")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for leaf in ("kda", "kda_conv"):
+        assert not copies_of(text, pool[leaf].shape)
+    assert not copies_of(text, (4, 40, 4096, 1280))
+    assert not materialized(text, [(40, 4096, 1280), (40, 1280, 4096),
+                                   (1, 40, 4096, 1280), (1, 40, 1280, 4096)])
+    assert f"f32[{bucket},{cfg.vocab}]" not in text
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
+    ladder = moe.capacity_ladder(bucket, cfg.moe)
+    if bucket > 64:
+        assert ladder == (256, 512)
+        assert set(calls) == {(str(8 * bucket), "1280"), (str(8 * bucket), "4096")}
+        for capacity in ladder:  # a bounded rung: three batched products
+            for width in (1280, 4096):
+                assert re.search(rf"= bf16\[40,{capacity},{width}\]\S* "
+                                 r"convolution\(", text)
+    else:             # few tokens: every held expert over every token
+        assert not calls and " conditional(" not in text
+    assert mem.alias_size_in_bytes >= 2.9e9
+    assert mem.temp_size_in_bytes < (2.5 if bucket == 1024 else 0.5) * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+HYBRID_ARGUMENT_BYTES = {"step": 14_350_592_512, "prefill-1024": 14_350_500_352}
+
+
+def test_the_hybrid_cells_arguments_are_the_parents(topo, as_tpu):
+    """nemotron-3-nano-30b's programs take the bytes they took before the
+    state pool carried a second kind (the parent's compile, 6cdc048: 14.35
+    GB of arguments, the pool aliased whole)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    for program in ("step", "prefill-1024"):
+        compiled, cfg, pool, sizes = hybrid_program(chip, program)
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes == HYBRID_ARGUMENT_BYTES[program]
+        assert mem.alias_size_in_bytes == 3_564_011_520
