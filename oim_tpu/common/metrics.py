@@ -632,6 +632,20 @@ SERVE_EXPERT_CALLS = DEFAULT.counter(
     "the grouped product over every assignment row), tallied on the "
     "device and fetched with each prompt's first token",
     labelnames=("rung",))
+SERVE_DECODE_ROUNDS = DEFAULT.counter(
+    "oim_serve_decode_rounds_total",
+    "plain decode rounds by how they were dispatched: ahead = behind a "
+    "round whose tokens the host had not fetched yet (the engine keeps one "
+    "round in flight and lands the one before it meanwhile), drained = "
+    "onto an empty queue (the first round, and the one after every "
+    "admission or speculative round)",
+    labelnames=("dispatch",))
+SERVE_OVERRUN_ROWS = DEFAULT.counter(
+    "oim_serve_overrun_rows_total",
+    "rows a decode round stepped once past their last token: the row "
+    "retired (EOS, length, cancel) when the round before landed, after "
+    "this one was dispatched; the token is in no stream "
+    "(serve/engine.py _land)")
 # Recurrent state beside the pages (a hybrid's recurrent layers, Mamba-2
 # or KDA: models/generate.py init_state_pool): a fixed size a slot, held
 # whole.

@@ -21,9 +21,12 @@ max-tokens budget returns the slot's pages, so throughput is bounded by
 pool and slot occupancy, not by the slowest request in a static batch.
 
 Scheduling stays off the decode hot path: the engine thread's loop is
-admit-if-free-slot, one device step, emit — no locks are held across the
-device dispatch, and token streams drain through per-request queues so a
-slow consumer never stalls the batch.
+admit-if-free-slot, dispatch one device step, emit the step BEFORE it —
+one decode round stays in flight while the host lands the one before
+(``_plain_once``, ``_land``), so the device does not wait for the emit
+loop. No locks are held across the device dispatch, and token streams
+drain through per-request queues so a slow consumer never stalls the
+batch.
 
 Prompt-prefix KV reuse (serve/prefixcache.py): a retiring slot donates
 its prompt's full-block pages to a content-addressed prefix store by
@@ -139,6 +142,16 @@ class _Request:
     # Prompt tokens whose K/V came from the prefix cache (0 = the whole
     # prompt was prefilled): the per-request hit record.
     prefix_tokens: int = 0
+
+
+@dataclasses.dataclass
+class _Round:
+    """One plain decode round on the device that the host has not landed
+    yet (``ServeEngine._land``)."""
+    tok: Any        # [B] device array: the tokens the step returns
+    load: list      # a dropless expert model's load numbers, else []
+    rows: list      # (slot, request) of the rows live at its dispatch
+    operands: Any   # the step's three [B] operands, released at landing
 
 
 class GenHandle:
@@ -793,6 +806,16 @@ class ServeEngine:
         # to ONE [B] token fetch (the emit). None = mirrors are fresher
         # (admission wrote a row): the next step re-uploads once.
         self._dev: tuple | None = None
+        # The plain round on the device that the host has not landed yet
+        # (_plain_once dispatches the next round before it lands this one;
+        # _land). At most one, and none while _dev is None.
+        self._inflight: _Round | None = None
+        # Plain rounds by how they were dispatched (behind an unlanded round,
+        # or onto an empty queue), and rows a round stepped once past their
+        # last token (oim_serve_decode_rounds_total,
+        # oim_serve_overrun_rows_total; stats() shows the sums).
+        self._rounds = {"ahead": 0, "drained": 0}
+        self._overrun_rows = 0
         self._pending: collections.deque[_Request] = collections.deque()
         # Engine-thread command queue: the device pool's buffers are
         # DONATED to the jitted step programs, so any D2H read of them
@@ -912,6 +935,12 @@ class ServeEngine:
                 self._stopping = True
             self._work.notify()
         self._thread.join(timeout=timeout)
+        rounds = sum(self._rounds.values())
+        if rounds:
+            from_context().info(
+                "decode rounds dispatched", **self._rounds,
+                ahead_share=round(self._rounds["ahead"] / rounds, 4),
+                overrun_rows=self._overrun_rows)
         if self.cfg.n_experts:
             rungs = self._rung_calls()
             calls = sum(rungs.values())
@@ -967,6 +996,12 @@ class ServeEngine:
                 # (Replica.parse reads only the fields it knows).
                 "target_steps": self._target_steps,
                 "decode_tokens": self._decode_tokens,
+                # Plain rounds dispatched behind an unlanded round / onto
+                # an empty queue, and rows stepped once past their last
+                # token (_plain_once, _land).
+                "decode_rounds_ahead": self._rounds["ahead"],
+                "decode_rounds_drained": self._rounds["drained"],
+                "overrun_rows": self._overrun_rows,
                 # Disaggregation role rides the heartbeat row; pre-role
                 # routers ignore it, new routers split requests across
                 # tiers (missing/malformed reads back as "mixed").
@@ -1176,6 +1211,7 @@ class ServeEngine:
                     done = (self._stopping or self._draining) and not any(
                         s is not None for s in self._slots)
                 if done:
+                    self._land()  # rows past their last token: not waited for
                     self._fail_cmds()
                     return
                 if stop_now:
@@ -1187,6 +1223,10 @@ class ServeEngine:
                     self._admit()
                 if any(s is not None for s in self._slots):
                     self._decode_once()
+                else:
+                    # The round in flight stepped nothing but rows past
+                    # their last token: nothing of it is waited for.
+                    self._land()
         except Exception as err:  # noqa: BLE001 - the loop IS the process
             import traceback
 
@@ -1212,6 +1252,7 @@ class ServeEngine:
                 if not self._cmds:
                     return
                 fn, box = self._cmds.popleft()
+            self._land()  # a command reads the pools with nothing in flight
             try:
                 box["result"] = fn()
             except Exception as err:  # noqa: BLE001 - relayed to caller
@@ -1398,6 +1439,9 @@ class ServeEngine:
         return m
 
     def _evict_all(self, reason: str) -> None:
+        # The round in flight is discarded: its tokens reach no stream, and
+        # nothing of it is waited for (no request is left to step).
+        self._inflight = None
         for i, req in enumerate(self._slots):
             if req is not None:
                 # Hard eviction (ungraceful stop / engine error): no
@@ -1500,17 +1544,17 @@ class ServeEngine:
         return {f"expert_calls_{name}_rung": int(calls) for name, calls
                 in zip(self._rung_names, self._expert_rungs)}
 
-    def _count_expert_rungs(self, tok, rungs: list) -> int:
-        """The prompt's first token, fetched; with it, in the same wait, a
-        held share's tallies of the prompt's prefill calls (``rungs``: one
-        a call, [] for any other model)."""
-        tok, rungs = self._jax.device_get((tok, rungs))
+    def _count_expert_rungs(self, tok, key, rungs: list) -> tuple:
+        """The prompt's first token and its RNG carry, fetched; with them,
+        in the same wait, a held share's tallies of the prompt's prefill
+        calls (``rungs``: one a call, [] for any other model)."""
+        tok, key, rungs = self._jax.device_get((tok, key, rungs))
         if rungs:
             calls = np.sum(rungs, axis=0)
             self._expert_rungs += calls
             for name, n in zip(self._rung_names, calls):
                 M.SERVE_EXPERT_CALLS.labels(rung=name).inc(int(n))
-        return int(tok)
+        return int(tok), key
 
     def _bucket(self, n: int) -> int:
         b = self.MIN_PREFILL_BUCKET
@@ -1521,23 +1565,28 @@ class ServeEngine:
     def _sync_host(self) -> None:
         """Pull the device-resident step operands back into the host
         mirrors (writable copies) before an admission mutates a row; the
-        next decode step re-uploads the merged state once."""
+        next decode step re-uploads the merged state once. Whatever round
+        is in flight lands first: the mirrors are read behind no round
+        whose tokens the host still holds back (an admission landed it
+        while its prefill ran, so this finds none)."""
+        self._land()
         with tracing.annotate("serve.sync"):
             if self._spec_keys_dev is not None:
                 self._spec_keys = np.array(self._spec_keys_dev)
                 self._spec_keys_dev = None
             if self._dev is None:
                 return
-            d_tokens, d_pos, d_keys, _ = self._dev
-            self._tokens = np.array(d_tokens)
-            self._pos = np.array(d_pos)
-            self._keys = np.array(d_keys)
+            # one wait for the three, and writable copies of what came
+            self._tokens, self._pos, self._keys = map(
+                np.array, self._jax.device_get(self._dev[:3]))
             self._dev = None
 
     def _admit(self) -> None:
         """Insert queued requests into free slots (prefill between decode
         steps: new work overlaps residents' decoding at step granularity;
-        with ``prefill_chunk`` one request a call, see the end).
+        with ``prefill_chunk`` one request a call, see the end). The map
+        and the prompt's first dispatch happen with the residents' round
+        still in flight; ``_prefill_slot`` lands it while the prefill runs.
         Admission reserves the request's pages first; an exhausted pool
         leaves the request AT THE HEAD of the queue (FIFO preserved) and
         returns — retirements free pages, the next loop pass retries.
@@ -1614,7 +1663,7 @@ class ServeEngine:
             dkey = self._draft_prefill_slot(req, free, n) if spec_row \
                 else None
             self._sync_host()  # merge device state before writing the row
-            self._keys[free] = np.asarray(key)
+            self._keys[free] = key
             self._tokens[free] = tok
             self._pos[free] = n
             self._temps[free] = req.temperature
@@ -1764,7 +1813,11 @@ class ServeEngine:
                     jnp.asarray(self._tables[slot]), jnp.int32(P),
                     self._jax.random.PRNGKey(req.seed),
                     jnp.float32(req.temperature), *self._state_row(slot))
-                tok = self._count_expert_rungs(tok, rungs)
+                # The prefill is queued behind the round in flight: that
+                # round lands while the prefill runs, and only then is the
+                # prompt's token waited for (_land).
+                self._land()
+                tok, key = self._count_expert_rungs(tok, key, rungs)
         if self._prefix is not None:
             if P:
                 req.prefix_tokens = P
@@ -1783,11 +1836,15 @@ class ServeEngine:
         round over the RESIDENT slots between slices — admission never
         stalls a long prompt behind the batch, and the batch's decode
         cadence never stalls behind a long prompt. No slice but the last
-        is waited for: the round is dispatched behind the slice in flight
-        and the next slice behind the round (``_decode_once``'s
-        ``queue_behind``), so the device runs slice, round, slice back to
-        back while the host fetches and emits, and a resident's gap across
-        a slice is the device's time alone. Byte-identical to
+        is waited for: the first slice is dispatched behind the round in
+        flight, each round behind the slice in flight and the next slice
+        behind the round (``_decode_once``'s ``queue_behind``), so the
+        device runs round, slice, round, slice back to back while the host
+        lands the round before, and a resident's gap across a slice is the
+        device's time alone. The round behind which the LAST slice was
+        queued lands before that slice's token is waited for (``_land``:
+        the host waits behind no round it has not landed, or the
+        residents' tokens would wait for a second slice). Byte-identical to
         one full prefill: every slice runs the SAME compiled program
         over the same pages at shifted ``start`` (attention math is
         position-indexed, not dispatch-indexed), and every slice gets
@@ -1851,11 +1908,14 @@ class ServeEngine:
                 if resident:
                     self._decode_once(
                         queue_behind=lambda off=off: nxt.append(dispatch(off)))
-                tok.block_until_ready()  # behind a round: done already
+                else:  # a round of rows past their last token: dropped
+                    self._land()
+                tok.block_until_ready()  # behind a landed round
                 landed(since)
                 tok, key, since = nxt[0] if nxt else dispatch(off)
+            self._land()  # the round the last slice is queued behind
             # device sync: the prompt is in the pages HERE
-            tok = self._count_expert_rungs(tok, rungs)
+            tok, key = self._count_expert_rungs(tok, key, rungs)
             landed(since)
         self._tables[slot, :] = table_row
         self._tables_dev = None
@@ -1957,10 +2017,13 @@ class ServeEngine:
         draft-propose / target-verify round when a draft model is
         configured, the valve is open and any live slot holds a draft
         cache; one plain lockstep decode step otherwise (a closed
-        valve's plain rounds tick the re-probe cooldown).
+        valve's plain rounds tick the re-probe cooldown). A plain round
+        leaves ONE ROUND IN FLIGHT: it dispatches this round and lands the
+        one before it (``_plain_once``, ``_land``); a speculative round is
+        dispatched onto an empty queue and waited for.
 
         ``queue_behind`` is called between the round's dispatch and the
-        fetch that waits for it: what it dispatches runs on the device
+        fetch the host then waits in: what it dispatches runs on the device
         right behind the round, while the host fetches and emits (a
         chunked prefill's next slice: ``_prefill_chunked``)."""
         # Chaos lever: an armed fault here wedges the engine — the run
@@ -1983,12 +2046,15 @@ class ServeEngine:
         self._plain_once(queue_behind)
 
     def _observe_ici(self, live) -> None:
-        """One ICI-allreduce observation per target dispatch (sharded
+        """One ICI-allreduce observation per landed round (sharded
         replicas only): the per-layer collectives are fused inside the
         jitted step and cannot be host-timed individually, so a tiny
         compiled psum over the SAME mesh is timed instead — the
         exemplar carries a live request's trace_id so a slow allreduce
-        links back to the request it stalled."""
+        links back to the request it stalled. The probe blocks, behind
+        whatever is queued: after a plain round's emit loop that is the
+        round in flight, so a sharded replica's loop is still step + host
+        and the observation holds that round's rest (ROADMAP S3)."""
         from oim_tpu.serve import shard as shardlib
 
         M.SERVE_ICI_ALLREDUCE.observe(
@@ -2005,6 +2071,7 @@ class ServeEngine:
         (spec_mask pins their accepted count to 0), so mixed
         spec/non-spec batches stay lockstep."""
         jnp = self._jnp
+        self._land()  # a plain round in flight: this one runs behind none
         with tracing.annotate("serve.upload"):
             if self._dev is None:
                 self._dev = (
@@ -2104,18 +2171,23 @@ class ServeEngine:
             del d_tokens, d_pos, d_keys, draft_toks, draft_logits
 
     def _plain_once(self, queue_behind=None) -> None:
-        """One lockstep decode step over every resident slot; idle rows
-        compute a discarded garbage token.
+        """One lockstep decode step over every resident slot, dispatched
+        BEFORE the round before it is landed: the engine keeps one round in
+        flight, and the host fetches, emits and retires round n while the
+        device runs round n + 1, so the loop's period is the longer of the
+        step and the host's work, not their sum. Idle rows compute a
+        discarded garbage token.
 
         The hot loop is device-resident: each step's outputs (token, pos,
-        key chain) ARE the next step's operands, so steady-state decode
-        costs one jit dispatch plus one [B] token fetch — no per-step
-        host-mirror round trips (the mirrors re-sync only around
-        admissions, in _sync_host). With several engines in one process
-        (bench --replicas, replica-packed hosts) the GIL-held Python
-        slice per step is what bounds aggregate throughput, so this is
-        the difference between replicas that scale and replicas that
-        serialize."""
+        key chain) ARE the next step's operands, so round n + 1 needs
+        nothing of round n on the host — no per-step host-mirror round
+        trips (the mirrors re-sync only around admissions, in _sync_host,
+        and the round after one starts from uploaded operands with nothing
+        in flight: a DRAINED dispatch, where every other is AHEAD). With
+        several engines in one process (bench --replicas, replica-packed
+        hosts) the GIL-held Python slice per step is what bounds aggregate
+        throughput, so this is the difference between replicas that scale
+        and replicas that serialize."""
         jnp = self._jnp
         with tracing.annotate("serve.upload"):
             if self._dev is None:
@@ -2125,27 +2197,78 @@ class ServeEngine:
             if self._tables_dev is None:
                 self._tables_dev = jnp.asarray(self._tables)
         d_tokens, d_pos, d_keys, d_temps = self._dev
+        with self._lock:
+            rows = [(i, r) for i, r in enumerate(self._slots) if r is not None]
         with tracing.annotate("serve.dispatch"):
             tok, self._cache, keys, pos, *load = self._step(
                 self.params, self._cache, d_tokens, d_pos, d_keys, d_temps,
                 self._tables_dev)
         self._dev = (tok, pos, keys, d_temps)
+        how = "drained" if self._inflight is None else "ahead"
+        self._rounds[how] += 1
+        M.SERVE_DECODE_ROUNDS.labels(dispatch=how).inc()
         self._count_expert_rows(self.max_batch)
+        this = _Round(tok, load, rows, (d_tokens, d_pos, d_keys))
+        del d_tokens, d_pos, d_keys
         if queue_behind is not None:
             queue_behind()
+        self._land(then=this)
+
+    def _land(self, then: _Round | None = None) -> None:
+        """Land the plain round in flight, if one is: fetch its tokens (the
+        one wait for the device), emit and retire its rows, release its
+        operands. ``then``, a round dispatched behind it, is in flight
+        afterwards. Everything that waits for the device lands first what
+        is in flight (an admission after its prompt's dispatch, _sync_host,
+        a speculative round, a command): the host never blocks on anything
+        queued behind a round whose tokens it still holds back.
+
+        The overrun step. A row that retires here (EOS, length, cancel) was
+        stepped once more by ``then``, with the table row it had. Every
+        retirement takes that step, the one by length too, though the host
+        knows it a round early (one path; splitting a retirement in two
+        would save a step of a row that is stepped as an idle row anyway).
+        It is harmless by construction:
+
+        * its one write is at the position after the row's last token,
+          which ``_blocks_needed`` reserves no page for: behind a page edge
+          the table entry is 0, the scratch page; elsewhere it falls in the
+          row's last page at an offset the row never read, and that page is
+          the row's on the device's timeline: freed here, it may go to the
+          next admission, whose prefill is dispatched after ``then`` and so
+          ordered behind the stale write, above its causal horizon until it
+          overwrites it;
+        * the prefix store keeps a prompt's FULL blocks, and decode
+          positions lie behind them;
+        * a hybrid's state row is stepped once more and is dead where it
+          lies: the next prefill at ``start`` = 0 begins from zeros
+          (``generate.init_state_pool``);
+        * ``pos`` is clamped at ``max_seq`` as an idle row's is, and the row
+          IS an idle row from the round after (its table row zeroed here,
+          uploaded before the next dispatch).
+
+        The overrun token is in no stream and in no count of tokens: a row
+        of a round is landed only while its request still holds its slot.
+        The round's expert load counts every row, as it does idle rows."""
+        rnd, self._inflight = self._inflight, then
+        if rnd is None:
+            return
+        live = [(i, r) for i, r in rnd.rows if self._slots[i] is r]
+        overrun = len(rnd.rows) - len(live)
+        if overrun:
+            self._overrun_rows += overrun
+            M.SERVE_OVERRUN_ROWS.inc(overrun)
+        if not live:
+            return  # nothing to emit: not waited for
         with tracing.annotate("serve.fetch"):
-            # Forces the step; the only per-step fetch (a dropless expert
+            # Waits for the step; the only per-step fetch (a dropless expert
             # model's two load numbers ride the same round trip).
-            if load:
-                tok, load = self._jax.device_get((tok, load[0]))
+            if rnd.load:
+                tok, load = self._jax.device_get((rnd.tok, rnd.load[0]))
                 self._expert_load += (1.0, *load)
             else:
-                tok = np.asarray(tok)
+                tok = np.asarray(rnd.tok)
         self._target_steps += 1
-        with self._lock:
-            live = [(i, r) for i, r in enumerate(self._slots) if r is not None]
-        if self.shard > 1:
-            self._observe_ici(live)
         with tracing.annotate("serve.emit"):
             for i, req in live:
                 if req.cancelled.is_set():
@@ -2162,4 +2285,6 @@ class ServeEngine:
                 self._retire_if_done(i, req, int(tok[i]))
             # The round's operands die here, under the name, and not at the
             # frame's exit: three [B] device arrays cost 1-3 ms to release.
-            del d_tokens, d_pos, d_keys
+            rnd.operands = None
+        if self.shard > 1:
+            self._observe_ici(live)

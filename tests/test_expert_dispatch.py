@@ -203,14 +203,15 @@ def test_the_engine_streams_what_solo_generate_does(
 def test_the_counter_counts_rows_by_dispatch(expert_params, engine):
     """A known sequence: one admission above the crossing, one below, and
     the decode rounds between; rows over the two expert layers, from the
-    programs' shapes."""
+    programs' shapes. Every round DISPATCHED counts, the one that steps a
+    row past its last token too (the engine keeps a round in flight)."""
     e, k, layers, b = (EXPERT.n_experts, EXPERT.moe_top_k, EXPERT.n_layers,
                        engine.max_batch)
 
     def snapshot():
         s = engine.stats()
         return (s["expert_rows_dropless"], s["expert_rows_padded"],
-                s["target_steps"],
+                s["decode_rounds_ahead"] + s["decode_rounds_drained"],
                 M.SERVE_EXPERT_ROWS.labels(dispatch="dropless").value,
                 M.SERVE_EXPERT_ROWS.labels(dispatch="padded").value)
 

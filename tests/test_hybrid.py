@@ -754,8 +754,9 @@ def test_the_engine_counts_expert_calls_by_rung(
             assert sum(moved.values()) == layers * slices
             assert moved["whole"] == (layers * slices if biased else 0)
         # one fetch a prompt carried its slices' tallies with the token
-        tallies = [len(x[1]) for x in fetches
-                   if isinstance(x, tuple) and isinstance(x[1], list)]
+        # (and its RNG carry: (token, carry, tallies))
+        tallies = [len(x[-1]) for x in fetches
+                   if isinstance(x, tuple) and isinstance(x[-1], list)]
         assert tallies == [3, 1]
         final = engine.stats()
         assert {name: exported()[name] - at_start[name]

@@ -191,6 +191,144 @@ class TestEngineInvariants:
             assert order[i - 1] == "step" and order[i + 1] == "emit", order
         assert out == solo_tokens(params, cfg, prompt, 4)
 
+    @staticmethod
+    def _record(eng):
+        """``order``: every decode step's and prefill's dispatch ("step",
+        "slice"), every wait for a prompt's first token ("token"), every
+        mirror read-back ("sync") and every emitted token (("emit", its
+        request)), as the engine's thread makes them."""
+        order = []
+        prefill, step, emit = eng._prefill, eng._step, eng._emit
+        token, sync = eng._count_expert_rungs, eng._sync_host
+        eng._prefill = lambda *a: (order.append("slice"), prefill(*a))[1]
+        eng._step = lambda *a: (order.append("step"), step(*a))[1]
+        eng._emit = lambda req, t: (order.append(("emit", req)),
+                                    emit(req, t))[1]
+        eng._count_expert_rungs = lambda *a: (order.append("token"),
+                                              token(*a))[1]
+        eng._sync_host = lambda: (order.append("sync"), sync())[1]
+        return order
+
+    def test_a_round_is_dispatched_before_the_one_before_it_is_emitted(
+            self, model):
+        """Steady state, one stream: every step but the first after an
+        upload is dispatched BEFORE the emit of the round before it, the
+        step past the stream's last token too (the overrun step: its token
+        is in no stream). The device never waits for the emit loop."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=2, max_seq=64)
+        order = self._record(eng)
+        try:
+            h = eng.submit([1, 2, 3], max_new=10)
+            out = h.result(timeout=120)
+        finally:
+            eng.stop(timeout=30)
+        e = ("emit", h._req)
+        assert order == (["slice", "token", "sync", e, "step"]
+                         + ["step", e] * 9), order
+        assert out == solo_tokens(params, cfg, [1, 2, 3], 10)
+        assert len(out) == 10 and eng.stats()["overrun_rows"] == 1
+
+    @pytest.mark.parametrize("chunk", [0, 8])
+    def test_an_admission_lands_the_round_in_flight_before_it_waits(
+            self, model, chunk):
+        """With a resident, a prompt's first slice (and a one-shot prefill)
+        is dispatched BEHIND a round in flight, and the host lands every
+        round in flight — the resident's emits — BEFORE it waits for the
+        prompt's token. The other order doubles the resident's gap across
+        a last slice (slice + round + slice): the host would hold back a
+        finished round's tokens for the whole of the slice behind it."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=4, max_seq=64,
+                          queue_depth=8, prefill_chunk=chunk)
+        order = self._record(eng)
+        # The engine's thread waits inside the landing of the resident's
+        # fifth token until the prompt is queued: a round is in flight.
+        at_five, queued = hold_at_fifth_token(eng, max_new=40)
+        try:
+            first = eng.submit([1, 2, 3], max_new=40)
+            assert at_five.wait(60)
+            prompt = list(range(1, 30))  # chunk 8: slices of 8, 8, 8, 5
+            late = eng.submit(prompt, max_new=4)
+            queued.set()
+            out = late.result(timeout=120)
+            first.result(timeout=120)
+        finally:
+            eng.stop(timeout=30)
+        e = ("emit", first._req)
+        at = [i for i, what in enumerate(order) if what == "slice"][1:]
+        assert len(at) == (4 if chunk else 1)
+        # Behind a round in flight: one more step dispatched than landed.
+        before = order[:at[0]]
+        assert before.count("step") == before.count(e) - 1 + 1, before
+        # The last (or only) slice, then the resident's rounds landed, and
+        # only then the wait: one round in flight beside a one-shot prefill,
+        # two behind a later slice (the round it was queued behind, and the
+        # one before that, landed by the dispatching round itself).
+        landed = [e, e] if chunk else [e]
+        i = at[-1]
+        assert order[i + 1:i + 2 + len(landed)] == landed + ["token"], order
+        # After the admission: mirrors back, the prompt's own first token,
+        # and a round dispatched onto an empty queue, nothing to land yet.
+        assert order[i + 2 + len(landed):i + 5 + len(landed)] == [
+            "sync", ("emit", late._req), "step"], order
+        assert out == solo_tokens(params, cfg, prompt, 4)
+
+    def test_the_counters_read_what_a_scripted_run_implies(self, model):
+        """A resident of 20 tokens, a second request admitted beside it at
+        its fifth (4 tokens: it leaves first), then a third alone that ends
+        by EOS. Three admissions, each into a loop pass of its own, make
+        three drained rounds (uploaded operands, nothing in flight); every
+        other round is dispatched ahead. Each retirement is one overrun
+        row, and each time the engine runs empty one round has stepped
+        nothing else. ``stats()``, the exported counters and the stop line
+        carry the same numbers."""
+        import io
+
+        from oim_tpu.common import logging as oim_logging
+
+        params, cfg = model
+        ref = solo_tokens(params, cfg, [7, 8], 8)
+        eos = next(t for t in ref[1:] if t != ref[0])
+        ref = ref[:ref.index(eos) + 1]
+        eng = ServeEngine(params, cfg, max_batch=2, max_seq=64)
+        rounds = {how: M.SERVE_DECODE_ROUNDS.labels(dispatch=how).value
+                  for how in ("ahead", "drained")}
+        overrun = M.SERVE_OVERRUN_ROWS.value
+        at_five, queued = hold_at_fifth_token(eng, max_new=20)
+        log = io.StringIO()
+        try:
+            first = eng.submit([1, 2, 3], max_new=20)
+            assert at_five.wait(60)
+            second = eng.submit([4, 5], max_new=4)
+            queued.set()
+            assert second.result(timeout=120) == solo_tokens(
+                params, cfg, [4, 5], 4)
+            assert len(first.result(timeout=120)) == 20
+            by_eos = eng.submit([7, 8], max_new=8, eos=eos)
+            assert by_eos.result(timeout=120) == ref
+            assert by_eos.finish_reason == "eos"
+        finally:
+            with oim_logging.with_logger(oim_logging.Logger(output=log)):
+                eng.stop(timeout=30)
+        stats = eng.stats()
+        # 19 rounds carry the resident's tokens, len(ref) - 1 the third's,
+        # and one more behind each of the two runs steps a retired row only.
+        steps = 19 + len(ref) - 1
+        assert stats["target_steps"] == steps
+        assert stats["decode_rounds_drained"] == 3
+        assert stats["decode_rounds_ahead"] == steps + 2 - 3
+        assert stats["overrun_rows"] == 3
+        assert M.SERVE_DECODE_ROUNDS.labels(dispatch="drained").value \
+            == rounds["drained"] + 3
+        assert M.SERVE_DECODE_ROUNDS.labels(dispatch="ahead").value \
+            == rounds["ahead"] + steps - 1
+        assert M.SERVE_OVERRUN_ROWS.value == overrun + 3
+        line = next(ln for ln in log.getvalue().splitlines()
+                    if "decode rounds dispatched" in ln)
+        assert f"ahead: {steps - 1}" in line and "drained: 3" in line
+        assert "overrun_rows: 3" in line and "ahead_share: 0." in line
+
     def test_slot_reuse_leaks_nothing(self, model):
         """A slot's next occupant sees a zero cache: with max_batch=1
         every request reuses THE slot, and each must still match solo —
@@ -316,6 +454,224 @@ class TestEngineInvariants:
                 h.cancel()
         finally:
             eng.stop(drain=False, timeout=30)
+
+
+# -- one decode round in flight (ISSUE 38) ----------------------------------
+
+RUNAHEAD_PAGE = 8
+RUNAHEAD_SEQ = 64
+# A model family at test size -> (its configuration, what its engine needs
+# said). Recurrent state refuses a prefix store.
+FAMILIES = {
+    "dense": lambda: (llama.tiny(vocab=64, dim=32, n_layers=2), {}),
+    "padded-experts": lambda: (
+        llama.tiny(vocab=64, dim=32, n_layers=2, n_experts=4), {}),
+    "latent": lambda: (llama.tiny_latent(vocab=64, n_layers=2), {}),
+    "mamba-hybrid": lambda: (llama.tiny_hybrid(vocab=64, pattern="ME*E"),
+                             {"prefix_cache_bytes": 0}),
+    "kda-hybrid": lambda: (llama.tiny_kda(vocab=64, n_layers=4),
+                           {"prefix_cache_bytes": 0}),
+}
+# name -> (prompt length, max_new, temperature, seed). B ends by length at
+# a page edge (5 + 12 - 1 = 16: the position after its last token opens a
+# page it never reserved), F at max_seq (26 + 38 = 64).
+RUNAHEAD_REQS = {
+    "A": (19, 12, 0.0, 0), "B": (5, 12, 0.8, 1), "C": (11, 12, 0.0, 2),
+    "D": (17, 6, 0.7, 3), "F": (26, 38, 0.0, 4), "G": (3, 7, 0.9, 5),
+}
+_family_cache: dict = {}
+
+
+def runahead_family(name):
+    """(params, cfg, engine kwargs, {request: (prompt, its solo tokens)})
+    of one family, built once a process. Solo is ``generate()`` where it
+    runs the family; a hybrid pattern has no dense cache to generate from,
+    so its solo run is the same engine geometry serving the request ALONE
+    (same compiled programs, no batch-mate, no slot or page reused)."""
+    if name in _family_cache:
+        return _family_cache[name]
+    cfg, kwargs = FAMILIES[name]()
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(38)
+    prompts = {key: rng.integers(1, 64, n).tolist()
+               for key, (n, _, _, _) in RUNAHEAD_REQS.items()}
+    alone = None
+    if cfg.pattern:
+        alone = runahead_engine(params, cfg, kwargs, 0)
+    solo = {}
+    try:
+        for key, (_, n_new, temp, seed) in RUNAHEAD_REQS.items():
+            if alone is None:
+                solo[key] = solo_tokens(params, cfg, prompts[key], n_new,
+                                        temp, seed, max_seq=RUNAHEAD_SEQ)
+            else:
+                solo[key] = alone.submit(
+                    prompts[key], max_new=n_new, temperature=temp,
+                    seed=seed).result(timeout=300)
+    finally:
+        if alone is not None:
+            alone.stop(timeout=30)
+    _family_cache[name] = (params, cfg, kwargs,
+                           {k: (prompts[k], solo[k]) for k in prompts})
+    return _family_cache[name]
+
+
+def runahead_engine(params, cfg, kwargs, chunk, **more):
+    return ServeEngine(params, cfg, max_batch=3, max_seq=RUNAHEAD_SEQ,
+                       queue_depth=16, prefix_block=RUNAHEAD_PAGE,
+                       prefill_chunk=chunk, **kwargs, **more)
+
+
+def submit_named(eng, reqs, key, **kw):
+    _, n_new, temp, seed = RUNAHEAD_REQS[key]
+    return eng.submit(reqs[key][0], max_new=n_new, temperature=temp,
+                      seed=seed, **kw)
+
+
+def assert_nothing_held(eng):
+    """After a stopped engine: no round in flight, and the page pool, the
+    draft pool and the state pool hold nothing (the prefix store's own
+    references dropped first: they are the store's, not a leak)."""
+    assert eng._inflight is None
+    eng.evict_prefix_store()
+    eng.evict_host_tier()
+    pool = eng.pool_stats()
+    assert pool["used_pages"] == 0, pool
+    assert pool["state_slots_live"] == 0
+    assert eng.spec_stats()["draft_used_pages"] == 0
+
+
+def gate_emit(eng, when):
+    """Replace ``eng._emit`` by one that calls ``when(req)`` after every
+    emitted token, on the engine's thread, inside the landing of a round:
+    the next round is already dispatched behind it."""
+    emit = eng._emit
+
+    def gated(req, token):
+        emit(req, token)
+        when(req)
+
+    eng._emit = gated
+
+
+def hold_at_fifth_token(eng, max_new):
+    """(reached, go): the engine's thread sets ``reached`` inside the
+    landing of the fifth token of the request that asked for ``max_new``
+    and waits there for ``go``: what the test queues meanwhile is admitted
+    beside that request, a round in flight."""
+    reached, go = threading.Event(), threading.Event()
+    gate_emit(eng, lambda req: req.emitted == 5 and req.max_new == max_new
+              and (reached.set(), go.wait(30)))
+    return reached, go
+
+
+RUNAHEAD_CASES = ("eos-midbatch", "length-at-edges", "cancel", "stop-drain",
+                  "stop-nodrain")
+
+
+@pytest.mark.parametrize("case", RUNAHEAD_CASES)
+@pytest.mark.parametrize("chunk", [0, 8])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_streams_stay_solo_with_a_round_in_flight(family, chunk, case):
+    """The engine dispatches round n + 1 before it lands round n, so a row
+    that retires when round n lands was stepped once more, with its old
+    table row and state. Every stream still equals its solo run, the
+    overrun token is in none of them, and nothing is left in any pool:
+
+    * ``eos-midbatch``: a row ends by EOS in a full batch, the page pool
+      sized so that the queued prompt needs the pages it frees: slot and
+      pages go to that prompt in the same pass, behind the overrun step;
+    * ``length-at-edges``: rows end by length with the overrun write at a
+      page edge (unreserved: the scratch page) and at ``max_seq``;
+    * ``cancel``: a row is cancelled inside a landing, a round in flight;
+    * ``stop-drain`` / ``stop-nodrain``: ``stop()`` arrives inside a
+      landing; residents finish whole, or are cut at a prefix of solo."""
+    params, cfg, kwargs, reqs = runahead_family(family)
+    solo = {key: tokens for key, (_, tokens) in reqs.items()}
+    more = {}
+    if case == "eos-midbatch":
+        # A, B, C hold 4 + 2 + 3 pages of 11; D needs 3: B's two and one.
+        more["kv_pool_tokens"] = 11 * RUNAHEAD_PAGE
+    eng = runahead_engine(params, cfg, kwargs, chunk, **more)
+    outs, handles, stopped = {}, {}, False
+    before = eng.stats()["overrun_rows"]
+    try:
+        if case == "eos-midbatch":
+            eos = next(t for t in solo["B"][1:] if t != solo["B"][0])
+            solo["B"] = solo["B"][:solo["B"].index(eos) + 1]
+            for key in "ABCD":
+                handles[key] = submit_named(
+                    eng, reqs, key, **({"eos": eos} if key == "B" else {}))
+        elif case == "length-at-edges":
+            for key in "BFCG":
+                handles[key] = submit_named(eng, reqs, key)
+        elif case == "cancel":
+            cut = {}
+
+            def when(req):
+                if req is cut.get("req") and req.emitted >= 4 \
+                        and "at" not in cut:
+                    cut["at"] = req.emitted
+                    req.cancelled.set()
+
+            gate_emit(eng, when)
+            handles["B"] = submit_named(eng, reqs, "B")
+            cut["req"] = handles["B"]._req
+            for key in "ACD":
+                handles[key] = submit_named(eng, reqs, key)
+        else:
+            inside, go = threading.Event(), threading.Event()
+            first = {}
+
+            def when(req):
+                if req is first.get("req") and req.emitted == 3:
+                    inside.set()
+                    go.wait(30)
+
+            gate_emit(eng, when)
+            handles["A"] = submit_named(eng, reqs, "A")
+            first["req"] = handles["A"]._req
+            for key in "BCD":
+                handles[key] = submit_named(eng, reqs, key)
+            assert inside.wait(60)
+            stopper = threading.Thread(target=eng.stop, kwargs={
+                "drain": case == "stop-drain", "timeout": 60})
+            stopper.start()
+            assert wait_for(lambda: eng._draining, interval=0.001)
+            assert eng._inflight is not None  # stop found a round in flight
+            go.set()
+            stopper.join(90)
+            stopped = True
+        outs = {key: h.result(timeout=120) for key, h in handles.items()}
+    finally:
+        if not stopped:
+            eng.stop(timeout=60)
+    reasons = {key: h.finish_reason for key, h in handles.items()}
+    if case == "cancel":
+        solo["B"] = solo["B"][:cut["at"]]
+        assert reasons == {"A": "length", "B": "cancelled", "C": "length",
+                           "D": "length"}
+    elif case == "eos-midbatch":
+        assert reasons == {"A": "length", "B": "eos", "C": "length",
+                           "D": "length"}
+        assert len(solo["B"]) >= 2  # ended by a decode round, not its prefill
+        assert handles["D"]._req.admitted_at >= handles["B"]._req.finished_at
+    elif case == "stop-drain":
+        assert reasons == {"A": "length", "B": "length", "C": "length",
+                           "D": "drained"}
+        solo["D"] = []
+    elif case == "stop-nodrain":
+        assert set(reasons.values()) == {"drained"}
+        assert len(outs["A"]) >= 3
+        solo = {key: solo[key][:len(outs[key])] for key in outs}
+        assert outs["D"] == []
+    else:
+        assert set(reasons.values()) == {"length"}
+    for key, out in outs.items():
+        assert out == solo[key], (key, out, solo[key])
+    if case != "stop-nodrain":  # every retirement by a round overran once
+        assert eng.stats()["overrun_rows"] - before >= 1
+    assert_nothing_held(eng)
 
 
 class TestWeights:

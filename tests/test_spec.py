@@ -18,6 +18,7 @@ drain (the PR 11 refcount-census discipline applied to the second
 pool); and a negative temperature is refused at submit time.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -402,6 +403,57 @@ class TestSpecEngine:
         finally:
             eng.stop(drain=False, timeout=30)
         assert eng.spec_stats()["draft_used_pages"] == 0
+
+    def test_a_speculative_round_runs_with_nothing_in_flight(self, model):
+        """Plain rounds keep one round in flight; a speculative round is
+        dispatched onto an empty queue and waited for. A draft pool with
+        room for ONE short request: the long resident gets no draft pages
+        and decodes plainly, a round ahead, until the short request beside
+        it brings a draft row — from then on every round speculates, and
+        each finds nothing in flight (``_spec_once`` lands a plain round
+        before it runs; here the admission already had). Plain again, and
+        ahead again, once the short request is gone. Tokens stay solo's and
+        both pools end empty."""
+        params, cfg = model
+        eng = ServeEngine(params, cfg, max_batch=2, max_seq=64,
+                          queue_depth=8, draft_params=params,
+                          draft_cfg=cfg, spec_tokens=3,
+                          spec_pool_tokens=32)  # two 16-token pages
+        in_flight = []
+        propose = eng._propose
+        eng._propose = lambda *a: (in_flight.append(eng._inflight),
+                                   propose(*a))[1]
+        # The engine's thread waits at the resident's fifth token until the
+        # short request is queued: it is admitted BESIDE the resident.
+        at_five, queued = threading.Event(), threading.Event()
+        emit = eng._emit
+        eng._emit = lambda req, t: (emit(req, t), req.emitted == 5
+                                    and req.max_new == 40
+                                    and (at_five.set(), queued.wait(30)))
+        try:
+            plain = eng.submit([1, 2, 3], max_new=40, seed=0)  # 3 pages
+            assert at_five.wait(60)
+            assert eng.stats()["decode_rounds_ahead"] >= 2
+            assert not in_flight and not any(eng._spec_row)
+            spec = eng.submit([4, 5, 6], max_new=12, seed=1)  # 1 page
+            queued.set()
+            assert spec.result(timeout=300) == solo_tokens(
+                params, cfg, [4, 5, 6], 12, seed=1)
+            assert plain.result(timeout=300) == solo_tokens(
+                params, cfg, [1, 2, 3], 40, seed=0)
+            stats = eng.stats()
+        finally:
+            eng.stop(timeout=30)
+        assert in_flight and all(r is None for r in in_flight)
+        assert stats["spec_rounds"] == len(in_flight)
+        # Onto an empty queue: the first round, and the plain round behind
+        # the last speculative one; every other plain round ran ahead.
+        assert stats["decode_rounds_drained"] == 2
+        assert stats["decode_rounds_ahead"] > stats["spec_rounds"]
+        assert eng._inflight is None
+        assert eng.spec_stats()["draft_used_pages"] == 0
+        eng.evict_prefix_store()
+        assert eng.pool_stats()["used_pages"] == 0
 
     def test_valve_fallback_and_reprobe_stay_byte_identical(
             self, model, draft_model):
