@@ -211,7 +211,8 @@ def _scan_groups(body, carry, params, cfg: Config, xs=None):
             layer, part, i = inp
             return body(carry, (moe.at_layer(layer, whole, i), part))
 
-        carry, y = lax.scan(step, carry, (sliced, part, jnp.arange(n)))
+        with jax.named_scope("blk_loop"):  # the scan's own cut of a layer
+            carry, y = lax.scan(step, carry, (sliced, part, jnp.arange(n)))
         ys.append(y)
         at += n
     return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
@@ -236,9 +237,10 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     # scan, so lift everything to jax arrays first (no-op when already on
     # device).
     params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
-    positions = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("tok_embed"):
+        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
+        positions = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
+        x = params["embed"][tokens].astype(cfg.dtype)
 
     def attend(c, q, *new):
         if cfg.kv_lora_rank:
@@ -258,8 +260,9 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
         return x, c
 
     x, cache = _scan_groups(body, x, params, cfg, cache)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("tok_head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, cache
 
 
@@ -416,21 +419,24 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     S = tables.shape[1] * page
     cfg = _no_drop(cfg, B * T)
     params = jax.tree.map(jnp.asarray, params)
-    cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
-    positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("tok_embed"):
+        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
+        positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
+        x = params["embed"][tokens].astype(cfg.dtype)
 
     def attend_at(l):
         """Layer l's attention over the pool it is handed."""
         def attend(pool, q, *new):
             if cfg.kv_lora_rank:
                 latent, wkv_b = new
-                kv = pool["kv"].at[l, phys, off].set(latent, mode="drop")
+                with jax.named_scope("blk_kv_write"):
+                    kv = pool["kv"].at[l, phys, off].set(latent, mode="drop")
                 return latent_attention.paged_attention(
                     q, kv, l, tables, pos, wkv_b, cfg.latent), {"kv": kv}
             k, v = new
-            pk = pool["k"].at[l, phys, off].set(k, mode="drop")
-            pv = pool["v"].at[l, phys, off].set(v, mode="drop")
+            with jax.named_scope("blk_kv_write"):
+                pk = pool["k"].at[l, phys, off].set(k, mode="drop")
+                pv = pool["v"].at[l, phys, off].set(v, mode="drop")
             return (paged_attention(q, pk, pv, l, tables, pos),
                     {**pool, "k": pk, "v": pv})
         return attend
@@ -440,8 +446,9 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
         x, pool, load = _hybrid_paged(
             params, x, pool, cfg, cos, sin, positions, attend_at,
             tables[:, 0] != 0, slot, n_tokens, pos)
-        return (rmsnorm(x, params["final_norm"], cfg.norm_eps), pool,
-                _mean_load(load, n_moe))
+        with jax.named_scope("tok_head"):
+            x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        return x, pool, _mean_load(load, n_moe)
 
     def body(carry, inp):
         x, pool, l = carry  # pool leaves: [L, n_pages, page, ...]
@@ -452,8 +459,9 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
 
     (x, pool, _), load = _scan_groups(
         body, (x, pool, jnp.int32(0)), params, cfg)
-    return (rmsnorm(x, params["final_norm"], cfg.norm_eps), pool,
-            _mean_load(jnp.sum(load, axis=0), n_moe))
+    with jax.named_scope("tok_head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    return x, pool, _mean_load(jnp.sum(load, axis=0), n_moe)
 
 
 def _mean_load(load, n_moe: int):
@@ -588,8 +596,9 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     # The last real row is taken BEFORE the head: one row of logits is
     # kept, so one row is computed (at 129 280 rows of vocabulary a
     # 2048-token chunk's float32 logits would be 1 GB for nothing).
-    last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
-    logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
+    with jax.named_scope("tok_head"):
+        last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
+        logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
     if with_rungs:
         return logits, pool, load[2:].astype(jnp.int32)
     return logits, pool
@@ -627,7 +636,8 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
     x, pool, load = _forward_paged(
         params, tokens[:, None], pool, page_tables, pos, phys[:, None],
         (pos % page_tokens)[:, None], cfg, axis)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("tok_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     if with_load:
         return logits[:, 0], pool, load[:2]
     return logits[:, 0], pool
@@ -677,7 +687,8 @@ def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
     x, pool, _ = _forward_paged(
         params, tokens, pool, page_tables, pos, phys,
         positions % page_tokens, cfg, axis)
-    return (x @ params["lm_head"]).astype(jnp.float32), pool
+    with jax.named_scope("tok_head"):
+        return (x @ params["lm_head"]).astype(jnp.float32), pool
 
 
 def generate(params, prompt, n_new: int, cfg: Config,
@@ -698,10 +709,11 @@ def generate(params, prompt, n_new: int, cfg: Config,
         rng = jax.random.PRNGKey(0)
 
     def sample(logits, key):
-        if temperature > 0:
-            return jax.random.categorical(
-                key, logits / temperature).astype(prompt.dtype)
-        return jnp.argmax(logits, axis=-1).astype(prompt.dtype)
+        with jax.named_scope("tok_head"):
+            if temperature > 0:
+                return jax.random.categorical(
+                    key, logits / temperature).astype(prompt.dtype)
+            return jnp.argmax(logits, axis=-1).astype(prompt.dtype)
 
     cache = init_cache(cfg, B, s)
     logits, cache = cached_forward(params, prompt, cache, 0, cfg)

@@ -696,13 +696,16 @@ def _block(x, layer, cfg: Config, cos, sin, positions, attend, cache=None,
 
     ``reduce`` sums the two row-split projections over a tensor-parallel
     axis. Returns (x, aux, cache)."""
-    h = rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("blk_qkv"):
+        h = rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
     attn, cache = _attention(h, layer, cfg, cos, sin, positions, attend,
                              cache)
-    x = x + reduce(attn @ layer["wo"])
-    h = rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
-    ffn, aux = _ffn(h, layer, cfg, load)
-    return x + reduce(ffn), aux, cache
+    with jax.named_scope("blk_out"):
+        x = x + reduce(attn @ layer["wo"])
+    with jax.named_scope("blk_ffn"):
+        h = rmsnorm(x, layer["mlp_norm"], cfg.norm_eps)
+        ffn, aux = _ffn(h, layer, cfg, load)
+        return x + reduce(ffn), aux, cache
 
 
 def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
@@ -712,27 +715,33 @@ def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
     eps = cfg.norm_eps
     if cfg.kv_lora_rank:
         m = cfg.latent
-        q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"], eps) @ layer["wq_b"]
-             ).reshape(B, T, m.heads, m.nope + m.rope)
-        q = jnp.concatenate(
-            [q[..., :m.nope],
-             apply_rope(q[..., m.nope:], cos, sin, positions)], axis=-1)
-        ckv = h @ layer["wkv_a"]
-        k_r = apply_rope(ckv[..., None, m.rank:], cos, sin, positions)
-        latent = m.entry(rmsnorm(ckv[..., :m.rank], layer["kv_norm"], eps),
-                         k_r[..., 0, :])
-        attn, cache = attend(cache, q, latent, layer["wkv_b"])
+        with jax.named_scope("blk_qkv"):
+            q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"], eps)
+                 @ layer["wq_b"]).reshape(B, T, m.heads, m.nope + m.rope)
+            q = jnp.concatenate(
+                [q[..., :m.nope],
+                 apply_rope(q[..., m.nope:], cos, sin, positions)], axis=-1)
+            ckv = h @ layer["wkv_a"]
+            k_r = apply_rope(ckv[..., None, m.rank:], cos, sin, positions)
+            latent = m.entry(
+                rmsnorm(ckv[..., :m.rank], layer["kv_norm"], eps),
+                k_r[..., 0, :])
+        with jax.named_scope("blk_attn"):
+            attn, cache = attend(cache, q, latent, layer["wkv_b"])
     else:
-        q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-        if cfg.attn_rope:
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-        attn, cache = attend(cache, q, k, v)
-    attn = attn.reshape(B, T, cfg.o_dim)
-    if cfg.use_gqa_gate:  # one gate an output element, before ``wo``
-        attn = attn * jax.nn.sigmoid(h @ layer["wg"])
+        with jax.named_scope("blk_qkv"):
+            q = (h @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
+            k = (h @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+            if cfg.attn_rope:
+                q = apply_rope(q, cos, sin, positions)
+                k = apply_rope(k, cos, sin, positions)
+        with jax.named_scope("blk_attn"):
+            attn, cache = attend(cache, q, k, v)
+    with jax.named_scope("blk_out"):
+        attn = attn.reshape(B, T, cfg.o_dim)
+        if cfg.use_gqa_gate:  # one gate an output element, before ``wo``
+            attn = attn * jax.nn.sigmoid(h @ layer["wg"])
     return attn, cache
 
 
@@ -773,7 +782,8 @@ def run_pattern(params, cfg: Config, carry, mixers: dict):
 
     def layer_at(kind, i):
         sliced, whole = groups[kind]
-        return moe.at_layer(jax.tree.map(lambda a: a[i], sliced), whole, i)
+        with jax.named_scope("blk_loop"):  # a layer's leaves cut from the stack
+            return moe.at_layer(jax.tree.map(lambda a: a[i], sliced), whole, i)
 
     at = dict.fromkeys(HYBRID_GROUPS, 0)
     for unit, repeat in pattern_runs(cfg.pattern):
@@ -786,8 +796,10 @@ def run_pattern(params, cfg: Config, carry, mixers: dict):
         if repeat == 1:
             carry = once(carry, 0)
         else:
-            carry, _ = lax.scan(lambda c, j, once=once: (once(c, j), None),
-                                carry, jnp.arange(repeat))
+            with jax.named_scope("blk_loop"):
+                carry, _ = lax.scan(
+                    lambda c, j, once=once: (once(c, j), None),
+                    carry, jnp.arange(repeat))
         for kind in unit:
             at[kind] += repeat
     return carry
@@ -797,18 +809,21 @@ def _expert_mixer(x, layer, cfg: Config, load: bool = False):
     """A hybrid's expert layer: (x + experts(norm(x)), aux)."""
     from oim_tpu.models import moe
 
-    h = rmsnorm(x, layer["norm"], cfg.norm_eps)
-    out, aux = moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
-                         with_load=load)
-    return x + out, aux
+    with jax.named_scope("blk_ffn"):
+        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+        out, aux = moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
+                             with_load=load)
+        return x + out, aux
 
 
 def _attn_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache):
     """A hybrid's attention layer: (x + attention(norm(x)), cache)."""
-    h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+    with jax.named_scope("blk_qkv"):
+        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
     attn, cache = _attention(h, layer, cfg, cos, sin, positions, attend,
                              cache)
-    return x + attn @ layer["wo"], cache
+    with jax.named_scope("blk_out"):
+        return x + attn @ layer["wo"], cache
 
 
 def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
@@ -875,8 +890,9 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
     if attn_fn is None:
         attn_fn = default_attention
     T = tokens.shape[1]
-    cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("tok_embed"):
+        cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
+        x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.pattern:
         x, aux = _hybrid_hidden(params, x, cfg, cos, sin, attn_fn)
         return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
@@ -891,7 +907,8 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
             body, prevent_cse=False, policy=_remat_policy(cfg))
     aux = jnp.zeros((2,), jnp.float32)
     for group in layer_groups(params):
-        x, group_aux = lax.scan(body, x, group)
+        with jax.named_scope("blk_loop"):
+            x, group_aux = lax.scan(body, x, group)
         aux = aux + jnp.sum(group_aux, axis=0)
     return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
 

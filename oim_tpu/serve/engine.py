@@ -262,21 +262,22 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
 
     def step(params, cache, tokens, pos, keys, temps, tables):
         logits, cache, *load = _decode(params, tokens, cache, tables, pos)
-        split = jax.vmap(jax.random.split)(keys)  # [B, 2, key]
-        carry, subs = split[:, 0], split[:, 1]
-        # Sampling matches generate() bit-for-bit per row: each slot
-        # samples its OWN key against a [1, vocab] row — the shapes a
-        # solo batch-1 run feeds categorical — so a sampled request's
-        # tokens don't depend on its batch-mates. Greedy rows compute
-        # the (discarded) sampled branch against temperature 1.
-        safe = jnp.where(temps > 0, temps, 1.0)
+        with jax.named_scope("tok_head"):  # sampling: the model's head scope
+            split = jax.vmap(jax.random.split)(keys)  # [B, 2, key]
+            carry, subs = split[:, 0], split[:, 1]
+            # Sampling matches generate() bit-for-bit per row: each slot
+            # samples its OWN key against a [1, vocab] row — the shapes a
+            # solo batch-1 run feeds categorical — so a sampled request's
+            # tokens don't depend on its batch-mates. Greedy rows compute
+            # the (discarded) sampled branch against temperature 1.
+            safe = jnp.where(temps > 0, temps, 1.0)
 
-        def samp(key, row, t):
-            return jax.random.categorical(key, (row / t)[None, :])[0]
+            def samp(key, row, t):
+                return jax.random.categorical(key, (row / t)[None, :])[0]
 
-        sampled = jax.vmap(samp)(subs, logits, safe)
-        greedy = jnp.argmax(logits, axis=-1)
-        tok = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+            sampled = jax.vmap(samp)(subs, logits, safe)
+            greedy = jnp.argmax(logits, axis=-1)
+            tok = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
         # The step returns its OWN next operands (tok / pos+1 / key
         # chain), so steady-state decode re-dispatches device arrays
         # instead of re-uploading host mirrors (see _decode_once).
@@ -299,11 +300,12 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
         # as ever.
         last, cache, *rungs = _prefill_fwd(
             params, tokens, n_tokens, cache, table, start, *slot)
-        carry, sub = jax.random.split(key)
-        safe = jnp.where(temp > 0, temp, 1.0)
-        sampled = jax.random.categorical(sub, (last / safe)[None, :])[0]
-        tok = jnp.where(
-            temp > 0, sampled, jnp.argmax(last)).astype(jnp.int32)
+        with jax.named_scope("tok_head"):
+            carry, sub = jax.random.split(key)
+            safe = jnp.where(temp > 0, temp, 1.0)
+            sampled = jax.random.categorical(sub, (last / safe)[None, :])[0]
+            tok = jnp.where(
+                temp > 0, sampled, jnp.argmax(last)).astype(jnp.int32)
         return (tok, cache, carry, *rungs)
 
     return (jax.jit(step, donate_argnums=(1,)),
