@@ -626,11 +626,13 @@ SERVE_EXPERT_ROWS = DEFAULT.counter(
     labelnames=("dispatch",))
 SERVE_EXPERT_CALLS = DEFAULT.counter(
     "oim_serve_expert_calls_total",
-    "expert-layer calls of a held share's prefill programs by the rung "
+    "expert-layer calls of the prefill programs that have a ladder by the rung "
     "their routed products ran on (models/moe.py capacity_ladder: first "
     "and second are batched products at a capacity an expert, whole is "
-    "the grouped product over every assignment row), tallied on the "
-    "device and fetched with each prompt's first token",
+    "the rung with the grouped product: over every assignment row of a "
+    "held share, over the rows past the capacity where every expert is "
+    "held), tallied on the device and fetched with each prompt's first "
+    "token",
     labelnames=("rung",))
 SERVE_DECODE_ROUNDS = DEFAULT.counter(
     "oim_serve_decode_rounds_total",

@@ -117,7 +117,20 @@ def shard_config(cfg: Config, n: int) -> Config:
 # rows an expert (28 % at 1024 tokens), so it wins only where padded does
 # four times its work at full speed: from 640 tokens, the first size
 # measured at which it did. A faster grouped product (a row tile,
-# ``megablox.gmm``: ROADMAP S8 (a)) moves this down.
+# ``megablox.gmm``: ROADMAP S8 (a)) moves this down. Since PR 41 the dropless
+# form of a whole set runs batched products at a capacity of N / 2 rows an
+# expert here and only the rows past it through the grouped product
+# (``moe.capacity_ladder``), and reads (``moe.py``'s tables, two layers
+# scanned, the same chip; no expert over the capacity / the fullest at 2.5
+# times the uniform rows, as the batch cell's seeded router fills it):
+#
+#   tokens     256    512           640    1024           2048 (no capacity)
+#   dropless   4.33   4.95 / 6.70   6.79   8.93 / 10.82   18.84
+#
+# which is under the padded row from 256 tokens on (8.89 -> 4.95-6.70 at 512,
+# the bucket that is ``itl_p90_ms.batch``): the crossing would move to 256 or
+# below. It stays at 640 in PR 41, so that PR's before and after differ by
+# one thing; the next issue moves it (ROADMAP S2 (c)).
 DROPLESS_FROM_TOKENS = 640
 
 
@@ -397,9 +410,10 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     through ``tables`` [B, n_blocks]. Returns (hidden [B, T, D] after the
     final norm, updated pool, load): ``load`` f32 is the mean over the
     expert layers of [experts that got a row, rows of the fullest expert
-    over the mean] (zeros without a dropless expert layer) and, of a held
-    share of the experts, how many expert layers' routed products ran on
-    each rung (``moe.RUNG_NAMES``, ``moe.capacity_ladder``).
+    over the mean] (zeros without a dropless expert layer) and, where the
+    call's routed products have a ladder (``moe.load_width``), how many
+    expert layers' products ran on each rung (``moe.RUNG_NAMES``,
+    ``moe.capacity_ladder``).
 
     The pool is part of the scan's CARRY, with the layer index beside it:
     each layer scatters its rows into pool[l] and reads pool[l] where it
@@ -538,7 +552,8 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
 
     return run_pattern(
         params, cfg,
-        (x, pool, jnp.zeros((moe.load_width(cfg.moe) - 2,), jnp.float32)),
+        (x, pool, jnp.zeros(
+            (moe.load_width(cfg.moe, x.shape[0] * x.shape[1]) - 2,), jnp.float32)),
         {"E": experts, "*": attention,
          **{kind: recurrent(kind) for kind in cfg.recurrent}})
 
@@ -553,9 +568,10 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     occupying logical positions [start, start + n_tokens).
 
     Returns (last real token's logits [vocab] f32, updated pool) and,
-    ``with_rungs`` (a held share of the experts), how many expert layers'
-    routed products ran on each rung ([len(moe.RUNG_NAMES)] int32, see
-    ``_forward_paged``). This is
+    ``with_rungs`` in a program whose routed products have a ladder (a held
+    share's, a whole set's from ``moe.WHOLE_FROM_ROWS`` rows an expert), how
+    many expert layers' products ran on each rung ([len(moe.RUNG_NAMES)]
+    int32, see ``_forward_paged``). This is
     BOTH prefill paths in one program: the full path is start=0 with the
     whole prompt as ``tokens``; the prefix-cache hit passes only the
     UNCACHED TAIL with ``start`` = the cached depth as a traced scalar —
@@ -599,7 +615,7 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     with jax.named_scope("tok_head"):
         last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
         logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
-    if with_rungs:
+    if with_rungs and load.shape[0] > 2:
         return logits, pool, load[2:].astype(jnp.int32)
     return logits, pool
 

@@ -672,7 +672,8 @@ def _ffn(h, layer, cfg: Config, load: bool = False):
         return moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
                          with_load=load)
     gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-    width = (moe.load_width(cfg.moe) if cfg.n_experts else 4) if load else 2
+    width = (moe.load_width(cfg.moe, h.shape[0] * h.shape[1])
+             if cfg.n_experts else 4) if load else 2
     return gated @ layer["w_down"], jnp.zeros((width,), jnp.float32)
 
 

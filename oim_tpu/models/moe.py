@@ -13,6 +13,7 @@ expert FFNs in the model dtype.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -337,30 +338,109 @@ CAPACITY_MULTIPLE = 5  # of the rows uniform routing sends an expert
 ROW_TILE = 128
 RUNG_NAMES = ("first", "second", "whole")
 
+# What a WHOLE SET's routed products (``held == ()``: every expert's weights
+# are here) are sized to: ONE capacity, twice the rows uniform routing sends
+# an expert, and behind it not the grouped product over every row but the
+# same capacity with the rows PAST it through the grouped product
+# (``_routed_products.spilled``). On a v5e, bf16, two stacked layers, ms a
+# layer, router to weighed sum (scripts/expert_dispatch_crossing.py --sweep
+# whole; PERF.md section 6, PR 41):
+#
+#   joyai-llm-flash (E 256, k 8, D 2048, F 768: a layer's experts 2.42 GB =
+#   2.95 ms at the HBM peak; uniform routing sends k x N / E rows an expert)
+#   tokens N         32     64     128    256    512    1024   2048
+#   rows an expert   1      2      4      8      16     32     64
+#   grouped          4.33   7.91   9.15   9.41   9.70   10.29  11.33
+#   capacity 128     3.82*  4.16*  4.84   5.04   5.22   5.60   6.02
+#   capacity 256                          6.67   6.93   7.30   7.53
+#   capacity 512                                 11.58  11.99  11.85
+#   (* a capacity of N.) The three batched products alone: 3.52 / 3.93 / 5.02
+#   / 8.50 / 15.48 ms at C = 64 / 128 / 256 / 512 / 1024 (75-84 % of the
+#   bytes' roofline to 128 rows, the MXU beyond). The three grouped products
+#   alone cost what the groups that HAVE rows cost, about 9-12 us a group a
+#   product whatever its rows: 4.11 ms at 165 groups (32 distinct tokens),
+#   8.62 at 249 (128 tokens), 10.21 at 256 (2048 tokens); the longctx cell's
+#   decode step touches 51-63 (its idle slots' rows are one row) and pays 1.5
+#   ms a layer where a batched set would pay 3.8: hence no capacity under 4
+#   uniform rows an expert (249 of 256 experts touched at 128 tokens).
+#
+#   mixtral-8x7b (E 8, k 2, D 4096, F 14336: 2.82 GB = 3.44 ms; N / 4 rows)
+#   tokens N         256    512    640    1024   2048
+#   grouped          9.45   10.69  9.40   13.17  18.84
+#   capacity N / 2   4.33   4.95   6.79+  8.93   17.08   (+ 384 = 3 row tiles)
+#   capacity N       4.76   8.71   10.58  16.31  33.61
+#   padded, room N   4.75   8.89   10.67  16.87  -
+#   The batched products alone run at 94 % of the MXU from 512 rows an expert,
+#   the grouped ones at 29 % at 1024 tokens and 42 % at 2048.
+#
+# TABLE-SKEW. The seeded models route far from uniformly (--sweep skew: the
+# fullest expert's rows over the uniform rows, per expert-layer call of a
+# run of the cell): joyai-llm-flash.longctx's 2048-token slices 2.8 / 4.6 /
+# 7.6 / 13.8 times at p5 / p50 / p90 / p99 of 748 calls (none within 2
+# times, 35 % within 4, 92 % within 8); mixtral-8x7b.batch's 1024 bucket
+# 1.6 / 2.2 / 3.0 / 3.3 times of 296 (35 % within 2, 90 % within 3). A ladder
+# of capacities with the grouped product over EVERY row behind it (a held
+# share's form) would need 512 rows an expert to hold 92 % of longctx's
+# slices, which costs what the grouped product costs; at 128 / 256 it left
+# 64 % of the calls on the grouped product and a slice gap at 77 ms for the
+# parent's 82. With the rows past the capacity alone through the grouped
+# product, which then has few groups with rows, under tokens that share a
+# direction (--lean: fullest expert 22 times the uniform rows of joyai, 2.4
+# times of mixtral; the grouped product over every row / this form):
+#
+#   joyai    128 tokens 5.68 / 4.83   512: 7.60 / 6.04   2048: 10.15 / 9.19
+#   mixtral  512 tokens 10.67 / 6.70  1024: 13.30 / 10.82  2048: 18.86 / 19.64
+#
+# and at a fullest expert of 31 times (joyai; a few experts take nearly every
+# row, the grouped product has few groups and the capacity still reads every
+# expert) 7.37 / 9.29 at 2048 tokens: past the cells' own skew (their p100 is
+# 15.5 and 3.6) the form loses and nothing chooses it away; the counters say
+# how often the rows spill. Mixtral's 2048 bucket loses under its own skew:
+# past 512 rows an expert the grouped product's row tiles are full, hence no
+# capacity over ``WHOLE_UP_TO_ROWS`` uniform rows (a capacity of 512).
+WHOLE_MULTIPLE = 2      # of the rows uniform routing sends an expert
+WHOLE_FROM_ROWS = 4     # uniform rows an expert from which there is a capacity
+WHOLE_UP_TO_ROWS = 256  # and up to which: over it the grouped product alone
+
 
 def capacity_ladder(n_tokens: int, cfg: MoEConfig) -> tuple:
-    """The static capacities (rows an expert) a held share's products are
-    compiled for, of which a call takes the smallest that holds its fullest
-    expert: ``CAPACITY_MULTIPLE`` times the rows uniform routing sends an
-    expert (k x N / E) in whole row tiles, not under ``MIN_CAPACITY`` (fewer
-    rows cost the same), and twice that; neither over N (no expert gets a
-    token twice: a capacity of N always holds). Behind a last capacity
-    under N stands the grouped product over every assignment row, so any
-    routing whatever is computed in full. Every expert held: nothing to
-    cut, the grouped product alone."""
-    if not cfg.held:
-        return ()
+    """The static capacities (rows an expert) the routed products of a call
+    of ``n_tokens`` are compiled for, of which a call takes the smallest
+    that holds its fullest expert: a multiple of the rows uniform routing
+    sends an expert (k x N / E) in whole row tiles, never over N (no expert
+    gets a token twice: a capacity of N always holds). Behind a last
+    capacity under N stands a grouped product, so any routing whatever is
+    computed in full. () is the grouped product over every row alone.
+
+    - A held share: ``CAPACITY_MULTIPLE`` times, not under ``MIN_CAPACITY``
+      (fewer rows cost the same), and twice that; behind them the grouped
+      product over every assignment row.
+    - A whole set, from ``WHOLE_FROM_ROWS`` to ``WHOLE_UP_TO_ROWS`` uniform
+      rows an expert: one capacity, ``WHOLE_MULTIPLE`` times; behind it the
+      same capacity with the rows past it through the grouped product
+      (``_routed_products``)."""
     expected = cfg.top_k * n_tokens / cfg.n_experts
-    first = -(-int(CAPACITY_MULTIPLE * expected) // ROW_TILE) * ROW_TILE
-    first = min(max(first, MIN_CAPACITY), n_tokens)
+    if cfg.held:
+        multiple, least = CAPACITY_MULTIPLE, MIN_CAPACITY
+    elif not WHOLE_FROM_ROWS <= expected <= WHOLE_UP_TO_ROWS:
+        return ()
+    else:
+        multiple, least = WHOLE_MULTIPLE, ROW_TILE
+    first = -(-int(multiple * expected) // ROW_TILE) * ROW_TILE
+    first = min(max(first, least), n_tokens)
+    if not cfg.held:
+        return (first,)
     return (first, min(2 * first, n_tokens))[:1 + (first < n_tokens)]
 
 
-def load_width(cfg: MoEConfig) -> int:
-    """Entries of ``apply``'s ``with_load`` vector: [aux, dropped, experts
-    that got a row, fullest over mean] and, for a held share, the call's
-    rung (one of ``RUNG_NAMES`` set to 1; zeros in the dense form)."""
-    return 4 + (len(RUNG_NAMES) if cfg.held else 0)
+def load_width(cfg: MoEConfig, n_tokens: int) -> int:
+    """Entries of ``apply``'s ``with_load`` vector in a call of ``n_tokens``:
+    [aux, dropped, experts that got a row, fullest over mean] and, where the
+    call's products have a ladder (and in every call of a held share), the
+    call's rung (one of ``RUNG_NAMES`` set to 1; zeros in the dense form)."""
+    rungs = cfg.dispatch == "ragged" and (
+        cfg.held or capacity_ladder(n_tokens, cfg))
+    return 4 + (len(RUNG_NAMES) if rungs else 0)
 
 
 def _layer_leaves(params):
@@ -385,48 +465,74 @@ def _batched_ffn(leaves, x):
         return jnp.einsum("ecf,efd->ecd", hidden, leaves["w_down"])
 
 
-def _held_products(params, tokens, flat, order, counts, cfg: MoEConfig):
-    """The routed products of a held share, sized to the rows held here:
-    (y [k x N, D] in assignment order, the rung taken). Sorted, expert e's
-    assignments are the ``counts[e]`` rows from ``starts[e]`` (bin e, held
-    elsewhere, sorts last). A bounded rung gathers each held expert's rows
-    into [e, C, D], C the smallest capacity of ``capacity_ladder`` that
-    holds the fullest expert (chosen on the device), and runs batched
-    products over this layer's leaves; rows past an expert's count repeat
-    token 0 and no assignment reads them. The last rung, unless a capacity
-    of N stands before it, is the grouped product over every assignment
-    row. An assignment of an absent rank reads some row of the result and
-    the caller masks it (``mine``)."""
+def _routed_products(params, tokens, flat, order, counts, cfg: MoEConfig):
+    """The routed products of a call, sized to the rows each expert got: (y
+    [k x N, D] in assignment order, the rung taken). Sorted, expert e's
+    assignments are the ``counts[e]`` rows from ``starts[e]`` (a held
+    share's bin e, held elsewhere, sorts last). A bounded rung gathers each
+    expert's rows into [e, C, D], C the smallest capacity of
+    ``capacity_ladder`` that holds the fullest expert (chosen on the
+    device), and runs batched products over this layer's leaves; rows past
+    an expert's count repeat token 0 and no assignment reads them. The last
+    rung, unless a capacity of N stands before it, holds the grouped
+    product: over every assignment row for a held share (PR 35: 0.4-1 % of
+    its calls), over the rows past the last capacity for a whole set, whose
+    router overfills a few experts in most calls (``spilled``). Without a
+    ladder the grouped product over every row is the call. An assignment of
+    an absent rank reads some row of the result and the caller masks it
+    (``mine``)."""
     e, k, rows = cfg.n_held, cfg.top_k, order.shape[0]
     d = tokens.shape[-1]
+
+    def whole(back=None):
+        with jax.named_scope("moe_route"):
+            x = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
+        y = grouped_ffn(params, x, counts)
+        # Alone, the un-sort is traced behind the products, where it always
+        # stood: a decode step's compiled text is held to its hash (tier-1).
+        return jnp.take(y, jnp.argsort(order) if back is None else back, axis=0)
+
+    ladder = capacity_ladder(rows // k, cfg)
+    if not ladder:
+        return whole(), len(RUNG_NAMES) - 1
     back = jnp.argsort(order)  # where each assignment sorted to
     starts = jnp.cumsum(counts) - counts
     held = jnp.minimum(flat, e - 1)
     rank = back - starts[held]  # an assignment's place among its expert's
 
-    def bounded(c):
-        def run():
-            with jax.named_scope("moe_route"):
-                slot = jnp.arange(c)
-                at = jnp.minimum(starts[:, None] + slot, rows - 1)
-                source = jnp.where(slot < counts[:, None], order[at] // k, 0)
-                x = jnp.take(tokens, source.reshape(-1), axis=0)
-            y = _batched_ffn(_layer_leaves(params), x.reshape(e, c, d))
-            return jnp.take(y.reshape(e * c, d),
-                            held * c + jnp.clip(rank, 0, c - 1), axis=0)
-        return run
-
-    def whole():
+    def batched(c):
+        """Each expert's first ``c`` rows through the batched products, and
+        back to assignment order: y [k x N, D], right where ``rank < c``."""
         with jax.named_scope("moe_route"):
-            x = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
-        return jnp.take(grouped_ffn(params, x, counts), back, axis=0)
+            slot = jnp.arange(c)
+            at = jnp.minimum(starts[:, None] + slot, rows - 1)
+            source = jnp.where(slot < counts[:, None], order[at] // k, 0)
+            x = jnp.take(tokens, source.reshape(-1), axis=0)
+        y = _batched_ffn(_layer_leaves(params), x.reshape(e, c, d))
+        return jnp.take(y.reshape(e * c, d),
+                        held * c + jnp.clip(rank, 0, c - 1), axis=0)
 
-    ladder = capacity_ladder(rows // k, cfg)
-    runs = [bounded(c) for c in ladder]
-    if not ladder or ladder[-1] < rows // k:  # a capacity of N always holds
-        runs.append(whole)
+    def spilled(c):
+        """``batched(c)``, and the rows past ``c`` of the experts that got
+        more through the grouped product: sorted, they move to the front in
+        expert order, expert e owning ``counts[e] - c`` of them. The grouped
+        product pays for the groups that have rows (few: the experts over
+        the capacity), whatever rows it is handed."""
+        with jax.named_scope("moe_route"):
+            past = jnp.arange(rows) - starts[held[order]] >= c  # sorted order
+            first = jnp.argsort(~past, stable=True)
+            x = jnp.take(tokens, order[first] // k, axis=0)  # [N * k, D]
+        y = grouped_ffn(params, x, jnp.maximum(counts - c, 0))
+        place = jnp.cumsum(past) - 1  # of a sorted row among those past c
+        return jnp.where((rank < c)[:, None], batched(c),
+                         jnp.take(y, place[back], axis=0))
+
+    runs = [functools.partial(batched, c) for c in ladder]
+    if ladder[-1] < rows // k:  # a capacity of N always holds
+        runs.append(functools.partial(whole, back) if cfg.held
+                    else functools.partial(spilled, ladder[-1]))
     if len(runs) == 1:
-        return runs[0](), jnp.int32(0 if ladder else len(RUNG_NAMES) - 1)
+        return runs[0](), jnp.int32(0)
     rung = jnp.sum(jnp.max(counts) > jnp.asarray(ladder, jnp.int32))
     y = jax.lax.switch(jnp.minimum(rung, len(runs) - 1), runs)
     return y, jnp.where(rung == len(ladder), len(RUNG_NAMES) - 1, rung)
@@ -436,10 +542,12 @@ def _dropless(params, x, cfg: MoEConfig):
     """x [B, T, D] -> (out, load f32): every token through all k of
     its experts, or through those of them that are held here (``cfg.held``:
     the others' assignments sort behind the last group and take part in no
-    product; few tokens run ``_dense_held``, more ``_held_products``).
-    ``load`` = [experts that got a row, rows of the fullest expert over the
-    mean], over the experts held: what the serving engine counts; a held
-    share's is followed by its rung (``load_width``)."""
+    product; few tokens run ``_dense_held``). The products are
+    ``_routed_products``: one body for a held share and for a whole set,
+    which is the share whose range is every expert. ``load`` = [experts
+    that got a row, rows of the fullest expert over the mean], over the
+    experts held: what the serving engine counts; it is followed by the
+    call's rung where ``load_width`` says so."""
     b, t, d = x.shape
     n, e, k = b * t, cfg.n_held, cfg.top_k
     if cfg.held and n <= DENSE_UP_TO_TOKENS:
@@ -454,15 +562,9 @@ def _dropless(params, x, cfg: MoEConfig):
             flat = jnp.where(mine, local, e)       # bin e: held elsewhere
         order = jnp.argsort(flat, stable=True)     # sorted by expert
         counts = jnp.zeros((e + bool(cfg.held),), jnp.int32).at[flat].add(1)[:e]
-        if not cfg.held:
-            rows = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
     # Back in assignment order, each token's k rows weighed and summed in
     # float32: a gather, no scatter-add, so the sum's order is fixed.
-    if cfg.held:
-        y, rung = _held_products(params, tokens, flat, order, counts, cfg)
-    else:
-        y = grouped_ffn(params, rows, counts)
-        y = jnp.take(y, jnp.argsort(order), axis=0)
+    y, rung = _routed_products(params, tokens, flat, order, counts, cfg)
     y = y.reshape(n, k, d)
     if cfg.held:  # rows of no group are whatever the product left there
         mine = mine.reshape(n, k)
@@ -474,7 +576,7 @@ def _dropless(params, x, cfg: MoEConfig):
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * e / total])
-    if cfg.held:
+    if load_width(cfg, n) > 4:
         load = jnp.concatenate([load, jax.nn.one_hot(
             rung, len(RUNG_NAMES), dtype=jnp.float32)])
     return out.astype(x.dtype).reshape(b, t, d), load

@@ -201,13 +201,6 @@ def _counts_expert_load(cfg: Config) -> bool:
     return bool(cfg.n_experts) and cfg.moe_dispatch == "ragged"
 
 
-def _counts_expert_rungs(cfg: Config) -> bool:
-    """Whether the prefill programs tally the rung each expert layer's
-    routed products ran on: a held share's do (models/moe.py
-    capacity_ladder)."""
-    return _counts_expert_load(cfg) and bool(cfg.experts_held)
-
-
 @functools.lru_cache(maxsize=64)
 def _target_programs(cfg: Config, page: int, max_seq: int,
                      shard: int = 1):
@@ -258,7 +251,7 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
         def _prefill_fwd(p, t, n, c, tb, st, *slot):
             return gen.prefill_into_pages(
                 p, t, n, c, tb, st, cfg, page, None, *slot,
-                with_rungs=_counts_expert_rungs(cfg))
+                with_rungs=True)
 
     def step(params, cache, tokens, pos, keys, temps, tables):
         logits, cache, *load = _decode(params, tokens, cache, tables, pos)
@@ -294,10 +287,11 @@ def _target_programs(cfg: Config, page: int, max_seq: int,
                 temp, *slot):
         # ``slot``: the row of a hybrid's recurrent state this prompt
         # fills (models/generate.py); no other configuration is handed it.
-        # A held share's program also returns how many of its expert
-        # layers ran on each rung (moe.capacity_ladder), a fourth output the
-        # engine fetches with the prompt's first token; other models' are
-        # as ever.
+        # A program whose routed products have a ladder (a held share's,
+        # a whole set's bucket from moe.WHOLE_FROM_ROWS rows an expert) also
+        # returns how many of its expert layers ran on each rung
+        # (moe.capacity_ladder), a fourth output the engine fetches with the
+        # prompt's first token; other programs are as ever.
         last, cache, *rungs = _prefill_fwd(
             params, tokens, n_tokens, cache, table, start, *slot)
         with jax.named_scope("tok_head"):
@@ -717,7 +711,7 @@ class ServeEngine:
         # token count (oim_serve_expert_rows_total; stats() shows the sums).
         self._expert_rows = {"dropless": 0, "padded": 0}
         self._rows_of = gen.expert_rows
-        # Expert-layer calls of a held share's prefill programs by the rung
+        # Expert-layer calls of the prefill programs with a ladder by the rung
         # their routed products ran on (moe.capacity_ladder): tallied on
         # the device, fetched with each prompt's first token.
         from oim_tpu.models.moe import RUNG_NAMES
@@ -1540,16 +1534,15 @@ class ServeEngine:
         M.SERVE_EXPERT_ROWS.labels(dispatch=dispatch).inc(rows)
 
     def _rung_calls(self) -> dict:
-        """stats()' and the stop line's view of ``_expert_rungs``."""
-        if not _counts_expert_rungs(self.cfg):
-            return {}
+        """stats()' and the stop line's view of ``_expert_rungs`` (of an
+        expert model: its callers ask for no other)."""
         return {f"expert_calls_{name}_rung": int(calls) for name, calls
                 in zip(self._rung_names, self._expert_rungs)}
 
     def _count_expert_rungs(self, tok, key, rungs: list) -> tuple:
         """The prompt's first token and its RNG carry, fetched; with them,
-        in the same wait, a held share's tallies of the prompt's prefill
-        calls (``rungs``: one a call, [] for any other model)."""
+        in the same wait, the rung tallies of the prompt's prefill calls
+        (``rungs``: one a call of a program with a ladder, else [])."""
         tok, key, rungs = self._jax.device_get((tok, key, rungs))
         if rungs:
             calls = np.sum(rungs, axis=0)
@@ -1876,7 +1869,7 @@ class ServeEngine:
         table_dev = jnp.asarray(table_row)
         slot_dev = self._state_row(slot)
         key0 = self._jax.random.PRNGKey(req.seed)
-        rungs: list = []  # a held share's tally of each slice, on the device
+        rungs: list = []  # each slice's tally of rungs, on the device
 
         def dispatch(off: int):
             """One slice on its way: (token, RNG carry, when)."""

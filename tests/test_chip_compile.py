@@ -13,6 +13,7 @@ file (one xdist worker loads the TPU library; nothing at import time).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import math
@@ -568,6 +569,50 @@ def test_latent_prefill_chunk_carries_the_pool(topo, as_tpu, bucket):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+LATENT_LADDERS = {2048: (128,), 1024: (128,), 128: (128,)}
+
+
+@pytest.mark.parametrize("bucket", sorted(LATENT_LADDERS))
+def test_latent_slice_sizes_its_expert_products_to_the_rows(
+        topo, as_tpu, bucket):
+    """PR 41: a slice of joyai-llm-flash.longctx (the chunk, and two last
+    pieces) runs its 256 experts' products batched at a capacity,
+    [256, C, .] a rung of ``moe.capacity_ladder``, over this layer's leaves
+    read where they lie in the stack: no copy, slice or re-layout of an
+    expert leaf is written (0.8 GB, 1 ms a leaf; PR 28 paid 29 ms a step
+    for such a slice), the grouped products (over the rows past the
+    capacity) stand in the last rung only (absent where the capacity is a
+    token's worth), and the rungs' temporaries fit beside the pool."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, _, seq = serving_program(
+        chip, "longctx", f"prefill-{bucket}")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    L, E, D, F, k = (cfg.n_expert_layers, cfg.n_experts, cfg.dim,
+                     cfg.moe_intermediate_size, cfg.moe_top_k)
+    ladder = moe.capacity_ladder(bucket, cfg.moe)
+    assert ladder == LATENT_LADDERS[bucket] and (L, E, D, F) == (
+        4, 256, 2048, 768)
+    assert not materialized(text, [(E, D, F), (E, F, D), (1, E, D, F),
+                                   (1, E, F, D)])
+    for leaf in ((L, E, D, F), (E, D, F), (L, E, F, D), (E, F, D)):
+        assert not moves_of(text, leaf)
+    for capacity in ladder:  # a bounded rung: three batched products
+        for width in (F, D):
+            assert re.search(rf"= bf16\[{E},{capacity},{width}\]\S* "
+                             r"convolution\(", text)
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
+    if ladder[-1] < bucket:
+        assert sorted(calls) == sorted(
+            [(str(k * bucket), str(F))] * 2 + [(str(k * bucket), str(D))])
+        assert last_rung_only(text)
+    else:
+        assert not calls
+    assert text.count(" conditional(") == (len(ladder) > 1 or bool(calls))
+    assert not moves_of(text, pool["kv"].shape)
+    assert mem.temp_size_in_bytes < 1.25 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 # -- which expert dispatch an inference program runs (generate._no_drop) -----
 # PR 31: a capacity-padded expert configuration runs dropless in a call of
 # DROPLESS_FROM_TOKENS tokens or more. Dense and dropless-by-configuration
@@ -586,7 +631,19 @@ PARENT_TEXT = {
     ("batch", "step"): "11283d27517818e9",
     ("batch", "prefill-512"): "e79be2fbb0f12941",
     ("longctx", "step"): "0deb004e1b62525a",
-    ("longctx", "prefill-2048"): "8ffec8fc79cf6655",
+    # PR 41: a whole set's slice takes a capacity ladder from 4 rows an expert
+    # (moe.WHOLE_FROM_ROWS: joyai's 128-token bucket and up, so its 2048
+    # bucket left this table); its decode step and its 64-token bucket (one
+    # and two rows an expert) are the parent's, and so are the held shares'
+    # programs, whose ladder did not move (parent 364d93e).
+    ("longctx", "prefill-64"): "729e256973e5a89c",
+    ("agentbatch", "step"): "7fb8a848123f98d9",
+    ("agentbatch", "prefill-1024"): "118ca7b5018a3e90",
+    ("agentbatch64", "step"): "75d8955b9c14301f",
+    ("agentbatch64", "prefill-1024"): "5c6de209b271dea8",
+    # Mixtral's largest bucket sends an expert 512 rows, past
+    # moe.WHOLE_UP_TO_ROWS: the grouped products alone, the parent's text.
+    ("batch", "prefill-2048"): "3c0f52444fde52b4",
 }
 def test_program_text_drops_what_names_a_checkout():
     text = """HloModule jit_step, is_scheduled=true
@@ -614,37 +671,87 @@ source_file="/root/repo/x.py" source_line=3}
 def test_programs_outside_the_choice_are_the_parents(
         topo, as_tpu, cell, program):
     chip = SingleDeviceSharding(topo.devices[0])
-    compiled, cfg, *_ = serving_program(chip, cell, program)
+    compiled, cfg, *_ = {"agentbatch": hybrid_program,
+                         "agentbatch64": kda_program}.get(
+        cell, functools.partial(serving_program, cell=cell))(
+            chip, program=program)
     text = compiled.as_text()
-    if cfg.moe_dispatch != "ragged":  # Mixtral under the crossing, Mistral
-        assert "ragged-dot" not in text
+    tokens = int(program.partition("-")[2] or 0)  # a step: under any crossing
+    if cfg.moe_dispatch != "ragged" and tokens < gen.DROPLESS_FROM_TOKENS:
+        assert "ragged-dot" not in text  # Mixtral under the crossing, Mistral
     assert text_hash(text) == PARENT_TEXT[cell, program]
+
+
+def last_rung_only(text: str) -> bool:
+    """Whether every grouped product of the program stands in the LAST
+    branch of a conditional (the ladder's fallback), and nowhere else."""
+    last = {m.group(1) for m in re.finditer(
+        r"branch_computations=\{[^}]*?(%[\w.\-]+)\}", text)}
+    inside, ok = None, True
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            inside = line.split(" ", 1)[0]
+        elif re.match(r"\s*(ROOT )?%ragged-dot[\w.\-]* = ", line):
+            ok = ok and inside in last
+    return ok and bool(last)
+
+
+def test_last_rung_only_reads_the_hlo():
+    text = """
+%rung.1 (a: f32[4]) -> f32[4] {
+  ROOT %convolution.1 = f32[4]{0} convolution(%a, %a)
+}
+%rung.2 (a: f32[4]) -> f32[4] {
+  ROOT %ragged-dot-none.1 = bf16[8,4]{1,0} custom-call(%a)
+}
+ENTRY %main (p: f32[4]) -> f32[4] {
+  ROOT %conditional.1 = f32[4]{0} conditional(%i, %p, %p), \
+branch_computations={%rung.1, %rung.2}
+}"""
+    assert last_rung_only(text)
+    assert not last_rung_only(text.replace("{%rung.1, %rung.2}",
+                                           "{%rung.2, %rung.1}"))
+    assert not last_rung_only(text.replace(
+        "ROOT %conditional", "%ragged-dot-none.2 = bf16[8,4]{1,0} "
+        "custom-call(%p)\n  ROOT %conditional"))
 
 
 @pytest.mark.parametrize("bucket", [1024, 2048])
 def test_mixtral_prefill_runs_dropless(topo, as_tpu, bucket):
     """The batch cell's largest prefill bucket (a gap that holds one is its
-    itl_p95_ms) and the configuration's largest: three grouped
-    products a layer over k x N rows, no [E, N, F] or [E, N, D] operand,
-    and the expert leaves [L, E, D, F] go into the products whole: none is
-    copied, none sliced a layer at a time (a slice handed to a custom call
-    is a copy: PR 28's 29 ms a step)."""
+    itl_p95_ms) and the configuration's largest, since PR 41. The 1024
+    bucket: one capacity of twice the rows uniform routing sends an expert
+    (512: three batched products [8, 512, .], half the padded form), and
+    behind it the same with the rows past the capacity through three
+    grouped products a layer (handed k x N rows, of which they compute the
+    groups'). The 2048 bucket sends an expert 512 rows, past
+    ``moe.WHOLE_UP_TO_ROWS``: three grouped products over k x N rows alone,
+    as before. Neither holds an [E, N, F] or [E, N, D] operand, and the
+    expert leaves [L, E, D, F] go into every product where they lie: none
+    is copied, none sliced a layer at a time (a slice handed to a custom
+    call is a copy: PR 28's 29 ms a step)."""
     chip = SingleDeviceSharding(topo.devices[0])
     compiled, cfg, *_ = serving_program(chip, "batch", f"prefill-{bucket}")
     assert bucket >= gen.DROPLESS_FROM_TOKENS and cfg.moe_dispatch == "gather"
     text, mem = compiled.as_text(), compiled.memory_analysis()
     L, E, D, F, k = (cfg.n_layers, cfg.n_experts, cfg.dim, cfg.mlp_dim,
                      cfg.moe_top_k)
+    ladder = moe.capacity_ladder(bucket, gen._no_drop(cfg, bucket).moe)
+    assert ladder == {1024: (512,), 2048: ()}[bucket]
     calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
     assert sorted(calls) == sorted(
         [(str(k * bucket), str(F))] * 2 + [(str(k * bucket), str(D))])
+    assert text.count(" conditional(") == len(ladder)
+    assert last_rung_only(text) == bool(ladder)
     for width in (F, D):
         assert f"[{E},{bucket},{width}]" not in text
+        for capacity in ladder:
+            assert re.search(rf"= bf16\[{E},{capacity},{width}\]\S* "
+                             r"convolution\(", text)
+    assert not materialized(text, [(E, D, F), (E, F, D), (1, E, D, F),
+                                   (1, E, F, D)])
     for leaf in ((L, E, D, F), (E, D, F), (L, E, F, D), (E, F, D)):
         assert not moves_of(text, leaf)
-        shape = ",".join(str(d) for d in leaf)
-        assert not re.search(
-            rf"= bf16\[(1,)?{shape}\]\S* (dynamic-slice|copy|fusion)\(", text)
     if bucket == 1024:  # the parent's padded form held 288 MB here
         assert mem.temp_size_in_bytes < 288 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

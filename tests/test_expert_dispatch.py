@@ -72,8 +72,12 @@ def test_a_roomier_capacity_is_kept_below_the_crossing():
 
 # -- (2) the same prefill under both dispatches ------------------------------
 
+# 8 experts, top-2: Mixtral's ratio, at which a whole set's ladder is one
+# capacity of twice the uniform rows with the grouped products behind it
+# (``moe.capacity_ladder``; at 4 experts twice the uniform rows is a token's
+# worth, which always holds, and no grouped product is compiled).
 EXPERT = dataclasses.replace(
-    llama.tiny(vocab=251, dim=32, n_experts=4), max_seq=4 * X + 64)
+    llama.tiny(vocab=251, dim=32, n_experts=8), max_seq=4 * X + 64)
 
 
 @pytest.fixture(scope="module")
@@ -238,6 +242,34 @@ def test_the_counter_counts_rows_by_dispatch(expert_params, engine):
     assert end[4] - at[4] == end[1] - at[1]
 
 
+def test_the_engine_tallies_rungs_where_a_program_has_a_ladder(
+        expert_params, engine):
+    """A capacity-padded configuration's engine-level dispatch is ``gather``
+    and its decode step reports no expert load, yet its buckets above the
+    crossing run dropless on a ladder: those prefills tally their expert
+    layers' rungs (``oim_serve_expert_calls_total``), the padded ones below
+    return no tally, and decode rounds count on no rung."""
+    layers = EXPERT.n_layers
+
+    def calls():
+        s = engine.stats()
+        return sum(s[f"expert_calls_{name}_rung"] for name in moe.RUNG_NAMES)
+
+    def run(n):
+        prompt = [int(t) for t in
+                  np.random.default_rng(11 * n).integers(0, 251, (n,))]
+        engine.submit(prompt, max_new=4, temperature=0.0,
+                      eos=-1).result(timeout=300)
+
+    assert moe.capacity_ladder(
+        engine._bucket(X + 20), gen._no_drop(EXPERT, X + 20).moe)
+    at = calls()
+    run(X + 20)
+    assert calls() - at == layers
+    run(20)
+    assert calls() - at == layers
+
+
 def test_a_dense_engine_counts_no_expert_rows():
     cfg = llama.tiny(vocab=253)
     eng = ServeEngine(llama.init(jax.random.PRNGKey(0), cfg), cfg,
@@ -268,7 +300,13 @@ def test_the_trainers_expert_step_still_traces_gather():
     assert "scatter" in str(jax.make_jaxpr(trainer.step_fn)(state, batch))
     cfg = trainer.cfg.model_config()
     assert cfg.moe_dispatch == "gather"
-    # The same model's serving prefill over as many tokens does run them.
+    # The same model's serving prefill over as many tokens does run
+    # dropless (here at Mixtral's 8 experts: the grouped products alone,
+    # behind a capacity in the crossing's own bucket).
+    cfg = dataclasses.replace(cfg, n_experts=8)
+    ragged = gen._no_drop(cfg, 2 * X).moe
+    assert moe.capacity_ladder(X, ragged) == (384,)
+    assert moe.capacity_ladder(2 * X, ragged) == ()  # 320 rows an expert
     params = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     pool = jax.eval_shape(lambda: gen.init_page_pool(cfg, 2 * X // PAGE + 1,
                                                      PAGE))

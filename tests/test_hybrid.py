@@ -7,6 +7,7 @@ seeded weights. Self-contained: no cluster, no port."""
 
 import dataclasses
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -295,7 +296,7 @@ def test_a_held_share_runs_dense_at_few_tokens_and_grouped_above(
     # above the crossing a call says which rung it ran on (24 tokens: one
     # capacity, a token's worth, which always holds); the dense form ran on
     # none
-    assert dense_load.shape == grouped_load.shape == (moe.load_width(cfg),)
+    assert dense_load.shape == grouped_load.shape == (moe.load_width(cfg, 24),)
     assert not dense_load[4:].any() and list(grouped_load[4:]) == [1, 0, 0]
 
     def traced(x):
@@ -315,11 +316,20 @@ def test_a_held_share_runs_dense_at_few_tokens_and_grouped_above(
     monkeypatch.setattr(moe, "CAPACITY_MULTIPLE", 1)
     assert moe.capacity_ladder(65, cfg) == (16, 32)
     assert "ragged_dot" in traced(long) and "cond" in traced(long)
-    # every expert held: the grouped form whatever the tokens
+    # every expert held: the grouped form alone while uniform routing
+    # sends an expert fewer than WHOLE_FROM_ROWS rows (a decode step), a
+    # ladder of its own above (top-4 of 16: 8 tokens send 2 rows, 24 send 6)
     whole_cfg = llama.tiny_hybrid().moe
-    assert "ragged_dot" in str(jax.make_jaxpr(lambda x: moe.apply(
-        moe.init(jax.random.PRNGKey(0), 64, 48, whole_cfg, jnp.float32), x,
-        whole_cfg))(x))
+    monkeypatch.undo()
+
+    def whole_traced(x):
+        return str(jax.make_jaxpr(lambda x: moe.apply(
+            moe.init(jax.random.PRNGKey(0), 64, 48, whole_cfg, jnp.float32),
+            x, whole_cfg))(x))
+    assert "ragged_dot" in whole_traced(x[:, :4])
+    assert moe.capacity_ladder(8, whole_cfg) == ()
+    assert moe.capacity_ladder(24, whole_cfg) == (24,)
+    assert "ragged_dot" not in whole_traced(x) and "argsort" in whole_traced(x)
 
 
 def held_layer(rank, stacked):
@@ -470,18 +480,58 @@ def test_rows_of_no_assignment_never_reach_the_sum(
     np.testing.assert_array_equal(whole, clean_whole)
 
 
-def test_every_expert_held_has_nothing_to_cut():
-    """``held == ()``: no capacity, the grouped product alone; and the
-    ladder a slice of the published model's share is compiled for."""
-    cfg = llama.tiny_hybrid().moe
-    assert moe.capacity_ladder(1024, cfg) == () and moe.load_width(cfg) == 4
+WHOLE_LADDERS = {
+    # joyai-llm-flash, top-8 of 256: a decode or verify step (one row an
+    # expert at 32 tokens, two at 64) keeps the grouped product alone; a
+    # slice takes one capacity of twice the uniform rows in whole row tiles
+    "joyai-decode-32": ("joyai", 32, ()), "joyai-64": ("joyai", 64, ()),
+    "joyai-128": ("joyai", 128, (128,)), "joyai-512": ("joyai", 512, (128,)),
+    "joyai-2048": ("joyai", 2048, (128,)), "joyai-4096": ("joyai", 4096, (256,)),
+    # mixtral-8x7b, top-2 of 8, the programs generate._no_drop runs dropless
+    "mixtral-640": ("mixtral", 640, (384,)),
+    "mixtral-1024": ("mixtral", 1024, (512,)),
+    # past 256 uniform rows an expert the grouped product's row tiles are
+    # full: it runs alone, as the parent ran it
+    "mixtral-2048": ("mixtral", 2048, ()), "joyai-16384": ("joyai", 16384, ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WHOLE_LADDERS))
+def test_a_whole_sets_ladder_follows_the_calls_shapes(case):
+    """``held == ()``: the ladder of a model that holds every expert, from
+    E, k and N alone (the table beside ``moe.WHOLE_MULTIPLE``), and the
+    load vector carries a rung exactly where there is a ladder."""
+    from benchmarks import common
+
+    name, tokens, ladder = WHOLE_LADDERS[case]
+    cfg = {"joyai": llama.JOYAI_LLM_FLASH,
+           "mixtral": dataclasses.replace(common.program_config(
+               common.model_dict(common.load_json(os.path.join(
+                   common.ROOT, "benchmarks", "configs",
+                   "mixtral-8x7b.json")), "serve")),
+               moe_dispatch="ragged")}[name].moe
+    assert not cfg.held and moe.capacity_ladder(tokens, cfg) == ladder
+    assert moe.load_width(cfg, tokens) == 4 + (
+        len(moe.RUNG_NAMES) if ladder else 0)
+    # the configuration's own dispatch below the crossing carries none
+    assert moe.load_width(dataclasses.replace(cfg, dispatch="gather"),
+                          tokens) == 4
+
+
+def test_a_held_shares_ladder_is_what_it_was():
+    """The ladder a slice of the published model's share is compiled for:
+    its constants were set from ITS skew (PR 35), and a whole set's do not
+    move them."""
     share = dataclasses.replace(llama.NEMOTRON_3_NANO_30B, expert_rank="0/8").moe
     assert moe.capacity_ladder(1024, share) == LADDER_1024
     # a short last piece: one capacity, a token's worth, which always holds
     assert moe.capacity_ladder(128, share) == (128,)
     assert moe.capacity_ladder(512, share) == (256, 512)
     assert moe.capacity_ladder(2048, share) == (512, 1024)
-    assert moe.load_width(share) == 4 + len(moe.RUNG_NAMES)
+    for tokens in (32, 64, 1024):  # the dense form's zeros included
+        assert moe.load_width(share, tokens) == 4 + len(moe.RUNG_NAMES)
+    solar = dataclasses.replace(llama.SOLAR_OPEN2_250B, expert_rank="0/8").moe
+    assert moe.capacity_ladder(1024, solar) == (256, 512)
 
 
 LADDER_1024 = (256, 512)
@@ -768,16 +818,31 @@ def test_the_engine_counts_expert_calls_by_rung(
         _target_programs.cache_clear()
 
 
-def test_a_model_that_holds_every_expert_counts_no_rungs():
+def test_a_model_that_holds_every_expert_counts_its_prefills_rungs():
+    """The tiny latent model (top-4 of 16, every expert held): a 32-token
+    bucket sends an expert 8 rows and compiles a ladder, so its prefill
+    tallies its two expert layers' calls; an 8-token bucket (two rows an
+    expert) runs the grouped product alone and returns no tally; decode
+    rounds count on no rung."""
     cfg = llama.tiny_latent()
+    layers = cfg.n_expert_layers
+    assert moe.capacity_ladder(32, cfg.moe) == (32,) and layers == 2
     engine = ServeEngine(llama.init(jax.random.PRNGKey(0), cfg), cfg,
                          max_batch=2, max_seq=64)
     try:
-        engine.submit([1, 2, 3], max_new=2, temperature=0.0,
-                      eos=-1).result(timeout=300)
+        assert engine._bucket(3) == 8 and engine._bucket(20) == 32
+        assert moe.capacity_ladder(8, cfg.moe) == ()
         stats = engine.stats()
-        assert "expert_rows_dropless" in stats
-        assert not any(k.startswith("expert_calls_") for k in stats)
+        assert {stats[f"expert_calls_{name}_rung"]
+                for name in moe.RUNG_NAMES} == {0}
+        for n, calls in ((3, 0), (20, layers)):
+            engine.submit(list(range(1, n + 1)), max_new=4, temperature=0.0,
+                          eos=-1).result(timeout=300)
+            stats = engine.stats()
+            assert "expert_rows_dropless" in stats
+            # a capacity of a token's worth always holds: the first rung
+            assert [stats[f"expert_calls_{name}_rung"]
+                    for name in moe.RUNG_NAMES] == [calls, 0, 0]
     finally:
         engine.stop()
 

@@ -302,10 +302,21 @@ def test_the_dropless_dispatch_is_the_per_token_sum_over_chosen_experts():
     get none."""
     cfg, params, x = skewed_experts(bias_scale=0.2)
     out, load = moe.apply(params, x, cfg, with_load=True)
-    tokens = np.asarray(x.reshape(-1, 64), np.float64)
-    chosen, weight = (np.asarray(a) for a in moe.route(params, x.reshape(-1, 64), cfg))
-    counts = np.bincount(chosen.reshape(-1), minlength=16)
+    want, counts = per_token_sum(params, x, cfg)
     assert counts[3] >= 70 and (counts == 0).sum() >= 4  # of 80 rows
+    # float32 against float64 sums of ~100 terms of order 1: 1e-4
+    assert np.abs(np.asarray(out).reshape(-1, 64) - want).max() < 1e-4
+    assert load[2] == (counts > 0).sum()
+    assert load[3] == pytest.approx(counts.max() * 16 / (80 * 4))
+
+
+def per_token_sum(params, x, cfg):
+    """The definition in float64: each token through its own k experts,
+    weighed, and through the shared expert."""
+    d = x.shape[-1]
+    tokens = np.asarray(x.reshape(-1, d), np.float64)
+    chosen, weight = (np.asarray(a) for a in moe.route(
+        params, x.reshape(-1, d), cfg))
 
     def swiglu(h, wg, wu, wd):
         g = h @ np.asarray(wg, np.float64)
@@ -319,10 +330,121 @@ def test_the_dropless_dispatch_is_the_per_token_sum_over_chosen_experts():
                                   params["w_down"][e])
         s = params["shared"]
         want[n] += swiglu(h, s["w_gate"], s["w_up"], s["w_down"])
+    return want, np.bincount(chosen.reshape(-1), minlength=cfg.n_experts)
+
+
+# 96 tokens, top-2 of 16, every expert held: uniform routing sends an expert
+# 12 rows; with row tiles of 8 the capacity is 24 rows an expert, and behind
+# it the same capacity with the rows past it through the grouped product. The
+# bias leans towards expert 3 by this much: its rows are 20, 42, 82 and 96.
+WHOLE_RUNGS = {"first": 0.0, "a-few-rows-past": 0.15, "most-rows-past": 0.5,
+               "every-token": 1.0}
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("case", sorted(WHOLE_RUNGS))
+def test_a_whole_sets_rungs_are_the_per_token_sum_over_chosen_experts(
+        monkeypatch, case, stacked):
+    """A whole set's bounded rung, and the rung behind it (routing forced
+    past the capacity, up to every token on one expert), against the
+    definition in float64: the same top-k sum, no token dropped, whichever
+    products ran."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    cfg = moe.MoEConfig(n_experts=16, top_k=2, dispatch="ragged",
+                        scoring="sigmoid", routed_scale=2.5, n_shared=1)
+    assert moe.capacity_ladder(96, cfg) == (24,)
+    params = moe.init(jax.random.PRNGKey(2), 64, 32, cfg, jnp.float32,
+                      n_layers=3)
+    params["bias"] = params["bias"].at[1, 3].add(WHOLE_RUNGS[case])
+    layer = jax.tree.map(lambda a: a[1], params)
+    run = layer
+    if stacked:  # as the layer loop hands a layer over (moe.keep_stacked)
+        sliced, whole = moe.keep_stacked({"moe": params})
+        run = moe.at_layer(jax.tree.map(lambda a: a[1], sliced), whole,
+                           jnp.int32(1))["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 96, 64))
+    out, load = jax.jit(lambda p, x: moe.apply(p, x, cfg, with_load=True))(
+        run, x)
+    want, counts = per_token_sum(layer, x, cfg)
+    fullest = counts.max()
+    rung = "first" if case == "first" else "whole"
+    assert {"first": fullest <= 24, "a-few-rows-past": 24 < fullest <= 48,
+            "most-rows-past": 48 < fullest < 96,
+            "every-token": fullest == 96}[case], counts
+    assert dict(zip(moe.RUNG_NAMES, load[4:]))[rung] == 1 and load[4:].sum() == 1
     # float32 against float64 sums of ~100 terms of order 1: 1e-4
     assert np.abs(np.asarray(out).reshape(-1, 64) - want).max() < 1e-4
     assert load[2] == (counts > 0).sum()
-    assert load[3] == pytest.approx(counts.max() * 16 / (80 * 4))
+    assert load[3] == pytest.approx(fullest * 16 / (96 * 2))
+
+
+@pytest.mark.parametrize("lean", [0.0, 0.15, 1.0])
+def test_a_whole_sets_unowned_rows_never_reach_the_sum(monkeypatch, lean):
+    """Rows no assignment owns are whatever the products left there (a
+    capacity's slots past an expert's count, the grouped product's rows past
+    the groups' sum): non-finite values planted in every one of them reach
+    no token's sum, on either rung."""
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    cfg = moe.MoEConfig(n_experts=16, top_k=2, dispatch="ragged",
+                        scoring="sigmoid", routed_scale=2.5, n_shared=1)
+    params = moe.init(jax.random.PRNGKey(2), 64, 32, cfg, jnp.float32)
+    params["bias"] = params["bias"].at[3].add(lean)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 96, 64))
+
+    def run():
+        return jax.jit(lambda p, x: moe.apply(p, x, cfg, with_load=True))(
+            params, x)
+    clean, load = run()
+    chosen, _ = moe.route(params, x.reshape(-1, 64), cfg)
+    counts = jnp.bincount(chosen.reshape(-1), length=16)
+    grouped, batched = moe.grouped_ffn, moe._batched_ffn
+
+    def bad(rows):
+        return jnp.where(jnp.arange(rows) % 2 == 0, jnp.nan, jnp.inf)
+
+    def planted_grouped(p, rows, group_sizes):
+        y = grouped(p, rows, group_sizes)
+        live = jnp.arange(y.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None], y, bad(y.shape[0])[:, None])
+
+    def planted_batched(leaves, x):
+        y = batched(leaves, x)
+        live = jnp.arange(y.shape[1]) < counts[:, None]
+        return jnp.where(live[..., None], y, bad(y.shape[1])[None, :, None])
+
+    monkeypatch.setattr(moe, "grouped_ffn", planted_grouped)
+    monkeypatch.setattr(moe, "_batched_ffn", planted_batched)
+    planted, planted_load = run()
+    np.testing.assert_array_equal(planted_load, load)
+    assert load[4 if lean == 0.0 else 6] == 1
+    assert np.isfinite(planted).all()
+    np.testing.assert_array_equal(planted, clean)
+
+
+def test_a_decode_sized_call_keeps_the_grouped_product():
+    """One row an expert (32 rows of a decode step, top-8 of 256): a
+    batched product would read every expert's bytes where the grouped one
+    reads the touched experts'; the call traces the grouped products alone,
+    no capacity, no branch, and its load carries no rung."""
+    cfg = moe.MoEConfig(n_experts=256, top_k=8, dispatch="ragged",
+                        scoring="sigmoid", routed_scale=2.5, n_shared=1)
+    params = jax.eval_shape(lambda: moe.init(
+        jax.random.PRNGKey(0), 64, 32, cfg, jnp.float32))
+
+    def traced(tokens):
+        x = jax.ShapeDtypeStruct((tokens, 1, 64), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda p, x: moe.apply(
+            p, x, cfg, with_load=True))(params, x)
+        return str(jaxpr), jaxpr.out_avals[1].shape
+    for tokens in (32, 64, 96):  # a step, and verify steps of 2 and 3 tokens
+        text, load = traced(tokens)
+        assert text.count("ragged_dot_general[") == 3
+        assert "cond[" not in text and load == (4,)
+    text, load = traced(128)  # 4 rows an expert: a token's worth holds
+    assert "ragged_dot_general[" not in text and "cond[" not in text
+    assert load == (7,)
+    text, load = traced(256)  # a capacity under the tokens: the rows past it
+    assert text.count("ragged_dot_general[") == 3 and "cond[" in text
 
 
 def test_the_bias_changes_choices_and_not_weights():

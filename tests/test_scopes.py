@@ -15,7 +15,7 @@ import pytest
 
 from benchmarks import common
 from oim_tpu.models import generate as gen
-from oim_tpu.models import llama
+from oim_tpu.models import llama, moe
 from oim_tpu.ops import kda, ssm
 from oim_tpu.serve import engine
 
@@ -31,7 +31,9 @@ EXPERTS = {"moe_route", "moe_gmm"}
 # each program; no entry: the family has no such program)
 FAMILIES = {
     "tiny": (llama.tiny, {"prefill": set(), "step": set(), "verify": set()}),
-    "tiny_experts": (lambda: llama.tiny(n_experts=4),
+    # 8 experts, top-2: the dropless bucket's ladder is one capacity with the
+    # grouped products behind it (at 4 a token's worth, which always holds)
+    "tiny_experts": (lambda: llama.tiny(n_experts=8),
                      {"prefill": set(), "prefill_dropless": EXPERTS,
                       "step": set(), "verify": set()}),
     "tiny_latent": (llama.tiny_latent,
@@ -45,6 +47,24 @@ FAMILIES = {
 }
 CASES = [(family, program) for family, (_, programs) in FAMILIES.items()
          for program in programs]
+
+
+def _tokens(program: str) -> int:
+    return BUCKETS.get(program, SLOTS * (SPEC if program == "verify" else 1))
+
+
+def _has_grouped_products(cfg, program: str) -> bool:
+    """Whether the program's expert layers compile ``lax.ragged_dot``: the
+    dropless dispatch does unless a capacity of a token's worth closes its
+    ladder (``moe.capacity_ladder``) or a held share runs dense."""
+    n = _tokens(program)
+    run = gen._no_drop(cfg, n)
+    if not run.n_experts or run.moe_dispatch != "ragged":
+        return False
+    if run.moe.held and n <= moe.DENSE_UP_TO_TOKENS:
+        return False
+    ladder = moe.capacity_ladder(n, run.moe)
+    return not ladder or ladder[-1] < n
 
 
 def _lowered(cfg, program: str):
@@ -114,7 +134,9 @@ def test_program_carries_the_vocabulary(family, program):
     reader = common.plugin(REPO, "readers", "scope_share")
     grouped = set(re.findall(r'loc\("([^"]*ragged_dot[^"]*)"',
                              lowered.as_text(debug_info=True)))
-    assert bool(grouped) == ("moe_gmm" in want)
+    assert bool(grouped) == _has_grouped_products(make(), program)
+    if program in ("step", "prefill_dropless"):  # both forms are held
+        assert bool(grouped) == ("moe_gmm" in want)
     assert all("moe_gmm/" in name for name in grouped)
     assert set(reader.REWRITTEN.values()) == {"moe_gmm"}
     for kernel in want & {"mla_decode", "mla_prefill"}:
