@@ -56,7 +56,8 @@ DEFAULT_VOLUME = "weights"
 # --model names the trainer has none for: llama.py's constant of each.
 SERVED_ONLY = {"joyai-llm-flash": "JOYAI_LLM_FLASH",
                "nemotron-3-nano-30b": "NEMOTRON_3_NANO_30B",
-               "solar-open2-250b": "SOLAR_OPEN2_250B"}
+               "solar-open2-250b": "SOLAR_OPEN2_250B",
+               "gigachat35-432b-a28b": "GIGACHAT35_432B"}
 
 
 def _load_params(args, log):

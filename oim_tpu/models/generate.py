@@ -34,7 +34,9 @@ from oim_tpu.models.llama import (
     Config,
     _attn_mixer,
     _block,
-    _expert_mixer,
+    _ffn_mixer,
+    _norm,
+    _residual,
     layer_groups,
     run_pattern,
 )
@@ -251,7 +253,8 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     # device).
     params = jax.tree.map(jnp.asarray, params)
     with jax.named_scope("tok_embed"):
-        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
+        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta,
+                                    cfg.rope_yarn)
         positions = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
         x = params["embed"][tokens].astype(cfg.dtype)
 
@@ -355,7 +358,8 @@ def page_bytes(cfg: Config, page_tokens: int) -> int:
 # the engine's batch: a Mamba-2 model's {"ssm": [Lm, slots, H, P, N], "conv":
 # [Lm, slots, (K - 1) * conv_dim]}, a KDA model's {"kda": [Lk, slots, H, d,
 # d], "kda_conv": [Lk, slots, (K - 1) * 3 H d]} (ops/ssm.py says why a slot's
-# conv window is kept flat). A new kind of state is one more entry of
+# conv window is kept flat), a GatedDeltaNet model's {"gdn": [Lg, slots, Hv,
+# dk, dv], "gdn_conv": ...}. A new kind of state is one more entry of
 # ``llama.RECURRENT_KINDS`` whose module has ``Dims`` (``slot_leaves``,
 # ``state_leaf``, ``window_leaf``), ``step`` and ``scan``. The leaves ride
 # in the SAME dict as the page leaves, so the serving programs donate and
@@ -380,7 +384,7 @@ def init_state_pool(cfg: Config, slots: int) -> dict:
 
 
 def state_bytes_by_kind(cfg: Config, slots: int = 1) -> dict:
-    """{kind's name ("mamba", "kda"): device bytes of ``slots`` slots'
+    """{kind's name ("mamba", "kda", "gdn"): device bytes of ``slots`` slots'
     recurrent state over all layers of that kind}."""
     import math
 
@@ -434,7 +438,8 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
     cfg = _no_drop(cfg, B * T)
     params = jax.tree.map(jnp.asarray, params)
     with jax.named_scope("tok_embed"):
-        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta)
+        cos, sin = rope_frequencies(cfg.rope_dim, S, cfg.rope_theta,
+                                    cfg.rope_yarn)
         positions = jnp.broadcast_to(pos, (B,))[:, None] + jnp.arange(T)
         x = params["embed"][tokens].astype(cfg.dtype)
 
@@ -446,7 +451,7 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
                 with jax.named_scope("blk_kv_write"):
                     kv = pool["kv"].at[l, phys, off].set(latent, mode="drop")
                 return latent_attention.paged_attention(
-                    q, kv, l, tables, pos, wkv_b, cfg.latent), {"kv": kv}
+                    q, kv, l, tables, pos, wkv_b, cfg.latent), {**pool, "kv": kv}
             k, v = new
             with jax.named_scope("blk_kv_write"):
                 pk = pool["k"].at[l, phys, off].set(k, mode="drop")
@@ -461,7 +466,7 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
             params, x, pool, cfg, cos, sin, positions, attend_at,
             tables[:, 0] != 0, slot, n_tokens, pos)
         with jax.named_scope("tok_head"):
-            x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            x = _norm(x, params["final_norm"], cfg)
         return x, pool, _mean_load(load, n_moe)
 
     def body(carry, inp):
@@ -501,7 +506,7 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
 
         def mixer(carry, layer, i):
             x, pool, load = carry
-            h = rmsnorm(x, layer["norm"], eps)
+            h = _norm(x, layer["norm"], cfg)
             sp, cp = pool[state], pool[conv]
             # The state's read and write-back stand under the mixer's scope
             # too: the compiler fuses them with its update, and the profile
@@ -516,7 +521,7 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                     c2 = jnp.where(live[:, None], c2.reshape(c.shape), c)
                     pool = {**pool, state: sp.at[i].set(s2),
                             conv: cp.at[i].set(c2)}
-                return (x + y[:, None], pool, load)
+                return (_residual(x, y[:, None], layer, cfg), pool, load)
             # A prompt slice of one slot, from zeros at position 0.
             with jax.named_scope(scan_scope):
                 s = lax.dynamic_slice(sp, (i, slot, 0, 0, 0),
@@ -533,13 +538,13 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                             sp, s2[None], (i, slot, 0, 0, 0)),
                         conv: lax.dynamic_update_slice(
                             cp, c2.reshape(1, 1, -1), (i, slot, 0))}
-            return (x + y, pool, load)
+            return (_residual(x, y, layer, cfg), pool, load)
 
         return mixer
 
     def experts(carry, layer, _):
         x, pool, load = carry
-        x, aux = _expert_mixer(x, layer, cfg, load=True)
+        x, aux = _ffn_mixer(x, layer, cfg, load=True)
         return (x, pool, load + aux[2:])
 
     def attention(carry, layer, i):
@@ -554,7 +559,7 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
         params, cfg,
         (x, pool, jnp.zeros(
             (moe.load_width(cfg.moe, x.shape[0] * x.shape[1]) - 2,), jnp.float32)),
-        {"E": experts, "*": attention,
+        {"E": experts, "D": experts, "*": attention,
          **{kind: recurrent(kind) for kind in cfg.recurrent}})
 
 

@@ -20,29 +20,31 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oim_tpu.ops import kda, ssm
+from oim_tpu.ops import gdn, kda, ssm
 from oim_tpu.ops.attention import attention as default_attention
 from oim_tpu.ops.losses import chunked_softmax_cross_entropy, softmax_cross_entropy
-from oim_tpu.ops.norms import rmsnorm
+from oim_tpu.ops.norms import gated_rmsnorm, rmsnorm
 from oim_tpu.ops.rope import apply_rope, rope_frequencies
 from oim_tpu.parallel.sharding import EMBED, HEAD, KV_HEAD, LAYER, MLP, VOCAB
 
 
-# A hybrid's parameters are stacked a KIND of mixer ("M" Mamba-2, "K" KDA,
-# "E" experts, "*" attention), whatever the order its pattern runs them in
-# (``run_pattern``).
-HYBRID_GROUPS = {"M": "mamba_layers", "K": "kda_layers",
-                 "E": "expert_layers", "*": "attn_layers"}
+# A hybrid's parameters are stacked a KIND of block ("M" Mamba-2, "K" KDA,
+# "G" GatedDeltaNet, "E" experts, "D" a dense FFN, "*" attention: GQA, or
+# latent where ``kv_lora_rank`` says so), whatever the order its pattern
+# runs them in (``run_pattern``).
+HYBRID_GROUPS = {"M": "mamba_layers", "K": "kda_layers", "G": "gdn_layers",
+                 "E": "expert_layers", "D": "ffn_layers", "*": "attn_layers"}
 # The kinds that carry recurrent state, each with its module: ``Dims`` (the
 # mixer's sizes, what a slot keeps and under which leaves of the state
 # pool), ``step``, ``scan``, ``SCOPES`` and ``NAME``.
-RECURRENT_KINDS = {"M": ssm, "K": kda}
+RECURRENT_KINDS = {"M": ssm, "K": kda, "G": gdn}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,6 +139,42 @@ class Config:
     use_gqa_gate: bool = False
     kda_allow_neg_eigval: bool = False
     kda_use_full_proj: bool = False
+    # A hybrid of latent and linear attention (the gigachat3_5 family's
+    # published keys): linear_num_value_heads > 0 selects it. Published
+    # layer i of ``n_layers`` is a mixer block then an FFN block: latent
+    # attention ("*", the keys above) where i is in ``full_attention_layers``,
+    # else a GatedDeltaNet mixer ("G", ops/gdn.py: ``linear_num_key_heads``
+    # query and key heads of ``linear_key_head_dim`` under
+    # ``linear_num_value_heads`` value heads of ``linear_value_head_dim``, a
+    # conv of ``linear_conv_kernel_dim``, the output gate scaled by
+    # ``linear_sigmoid_gate_scale``); the dense FFN ("D", width mlp_dim) in
+    # the first ``first_k_dense_replace`` layers, else an expert block ("E").
+    # Without a pattern given the one the blocks run in is derived
+    # (``pattern``: "GDGDGD*EGEGEGE..." a period of four behind three dense
+    # layers). ``gated_attention`` multiplies the latent attention's output,
+    # before ``wo``, by sigmoid(x W_g), as ``use_gqa_gate`` does a GQA's.
+    full_attention_layers: tuple = ()
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_sigmoid_gate_scale: float = 1.0
+    gated_attention: bool = False
+    # Every norm of the model: "rms", or "zero_centered_gated", an RMSNorm
+    # whose weight passes 2 sigmoid(.) (ops/norms.py ``gated_rmsnorm``; its
+    # weights start at 0). ``layernorm_type`` "pre_post" norms each sublayer
+    # of a hybrid pattern after it too: ``x + norm(sublayer(norm(x)))``.
+    norm_type: str = "rms"
+    layernorm_type: str = "pre"
+    # The cut of every gated FFN's two products (models/moe.py ``swiglu``).
+    swiglu_limit: float = 0.0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast, beta_slow)
+    # stretches the rotary tables (ops/rope.py); ``use_mla_scaling_factor``
+    # puts its temperature, (0.1 ln(factor) + 1)^2, on the latent attention's
+    # softmax scale.
+    rope_yarn: tuple = ()
+    use_mla_scaling_factor: bool = False
     # Rematerialize each layer's activations in the backward pass
     # (jax.checkpoint around the scan body): ~1/3 more FLOPs for O(1)-layer
     # activation memory — what makes 8B-class configs at long context fit
@@ -190,10 +228,26 @@ class Config:
                 f"hybrid_override_pattern {given!r} must be n_layers "
                 f"({self.n_layers}) characters of {sorted(HYBRID_GROUPS)}")
         pattern = self.pattern
-        if self.use_gqa_gate and "*" not in pattern:
+        if self.attn_gate and "*" not in pattern:
             raise ValueError(
-                "use_gqa_gate gates a hybrid pattern's attention layers: "
-                "this configuration has none")
+                "use_gqa_gate / gated_attention gate a hybrid pattern's "
+                "attention layers: this configuration has none")
+        if self.norm_type not in ("rms", "zero_centered_gated") \
+                or self.layernorm_type not in ("pre", "pre_post"):
+            raise ValueError(
+                f"norm_type {self.norm_type!r} / layernorm_type "
+                f"{self.layernorm_type!r}: expected 'rms' or "
+                "'zero_centered_gated', and 'pre' or 'pre_post'")
+        if (self.post_norm or self.norm_type != "rms") and not pattern:
+            raise ValueError(
+                "layernorm_type='pre_post' and norm_type="
+                "'zero_centered_gated' are a hybrid pattern's: the "
+                "attention-then-FFN block has one RMSNorm a sublayer")
+        if self.use_mla_scaling_factor and not (
+                self.kv_lora_rank and self.rope_yarn):
+            raise ValueError(
+                "use_mla_scaling_factor scales a latent attention's softmax "
+                "by YaRN's temperature: it needs kv_lora_rank and rope_yarn")
         if pattern:
             if "M" in pattern and not (
                     self.mamba_num_heads and self.mamba_head_dim
@@ -210,10 +264,21 @@ class Config:
                 raise ValueError(
                     "a pattern with 'E' needs n_experts and "
                     "moe_dispatch='ragged' (the hybrid's experts run dropless)")
-            if self.kv_lora_rank or self.first_k_dense_replace:
+            if "G" in pattern and not (
+                    self.linear_num_key_heads and self.linear_num_value_heads
+                    and self.linear_key_head_dim and self.linear_value_head_dim
+                    and self.linear_num_value_heads
+                    % self.linear_num_key_heads == 0):
                 raise ValueError(
-                    "a hybrid pattern runs GQA attention and no leading "
-                    "dense layers")
+                    "a pattern with 'G' needs linear_num_value_heads (a "
+                    "multiple of linear_num_key_heads), linear_key_head_dim "
+                    "and linear_value_head_dim")
+            if self.first_k_dense_replace and (
+                    given or "D" not in pattern):
+                raise ValueError(
+                    "a hybrid pattern names its dense FFN blocks itself "
+                    "('D'): first_k_dense_replace is read only where the "
+                    "gigachat3_5 family's pattern is derived from it")
         self.experts_held  # a malformed expert_rank fails here
 
     @property
@@ -247,6 +312,7 @@ class Config:
             shared_dim=self.moe_shared_expert_intermediate_size,
             act=self.mlp_hidden_act,
             held=self.experts_held,
+            swiglu_limit=self.swiglu_limit,
         )
 
     @property
@@ -258,6 +324,28 @@ class Config:
             heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
             groups=self.n_groups, state=self.ssm_state_size,
             conv=self.conv_kernel, chunk=self.chunk_size)
+
+    @property
+    def gdn(self):
+        """The GatedDeltaNet mixer's sizes, or None without such layers."""
+        if "G" not in self.pattern:
+            return None
+        return gdn.Dims(
+            k_heads=self.linear_num_key_heads,
+            v_heads=self.linear_num_value_heads,
+            k_dim=self.linear_key_head_dim, v_dim=self.linear_value_head_dim,
+            conv=self.linear_conv_kernel_dim,
+            gate_scale=self.linear_sigmoid_gate_scale,
+            gated_norm=self.norm_type == "zero_centered_gated")
+
+    @property
+    def attn_gate(self) -> bool:
+        """Whether a pattern's attention multiplies its output by a gate."""
+        return self.use_gqa_gate or self.gated_attention
+
+    @property
+    def post_norm(self) -> bool:
+        return self.layernorm_type == "pre_post"
 
     @property
     def kda(self):
@@ -272,9 +360,17 @@ class Config:
     def pattern(self) -> str:
         """One character a block, in the order the blocks run ("" for the
         attention-then-FFN block): ``hybrid_override_pattern`` where given,
-        else the solar_open2 family's, two blocks a published layer."""
-        if self.hybrid_override_pattern or not self.kda_num_heads:
+        else the solar_open2 or the gigachat3_5 family's, two blocks a
+        published layer."""
+        if self.hybrid_override_pattern:
             return self.hybrid_override_pattern
+        if self.linear_num_value_heads:
+            return "".join(
+                ("*" if i in self.full_attention_layers else "G")
+                + ("D" if i < self.first_k_dense_replace else "E")
+                for i in range(self.n_layers))
+        if not self.kda_num_heads:
+            return ""
         return "".join(("*" if i in self.gqa_layers else "K") + "E"
                        for i in range(self.n_layers))
 
@@ -298,9 +394,11 @@ class Config:
     @property
     def recurrent(self) -> dict:
         """{kind: its mixer's Dims} of the pattern's kinds of recurrent
-        layer ("M": ops/ssm.py, "K": ops/kda.py); {} without any."""
+        layer ("M": ops/ssm.py, "K": ops/kda.py, "G": ops/gdn.py); {}
+        without any."""
         return {kind: dims
-                for kind, dims in (("M", self.mamba), ("K", self.kda))
+                for kind, dims in (("M", self.mamba), ("K", self.kda),
+                                   ("G", self.gdn))
                 if dims is not None}
 
     @property
@@ -331,9 +429,11 @@ class Config:
             return None
         from oim_tpu.ops.latent_attention import Dims
 
+        mscale = (0.1 * math.log(self.rope_yarn[0]) + 1.0
+                  if self.use_mla_scaling_factor else 1.0)
         return Dims(heads=self.n_heads, rank=self.kv_lora_rank,
                     nope=self.qk_nope_head_dim, rope=self.qk_rope_head_dim,
-                    v=self.v_head_dim)
+                    v=self.v_head_dim, mscale=mscale)
 
     @property
     def rope_dim(self) -> int:
@@ -425,6 +525,57 @@ SOLAR_OPEN2_250B = Config(
     use_gqa_gate=True, kda_allow_neg_eigval=True, n_experts=320, moe_top_k=8,
     moe_intermediate_size=1280, n_shared_experts=1, scoring_func="sigmoid",
     routed_scaling_factor=1.0, moe_dispatch="ragged")
+
+
+# GigaChat3.5-432B-A28B as published: 40 layers, each a mixer block then an
+# FFN block, every sublayer between two zero-centred gated norms; the mixer
+# is gated latent attention (YaRN over 32 768 positions) in every fourth
+# layer from 3 and a GatedDeltaNet mixer (a gated delta rule with one decay
+# a head, 32 key heads under 64 value heads, ops/gdn.py) in the other 30; the
+# FFN is a dense SwiGLU in layers 0-2 and 256 sigmoid-routed SwiGLU experts
+# top-8 beside a shared one in the other 37, every SwiGLU cut at 10. The two
+# multi-token-prediction modules are not part of the served forward.
+# https://huggingface.co/ai-sage/GigaChat3.5-432B-A28B/blob/main/config.json
+# 864 GB in bfloat16: one v5e chip holds one rank of a 16-way expert-parallel
+# group over the leading dense layer and one period of four
+# (benchmarks/configs/gigachat35-432b-a28b.json: the pattern "GD*EGEGEGE",
+# expert_rank=0/16, vocab=16032).
+GIGACHAT35_432B = Config(
+    vocab=128256, dim=7168, n_layers=40, n_heads=64, n_kv_heads=64,
+    head_dim=112, mlp_dim=18432, max_seq=262144, rope_theta=100000.0,
+    rope_yarn=(8.0, 32768, 32.0, 1.0), use_mla_scaling_factor=True,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, gated_attention=True,
+    full_attention_layers=tuple(range(3, 40, 4)), linear_num_key_heads=32,
+    linear_num_value_heads=64, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_conv_kernel_dim=4,
+    linear_sigmoid_gate_scale=2.0, norm_type="zero_centered_gated",
+    layernorm_type="pre_post", swiglu_limit=10.0, n_experts=256, moe_top_k=8,
+    moe_intermediate_size=2048, n_shared_experts=1, first_k_dense_replace=3,
+    scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
+
+
+def tiny_gdn(vocab: int = 512, pattern: str = "GD*EGEGEGE",
+             dtype=jnp.float32, expert_rank: str = "") -> Config:
+    """The gigachat3_5 family's layers at test scale, in the pattern the
+    benchmark's cut runs: a leading GatedDeltaNet + dense FFN layer, then a
+    period of gated latent attention and three GatedDeltaNet mixers with an
+    expert block behind each; gated norms before and after every sublayer,
+    YaRN, the cut SwiGLU (at 1: test activations reach it)."""
+    return Config(
+        vocab=vocab, dim=64, n_layers=len(pattern), n_heads=4, n_kv_heads=4,
+        head_dim=16, mlp_dim=96, max_seq=512, rope_theta=1e5, dtype=dtype,
+        rope_yarn=(8.0, 64, 32.0, 1.0), use_mla_scaling_factor=True,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, gated_attention=True,
+        hybrid_override_pattern=pattern, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, linear_conv_kernel_dim=4,
+        linear_sigmoid_gate_scale=2.0, norm_type="zero_centered_gated",
+        layernorm_type="pre_post", swiglu_limit=1.0, n_experts=16,
+        moe_top_k=4, moe_intermediate_size=32, n_shared_experts=1,
+        scoring_func="sigmoid", routed_scaling_factor=2.5,
+        moe_dispatch="ragged", expert_rank=expert_rank)
 
 
 def tiny_kda(vocab: int = 512, n_layers: int = 4, dtype=jnp.float32,
@@ -541,41 +692,79 @@ def layer_groups(params) -> list:
     return [params[g] for g in LAYER_GROUPS if g in params]
 
 
+def _norm_weight(cfg: Config, shape):
+    """A norm's weight as a fresh model has it: what multiplies by 1."""
+    gated = cfg.norm_type == "zero_centered_gated"
+    return (jnp.zeros if gated else jnp.ones)(shape, jnp.float32)
+
+
+def _latent_leaves(rng, cfg: Config, L: int) -> dict:
+    """The latent attention's projections and inner norms, stacked [L, ...]
+    (``wo`` and the block's norms are the caller's)."""
+    D, m = cfg.dim, cfg.latent
+    ks = jax.random.split(rng, 4)
+    return dict(
+        wq_a=_dense(ks[0], (L, D, cfg.q_lora_rank), cfg.dtype, D**-0.5),
+        q_norm=_norm_weight(cfg, (L, cfg.q_lora_rank)),
+        wq_b=_dense(ks[1], (L, cfg.q_lora_rank, cfg.q_dim), cfg.dtype),
+        wkv_a=_dense(ks[2], (L, D, m.rank + m.rope), cfg.dtype, D**-0.5),
+        kv_norm=_norm_weight(cfg, (L, m.rank)),
+        wkv_b=_dense(ks[3], (L, m.rank, m.heads * (m.nope + m.v)), cfg.dtype),
+    )
+
+
 def _init_hybrid(rng, cfg: Config) -> dict:
-    """The stacked groups of a hybrid, one a kind of mixer it has, each
-    layer ONE mixer behind ONE norm."""
+    """The stacked groups of a hybrid, one a kind of block it has, each
+    layer ONE sublayer behind ONE norm (and before one more where
+    ``layernorm_type`` is "pre_post")."""
     from oim_tpu.models import moe
 
     D = cfg.dim
     ks = jax.random.split(rng, 7)
     fan = D**-0.5
     n_m, n_e, n_a, n_k = (cfg.n_of(k) for k in "ME*K")
+    n_g, n_d = cfg.n_of("G"), cfg.n_of("D")
     groups = {}
+    if n_g:
+        groups["gdn_layers"] = gdn.init(
+            jax.random.fold_in(rng, 8), D, cfg.gdn, cfg.dtype, n_g)
+    if n_d:
+        kd = jax.random.split(jax.random.fold_in(rng, 9), 3)
+        groups["ffn_layers"] = {
+            "w_gate": _dense(kd[0], (n_d, D, cfg.mlp_dim), cfg.dtype, fan),
+            "w_up": _dense(kd[1], (n_d, D, cfg.mlp_dim), cfg.dtype, fan),
+            "w_down": _dense(kd[2], (n_d, cfg.mlp_dim, D), cfg.dtype,
+                             cfg.mlp_dim**-0.5)}
     if n_k:
-        groups["kda_layers"] = {
-            "norm": jnp.ones((n_k, D), jnp.float32),
-            **kda.init(ks[6], D, cfg.kda, cfg.dtype, n_k)}
+        groups["kda_layers"] = kda.init(ks[6], D, cfg.kda, cfg.dtype, n_k)
     if n_m:
-        groups["mamba_layers"] = {
-            "norm": jnp.ones((n_m, D), jnp.float32),
-            **ssm.init(ks[0], D, cfg.mamba, cfg.dtype, n_m)}
+        groups["mamba_layers"] = ssm.init(ks[0], D, cfg.mamba, cfg.dtype, n_m)
     if n_e:
         groups["expert_layers"] = {
-            "norm": jnp.ones((n_e, D), jnp.float32),
             "moe": moe.init(ks[1], D, cfg.expert_dim, cfg.moe, cfg.dtype,
                             n_layers=n_e)}
     if n_a:
         groups["attn_layers"] = {
-            "norm": jnp.ones((n_a, D), jnp.float32),
-            "wq": _dense(ks[2], (n_a, D, cfg.q_dim), cfg.dtype, fan),
-            "wk": _dense(ks[3], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
-            "wv": _dense(ks[4], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
             "wo": _dense(ks[5], (n_a, cfg.o_dim, D), cfg.dtype,
                          cfg.o_dim**-0.5)}
-        if cfg.use_gqa_gate:
+        if cfg.kv_lora_rank:
+            groups["attn_layers"].update(
+                _latent_leaves(jax.random.fold_in(rng, 10), cfg, n_a))
+        else:
+            groups["attn_layers"].update(
+                wq=_dense(ks[2], (n_a, D, cfg.q_dim), cfg.dtype, fan),
+                wk=_dense(ks[3], (n_a, D, cfg.kv_dim), cfg.dtype, fan),
+                wv=_dense(ks[4], (n_a, D, cfg.kv_dim), cfg.dtype, fan))
+        if cfg.attn_gate:
             groups["attn_layers"]["wg"] = _dense(
                 jax.random.fold_in(rng, 7), (n_a, D, cfg.o_dim), cfg.dtype,
                 fan)
+    for kind, name in HYBRID_GROUPS.items():
+        if name in groups:  # the block's norm(s), whatever its kind
+            groups[name]["norm"] = _norm_weight(cfg, (cfg.n_of(kind), D))
+            if cfg.post_norm:
+                groups[name]["post_norm"] = _norm_weight(
+                    cfg, (cfg.n_of(kind), D))
     return groups
 
 
@@ -586,7 +775,7 @@ def init(rng, cfg: Config = LLAMA3_8B):
     lead = cfg.n_dense_layers
     params = {
         "embed": _dense(ks[0], (cfg.vocab, D), cfg.dtype, scale=0.02),
-        "final_norm": jnp.ones((D,), jnp.float32),
+        "final_norm": _norm_weight(cfg, (D,)),
         "lm_head": _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan),
     }
     if cfg.pattern:
@@ -606,8 +795,8 @@ def param_logical_axes(cfg: Config = LLAMA3_8B):
             "no sharding rules yet for latent attention, leading dense "
             "layers or shared experts: this block is served on one chip "
             "and not trained (ROADMAP.md, Reach); nor for a hybrid pattern's "
-            "mixers (Mamba-2, KDA, gated attention) or a held share of the "
-            "experts")
+            "blocks (Mamba-2, KDA, GatedDeltaNet, gated attention, gated "
+            "norms) or a held share of the experts")
     layers = {
         "attn_norm": (LAYER, None),
         "wq": (LAYER, EMBED, HEAD),
@@ -671,7 +860,8 @@ def _ffn(h, layer, cfg: Config, load: bool = False):
     if "moe" in layer:
         return moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
                          with_load=load)
-    gated = jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
+    gated = moe.swiglu(h @ layer["w_gate"], h @ layer["w_up"],
+                       cfg.swiglu_limit)
     width = (moe.load_width(cfg.moe, h.shape[0] * h.shape[1])
              if cfg.n_experts else 4) if load else 2
     return gated @ layer["w_down"], jnp.zeros((width,), jnp.float32)
@@ -679,6 +869,21 @@ def _ffn(h, layer, cfg: Config, load: bool = False):
 
 def _same(x):
     return x
+
+
+def _norm(x, weight, cfg: Config):
+    """Every norm of the model (``Config.norm_type``), at its epsilon."""
+    if cfg.norm_type == "zero_centered_gated":
+        return gated_rmsnorm(x, weight, cfg.norm_eps)
+    return rmsnorm(x, weight, cfg.norm_eps)
+
+
+def _residual(x, out, layer, cfg: Config):
+    """``x`` plus a hybrid pattern's sublayer output, normed once more
+    first where ``layernorm_type`` is "pre_post"."""
+    if cfg.post_norm:
+        out = _norm(out, layer["post_norm"], cfg)
+    return x + out
 
 
 def _block(x, layer, cfg: Config, cos, sin, positions, attend, cache=None,
@@ -713,11 +918,10 @@ def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
     """The attention of a block on the normed activations h [B, T, D], up
     to (not with) ``wo``: (output [B, T, o_dim], cache). See ``_block``."""
     B, T, _ = h.shape
-    eps = cfg.norm_eps
     if cfg.kv_lora_rank:
         m = cfg.latent
         with jax.named_scope("blk_qkv"):
-            q = (rmsnorm(h @ layer["wq_a"], layer["q_norm"], eps)
+            q = (_norm(h @ layer["wq_a"], layer["q_norm"], cfg)
                  @ layer["wq_b"]).reshape(B, T, m.heads, m.nope + m.rope)
             q = jnp.concatenate(
                 [q[..., :m.nope],
@@ -725,7 +929,7 @@ def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
             ckv = h @ layer["wkv_a"]
             k_r = apply_rope(ckv[..., None, m.rank:], cos, sin, positions)
             latent = m.entry(
-                rmsnorm(ckv[..., :m.rank], layer["kv_norm"], eps),
+                _norm(ckv[..., :m.rank], layer["kv_norm"], cfg),
                 k_r[..., 0, :])
         with jax.named_scope("blk_attn"):
             attn, cache = attend(cache, q, latent, layer["wkv_b"])
@@ -741,7 +945,7 @@ def _attention(h, layer, cfg: Config, cos, sin, positions, attend, cache):
             attn, cache = attend(cache, q, k, v)
     with jax.named_scope("blk_out"):
         attn = attn.reshape(B, T, cfg.o_dim)
-        if cfg.use_gqa_gate:  # one gate an output element, before ``wo``
+        if cfg.attn_gate:  # one gate an output element, before ``wo``
             attn = attn * jax.nn.sigmoid(h @ layer["wg"])
     return attn, cache
 
@@ -806,25 +1010,37 @@ def run_pattern(params, cfg: Config, carry, mixers: dict):
     return carry
 
 
-def _expert_mixer(x, layer, cfg: Config, load: bool = False):
-    """A hybrid's expert layer: (x + experts(norm(x)), aux)."""
-    from oim_tpu.models import moe
-
+def _ffn_mixer(x, layer, cfg: Config, load: bool = False):
+    """A hybrid's FFN block, experts ("E") or dense ("D", which its leaves
+    say): (x + ffn(norm(x)), aux)."""
     with jax.named_scope("blk_ffn"):
-        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
-        out, aux = moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
-                             with_load=load)
-        return x + out, aux
+        out, aux = _ffn(_norm(x, layer["norm"], cfg), layer, cfg, load)
+        return _residual(x, out, layer, cfg), aux
 
 
 def _attn_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache):
     """A hybrid's attention layer: (x + attention(norm(x)), cache)."""
     with jax.named_scope("blk_qkv"):
-        h = rmsnorm(x, layer["norm"], cfg.norm_eps)
+        h = _norm(x, layer["norm"], cfg)
     attn, cache = _attention(h, layer, cfg, cos, sin, positions, attend,
                              cache)
     with jax.named_scope("blk_out"):
-        return x + attn @ layer["wo"], cache
+        return _residual(x, attn @ layer["wo"], layer, cfg), cache
+
+
+def _full_attend(cfg: Config, attn_fn: AttentionFn):
+    """``_attention``'s ``attend`` over a whole sequence, no cache."""
+    if cfg.kv_lora_rank:
+        from oim_tpu.ops import latent_attention
+
+        def attend(_, q, latent, wkv_b):
+            return latent_attention.full_attention(
+                q, latent, wkv_b, cfg.latent), None
+    else:
+        def attend(_, q, k, v):
+            return attn_fn(q, k, v, causal=True), None
+
+    return attend
 
 
 def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
@@ -840,47 +1056,37 @@ def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
 
         def mixer(carry, layer, _):
             x, aux = carry
-            h = rmsnorm(x, layer["norm"], cfg.norm_eps)
             y, _, _ = module.scan(
-                layer, h, jnp.zeros((B,) + state, dt),
+                layer, _norm(x, layer["norm"], cfg),
+                jnp.zeros((B,) + state, dt),
                 jnp.zeros((B,) + dims.window, window_dt), T, dims,
                 cfg.norm_eps)
-            return x + y, aux
+            return _residual(x, y, layer, cfg), aux
 
         return mixer
 
     def experts(carry, layer, _):
         x, aux = carry
-        x, layer_aux = _expert_mixer(x, layer, cfg)
+        x, layer_aux = _ffn_mixer(x, layer, cfg)
         return x, aux + layer_aux
 
     def attention(carry, layer, _):
         x, aux = carry
-        x, _ = _attn_mixer(
-            x, layer, cfg, cos, sin, None,
-            lambda _, q, k, v: (attn_fn(q, k, v, causal=True), None), None)
+        x, _ = _attn_mixer(x, layer, cfg, cos, sin, None,
+                           _full_attend(cfg, attn_fn), None)
         return x, aux
 
     return run_pattern(
         params, cfg, (x, jnp.zeros((2,), jnp.float32)),
-        {"E": experts, "*": attention,
+        {"E": experts, "D": experts, "*": attention,
          **{kind: recurrent(kind) for kind in cfg.recurrent}})
 
 
 def _layer(x, layer, cfg: Config, cos, sin, attn_fn: AttentionFn):
     """The block over a whole sequence, no cache. Returns (x, aux_loss);
     aux is 0 for dense FFN layers."""
-    if cfg.kv_lora_rank:
-        from oim_tpu.ops import latent_attention
-
-        def attend(_, q, latent, wkv_b):
-            return latent_attention.full_attention(
-                q, latent, wkv_b, cfg.latent), None
-    else:
-        def attend(_, q, k, v):
-            return attn_fn(q, k, v, causal=True), None
-
-    x, aux, _ = _block(x, layer, cfg, cos, sin, None, attend)
+    x, aux, _ = _block(x, layer, cfg, cos, sin, None,
+                       _full_attend(cfg, attn_fn))
     return x, aux
 
 
@@ -892,11 +1098,12 @@ def hidden_states(params, tokens, cfg: Config = LLAMA3_8B,
         attn_fn = default_attention
     T = tokens.shape[1]
     with jax.named_scope("tok_embed"):
-        cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta)
+        cos, sin = rope_frequencies(cfg.rope_dim, T, cfg.rope_theta,
+                                    cfg.rope_yarn)
         x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.pattern:
         x, aux = _hybrid_hidden(params, x, cfg, cos, sin, attn_fn)
-        return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
+        return _norm(x, params["final_norm"], cfg), aux
 
     def body(x, layer):
         x, aux = _layer(x, layer, cfg, cos, sin, attn_fn)
@@ -1409,17 +1616,6 @@ def _param_counts(cfg: Config, experts: int) -> int:
                + (cfg.n_experts if cfg.scoring_func == "sigmoid" else 0))
     else:
         ffn = dense
-    if cfg.pattern:
-        m = cfg.mamba
-        mamba = (D * m.proj_dim + (m.conv + 1) * m.conv_dim + 3 * m.heads
-                 + m.inner + m.inner * D) if m else 0
-        linear = kda.n_params(D, cfg.kda) if cfg.kda else 0
-        attn = (D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.o_dim * D
-                + (D * cfg.o_dim if cfg.use_gqa_gate else 0))
-        layers = (cfg.n_of("M") * mamba + cfg.n_of("K") * linear
-                  + cfg.n_of("E") * ffn + cfg.n_of("*") * attn
-                  + len(cfg.pattern) * D)  # a norm a block
-        return cfg.vocab * D + layers + D + D * cfg.vocab
     if cfg.kv_lora_rank:
         m = cfg.latent
         attn = (D * cfg.q_lora_rank + cfg.q_lora_rank
@@ -1427,6 +1623,19 @@ def _param_counts(cfg: Config, experts: int) -> int:
                 + m.rank * m.heads * (m.nope + m.v) + cfg.o_dim * D)
     else:
         attn = D * cfg.q_dim + 2 * D * cfg.kv_dim + cfg.q_dim * D
+    if cfg.pattern:
+        m = cfg.mamba
+        mamba = (D * m.proj_dim + (m.conv + 1) * m.conv_dim + 3 * m.heads
+                 + m.inner + m.inner * D) if m else 0
+        linear = kda.n_params(D, cfg.kda) if cfg.kda else 0
+        delta = gdn.n_params(D, cfg.gdn) if cfg.gdn else 0
+        attn += D * cfg.o_dim if cfg.attn_gate else 0
+        layers = (cfg.n_of("M") * mamba + cfg.n_of("K") * linear
+                  + cfg.n_of("G") * delta + cfg.n_of("E") * ffn
+                  + cfg.n_of("D") * dense + cfg.n_of("*") * attn
+                  # a norm a block, two where one follows it too
+                  + len(cfg.pattern) * D * (2 if cfg.post_norm else 1))
+        return cfg.vocab * D + layers + D + D * cfg.vocab
     lead = cfg.n_dense_layers
     layers = L * (2 * D + attn) + lead * dense + (L - lead) * ffn
     return cfg.vocab * D + layers + D + D * cfg.vocab

@@ -71,6 +71,10 @@ class MoEConfig:
     # part of each token's sum (and the shared expert whole) and leaves
     # out what the absent ranks would add. () holds every expert.
     held: tuple = ()
+    # ``swiglu_limit``: a gated expert's ``w_gate`` product is cut at it from
+    # above and its ``w_up`` product to [-limit, limit] before the
+    # activation (``swiglu``); 0 cuts nothing.
+    swiglu_limit: float = 0.0
 
     @property
     def n_held(self) -> int:
@@ -150,12 +154,21 @@ def _gated(act: str) -> bool:
     return act == "silu"
 
 
-def _shared_ffn(s, tokens):
+def swiglu(gate, up, limit: float = 0.0):
+    """``silu(gate) * up``, the inside of every gated FFN of the model
+    (routed, shared, dense); with ``limit`` (``swiglu_limit``) of
+    ``min(gate, limit)`` and ``clip(up, -limit, limit)``."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def _shared_ffn(s, tokens, limit: float = 0.0):
     """The shared expert on tokens [N, D]; which form is read from its
     leaves, as ``grouped_ffn``."""
     if "w_gate" in s:
-        return (jax.nn.silu(tokens @ s["w_gate"]) * (tokens @ s["w_up"])
-                ) @ s["w_down"]
+        return swiglu(tokens @ s["w_gate"], tokens @ s["w_up"], limit
+                      ) @ s["w_down"]
     return jnp.square(jax.nn.relu(tokens @ s["w_up"])) @ s["w_down"]
 
 
@@ -225,7 +238,7 @@ def at_layer(layer: dict, whole: dict, index) -> dict:
     return {**layer, "moe": {**layer["moe"], "stack": (whole, index)}}
 
 
-def grouped_ffn(params, rows, group_sizes):
+def grouped_ffn(params, rows, group_sizes, limit: float = 0.0):
     """The expert FFN over ``rows`` [M, D] sorted by expert, expert e
     owning the next ``group_sizes[e]`` of them: three grouped products for
     the gated SwiGLU, two for the non-gated squared ReLU (a tree without
@@ -246,7 +259,7 @@ def grouped_ffn(params, rows, group_sizes):
         up = jax.lax.ragged_dot(rows, params["w_up"], group_sizes)
         if "w_gate" in params:
             gate = jax.lax.ragged_dot(rows, params["w_gate"], group_sizes)
-            hidden = jax.nn.silu(gate) * up
+            hidden = swiglu(gate, up, limit)
         else:
             hidden = jnp.square(jax.nn.relu(up))
         return jax.lax.ragged_dot(hidden, params["w_down"], group_sizes)
@@ -288,14 +301,15 @@ def _dense_held(params, x, cfg: MoEConfig):
     with jax.named_scope("moe_gmm"):
         up = jnp.einsum("nd,edf->enf", tokens, leaves["w_up"])
         if "w_gate" in leaves:
-            hidden = jax.nn.silu(jnp.einsum(
-                "nd,edf->enf", tokens, leaves["w_gate"])) * up
+            hidden = swiglu(jnp.einsum(
+                "nd,edf->enf", tokens, leaves["w_gate"]), up, cfg.swiglu_limit)
         else:
             hidden = jnp.square(jax.nn.relu(up))
         y = jnp.einsum("enf,efd->end", hidden, leaves["w_down"])
     out = jnp.einsum("end,ne->nd", y.astype(jnp.float32), weight)
     if cfg.n_shared:
-        out = out + _shared_ffn(params["shared"], tokens).astype(jnp.float32)
+        out = out + _shared_ffn(params["shared"], tokens,
+                                cfg.swiglu_limit).astype(jnp.float32)
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),
         jnp.max(counts).astype(jnp.float32) * e
@@ -451,15 +465,15 @@ def _layer_leaves(params):
     return {k: v[index] for k, v in whole.items()}
 
 
-def _batched_ffn(leaves, x):
+def _batched_ffn(leaves, x, limit: float = 0.0):
     """The expert FFN over x [e, C, D], expert e's rows in x[e]: batched
     products [e, C, D] x [e, D, F] (which form is read from the leaves, as
     ``grouped_ffn``)."""
     with jax.named_scope("moe_gmm"):
         up = jnp.einsum("ecd,edf->ecf", x, leaves["w_up"])
         if "w_gate" in leaves:
-            hidden = jax.nn.silu(jnp.einsum(
-                "ecd,edf->ecf", x, leaves["w_gate"])) * up
+            hidden = swiglu(jnp.einsum(
+                "ecd,edf->ecf", x, leaves["w_gate"]), up, limit)
         else:
             hidden = jnp.square(jax.nn.relu(up))
         return jnp.einsum("ecf,efd->ecd", hidden, leaves["w_down"])
@@ -487,7 +501,7 @@ def _routed_products(params, tokens, flat, order, counts, cfg: MoEConfig):
     def whole(back=None):
         with jax.named_scope("moe_route"):
             x = jnp.take(tokens, order // k, axis=0)  # [N * k, D]
-        y = grouped_ffn(params, x, counts)
+        y = grouped_ffn(params, x, counts, cfg.swiglu_limit)
         # Alone, the un-sort is traced behind the products, where it always
         # stood: a decode step's compiled text is held to its hash (tier-1).
         return jnp.take(y, jnp.argsort(order) if back is None else back, axis=0)
@@ -508,7 +522,8 @@ def _routed_products(params, tokens, flat, order, counts, cfg: MoEConfig):
             at = jnp.minimum(starts[:, None] + slot, rows - 1)
             source = jnp.where(slot < counts[:, None], order[at] // k, 0)
             x = jnp.take(tokens, source.reshape(-1), axis=0)
-        y = _batched_ffn(_layer_leaves(params), x.reshape(e, c, d))
+        y = _batched_ffn(_layer_leaves(params), x.reshape(e, c, d),
+                         cfg.swiglu_limit)
         return jnp.take(y.reshape(e * c, d),
                         held * c + jnp.clip(rank, 0, c - 1), axis=0)
 
@@ -522,7 +537,8 @@ def _routed_products(params, tokens, flat, order, counts, cfg: MoEConfig):
             past = jnp.arange(rows) - starts[held[order]] >= c  # sorted order
             first = jnp.argsort(~past, stable=True)
             x = jnp.take(tokens, order[first] // k, axis=0)  # [N * k, D]
-        y = grouped_ffn(params, x, jnp.maximum(counts - c, 0))
+        y = grouped_ffn(params, x, jnp.maximum(counts - c, 0),
+                        cfg.swiglu_limit)
         place = jnp.cumsum(past) - 1  # of a sorted row among those past c
         return jnp.where((rank < c)[:, None], batched(c),
                          jnp.take(y, place[back], axis=0))
@@ -571,7 +587,8 @@ def _dropless(params, x, cfg: MoEConfig):
         y, w = jnp.where(mine[..., None], y, 0), jnp.where(mine, w, 0.0)
     out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
     if cfg.n_shared:
-        out = out + _shared_ffn(params["shared"], tokens).astype(jnp.float32)
+        out = out + _shared_ffn(params["shared"], tokens,
+                                cfg.swiglu_limit).astype(jnp.float32)
     total = jnp.maximum(jnp.sum(counts), 1) if cfg.held else n * k
     load = jnp.stack([
         jnp.sum(counts > 0).astype(jnp.float32),

@@ -60,6 +60,10 @@ LANES = 128
 # (PERF.md section 6, PR 29); 1024 keeps the two buffers at 2.6 MB of VMEM
 # beside q and o, which grow with the heads.
 BLOCK_TOKENS = 1024
+# What the TPU compiler gives a kernel's scoped allocations unless asked for
+# more, and the room a call leaves beside what it counts itself.
+VMEM_SCOPE = 16 << 20
+VMEM_ROOM = 4 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,10 +73,14 @@ class Dims:
     nope: int    # qk_nope_head_dim
     rope: int    # qk_rope_head_dim, shared by all heads
     v: int       # v_head_dim
+    # YaRN's attention temperature (``use_mla_scaling_factor``: 0.1 ln(factor)
+    # + 1 where the rotary tables are stretched): the softmax scale takes its
+    # square, as a query and a key each scaled by it would.
+    mscale: float = 1.0
 
     @property
     def scale(self) -> float:
-        return (self.nope + self.rope) ** -0.5
+        return (self.nope + self.rope) ** -0.5 * self.mscale ** 2
 
     @property
     def width(self) -> int:
@@ -330,8 +338,17 @@ def _latent_decode(qq, pool, layer, tables, pos, d: Dims, pages: int,
     kernel = functools.partial(
         _latent_kernel, scale=d.scale, pages=pages, page=page, rank=d.rank,
         n_blocks=span)
+    # Every row's query and part stand in VMEM for the one grid step, beside
+    # the two page buffers: 3.9 MB at 32 rows of 32 heads, 18 MB at 64 rows
+    # of 64, over the compiler's 16 MB scope of a v5e's 128. Only a call
+    # that needs more than the scope asks for it.
+    need = (B * H * width * qq.dtype.itemsize + B * H * (d.rank + LANES) * 4
+            + 2 * pages * page * width * pool.dtype.itemsize)
+    params = (pltpu.CompilerParams(vmem_limit_bytes=need + VMEM_ROOM)
+              if need > VMEM_SCOPE - VMEM_ROOM else None)
     walk = pl.pallas_call(
         kernel,
+        compiler_params=params,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(1,),

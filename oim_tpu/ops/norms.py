@@ -8,6 +8,7 @@ over a 4k-wide embed).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -18,6 +19,13 @@ def rmsnorm(x, weight, eps: float = 1e-6):
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_rmsnorm(x, weight, eps: float = 1e-6):
+    """The zero-centred gated norm: RMSNorm whose weight passes a sigmoid,
+    ``x * rsqrt(mean(x^2) + eps) * 2 sigmoid(w)``: 1 at ``w`` = 0, between
+    0 and 2 whatever ``w``. The tensors and the cost of an RMSNorm."""
+    return rmsnorm(x, 2.0 * jax.nn.sigmoid(weight.astype(jnp.float32)), eps)
 
 
 def layernorm(x, weight, bias, eps: float = 1e-6):

@@ -7,14 +7,41 @@ QK projection's epilogue.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
-def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0):
-    """cos/sin tables [max_seq, head_dim//2], float32."""
+def yarn_ramp(head_dim: int, theta: float, original_max: int,
+              beta_fast: float, beta_slow: float):
+    """YaRN's blend a pair i of ``head_dim`` / 2, float32 in [0, 1]: 0 where
+    the pair turns ``beta_fast`` times or more over ``original_max``
+    positions (kept as trained), 1 where it turns ``beta_slow`` times or
+    fewer (interpolated), a ramp between the two pairs' whole indices."""
+    def pair(turns: float) -> float:
+        return (head_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low, high = math.floor(pair(beta_fast)), math.ceil(pair(beta_slow))
+    at = jnp.arange(head_dim // 2, dtype=jnp.float32)
+    return jnp.clip((at - low) / max(high - low, 1e-3), 0.0, 1.0)
+
+
+def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0,
+                     yarn: tuple = ()):
+    """cos/sin tables [max_seq, head_dim//2], float32. ``yarn`` = (factor,
+    original_max_position_embeddings, beta_fast, beta_slow) divides the slow
+    pairs' frequencies by ``factor`` (``yarn_ramp``); the tables are not
+    scaled (the family's ``mscale`` over ``mscale_all_dim`` is 1: what YaRN
+    adds to the attention's temperature is the softmax scale's, see
+    ops/latent_attention.py ``Dims.mscale``)."""
     inv_freq = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    if yarn:
+        factor, original_max, beta_fast, beta_slow = yarn
+        ramp = yarn_ramp(head_dim, theta, original_max, beta_fast, beta_slow)
+        inv_freq = ramp * inv_freq / factor + (1.0 - ramp) * inv_freq
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
     return jnp.cos(freqs), jnp.sin(freqs)

@@ -52,16 +52,15 @@ def _model(tiny: bool, layers: int, dtype: str):
     return model, serve_kda.program_config(model)
 
 
-def agree(args) -> dict:
+def agree(args, model, cfg, weights, ref, leaf: str) -> dict:
+    """The comparison, for any family whose recurrent state is the pool's
+    leaf ``leaf`` (scripts/gdn_on_chip.py runs it over its own)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import weights_solar_open2 as weights
-    from benchmarks.reference import solar_open2_like as ref
     from oim_tpu.models import generate as gen
 
-    model, cfg = _model(args.tiny, args.layers, "float32")
     first, rest, steps, slots = (48, 24, 8, 3) if args.tiny else (1024, 512, 8, 4)
     seq = 128 if args.tiny else 2048
     params = weights.make_on_device(args.seed, model)
@@ -71,7 +70,6 @@ def agree(args) -> dict:
     tokens = rng.integers(0, cfg.vocab, first + rest + steps)
     tables = np.zeros((slots, seq // PAGE), np.int32)
     tables[1] = 1 + np.arange(seq // PAGE)
-    leaf = cfg.kda.state_leaf
 
     def rounded(pool):
         if not args.round_state:
@@ -129,7 +127,12 @@ def main(argv=None) -> int:
     from oim_tpu.cli.common import init_jax
 
     init_jax("cpu" if args.tiny else "tpu")
-    print("KDA " + json.dumps(agree(args)), flush=True)
+    from benchmarks import weights_solar_open2 as weights
+    from benchmarks.reference import solar_open2_like as ref
+
+    model, cfg = _model(args.tiny, args.layers, "float32")
+    print("KDA " + json.dumps(agree(args, model, cfg, weights, ref,
+                                    cfg.kda.state_leaf)), flush=True)
     return 0
 
 

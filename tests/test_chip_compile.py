@@ -1040,3 +1040,128 @@ def test_the_hybrid_cells_arguments_are_the_parents(topo, as_tpu):
         mem = compiled.memory_analysis()
         assert mem.argument_size_in_bytes == HYBRID_ARGUMENT_BYTES[program]
         assert mem.alias_size_in_bytes == 3_564_011_520
+
+
+# -- a third kind of recurrent state beside a LATENT pool (PR 42) --------------
+# gigachat35-432b-a28b.reasonbatch64 at the cell's own sizes: the leading
+# dense layer and one period of four, one rank of sixteen, 64 slots of
+# GatedDeltaNet state (1.10 GB) beside 65 537 latent pages (1.34 GB) and
+# 9.46 GB of weights.
+
+def gdn_cell(sharding):
+    from benchmarks import common
+    from benchmarks.runners import serve_gdn
+
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "gigachat35-432b-a28b.json"))
+    model = serve_gdn.model_dict(config, "serve")
+    cfg = serve_gdn.program_config(model)
+    sizes = config["serve"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(lambda: {
+        **gen.init_page_pool(cfg, sizes["kv_pool_tokens"] // PAGE + 1, PAGE),
+        **gen.init_state_pool(cfg, sizes["max_batch"])}), sharding)
+    return cfg, params, pool, sizes, model["max_seq"]
+
+
+def gdn_program(chip, program):
+    from oim_tpu.serve.engine import _target_programs
+
+    if ("gdn", program) not in _COMPILED:
+        cfg, params, pool, sizes, seq = gdn_cell(chip)
+        step, prefill = _target_programs(cfg, PAGE, seq)
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(
+                chip, sizes["max_batch"], seq))
+        else:
+            lowered = prefill.lower(
+                params, pool,
+                *prefill_operands(chip, int(program.split("-")[1]), seq),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=chip))  # the slot
+        _COMPILED["gdn", program] = lowered.compile(), cfg, pool, sizes
+    return _COMPILED["gdn", program]
+
+
+def test_gdn_widths_are_the_published_ones(topo):
+    cfg, params, pool, sizes, seq = gdn_cell(
+        SingleDeviceSharding(topo.devices[0]))
+    assert dataclasses.replace(
+        cfg, hybrid_override_pattern="", n_layers=40, first_k_dense_replace=3,
+        full_attention_layers=tuple(range(3, 40, 4)), expert_rank="",
+        vocab=128256, max_seq=262144, head_dim=112) == llama.GIGACHAT35_432B
+    assert cfg.pattern == "GD*EGEGEGE"
+    assert set(pool) == {"kv", "gdn", "gdn_conv"}
+    assert pool["kv"].shape == (1, 65537, 16, 640)
+    assert pool["gdn"].shape == (4, 64, 64, 128, 128) \
+        and pool["gdn"].dtype == jnp.float32
+    assert pool["gdn_conv"].shape == (4, 64, 3 * 16384)
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(pool))
+    assert 9.45e9 < weights < 9.48e9       # 4.73 B parameters
+    assert 2.43e9 < held < 2.45e9          # 1.10 of state + 1.34 of pages
+
+
+def test_gdn_decode_updates_state_and_pool_in_place(topo, as_tpu):
+    """The decode program at 64 slots: the latent Pallas kernel in the one
+    latent layer (64 heads), state and pages aliased to the donated buffers
+    and neither copied, no copy of an expert leaf (2048 is sixteen whole
+    lanes) nor of the head (16 032 rows are not whole lanes), the held
+    share's three products batched (no grouped product at 64 tokens), the
+    period's three expert + GatedDeltaNet blocks one scanned run, and
+    arguments + temporaries inside the chip."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = gdn_program(chip, "step")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert [name for name, _, _ in mosaic_kernels(text)] == ["_latent_kernel"]
+    for name, leaf in pool.items():
+        moved = copies_of(text, leaf.shape)
+        if name == "gdn_conv":  # as kda_conv: moved out once, asynchronously
+            moved = [m for m in moved if m[0] != "copy-start"]
+        assert not moved, (name, moved)
+    assert not copies_of(text, (4, 16, 7168, 2048))
+    assert not copies_of(text, (7168, 16032))
+    assert "ragged-dot" not in text
+    assert text.count(" while(") == 2  # the scanned run, the kernel's spans
+    state = sum(math.prod(pool[k].shape) * pool[k].dtype.itemsize
+                for k in pool)
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("bucket", [1024, 64])
+def test_gdn_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
+    """A prefill slice (the configuration's chunk, and a short last piece):
+    the slot's rows of the state cut out and written back in place, the
+    whole state never copied, one row of logits, the expert leaves whole,
+    the chunked delta rule's [chunks, heads, C, C] decays (no per-channel
+    [.., C, C, d]) inside the temporaries, and arguments + temporaries
+    inside 15.75 GB at 64 slots (what the configuration's max_batch rests
+    on)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = gdn_program(chip, f"prefill-{bucket}")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for leaf in ("gdn", "gdn_conv"):
+        assert not copies_of(text, pool[leaf].shape)
+    assert not copies_of(text, (4, 16, 7168, 2048))
+    assert not materialized(text, [(16, 7168, 2048), (16, 2048, 7168),
+                                   (1, 16, 7168, 2048), (1, 16, 2048, 7168)])
+    assert f"f32[{bucket},{cfg.vocab}]" not in text
+    assert not re.search(r"f32\[\d+,\d+,64,64,64,128\]", text)
+    calls = re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)
+    ladder = moe.capacity_ladder(bucket, cfg.moe)
+    if bucket > 64:
+        assert ladder == (256, 512)
+        assert set(calls) == {(str(8 * bucket), "2048"), (str(8 * bucket), "7168")}
+        for capacity in ladder:  # a bounded rung: three batched products
+            for width in (2048, 7168):
+                assert re.search(rf"= bf16\[16,{capacity},{width}\]\S* "
+                                 r"convolution\(", text)
+    else:             # few tokens: every held expert over every token
+        assert not calls and " conditional(" not in text
+    assert mem.alias_size_in_bytes >= 2.4e9
+    assert mem.temp_size_in_bytes < (2.5 if bucket == 1024 else 0.5) * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -461,13 +461,13 @@ def test_rows_of_no_assignment_never_reach_the_sum(
     def bad(rows):
         return jnp.where(jnp.arange(rows) % 2 == 0, jnp.nan, jnp.inf)
 
-    def planted_grouped(params, rows, group_sizes):
-        y = grouped(params, rows, group_sizes)
+    def planted_grouped(params, rows, group_sizes, *limit):
+        y = grouped(params, rows, group_sizes, *limit)
         live = jnp.arange(y.shape[0]) < jnp.sum(group_sizes)
         return jnp.where(live[:, None], y, bad(y.shape[0])[:, None])
 
-    def planted_batched(leaves, x):
-        y = batched(leaves, x)
+    def planted_batched(leaves, x, *limit):
+        y = batched(leaves, x, *limit)
         live = jnp.arange(y.shape[1]) < counts[:, None]
         return jnp.where(live[..., None], y, bad(y.shape[1])[None, :, None])
 

@@ -16,7 +16,7 @@ import pytest
 from benchmarks import common
 from oim_tpu.models import generate as gen
 from oim_tpu.models import llama, moe
-from oim_tpu.ops import kda, ssm
+from oim_tpu.ops import gdn, kda, ssm
 from oim_tpu.serve import engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -44,6 +44,11 @@ FAMILIES = {
                                         "step": EXPERTS | {"ssm_step"}}),
     "tiny_kda": (llama.tiny_kda, {"prefill": EXPERTS | {"kda_scan"},
                                   "step": EXPERTS | {"kda_step"}}),
+    # the GatedDeltaNet mixer's own names stand INSIDE the delta-rule
+    # family's (``inside`` below); its latent layer has the latent kernels'
+    "tiny_gdn": (llama.tiny_gdn,
+                 {"prefill": EXPERTS | {"kda_scan", "mla_prefill"},
+                  "step": EXPERTS | {"kda_step", "mla_decode"}}),
 }
 CASES = [(family, program) for family, (_, programs) in FAMILIES.items()
          for program in programs]
@@ -141,6 +146,16 @@ def test_program_carries_the_vocabulary(family, program):
     assert set(reader.REWRITTEN.values()) == {"moe_gmm"}
     for kernel in want & {"mla_decode", "mla_prefill"}:
         assert inside(kernel, "blk_attn")
+    if family == "tiny_gdn":
+        # the mixer's own scope under the vocabulary's: the benchmark's
+        # reader charges the innermost name it KNOWS (kda_*), the mixer's
+        # roofline reads its own (gdn_*), and neither name stands alone
+        own = "gdn_scan" if jitted == "prefill" else "gdn_step"
+        family_scope = own.replace("gdn", "kda")
+        assert inside(own, family_scope)
+        assert all(family_scope in path for path in paths if own in path)
+        assert reader.classify("/".join(
+            next(p for p in paths if own in p))) == family_scope
     # nothing of the vocabulary that the family should not have: a
     # recurrent mixer's name in a model without one would be a wrong ``with``
     assert found & set(reader.VOCABULARY) == want
@@ -150,6 +165,9 @@ def test_the_reader_knows_every_scope_the_program_sets():
     """``scope_share.VOCABULARY`` is the benchmark's copy of the names: one
     name more or less in the program's sources fails here."""
     named = set(ssm.SCOPES) | set(kda.SCOPES)
+    # a mixer's OWN names inside a vocabulary name ("kda_step/gdn_step") are
+    # not the vocabulary's: the outer name is what the reader charges
+    assert [s.split("/")[0] for s in gdn.SCOPES] == list(kda.SCOPES)
     for folder, _, files in os.walk(os.path.join(REPO, "oim_tpu")):
         for name in files:
             if name.endswith(".py"):
