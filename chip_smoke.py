@@ -550,6 +550,7 @@ def phase_serve(run: Run, blob: str, n_layers: int,
              new_tokens=[len(a["tokens"]) for a in answers],
              prompt_lens=list(run.sizes["prompt_lens"]),
              decode_attention=serving.get("decode_attention"),
+             prefill_attention=serving.get("prefill_attention"),
              bytes_in_use_after_load=memory_of(serving, "bytes_in_use"),
              peak_bytes_in_use=memory_of(stopped))
 
@@ -648,6 +649,7 @@ def phase_shard(run: Run, blob: str) -> None:
         run.emit(name, t, shard=shard, n_layers=s["n_layers"],
                  tokens=results[shard],
                  decode_attention=serving.get("decode_attention"),
+                 prefill_attention=serving.get("prefill_attention"),
                  bytes_in_use_after_load=memory_of(serving, "bytes_in_use"),
                  peak_bytes_in_use=memory_of(stopped))
     t = time.monotonic()

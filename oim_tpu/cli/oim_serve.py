@@ -540,7 +540,8 @@ def main(argv: list[str] | None = None) -> int:
         "oim-serve serving", endpoint=args.endpoint, addr=server.addr,
         model=args.model, n_layers=mcfg.n_layers, max_batch=args.max_batch,
         max_seq=args.max_seq, shard=args.shard,
-        decode_attention=engine.decode_attention, **device_memory(),
+        decode_attention=engine.decode_attention,
+        prefill_attention=engine.prefill_attention, **device_memory(),
     )
 
     registration = None

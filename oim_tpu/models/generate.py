@@ -298,7 +298,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
 # whole batch one token with per-row positions, verify a draft's
 # candidates — are ONE layer loop (``_forward_paged``) that carries the
 # pool, scatters this call's K/V through the table in place, and attends
-# through ``ops.paged_attention.paged_attention``. That function has two
+# through ``ops.paged_attention.paged_attention``. That function has three
 # paths and one rule (``_paged_plan``: shapes and backend only, no
 # option):
 #
@@ -311,21 +311,30 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
 #   probability -> 0 * finite = 0), so the attention sums are
 #   term-for-term identical: served tokens are BYTE-IDENTICAL to solo
 #   generate(). Tier-1 (CPU) runs and guards this path: tests/test_spec.py,
-#   the engine's paged-vs-solo identity tests.
-# * the KERNEL path, a decode step (T == 1) on a TPU when head_dim and
-#   the page fit the tiling: a Pallas kernel reads each row's live pages
-#   where they lie, with an online softmax (bf16 K/V and probabilities,
-#   f32 scores, statistics and accumulators, every live position
-#   attended; only the order of the f32 sums differs from the reference).
-#   It is NOT byte-identical to solo generate(): it is held to the
-#   benchmark's limits against the float32 reference on the chip
-#   (benchmarks/configs/*.json) and to the reference path in interpret
-#   mode (tests/test_ops.py); tests/test_chip_compile.py holds the
-#   compiled programs to "no copy, restack or gather of the pool".
+#   the engine's paged-vs-solo identity tests. On a TPU it is what is left
+#   for the shapes the kernels do not take: the speculative verify's
+#   T = K + 1 rows a slot, a head_dim or a page off the tiling.
+# * the two KERNEL paths on a TPU when head_dim and the page fit the
+#   tiling: a decode step (T == 1) and a prompt slice (T whole query
+#   blocks: every prefill bucket from 16 rows). Each is a Pallas kernel that
+#   reads a row's live pages where they lie, with an online softmax (bf16
+#   K/V and probabilities, f32 scores, statistics and accumulators, every
+#   live position attended; only the order of the f32 sums differs from
+#   the reference). The slice's kernel reads this call's own keys from the
+#   pool too (the scatter lands first), walks no further than a query
+#   block's last real position (``n_tokens``: pad rows cost nothing and
+#   their results are discarded) and gathers, copies or re-lays nothing of
+#   the pool's or the table's size. Neither is byte-identical to solo
+#   generate(): they are held to the benchmark's limits against the
+#   float32 reference on the chip (benchmarks/configs/*.json) and to the
+#   reference path in interpret mode (tests/test_ops.py);
+#   tests/test_chip_compile.py holds the compiled programs to "no copy,
+#   restack or gather of the pool".
 #
-# The program logs ``attention dispatch kernel=pallas_paged|jnp_gather``
-# once per trace, and ``ServeEngine.stats()["decode_attention"]`` shows
-# the same word on the replica's serve/<id> row.
+# The program logs ``attention dispatch kernel=pallas_paged|
+# pallas_paged_prefill|jnp_gather`` once per trace, and
+# ``ServeEngine.stats()["decode_attention"]`` / ``["prefill_attention"]``
+# show the same words on the replica's serve/<id> row.
 
 
 def init_page_pool(cfg: Config, n_pages: int, page_tokens: int):
@@ -456,7 +465,7 @@ def _forward_paged(params, tokens, pool, tables, pos, phys, off,
             with jax.named_scope("blk_kv_write"):
                 pk = pool["k"].at[l, phys, off].set(k, mode="drop")
                 pv = pool["v"].at[l, phys, off].set(v, mode="drop")
-            return (paged_attention(q, pk, pv, l, tables, pos),
+            return (paged_attention(q, pk, pv, l, tables, pos, n_tokens),
                     {**pool, "k": pk, "v": pv})
         return attend
 
@@ -581,8 +590,8 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     whole prompt as ``tokens``; the prefix-cache hit passes only the
     UNCACHED TAIL with ``start`` = the cached depth as a traced scalar —
     the cached prefix K/V is never copied anywhere, the slot's page
-    table simply references the store's pages and the gather reads them
-    in place (zero-copy sharing; K/V at a prompt position is a pure
+    table simply references the store's pages and the attention reads
+    them in place (zero-copy sharing; K/V at a prompt position is a pure
     function of the tokens at and before it — causal attention,
     absolute-position RoPE from 0 — so shared bytes are exactly what a
     full prefill would recompute). Because ``start`` is traced and the

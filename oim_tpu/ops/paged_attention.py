@@ -1,13 +1,13 @@
-"""Attention over the serving engine's page pool: a Pallas decode kernel
-that reads live pages where they lie (TPU) + the gather reference
-(everywhere).
+"""Attention over the serving engine's page pool: two Pallas kernels that
+read live pages where they lie (TPU), a decode step's and a prompt
+slice's, + the gather reference (everywhere).
 
 The pool here is the GQA one, {"k","v"} [L, n_pages, page_tokens, kv_heads,
 head_dim] (models/generate.py ``init_page_pool``; a latent-attention model's
 one-leaf pool has its own decode kernel and rule in ``ops/latent_attention.py``);
 logical position s of row b lives at pool[l, tables[b, s // page], s % page].
-Both paths take the WHOLE pool and the layer index, never a per-layer slice:
-a slice of a carried buffer is a copy of it.
+Every path takes the WHOLE pool and the layer index, never a per-layer
+slice: a slice of a carried buffer is a copy of it.
 
 - ``gather_attention``: materialize each row's logical [S] cache through
   its table, then ``cache_attention`` — the same function the solo dense
@@ -18,6 +18,13 @@ a slice of a carried buffer is a copy of it.
   each row's table up to its position only, DMAs those slabs into a
   double-buffered VMEM block and folds the block into an online softmax.
   No [B, S, kvh, hd] gather and no [B, kvh, g, 1, S] scores exist.
+- ``_paged_prefill``: T query rows a row, in blocks of query positions.
+  The same page walk, up to the query block's last REAL position and no
+  further (causal work only; a block of nothing but pad rows reads
+  nothing); a kv head's rows of a key block are taken apart from the
+  others' in VMEM and multiplied against that head's g query heads' rows
+  alone. No gathered table, no re-layout of the pool, no [.., T, S]
+  scores outside VMEM.
 
 ``paged_attention`` picks between them by shapes and backend alone
 (``_paged_plan``), as ``attention._flash_plan`` does for the flash kernels.
@@ -88,6 +95,29 @@ def gather_attention(q, pk, pv, layer, tables, pos):
 # ---------------------------------------------------------------- pallas ----
 
 
+def _page_walk(tables_ref, layer, rows, k_hbm, v_hbm, kbuf, vbuf, sems):
+    """The page walk both kernels have: -> ``walk(entry, n_live, slot, act)``,
+    which does ``act`` on the K and V copy of each of the first ``n_live``
+    pages of a key block (page j's table entry is ``tables_ref[entry(j)]``)
+    into block ``slot`` of the two buffers; the same descriptors start a
+    copy and wait for it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def walk(entry, n_live, slot, act):
+        def page_body(j, _):
+            pid = tables_ref[entry(j)]
+            dst = pl.ds(pl.multiple_of(j * rows, rows), rows)
+            act(pltpu.make_async_copy(
+                k_hbm.at[layer, pid], kbuf.at[slot, dst], sems.at[0, slot]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[layer, pid], vbuf.at[slot, dst], sems.at[1, slot]))
+
+        lax.fori_loop(0, n_live, page_body, None)
+
+    return walk
+
+
 def _paged_kernel(layer_ref, tables_ref, len_ref, next_ref,
                   q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
                   scale, pages, rows, kvh, n_blocks):
@@ -96,30 +126,20 @@ def _paged_kernel(layer_ref, tables_ref, len_ref, next_ref,
     this one is computed. A block is ``pages`` table entries; of those
     only the pages at or under the row's position are fetched."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, _ = q_ref.shape
     R = pages * rows  # K/V rows of a block: (token, kv head), head minor
     block_tokens = R // kvh
     page_tokens = rows // kvh
-    layer = layer_ref[0]
+    walk = _page_walk(tables_ref, layer_ref[0], rows, k_hbm, v_hbm,
+                      kbuf, vbuf, sems)
 
     def for_live_pages(b, i, slot, act):
-        """``act`` on the K and V copy of each page of block i of row b
-        that holds a position at or under the row's: the same descriptors
-        start a copy and wait for it."""
+        """``act`` on the copies of each page of block i of row b that
+        holds a position at or under the row's."""
         n_live = jnp.clip(
             pl.cdiv(len_ref[b] - i * block_tokens, page_tokens), 0, pages)
-
-        def page_body(j, _):
-            pid = tables_ref[b * n_blocks + i * pages + j]
-            dst = pl.ds(pl.multiple_of(j * rows, rows), rows)
-            act(pltpu.make_async_copy(
-                k_hbm.at[layer, pid], kbuf.at[slot, dst], sems.at[0, slot]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[layer, pid], vbuf.at[slot, dst], sems.at[1, slot]))
-
-        lax.fori_loop(0, n_live, page_body, None)
+        walk(lambda j: b * n_blocks + i * pages + j, n_live, slot, act)
 
     def start(b, i, slot):
         for_live_pages(b, i, slot, lambda copy: copy.start())
@@ -234,41 +254,304 @@ def _paged_decode(q, pk, pv, layer, tables, pos, pages: int,
       q, pk.reshape(L, n_pages, rows, hd), pv.reshape(L, n_pages, rows, hd))
 
 
-def _paged_plan(q, pk, tables) -> int | None:
-    """Pages a kernel block when the Pallas decode kernel applies to these
-    shapes (arrays or ShapeDtypeStructs) on this backend, else None — THE
-    dispatch rule. It reads shapes and the backend only."""
+# ---------------------------------------------------- pallas: prefill ----
+
+# Query rows of one kv head's product (g * block_q) and of a block over all
+# heads (H * block_q, what the statistics and the accumulator take of VMEM),
+# and K/V positions a key block of the prefill kernel: a query block of 512
+# positions at 32 heads over 8, 256 at 64 over 8, 128 at 32 over 2. On a
+# v5e, ms a layer for one 1024-row slice at depth 0 / 2048 / 7168 (bf16,
+# page 16, scripts/paged_prefill_on_chip.py; PERF.md section 6, PR 43),
+# query block x key block:
+#
+#   32 heads over 2 (gather 4.82 at any depth)    64 heads over 8 (gather 9.52)
+#   128 x 128  0.44 / 1.54 / 4.28                 0.88 / 3.39 / 9.64
+#   128 x 256  0.35 / 1.06 / 2.84                 0.80 / 2.75 / 7.62
+#   128 x 512  0.29 / 0.71 / 1.76                 0.59 / 1.59 / 4.11
+#   256 x 256  0.36 / 1.06 / 2.86                 0.61 / 1.98 / 5.38
+#   256 x 512  0.27 / 0.64 / 1.55                 0.48 / 1.28 / 3.26
+#   512 x 512  0.31 / 0.63 / 1.46                 (16 384 rows x 2: not tried)
+#
+# 32 heads over 8: a 4096-row bucket from 0 reads 2.35 at 512 x 512, 2.85 at
+# 256 x 512, 4.72 at 256 x 256 (gather 9.12); a 512-row one at depth 2048
+# 0.31 / 0.40 / 0.66 (gather 1.22); a 32-row one 0.05-0.07 (gather 0.08-0.09:
+# small buckets lose nothing on the kernel, so the rule has no lower bound
+# but a sublane tile). A block's fixed cost is its statistics ([rows, 1]
+# float32: a sublane a row) and the accumulator's rescale, both by the query
+# rows and not by the keys: a key block of 512 halves their share, and the
+# larger query block reads the keys fewer times. A product of 8192 rows (512
+# positions at 16 heads a kv head) gains 2-6 % on one of 2048 and takes five
+# times as long to compile (17 s a kernel here against 3.5, six kernels a
+# program): a replica's start pays that, so the product stops at 2048 rows.
+# A key block of 1024 reads 0.26 / 0.54 where 128 x 512 reads 0.29 / 0.71
+# (depth 0 / 2048; 512 x 1024 at 32 heads over 8: 0.25 / 0.50 against 0.26 /
+# 0.66): not taken yet, it was read alone and not in a cell (PERF.md
+# section 7).
+PREFILL_PRODUCT_ROWS = 2048
+PREFILL_HEAD_ROWS = 16384
+PREFILL_BLOCK_TOKENS = 512
+# VMEM the compiler grants a kernel unasked (a v5e has 128 MB), and what a
+# call adds to its own count of its blocks for the compiler's temporaries
+# (the f32 scores and probabilities of one head's product).
+VMEM_SCOPE = 16 << 20
+VMEM_ROOM = 12 << 20
+
+
+def _head_rows(buf, slot, h, kvh: int, n: int):
+    """Kv head ``h``'s ``n`` rows of block ``slot`` of a K/V buffer whose
+    rows are (token, kv head), head minor: every kvh-th row from row h. A
+    strided sublane read where a row is a 32-bit sublane; a 16-bit buffer
+    packs rows 2s and 2s + 1 into the halves of sublane s (the even row
+    low), so there a head is one half of every (kvh / 2)-th word, widened
+    to float32 in place (a bfloat16 IS the high half of its float32) and
+    rounded back with nothing lost."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if kvh == 1:
+        return buf[slot]
+    if buf.dtype.itemsize == 4:
+        return buf[slot, pl.ds(h, n, stride=kvh), :]
+    words = buf.bitcast(jnp.uint32)  # [2, R / 2, hd]
+    w = (words[slot] if kvh == 2
+         else words[slot, pl.ds(h // 2, n, stride=kvh // 2), :])
+    w = jnp.where(h % 2 == 1, w & jnp.uint32(0xFFFF0000), w << 16)
+    return pltpu.bitcast(w, jnp.float32).astype(buf.dtype)
+
+
+def _prefill_kernel(layer_ref, tables_ref, pos_ref, len_ref,
+                    q_ref, k_hbm, v_hbm, o_ref,
+                    kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
+                    scale, pages, rows, kvh, n_blocks, block_q):
+    """One block of ``block_q`` query positions of one row a grid step,
+    every head of it: the row's key blocks in turn up to the block's last
+    real position, the next in flight while this one is computed, each kv
+    head's rows of a block against its g query heads' rows."""
+    from jax.experimental import pallas as pl
+
+    b, i = pl.program_id(0), pl.program_id(1)
+    n_q = q_ref.shape[1]  # g * block_q query rows a kv head, (head, token)
+    R = pages * rows  # K/V rows of a block: (token, kv head), head minor
+    block_tokens = R // kvh
+    page_tokens = rows // kvh
+    walk = _page_walk(tables_ref, layer_ref[0], rows, k_hbm, v_hbm,
+                      kbuf, vbuf, sems)
+    p0 = pos_ref[b] + i * block_q  # the block's first position
+    n_real = jnp.clip(len_ref[b] - i * block_q, 0, block_q)
+    # Positions the block's rows may read: under its last real row's, and
+    # inside the table.
+    n_keys = jnp.minimum(p0 + n_real, n_blocks * page_tokens)
+
+    def for_live_pages(j, slot, act):
+        """``act`` on the copies of each page of key block j that holds a
+        position under ``n_keys``."""
+        n_live = jnp.clip(
+            pl.cdiv(n_keys - j * block_tokens, page_tokens), 0, pages)
+        walk(lambda e: b * n_blocks + j * pages + e, n_live, slot, act)
+
+    @pl.when(n_real == 0)  # nothing but pad rows: no page read, zeros out
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_real > 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        n_kb = pl.cdiv(n_keys, block_tokens)
+        for_live_pages(0, 0, lambda copy: copy.start())
+
+        # Row r of a head's query rows is token r % block_q. A pad row
+        # reads what the last real row reads: finite, and discarded.
+        q_tok = lax.rem(
+            lax.broadcasted_iota(jnp.int32, (n_q, 1), 0), block_q)
+        q_last = jnp.minimum(p0 + q_tok, n_keys - 1)
+        k_col = lax.broadcasted_iota(jnp.int32, (1, block_tokens), 1)
+        k_row = lax.broadcasted_iota(jnp.int32, (block_tokens, 1), 0)
+
+        def fold(j, masked: bool):
+            slot = lax.rem(j, 2)
+
+            @pl.when(j + 1 < n_kb)
+            def _():
+                for_live_pages(j + 1, 1 - slot, lambda copy: copy.start())
+
+            for_live_pages(j, slot, lambda copy: copy.wait())
+            first = j * block_tokens  # the block's first position
+
+            def head(h, _):
+                k = _head_rows(kbuf, slot, h, kvh, block_tokens)
+                v = _head_rows(vbuf, slot, h, kvh, block_tokens)
+                s = lax.dot_general(
+                    q_ref[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # [n_q, bt]
+                if masked:
+                    s = jnp.where(first + k_col <= q_last, s, NEG_INF)
+                    # Positions past the last real row's hold stale bytes
+                    # (or whatever VMEM held where no page was fetched):
+                    # zero them, so that 0 x NaN cannot reach the sum.
+                    v = jnp.where(first + k_row < n_keys, v,
+                                  jnp.zeros_like(v))
+                m_prev = m_ref[h]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True))
+                # Position 0 is under every row's: m_new is finite from
+                # the first block on, and a masked score's weight exact 0.
+                p = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[h] = l_ref[h] * corr + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * corr + lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
+
+            # A loop, not kvh copies of the body: the compiler unrolls a
+            # product of thousands of rows as it is, and a program's
+            # compile is part of a replica's start.
+            lax.fori_loop(0, kvh, head, None)
+
+        # Key blocks wholly at or under the block's first position need no
+        # mask; those that straddle the diagonal or the last real row's
+        # position do.
+        n_whole = jnp.minimum((p0 + 1) // block_tokens, n_kb)
+        lax.fori_loop(0, n_whole, lambda j, _: fold(j, False), None)
+        lax.fori_loop(n_whole, n_kb, lambda j, _: fold(j, True), None)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "pages", "interpret"))
+def _paged_prefill(q, pk, pv, layer, tables, pos, n_tokens, block_q: int,
+                   pages: int, interpret: bool = False):
+    """q [B, T, H, hd] at positions ``pos`` [B] + t over pool[layer]
+    through ``tables`` [B, nb] -> [B, T, H, hd]; row b's first
+    ``n_tokens`` [B] query rows are real, a query block of nothing but the
+    others reads nothing and gets zeros. Jitted, so that a program whose
+    layers are not one scan (a hybrid's six attention layers) traces and
+    lowers the kernel once and not a layer: a replica's start pays every
+    trace, compile cache or not (2 s a bucket here, 3-4 on the chip's
+    host, PR 43)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, H, hd = q.shape
+    L, n_pages, page, kvh, _ = pk.shape
+    nb = tables.shape[1]
+    rows, g, n_q = page * kvh, H // kvh, T // block_q
+    # A block's query rows stand head-major, [kvh, g * block_q, hd]: one
+    # product a kv head, no row of another head's in it.
+    qt = q.reshape(B, n_q, block_q, kvh, g, hd).transpose(0, 1, 3, 4, 2, 5)
+    qt = qt.reshape(B, n_q, kvh, g * block_q, hd)
+    block = pl.BlockSpec((None, None, kvh, g * block_q, hd),
+                         lambda b, i, *_: (b, i, 0, 0, 0))
+    kernel = functools.partial(
+        _prefill_kernel, scale=hd ** -0.5, pages=pages, rows=rows, kvh=kvh,
+        n_blocks=nb, block_q=block_q)
+    # Query and output blocks twice (the pipeline's), the three statistics
+    # ([.., 1] float32 takes whole lanes), the two K/V blocks twice.
+    need = (H * block_q * (4 * hd * q.dtype.itemsize + 4 * (hd + 256))
+            + 4 * pages * rows * hd * pk.dtype.itemsize)
+    out = pl.pallas_call(
+        kernel,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(need + VMEM_ROOM, VMEM_SCOPE)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, n_q),
+            in_specs=[
+                block,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * rows, hd), pk.dtype),
+                pltpu.VMEM((2, pages * rows, hd), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kvh, g * block_q, 1), jnp.float32),
+                pltpu.VMEM((kvh, g * block_q, 1), jnp.float32),
+                pltpu.VMEM((kvh, g * block_q, hd), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
+      n_tokens.astype(jnp.int32),
+      qt, pk.reshape(L, n_pages, rows, hd), pv.reshape(L, n_pages, rows, hd))
+    out = out.reshape(B, n_q, kvh, g, block_q, hd)
+    return out.transpose(0, 1, 4, 2, 3, 5).reshape(B, T, H, hd)
+
+
+# ------------------------------------------------------------- dispatch ----
+
+
+def _paged_plan(q, pk, tables) -> tuple[int, int] | None:
+    """(query positions a block, pages a key block) when a Pallas kernel
+    applies to these shapes (arrays or ShapeDtypeStructs) on this backend,
+    else None — THE dispatch rule. It reads shapes and the backend only.
+    One query position a block is the decode kernel."""
     T, H, hd = q.shape[1:]
     page, kvh = pk.shape[2], pk.shape[3]
     nb = tables.shape[1]
     # One page viewed [page * kvh, hd] must be whole sublane tiles of the
     # cache's dtype (16 rows of bf16, 8 of f32).
-    tile = 32 // jnp.dtype(pk.dtype).itemsize
-    if (jax.default_backend() != "tpu" or T != 1 or hd % 128 or H % kvh
+    itemsize = jnp.dtype(pk.dtype).itemsize
+    tile = 32 // itemsize
+    if (jax.default_backend() != "tpu" or hd % 128 or H % kvh
             or (page * kvh) % tile):
         return None
-    pages = max(BLOCK_TOKENS // page, 1)
+    if T == 1:
+        block_q, block_tokens = 1, BLOCK_TOKENS
+    else:
+        most = min(PREFILL_PRODUCT_ROWS // (H // kvh), PREFILL_HEAD_ROWS // H,
+                   T)
+        # The largest power of two under the bounds: a group of 7 heads
+        # takes 256 positions, not 292 that no bucket is a multiple of.
+        block_q, block_tokens = (1 << most.bit_length() - 1 if most else 0,
+                                 PREFILL_BLOCK_TOKENS)
+        # Whole query blocks, a head's rows of one whole sublane tiles of
+        # q; a 16-bit pool's heads come apart by the halves of a word.
+        if (not block_q or T % block_q
+                or block_q % (32 // jnp.dtype(q.dtype).itemsize)
+                or (itemsize == 2 and kvh > 1 and kvh % 2)):
+            return None
+    pages = max(block_tokens // page, 1)
     while nb % pages:  # a block never runs past the table
         pages //= 2
-    return pages
+    return block_q, pages
 
 
 def kernel_name(q, pk, tables) -> str:
     """The word ``paged_attention`` logs for these shapes, and the engine
     shows in its stats: which implementation a program takes."""
-    return ("jnp_gather" if _paged_plan(q, pk, tables) is None
-            else "pallas_paged")
+    plan = _paged_plan(q, pk, tables)
+    if plan is None:
+        return "jnp_gather"
+    return "pallas_paged" if plan[0] == 1 else "pallas_paged_prefill"
 
 
-def paged_attention(q, pk, pv, layer, tables, pos):
+def paged_attention(q, pk, pv, layer, tables, pos, n_tokens=None):
     """q [B,T,H,hd] at positions pos+t (``pos`` scalar or [B]) over
-    pool[layer] through ``tables`` [B, n_blocks] -> [B,T,H,hd]. Dispatch:
-    the Pallas kernel for a decode step on TPU, the gather reference
-    otherwise; one log line per trace says which."""
-    pages = _paged_plan(q, pk, tables)
-    _log_dispatch(kernel_name(q, pk, tables), q, pk, pages_per_block=pages)
-    if pages is None:
+    pool[layer] through ``tables`` [B, n_blocks] -> [B,T,H,hd]; of a row's
+    T query rows the first ``n_tokens`` (scalar or [B]; None: all) are
+    real, the others' results are the caller's to discard. Dispatch: a
+    Pallas kernel on a TPU (the decode kernel for T == 1, the prefill
+    kernel for whole query blocks), the gather reference otherwise; one
+    log line per trace says which."""
+    plan = _paged_plan(q, pk, tables)
+    block_q, pages = plan or (None, None)
+    _log_dispatch(kernel_name(q, pk, tables), q, pk, pages_per_block=pages,
+                  block_q=block_q)
+    if plan is None:
         return gather_attention(q, pk, pv, layer, tables, pos)
     pos_b = jnp.broadcast_to(jnp.asarray(pos), q.shape[:1])
-    return _paged_decode(q[:, 0], pk, pv, layer, tables, pos_b,
-                         pages)[:, None]
+    if block_q == 1:
+        return _paged_decode(q[:, 0], pk, pv, layer, tables, pos_b,
+                             pages)[:, None]
+    n_b = jnp.broadcast_to(
+        jnp.asarray(q.shape[1] if n_tokens is None else n_tokens),
+        q.shape[:1])
+    return _paged_prefill(q, pk, pv, layer, tables, pos_b, n_b, block_q,
+                          pages)

@@ -675,32 +675,34 @@ class ServeEngine:
         # already compiled.
         self._step, self._prefill = _target_programs(
             cfg, page, max_seq, self.shard)
-        # Which attention the decode program takes — the dispatch rule
-        # of ops/paged_attention.py (ops/latent_attention.py for a latent
-        # cache) on this engine's (member-local) shapes, the word the
-        # program logs when it is traced. stats() carries it, so a
-        # replica that silently missed the kernel can be told from its
+        # Which attention the decode program and the largest prefill
+        # program (the chunk, or the --max-seq bucket) take — the dispatch
+        # rule of ops/paged_attention.py (ops/latent_attention.py for a
+        # latent cache) on this engine's (member-local) shapes, the word
+        # the program logs when it is traced. stats() carries both, so a
+        # replica that silently missed a kernel can be told from its
         # serve/<id> row.
         from oim_tpu.ops import latent_attention, paged_attention
 
         self.cache_kind = "latent" if cfg.kv_lora_rank else "gqa"
-        tables = jax.ShapeDtypeStruct((max_batch, self.n_blocks), np.int32)
         if cfg.kv_lora_rank:
             d = cfg.latent
-            self.decode_attention = latent_attention.kernel_name(
-                jax.ShapeDtypeStruct(
-                    (max_batch, 1, d.heads, d.nope + d.rope), cfg.dtype),
-                self._cache["kv"], tables, d)
+            name = functools.partial(
+                latent_attention.kernel_name, pool=self._cache["kv"], d=d)
+            heads = (d.heads, d.nope + d.rope)
         else:
             lcfg = gen.shard_config(cfg, self.shard)
             pool_k = self._cache["k"]
-            self.decode_attention = paged_attention.kernel_name(
-                jax.ShapeDtypeStruct(
-                    (max_batch, 1, lcfg.n_heads, cfg.head_dim), cfg.dtype),
-                jax.ShapeDtypeStruct(
+            name = functools.partial(
+                paged_attention.kernel_name, pk=jax.ShapeDtypeStruct(
                     pool_k.shape[:3] + (lcfg.n_kv_heads, cfg.head_dim),
-                    pool_k.dtype),
-                tables)
+                    pool_k.dtype))
+            heads = (lcfg.n_heads, cfg.head_dim)
+        self.decode_attention, self.prefill_attention = (
+            name(jax.ShapeDtypeStruct((rows, t) + heads, cfg.dtype),
+                 tables=jax.ShapeDtypeStruct((rows, self.n_blocks), np.int32))
+            for rows, t in ((max_batch, 1), (1, self._bucket(
+                self.prefill_chunk or max_seq))))
         # Expert load of a dropless expert model's decode steps, summed:
         # [steps counted, experts that got a row (mean over the expert
         # layers), rows of the fullest expert over the mean]. stats()
@@ -936,7 +938,9 @@ class ServeEngine:
             from_context().info(
                 "decode rounds dispatched", **self._rounds,
                 ahead_share=round(self._rounds["ahead"] / rounds, 4),
-                overrun_rows=self._overrun_rows)
+                overrun_rows=self._overrun_rows,
+                decode_attention=self.decode_attention,
+                prefill_attention=self.prefill_attention)
         if self.cfg.n_experts:
             rungs = self._rung_calls()
             calls = sum(rungs.values())
@@ -1002,9 +1006,10 @@ class ServeEngine:
                 # routers ignore it, new routers split requests across
                 # tiers (missing/malformed reads back as "mixed").
                 "role": self.role,
-                # A build fact, not a load: "pallas_paged" or
-                # "jnp_gather" (see __init__).
+                # Build facts, not loads: "pallas_paged" /
+                # "pallas_paged_prefill" or "jnp_gather" (see __init__).
                 "decode_attention": self.decode_attention,
+                "prefill_attention": self.prefill_attention,
                 # Pages in use, and the kind of cache they hold ("gqa": K
                 # and V by head; "latent": one vector a position). Flat
                 # scalars: readers of the serve/<id> row take no nesting.

@@ -337,15 +337,63 @@ def test_cell_decode_reads_live_pages_in_place(topo, as_tpu, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def scores_of(text: str, t: int, seq: int) -> list:
+    """float32 arrays [.., t, seq] of an optimized HLO with more than one
+    row of leading dims (a head's, a group's): attention scores over a
+    whole table. [1, T, D] activations of a model whose width is ``seq``
+    are none."""
+    found = set()
+    for dims in re.findall(rf"f32\[([\d,]+),{t},{seq}\]", text):
+        if math.prod(int(d) for d in dims.split(",")) > 1:
+            found.add(f"f32[{dims},{t},{seq}]")
+    return sorted(found)
+
+
+def test_scores_of_reads_the_hlo():
+    text = """
+  %fusion.1 = f32[1,2,16,1024,8192]{4,3,2,1,0} fusion(%a), kind=kOutput
+  %fusion.2 = f32[64,1024,8192]{2,1,0} fusion(%a), kind=kOutput
+  %fusion.3 = f32[1,1024,8192]{2,1,0} fusion(%a), kind=kLoop
+  %fusion.4 = bf16[64,1024,8192]{2,1,0} fusion(%a), kind=kLoop
+  %fusion.5 = f32[1024,8192]{1,0} fusion(%a), kind=kLoop"""
+    assert scores_of(text, 1024, 8192) == [
+        "f32[1,2,16,1024,8192]", "f32[64,1024,8192]"]
+
+
+def reads_pages_in_place(text: str, cfg, pool, bucket: int, seq: int,
+                         calls: int) -> bool:
+    """Whether a prefill program attends through the Pallas prefill kernel,
+    ``calls`` times (once a layer loop, or a layer), and nothing copies,
+    restacks, gathers or re-lays an array of the pool's shape or a slot's
+    gathered [1, S, kvh, hd] view, and no [.., T, S] float32 scores exist."""
+    tail = (cfg.n_kv_heads, cfg.head_dim)
+    return ([name for name, _, _ in mosaic_kernels(text)]
+            == ["_prefill_kernel"] * calls
+            and not moves_of(text, pool["k"].shape)
+            and not moves_of(text, (1, seq) + tail, tail)
+            and not scores_of(text, bucket, seq))
+
+
+# Temporaries of each cell's commonest prefill bucket, bytes: the parent
+# held 521.7 MB (chat: the gathered view and f32[1, 8, 4, 1024, 4096]
+# scores) and 129.8 MB (batch); what is left, 1.3 and 117.9 MB, is the
+# bucket's activations and the expert dispatch.
+CELL_PREFILL_TEMP = {"chat": 64 << 20, "batch": 128 << 20}
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_cell_prefill_carries_the_pool(topo, as_tpu, cell):
-    """The cell's commonest prefill bucket: the pool is scattered into in
-    place (its gather of ONE slot's table stays until flash prefill over
-    pages, ROADMAP S2)."""
+    """The cell's commonest prefill bucket holds what the decode program
+    holds (PR 43): the pool is scattered into in place and attended where
+    it lies by the Pallas prefill kernel; no gather of the slot's table,
+    no copy or re-layout of the pool, no f32 scores over all S."""
     chip = SingleDeviceSharding(topo.devices[0])
-    compiled, _, pool, _, _ = serving_program(
+    compiled, cfg, pool, _, seq = serving_program(
         chip, cell, f"prefill-{CELLS[cell][1]}")
-    assert not moves_of(compiled.as_text(), pool["k"].shape)
+    assert reads_pages_in_place(
+        compiled.as_text(), cfg, pool, CELLS[cell][1], seq, calls=1)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < CELL_PREFILL_TEMP[cell]
 
 
 def test_verify_carries_the_pool(topo, as_tpu):
@@ -410,13 +458,12 @@ def test_train_step_fsdp_four_chips(topo, as_tpu):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decode_step_shard4(topo, as_tpu, monkeypatch):
-    """One --shard 4 decode step on a mesh of the described devices, at
-    the chat cell's 32 slots x 4096 positions (the smoke's pool of a
-    few MB the compiler parks in VMEM and back, which is no finding). The
-    member's
-    view (kv heads 8 / 4 = 2) takes the kernel inside the shard_map and
-    moves neither its slice of the pool nor a gathered view."""
+def shard4_program(topo, monkeypatch, program: str):
+    """(optimized HLO, cfg, member-local cfg, n_pages, b, seq) of one
+    --shard 4 serving program on a mesh of the described devices, at the
+    chat cell's 32 slots x 4096 positions (the smoke's pool of a few MB the
+    compiler parks in VMEM and back, which is no finding): ``step``, or
+    ``prefill-<bucket>``."""
     from jax.sharding import PartitionSpec as P
 
     from oim_tpu.serve import shard as shardlib
@@ -429,7 +476,7 @@ def test_decode_step_shard4(topo, as_tpu, monkeypatch):
     n_pages = b * seq // PAGE + 1
     _target_programs.cache_clear()
     try:
-        step, _ = _target_programs(cfg, PAGE, seq, 4)
+        step, prefill = _target_programs(cfg, PAGE, seq, 4)
         params = jax.tree_util.tree_map_with_path(
             lambda path, s: jax.ShapeDtypeStruct(
                 s.shape, s.dtype, sharding=NamedSharding(
@@ -441,16 +488,44 @@ def test_decode_step_shard4(topo, as_tpu, monkeypatch):
                     mesh, shardlib.pool_specs()[k]))
             for k, s in jax.eval_shape(
                 lambda: gen.init_page_pool(cfg, n_pages, PAGE)).items()}
-        text = step.lower(
-            params, pool, *step_operands(NamedSharding(mesh, P()), b, seq)
-        ).compile().as_text()
+        whole = NamedSharding(mesh, P())
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(whole, b, seq))
+        else:
+            lowered = prefill.lower(params, pool, *prefill_operands(
+                whole, int(program.split("-")[1]), seq))
+        text = lowered.compile().as_text()
     finally:
         _target_programs.cache_clear()  # never leak the described mesh
+    return text, cfg, gen.shard_config(cfg, 4), n_pages, b, seq
+
+
+def test_decode_step_shard4(topo, as_tpu, monkeypatch):
+    """One --shard 4 decode step. The member's view (kv heads 8 / 4 = 2)
+    takes the kernel inside the shard_map and moves neither its slice of
+    the pool nor a gathered view."""
+    text, cfg, lcfg, n_pages, b, seq = shard4_program(
+        topo, monkeypatch, "step")
     assert "all-reduce" in text
     assert "tpu_custom_call" in text
-    tail = (gen.shard_config(cfg, 4).n_kv_heads, cfg.head_dim)
+    tail = (lcfg.n_kv_heads, cfg.head_dim)
     assert not moves_of(text, (cfg.n_layers, n_pages, PAGE) + tail)
     assert not moves_of(text, (b, seq) + tail, tail)
+
+
+def test_prefill_bucket_shard4(topo, as_tpu, monkeypatch):
+    """One --shard 4 prefill bucket (PR 43): the member's view (8 query
+    heads over 2 kv heads) takes the prefill kernel inside the shard_map,
+    the plan read from the member-local shapes, and moves neither its
+    slice of the pool nor a gathered view of the slot's table."""
+    text, cfg, lcfg, n_pages, _, seq = shard4_program(
+        topo, monkeypatch, "prefill-1024")
+    assert "all-reduce" in text
+    assert [name for name, _, _ in mosaic_kernels(text)] == ["_prefill_kernel"]
+    tail = (lcfg.n_kv_heads, cfg.head_dim)
+    assert not moves_of(text, (cfg.n_layers, n_pages, PAGE) + tail)
+    assert not moves_of(text, (1, seq) + tail, tail)
+    assert not scores_of(text, 1024, seq)
 
 
 def test_byte_buffer_past_int32_is_refused(topo, no_compile_cache):
@@ -625,11 +700,14 @@ def test_latent_slice_sizes_its_expert_products_to_the_rows(
 # %broadcast_in_dim.N and name GQA's decode kernel %blk_attn.N, %closed_call.N
 # before; 3 to 37 lines a text, names only. (longctx, step) did not move: it
 # holds the name %mla_decode.N, which latent_kernel_roofline.longctx reads.)
+# PR 43 recorded the five GQA prefill entries anew after measuring their
+# cells (PERF.md section 6): the Pallas prefill kernel stands where the
+# gather and its scores stood. The six step / longctx entries did not move.
 PARENT_TEXT = {
     ("chat", "step"): "345dae5792673393",
-    ("chat", "prefill-1024"): "1cb65cd86639d968",
+    ("chat", "prefill-1024"): "0f3e2564b357de8f",
     ("batch", "step"): "11283d27517818e9",
-    ("batch", "prefill-512"): "e79be2fbb0f12941",
+    ("batch", "prefill-512"): "7b107c1cbf9f6a54",
     ("longctx", "step"): "0deb004e1b62525a",
     # PR 41: a whole set's slice takes a capacity ladder from 4 rows an expert
     # (moe.WHOLE_FROM_ROWS: joyai's 128-token bucket and up, so its 2048
@@ -638,12 +716,12 @@ PARENT_TEXT = {
     # programs, whose ladder did not move (parent 364d93e).
     ("longctx", "prefill-64"): "729e256973e5a89c",
     ("agentbatch", "step"): "7fb8a848123f98d9",
-    ("agentbatch", "prefill-1024"): "118ca7b5018a3e90",
+    ("agentbatch", "prefill-1024"): "10e6ff5e597902cd",
     ("agentbatch64", "step"): "75d8955b9c14301f",
-    ("agentbatch64", "prefill-1024"): "5c6de209b271dea8",
+    ("agentbatch64", "prefill-1024"): "67bab9fc0548b031",
     # Mixtral's largest bucket sends an expert 512 rows, past
     # moe.WHOLE_UP_TO_ROWS: the grouped products alone, the parent's text.
-    ("batch", "prefill-2048"): "3c0f52444fde52b4",
+    ("batch", "prefill-2048"): "64243e9ff7ec0d2b",
 }
 def test_program_text_drops_what_names_a_checkout():
     text = """HloModule jit_step, is_scheduled=true
@@ -858,17 +936,22 @@ def materialized(text: str, shapes) -> list:
     return found
 
 
+HYBRID_PREFILL_TEMP = 256 << 20  # bytes; the 1024 bucket: 127.0 MB, the parent's 1111.2
+
+
 @pytest.mark.parametrize("bucket", [1024, 512, 64, 32])
 def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     """A prefill slice (the configuration's chunk, and a short last piece):
     the slot's rows of the state cut out and written back in place, the
     whole state never copied, one row of logits, the expert leaves whole,
     and arguments + temporaries inside 15.75 GB at 48 slots (what the
-    configuration's max_batch rests on). The pages of two key/value heads
-    are re-laid around the gather of the slot's table in the 1024 bucket
-    (ROADMAP S2: flash prefill over pages): held to at most the ten
-    copies compiled in PR 33, so that a change that adds one is seen.
-    The held share's products (PR 35) are compiled once a rung: batched
+    configuration's max_batch rests on). The six attention layers attend
+    the pages where they lie through the Pallas prefill kernel (PR 43: no
+    gather of the slot's table, no re-layout of the two-head pool around
+    it, which PR 33 compiled as ten copies of the pool in the 1024 bucket,
+    no f32[1, 2, 16, T, 8192] scores: the 1024 bucket's temporaries were
+    1.16 GB of the compiled 15.51). The held share's products (PR 35) are
+    compiled once a rung: batched
     products [16, C, .] a capacity of ``moe.capacity_ladder``, which read
     this layer's leaves where they lie in the stack (no buffer of a
     layer's leaves is written), and LAST the grouped products over every
@@ -879,7 +962,7 @@ def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     text, mem = compiled.as_text(), compiled.memory_analysis()
     for leaf in ("ssm", "conv"):
         assert not copies_of(text, pool[leaf].shape)
-    assert len(copies_of(text, pool["k"].shape)) <= (10 if bucket == 1024 else 0)
+    assert reads_pages_in_place(text, cfg, pool, bucket, 8192, calls=6)
     assert not copies_of(text, (23, 16, 2688, 1920))
     assert not materialized(text, [(16, 2688, 1920), (16, 1920, 2688),
                                    (1, 16, 2688, 1920), (1, 16, 1920, 2688)])
@@ -899,7 +982,7 @@ def test_hybrid_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     else:             # few tokens: every held expert over every token
         assert not calls and " conditional(" not in text
     assert mem.alias_size_in_bytes >= 3.5e9
-    assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
+    assert mem.temp_size_in_bytes < HYBRID_PREFILL_TEMP
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
@@ -1000,11 +1083,15 @@ def test_kda_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     the pairwise decay of the chunked delta rule ([chunks, heads, C, C, d]
     float32: 0.5 GB at 1024 tokens) inside the temporaries, and arguments +
     temporaries inside 15.75 GB at 64 slots (what the configuration's
-    max_batch rests on; the 1024 bucket's largest temporary is the one
-    attention layer's scores, f32[64, 1024, 8192] = 2.15 GB: ROADMAP S2)."""
+    max_batch rests on). The one attention layer attends the pages where
+    they lie through the Pallas prefill kernel (PR 43): the 1024 bucket's
+    largest temporary, its scores f32[64, 1024, 8192] = 2.15 GB, is gone
+    (2090.0 MB of temporaries -> 335.5), and what is left is the KDA
+    layers' own."""
     chip = SingleDeviceSharding(topo.devices[0])
     compiled, cfg, pool, sizes = kda_program(chip, f"prefill-{bucket}")
     text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert reads_pages_in_place(text, cfg, pool, bucket, 8192, calls=1)
     for leaf in ("kda", "kda_conv"):
         assert not copies_of(text, pool[leaf].shape)
     assert not copies_of(text, (4, 40, 4096, 1280))
@@ -1023,7 +1110,7 @@ def test_kda_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     else:             # few tokens: every held expert over every token
         assert not calls and " conditional(" not in text
     assert mem.alias_size_in_bytes >= 2.9e9
-    assert mem.temp_size_in_bytes < (2.5 if bucket == 1024 else 0.5) * (1 << 30)
+    assert mem.temp_size_in_bytes < 0.5 * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 

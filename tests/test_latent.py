@@ -168,6 +168,7 @@ def test_the_engine_serves_the_references_tokens(f32):
     prompts = [prompt_tokens(n, i) for i, n in enumerate((70, 45, 101))]
     outs, stats = serve(cfg, params, prompts, 24, prefill_chunk=32)
     assert stats["decode_attention"] == "jnp_latent_absorbed"
+    assert stats["prefill_attention"] == "jnp_latent_expanded"
     assert stats["cache_kind"] == "latent"
     assert stats["expert_load_steps"] > 0
     assert 4 <= stats["experts_touched_sum"] / stats["expert_load_steps"] <= 16

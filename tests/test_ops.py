@@ -590,17 +590,162 @@ def test_paged_decode_kernel_matches_gather(case, kvh):
     np.testing.assert_allclose(got[~idle], want[~idle], atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.parametrize("backend,t,want", [
-    ("cpu", 1, None), ("tpu", 1, 16), ("tpu", 4, None)])
-def test_paged_dispatch_reads_shapes_and_backend_only(
-        monkeypatch, backend, t, want):
-    from oim_tpu.ops.paged_attention import _paged_plan
+PREFILL_T = 64  # query rows a call: four query blocks of 16
 
+
+def _prefill_case(name):
+    """(tables [B, nb], start [B], n_tokens [B]) of one scenario of the
+    prefill kernel, at key blocks of two pages (32 positions)."""
+    def mapped(pages):
+        row = np.zeros(N_BLOCKS, np.int32)
+        row[:len(pages)] = pages
+        return row
+
+    if name == "first":  # from 0; a block half pads, two of pads only
+        return (np.stack([mapped([3, 4])]), np.array([0], np.int32),
+                np.array([20], np.int32))
+    if name == "shared":  # later slices over the same two prefix pages
+        return (np.stack([mapped([1, 2, 5, 6, 7, 8, 9]),
+                          mapped([1, 2, 10, 11, 12, 13])]),
+                np.array([48, 32], np.int32), np.array([64, 50], np.int32))
+    if name == "straddle":  # from mid page: the first key block straddles
+        # the diagonal, and the last page holds 8 positions
+        return (np.stack([mapped([2, 3, 4, 5, 6, 7])]),
+                np.array([24], np.int32), np.array([64], np.int32))
+    if name == "depths":  # rows at their own depths and lengths; the last
+        # runs to the table's end
+        return (np.stack([mapped([1, 2, 3, 4]), mapped([5, 6, 7, 8]),
+                          mapped(np.arange(9, 17))]),
+                np.array([0, 16, 64], np.int32),
+                np.array([64, 33, 64], np.int32))
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("kvh,g", [(2, 16), (8, 4)])
+@pytest.mark.parametrize("case", ["first", "shared", "straddle", "depths"])
+def test_paged_prefill_kernel_matches_gather(case, kvh, g):
+    """The Pallas prefill kernel in interpret mode against gather +
+    cache_attention on one pool, real rows only (the others are the
+    caller's to discard: finite, and zeros where a whole query block is
+    pads). The kernel's pool has NaN wherever no real row may read (other
+    layers, unmapped pages, the scratch page, past a row's last real
+    position in a mapped page); the reference's has zeros there."""
+    from oim_tpu.ops.paged_attention import _paged_prefill, gather_attention
+
+    tables, start, n_tokens = _prefill_case(case)
+    B, T, hd, L, n_pages, layer = len(start), PREFILL_T, 128, 2, 24, 1
+    rng = np.random.RandomState(len(case) + kvh)
+    q = jnp.asarray(rng.randn(B, T, kvh * g, hd), jnp.bfloat16)
+    shape = (L, n_pages, PAGE, kvh, hd)
+    k, v = rng.randn(*shape), rng.randn(*shape)
+    live = np.zeros((L, n_pages, PAGE), bool)
+    for row, p, n in zip(tables, start, n_tokens):
+        at = np.arange(min(p + n, S_PAGED))
+        live[layer, row[at // PAGE], at % PAGE] = True
+    assert not live[:, 0].any() and live.any()
+    clean = {n: jnp.asarray(np.where(live[..., None, None], x, 0.0),
+                            jnp.bfloat16) for n, x in (("k", k), ("v", v))}
+    dirty = {n: jnp.asarray(np.where(live[..., None, None], x, np.nan),
+                            jnp.bfloat16) for n, x in (("k", k), ("v", v))}
+    args = (jnp.int32(layer), jnp.asarray(tables), jnp.asarray(start))
+    want = gather_attention(q, clean["k"], clean["v"], *args)
+    got = _paged_prefill(q, dirty["k"], dirty["v"], *args,
+                         jnp.asarray(n_tokens), block_q=16, pages=2,
+                         interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all()
+    real = np.arange(T)[None, :] < n_tokens[:, None]  # [B, T]
+    # bf16 outputs of O(1): two roundings of the probabilities apart.
+    np.testing.assert_allclose(got[real], want[real], atol=2e-2, rtol=2e-2)
+    pads_only = np.arange(T)[None, :] >= -(-n_tokens[:, None] // 16) * 16
+    assert not got[pads_only].any()  # such a block reads nothing
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid"])
+def test_prompt_slices_through_the_prefill_kernel_match_the_gather(
+        monkeypatch, family):
+    """``prefill_into_pages`` end to end, a 45-token prompt in two 32-row
+    slices (the second with 19 pad rows, from a traced ``start``): with the
+    prefill kernel (interpret mode, query blocks of 16, so one of pads
+    only) in the place of the gather, each slice's logits and the pool it
+    leaves agree with the gather path's. The kernel's pool starts as NaN:
+    whatever it reads past a real position shows."""
+    from oim_tpu.models import generate as gen, llama
+    from oim_tpu.ops import paged_attention as pa
+
+    cfg = (llama.tiny(vocab=64, dim=32, n_layers=2) if family == "dense"
+           else llama.tiny_hybrid(vocab=64))
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    page, nb, bucket = 8, 8, 32
+    prompt = np.random.RandomState(5).randint(0, 64, 45)
+    table = jnp.asarray(np.arange(1, nb + 1, dtype=np.int32))
+
+    def through_kernel(q, pk, pv, layer, tables, pos, n_tokens=None):
+        rows = q.shape[:1]
+        return pa._paged_prefill(
+            q, pk, pv, layer, tables, jnp.broadcast_to(pos, rows),
+            jnp.broadcast_to(n_tokens, rows), block_q=16, pages=2,
+            interpret=True)
+
+    def run(fill):
+        pool = {**jax.tree.map(lambda x: jnp.full_like(x, fill),
+                               gen.init_page_pool(cfg, nb + 1, page)),
+                **gen.init_state_pool(cfg, 1)}
+        out = []
+        for start in (0, bucket):
+            piece = prompt[start:start + bucket]
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :len(piece)] = piece
+            logits, pool = gen.prefill_into_pages(
+                params, jnp.asarray(toks), jnp.int32(len(piece)), pool,
+                table, jnp.int32(start), cfg, page)
+            out.append(np.asarray(logits))
+        real = np.asarray(table)[np.arange(len(prompt)) // page]
+        at = np.arange(len(prompt)) % page
+        return out, [np.asarray(pool[leaf])[:, real, at]
+                     for leaf in ("k", "v")]
+
+    want_logits, want_kv = run(0.0)
+    monkeypatch.setattr(gen, "paged_attention", through_kernel)
+    got_logits, got_kv = run(np.nan)
+    for got, want in zip(got_logits + got_kv, want_logits + want_kv):
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+DISPATCH_SHAPES = {
+    # name: (backend, T, head_dim, kv heads, page, pool dtype, the plan[,
+    # query heads a kv head: 4])
+    "cpu_step": ("cpu", 1, 128, 8, PAGE, "bfloat16", None),
+    "step": ("tpu", 1, 128, 8, PAGE, "bfloat16", (1, 16)),
+    "verify": ("tpu", 4, 128, 8, PAGE, "bfloat16", None),
+    "cpu_slice": ("cpu", 1024, 128, 8, PAGE, "bfloat16", None),
+    "slice": ("tpu", 1024, 128, 8, PAGE, "bfloat16", (512, 32)),
+    # a --shard 4 member's 8 heads over 2
+    "slice_of_a_member": ("tpu", 1024, 128, 2, PAGE, "bfloat16", (512, 32)),
+    "small_bucket": ("tpu", 32, 128, 8, PAGE, "bfloat16", (32, 32)),
+    # 28 heads over 4: the largest power of two under 2048 / 7 positions
+    "seven_heads_a_group": ("tpu", 1024, 128, 4, PAGE, "bfloat16", (256, 32), 7),
+    "narrow_head": ("tpu", 1024, 64, 8, PAGE, "bfloat16", None),
+    "page_off_the_tiling": ("tpu", 1024, 128, 3, 4, "float32", None),
+    "odd_heads_in_words": ("tpu", 1024, 128, 3, PAGE, "bfloat16", None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DISPATCH_SHAPES))
+def test_paged_dispatch_reads_shapes_and_backend_only(monkeypatch, name):
+    from oim_tpu.ops.paged_attention import _paged_plan, kernel_name
+
+    backend, t, hd, kvh, page, dtype, want, *g = DISPATCH_SHAPES[name]
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    q = jax.ShapeDtypeStruct((4, t, 32, 128), jnp.bfloat16)
-    pk = jax.ShapeDtypeStruct((2, 9, PAGE, 8, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((4, t, (g or [4])[0] * kvh, hd), jnp.bfloat16)
+    pk = jax.ShapeDtypeStruct((2, 9, page, kvh, hd), jnp.dtype(dtype))
     tables = jax.ShapeDtypeStruct((4, 256), jnp.int32)
     assert _paged_plan(q, pk, tables) == want
+    assert kernel_name(q, pk, tables) == (
+        "jnp_gather" if want is None else
+        "pallas_paged" if t == 1 else "pallas_paged_prefill")
 
 
 # -- latent decode attention (ops/latent_attention.py) -----------------------

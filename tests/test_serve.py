@@ -96,15 +96,18 @@ class TestEngineInvariants:
         serve/<id> row): on the CPU the reference path, and the kernel
         where the rule finds its shapes on a TPU. No option selects it."""
         assert engine.stats()["decode_attention"] == "jnp_gather"
+        assert engine.stats()["prefill_attention"] == "jnp_gather"
         params, cfg = model  # head_dim 16: off the kernel's tiling
         wide = dataclasses.replace(cfg, head_dim=128)
         with unittest.mock.patch.object(
                 jax, "default_backend", lambda: "tpu"):
-            for c, want in ((cfg, "jnp_gather"), (wide, "pallas_paged")):
+            for c, want in ((cfg, ("jnp_gather", "jnp_gather")),
+                            (wide, ("pallas_paged", "pallas_paged_prefill"))):
                 eng = ServeEngine(llama.init(jax.random.PRNGKey(0), c), c,
                                   max_batch=2, max_seq=64, queue_depth=8)
                 try:
-                    assert eng.decode_attention == want
+                    assert (eng.decode_attention,
+                            eng.prefill_attention) == want
                 finally:
                     eng.stop(drain=False, timeout=30)
 
@@ -328,6 +331,8 @@ class TestEngineInvariants:
                     if "decode rounds dispatched" in ln)
         assert f"ahead: {steps - 1}" in line and "drained: 3" in line
         assert "overrun_rows: 3" in line and "ahead_share: 0." in line
+        assert "decode_attention: 'jnp_gather'" in line
+        assert "prefill_attention: 'jnp_gather'" in line
 
     def test_slot_reuse_leaks_nothing(self, model):
         """A slot's next occupant sees a zero cache: with max_batch=1
