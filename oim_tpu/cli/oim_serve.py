@@ -57,7 +57,8 @@ DEFAULT_VOLUME = "weights"
 SERVED_ONLY = {"joyai-llm-flash": "JOYAI_LLM_FLASH",
                "nemotron-3-nano-30b": "NEMOTRON_3_NANO_30B",
                "solar-open2-250b": "SOLAR_OPEN2_250B",
-               "gigachat35-432b-a28b": "GIGACHAT35_432B"}
+               "gigachat35-432b-a28b": "GIGACHAT35_432B",
+               "zaya1-8b": "ZAYA1_8B"}
 
 
 def _load_params(args, log):

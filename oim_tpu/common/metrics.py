@@ -654,8 +654,9 @@ SERVE_OVERRUN_ROWS = DEFAULT.counter(
 SERVE_STATE_BYTES = DEFAULT.gauge(
     "oim_serve_state_bytes",
     "device bytes of the recurrent state the replica holds beside its page "
-    "pool: max_batch slots x the recurrent layers' state a slot (0 for a model "
-    "without such layers)")
+    "pool: max_batch slots x the recurrent layers' state a slot, or the tails "
+    "of compressed convolutional attention (0 for a model without such "
+    "layers)")
 SERVE_STATE_SLOTS_LIVE = DEFAULT.gauge(
     "oim_serve_state_slots_live",
     "slots whose row of the recurrent state belongs to a live request")

@@ -31,16 +31,20 @@ from jax import lax
 
 from oim_tpu.models.llama import (
     RECURRENT_KINDS,
+    STATE_KINDS,
     Config,
     _attn_mixer,
     _block,
+    _cca_mixer,
     _ffn_mixer,
     _norm,
     _residual,
+    head,
     layer_groups,
+    router_carry,
     run_pattern,
 )
-from oim_tpu.ops import latent_attention
+from oim_tpu.ops import cca, latent_attention
 from oim_tpu.ops.norms import rmsnorm
 from oim_tpu.ops.paged_attention import cache_attention, paged_attention
 from oim_tpu.ops.rope import rope_frequencies
@@ -278,7 +282,7 @@ def cached_forward(params, tokens, cache, pos, cfg: Config,
     x, cache = _scan_groups(body, x, params, cfg, cache)
     with jax.named_scope("tok_head"):
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        logits = (x @ head(params)).astype(jnp.float32)
     return logits, cache
 
 
@@ -370,8 +374,11 @@ def page_bytes(cfg: Config, page_tokens: int) -> int:
 # conv window is kept flat), a GatedDeltaNet model's {"gdn": [Lg, slots, Hv,
 # dk, dv], "gdn_conv": ...}. A new kind of state is one more entry of
 # ``llama.RECURRENT_KINDS`` whose module has ``Dims`` (``slot_leaves``,
-# ``state_leaf``, ``window_leaf``), ``step`` and ``scan``. The leaves ride
-# in the SAME dict as the page leaves, so the serving programs donate and
+# ``state_leaf``, ``window_leaf``), ``step`` and ``scan``. A layer of
+# compressed convolutional attention keeps pages AND, a slot, the TAIL its
+# convolutions and its value shift read at the next position ({"cca_tail":
+# [Lc, slots, tail]} float32, ops/cca.py): the same rows, the same rules. The
+# leaves ride in the SAME dict as the page leaves, so the serving programs donate and
 # update them with the pool. A prefill at
 # ``start`` = 0 begins from zeros (no call zeroes a row: a retired slot's
 # state is dead where it lies), a later slice of a chunked prefill from the
@@ -393,11 +400,11 @@ def init_state_pool(cfg: Config, slots: int) -> dict:
 
 
 def state_bytes_by_kind(cfg: Config, slots: int = 1) -> dict:
-    """{kind's name ("mamba", "kda", "gdn"): device bytes of ``slots`` slots'
-    recurrent state over all layers of that kind}."""
+    """{kind's name ("mamba", "kda", "gdn", "cca"): device bytes of ``slots``
+    slots' recurrent state (or tail) over all layers of that kind}."""
     import math
 
-    return {RECURRENT_KINDS[kind].NAME: slots * cfg.n_of(kind) * sum(
+    return {STATE_KINDS[kind].NAME: slots * cfg.n_of(kind) * sum(
         math.prod(shape) * jnp.dtype(dtype).itemsize
         for shape, dtype in leaves.values())
         for kind, leaves in cfg.state_leaves.items()}
@@ -514,7 +521,7 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
         step_scope, scan_scope = module.SCOPES
 
         def mixer(carry, layer, i):
-            x, pool, load = carry
+            x, pool, load, router = carry
             h = _norm(x, layer["norm"], cfg)
             sp, cp = pool[state], pool[conv]
             # The state's read and write-back stand under the mixer's scope
@@ -530,7 +537,8 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                     c2 = jnp.where(live[:, None], c2.reshape(c.shape), c)
                     pool = {**pool, state: sp.at[i].set(s2),
                             conv: cp.at[i].set(c2)}
-                return (_residual(x, y[:, None], layer, cfg), pool, load)
+                return (_residual(x, y[:, None], layer, cfg), pool, load,
+                        router)
             # A prompt slice of one slot, from zeros at position 0.
             with jax.named_scope(scan_scope):
                 s = lax.dynamic_slice(sp, (i, slot, 0, 0, 0),
@@ -547,29 +555,55 @@ def _hybrid_paged(params, x, pool, cfg: Config, cos, sin, positions,
                             sp, s2[None], (i, slot, 0, 0, 0)),
                         conv: lax.dynamic_update_slice(
                             cp, c2.reshape(1, 1, -1), (i, slot, 0))}
-            return (_residual(x, y, layer, cfg), pool, load)
+            return (_residual(x, y, layer, cfg), pool, load, router)
 
         return mixer
 
     def experts(carry, layer, _):
-        x, pool, load = carry
-        x, aux = _ffn_mixer(x, layer, cfg, load=True)
-        return (x, pool, load + aux[2:])
+        x, pool, load, router = carry
+        x, aux, router = _ffn_mixer(x, layer, cfg, load=True, router=router)
+        return (x, pool, load + aux[2:], router)
 
     def attention(carry, layer, i):
-        x, pool, load = carry
+        x, pool, load, router = carry
         x, pool = _attn_mixer(x, layer, cfg, cos, sin, positions,
                               attend_at(i), pool)
-        return (x, pool, load)
+        return (x, pool, load, router)
+
+    def conv_attention(carry, layer, i):
+        """Compressed convolutional attention: layer i's pages as GQA's, and
+        beside them the rows' tails, read and written where they lie (under
+        the mixing's scope, as a recurrent layer's state)."""
+        x, pool, load, router = carry
+        leaf = cfg.cca.tail_leaf
+        tp = pool[leaf]
+        with jax.named_scope(cca.SCOPE):
+            if slot is None:  # a decode step: every row's own tail
+                tail = tp[i]
+            else:  # a prompt slice of one slot, from zeros at position 0
+                tail = lax.dynamic_slice(tp, (i, slot, 0),
+                                         (1, 1, tp.shape[2]))[0]
+                tail = jnp.where(start == 0, jnp.zeros_like(tail), tail)
+        x, pool, new = _cca_mixer(x, layer, cfg, cos, sin, positions,
+                                  attend_at(i), pool, tail, n_tokens)
+        with jax.named_scope(cca.SCOPE):
+            if slot is None:  # an idle row keeps what it had
+                tp = tp.at[i].set(jnp.where(live[:, None], new, tail))
+            else:
+                tp = lax.dynamic_update_slice(tp, new[None], (i, slot, 0))
+        return (x, {**pool, leaf: tp}, load, router)
 
     from oim_tpu.models import moe
 
-    return run_pattern(
+    B, T = x.shape[:2]
+    x, pool, load, _ = run_pattern(
         params, cfg,
         (x, pool, jnp.zeros(
-            (moe.load_width(cfg.moe, x.shape[0] * x.shape[1]) - 2,), jnp.float32)),
-        {"E": experts, "D": experts, "*": attention,
+            (moe.load_width(cfg.moe, B * T) - 2,), jnp.float32),
+         router_carry(cfg, B, T)),
+        {"E": experts, "D": experts, "*": attention, "C": conv_attention,
          **{kind: recurrent(kind) for kind in cfg.recurrent}})
+    return x, pool, load
 
 
 def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
@@ -628,7 +662,7 @@ def prefill_into_pages(params, tokens, n_tokens, pool, page_table,
     # 2048-token chunk's float32 logits would be 1 GB for nothing).
     with jax.named_scope("tok_head"):
         last = lax.dynamic_slice_in_dim(x[0], n_tokens - 1, 1, axis=0)
-        logits = (last @ params["lm_head"]).astype(jnp.float32)[0]
+        logits = (last @ head(params)).astype(jnp.float32)[0]
     if with_rungs and load.shape[0] > 2:
         return logits, pool, load[2:].astype(jnp.int32)
     return logits, pool
@@ -667,7 +701,7 @@ def decode_step(params, tokens, pool, page_tables, pos, cfg: Config,
         params, tokens[:, None], pool, page_tables, pos, phys[:, None],
         (pos % page_tokens)[:, None], cfg, axis)
     with jax.named_scope("tok_head"):
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        logits = (x @ head(params)).astype(jnp.float32)
     if with_load:
         return logits[:, 0], pool, load[:2]
     return logits[:, 0], pool
@@ -718,7 +752,7 @@ def verify_step(params, tokens, pool, page_tables, pos, cfg: Config,
         params, tokens, pool, page_tables, pos, phys,
         positions % page_tokens, cfg, axis)
     with jax.named_scope("tok_head"):
-        return (x @ params["lm_head"]).astype(jnp.float32), pool
+        return (x @ head(params)).astype(jnp.float32), pool
 
 
 def generate(params, prompt, n_new: int, cfg: Config,

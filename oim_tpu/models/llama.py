@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from oim_tpu.ops import gdn, kda, ssm
+from oim_tpu.ops import cca, gdn, kda, ssm
 from oim_tpu.ops.attention import attention as default_attention
 from oim_tpu.ops.losses import chunked_softmax_cross_entropy, softmax_cross_entropy
 from oim_tpu.ops.norms import gated_rmsnorm, rmsnorm
@@ -37,14 +37,19 @@ from oim_tpu.parallel.sharding import EMBED, HEAD, KV_HEAD, LAYER, MLP, VOCAB
 
 # A hybrid's parameters are stacked a KIND of block ("M" Mamba-2, "K" KDA,
 # "G" GatedDeltaNet, "E" experts, "D" a dense FFN, "*" attention: GQA, or
-# latent where ``kv_lora_rank`` says so), whatever the order its pattern
-# runs them in (``run_pattern``).
+# latent where ``kv_lora_rank`` says so, "C" compressed convolutional
+# attention), whatever the order its pattern runs them in (``run_pattern``).
 HYBRID_GROUPS = {"M": "mamba_layers", "K": "kda_layers", "G": "gdn_layers",
-                 "E": "expert_layers", "D": "ffn_layers", "*": "attn_layers"}
+                 "E": "expert_layers", "D": "ffn_layers", "*": "attn_layers",
+                 "C": "cca_layers"}
 # The kinds that carry recurrent state, each with its module: ``Dims`` (the
 # mixer's sizes, what a slot keeps and under which leaves of the state
 # pool), ``step``, ``scan``, ``SCOPES`` and ``NAME``.
 RECURRENT_KINDS = {"M": ssm, "K": kda, "G": gdn}
+# Every kind that keeps something a SLOT whatever its position, in the
+# engine's accounting by ``NAME``: the recurrent kinds' state, and the tail
+# a "C" layer keeps beside its pages (``ops/cca.py``).
+STATE_KINDS = {**RECURRENT_KINDS, "C": cca}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +174,26 @@ class Config:
     layernorm_type: str = "pre"
     # The cut of every gated FFN's two products (models/moe.py ``swiglu``).
     swiglu_limit: float = 0.0
+    # Compressed convolutional attention over experts chosen by a router
+    # with memory (the zaya family's published keys): ``cca_time0`` > 0
+    # selects it. Published layer i of ``n_layers`` is a CCA sublayer ("C",
+    # ops/cca.py: q and k made in a latent of n_heads + n_kv_heads heads,
+    # mixed by two causal convolutions of ``cca_time0`` and ``cca_time1``
+    # taps, K and V final after it and served from the GQA page pool) then
+    # an expert block ("E") whose router (``scoring_func`` "mlp") is a
+    # network of width ``router_hidden_size`` that carries its state from
+    # one expert block to the next; without a pattern given it is derived
+    # ("CECE..."). ``partial_rotary_factor`` rotates that share of a head
+    # (GQA and CCA). ``residual_scaling`` puts a learned scale and bias on
+    # the stream and on every sublayer's output of a pattern before they
+    # are added; ``tie_word_embeddings`` makes the head the embedding's
+    # transpose (no ``lm_head`` leaf).
+    cca_time0: int = 0
+    cca_time1: int = 0
+    partial_rotary_factor: float = 1.0
+    router_hidden_size: int = 0
+    residual_scaling: bool = False
+    tie_word_embeddings: bool = False
     # YaRN: (factor, original_max_position_embeddings, beta_fast, beta_slow)
     # stretches the rotary tables (ops/rope.py); ``use_mla_scaling_factor``
     # puts its temperature, (0.1 ln(factor) + 1)^2, on the latent attention's
@@ -210,11 +235,37 @@ class Config:
             raise ValueError(
                 "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
                 "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
-        if self.n_experts and self.scoring_func == "sigmoid" \
+        if self.n_experts and self.scoring_func in ("sigmoid", "mlp") \
                 and self.moe_dispatch != "ragged":
             raise ValueError(
-                "scoring_func='sigmoid' routes dropless: it needs "
-                f"moe_dispatch='ragged', got {self.moe_dispatch!r}")
+                f"scoring_func={self.scoring_func!r} routes dropless: it "
+                f"needs moe_dispatch='ragged', got {self.moe_dispatch!r}")
+        if (self.scoring_func == "mlp") != bool(self.router_hidden_size):
+            raise ValueError(
+                "scoring_func='mlp' and router_hidden_size > 0 go together "
+                "(the router that is a network, and its width)")
+        if self.cca_time0 or self.cca_time1:
+            if (self.cca_time0, self.cca_time1) != (cca.TAPS, cca.TAPS):
+                raise ValueError(
+                    f"cca_time0 / cca_time1 = {self.cca_time0} / "
+                    f"{self.cca_time1}: both convolutions have "
+                    f"{cca.TAPS} taps (ops/cca.py)")
+            if self.kv_lora_rank:
+                raise ValueError(
+                    "compressed convolutional attention beside latent "
+                    "attention (kv_lora_rank > 0) is not implemented: one "
+                    "page pool holds one kind of entry")
+            if self.n_kv_heads % 2 or self.n_heads % self.n_kv_heads:
+                raise ValueError(
+                    "compressed convolutional attention needs an even "
+                    "n_kv_heads (half hold the shifted values) dividing "
+                    "n_heads")
+        if self.partial_rotary_factor != 1.0 and (
+                not 0.0 < self.partial_rotary_factor < 1.0
+                or (self.head_dim * self.partial_rotary_factor) % 2):
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor}: a "
+                "share of head_dim in (0, 1] that rotates whole pairs")
         if self.kda_num_heads and not self.kda_head_dim:
             raise ValueError("KDA layers (kda_num_heads > 0) need kda_head_dim")
         if self.kda_use_full_proj:
@@ -238,6 +289,10 @@ class Config:
                 f"norm_type {self.norm_type!r} / layernorm_type "
                 f"{self.layernorm_type!r}: expected 'rms' or "
                 "'zero_centered_gated', and 'pre' or 'pre_post'")
+        if self.residual_scaling and not pattern:
+            raise ValueError(
+                "residual_scaling is a hybrid pattern's: the "
+                "attention-then-FFN block adds its sublayers as they are")
         if (self.post_norm or self.norm_type != "rms") and not pattern:
             raise ValueError(
                 "layernorm_type='pre_post' and norm_type="
@@ -264,6 +319,13 @@ class Config:
                 raise ValueError(
                     "a pattern with 'E' needs n_experts and "
                     "moe_dispatch='ragged' (the hybrid's experts run dropless)")
+            if "C" in pattern and not self.cca_time0:
+                raise ValueError(
+                    "a pattern with 'C' needs cca_time0 and cca_time1")
+            if "C" in pattern and "*" in pattern:
+                raise ValueError(
+                    "a pattern with 'C' beside '*' is not implemented: the "
+                    "page pool's layers are one kind's")
             if "G" in pattern and not (
                     self.linear_num_key_heads and self.linear_num_value_heads
                     and self.linear_key_head_dim and self.linear_value_head_dim
@@ -307,6 +369,8 @@ class Config:
             capacity_factor=self.moe_capacity_factor,
             dispatch=self.moe_dispatch,
             scoring=self.scoring_func,
+            router_dim=self.router_hidden_size,
+            norm_eps=self.norm_eps,
             routed_scale=self.routed_scaling_factor,
             n_shared=self.n_shared_experts,
             shared_dim=self.moe_shared_expert_intermediate_size,
@@ -339,6 +403,15 @@ class Config:
             gated_norm=self.norm_type == "zero_centered_gated")
 
     @property
+    def cca(self):
+        """The compressed convolutional attention's sizes, or None without
+        such layers."""
+        if "C" not in self.pattern:
+            return None
+        return cca.Dims(heads=self.n_heads, kv_heads=self.n_kv_heads,
+                        head_dim=self.head_dim)
+
+    @property
     def attn_gate(self) -> bool:
         """Whether a pattern's attention multiplies its output by a gate."""
         return self.use_gqa_gate or self.gated_attention
@@ -369,6 +442,8 @@ class Config:
                 ("*" if i in self.full_attention_layers else "G")
                 + ("D" if i < self.first_k_dense_replace else "E")
                 for i in range(self.n_layers))
+        if self.cca_time0:
+            return "CE" * self.n_layers
         if not self.kda_num_heads:
             return ""
         return "".join(("*" if i in self.gqa_layers else "K") + "E"
@@ -388,7 +463,7 @@ class Config:
     def n_cache_layers(self) -> int:
         """Layers that keep a per-position cache: the attention layers."""
         if self.pattern:
-            return self.n_of("*")
+            return self.n_of("*") + self.n_of("C")
         return self.n_layers
 
     @property
@@ -408,8 +483,11 @@ class Config:
         state and a conv window as its ``Dims.slot_leaves`` names them; {}
         without such layers. The serving engine holds it beside the page
         pool, a row a slot (models/generate.py ``init_state_pool``)."""
+        kinds = dict(self.recurrent)
+        if self.cca is not None:  # the tail a slot keeps beside its pages
+            kinds["C"] = self.cca
         return {kind: dims.slot_leaves(self.dtype)
-                for kind, dims in self.recurrent.items()}
+                for kind, dims in kinds.items()}
 
     @property
     def expert_dim(self) -> int:
@@ -437,7 +515,9 @@ class Config:
 
     @property
     def rope_dim(self) -> int:
-        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+        if self.kv_lora_rank:
+            return self.qk_rope_head_dim
+        return int(self.head_dim * self.partial_rotary_factor)
 
     @property
     def q_dim(self) -> int:
@@ -553,6 +633,40 @@ GIGACHAT35_432B = Config(
     layernorm_type="pre_post", swiglu_limit=10.0, n_experts=256, moe_top_k=8,
     moe_intermediate_size=2048, n_shared_experts=1, first_k_dense_replace=3,
     scoring_func="sigmoid", routed_scaling_factor=2.5, moe_dispatch="ragged")
+
+
+# ZAYA1-8B (8.8B with its table, 0.76B a token) as published: 40 layers, each
+# a compressed-convolutional-attention sublayer (8 query and 2 key-value
+# heads of 128 made in a latent of 1280, two causal convolutions of two taps,
+# a rotary over half a head) then 16 SwiGLU experts of 2048, one a token,
+# chosen by a 256-wide router network that carries its state from layer to
+# layer; learned scales on the residual stream; one tied 262 272-row table.
+# https://huggingface.co/Zyphra/ZAYA1-8B/blob/main/config.json
+# 17.6 GB in bfloat16: one v5e chip holds a pipeline stage of 14 layers with
+# the table (benchmarks/configs/zaya1-8b.json).
+ZAYA1_8B = Config(
+    vocab=262272, dim=2048, n_layers=40, n_heads=8, n_kv_heads=2,
+    head_dim=128, mlp_dim=2048, max_seq=131072, rope_theta=5e6,
+    norm_eps=1e-5, cca_time0=2, cca_time1=2, partial_rotary_factor=0.5,
+    n_experts=16, moe_top_k=1, moe_intermediate_size=2048,
+    scoring_func="mlp", router_hidden_size=256, moe_dispatch="ragged",
+    residual_scaling=True, tie_word_embeddings=True)
+
+
+def tiny_cca(vocab: int = 512, n_layers: int = 3, dtype=jnp.float32,
+             expert_rank: str = "") -> Config:
+    """The zaya family's layer at test scale: compressed convolutional
+    attention (4 query heads over 2 key-value heads, half a head rotated),
+    then top-1 of 8 experts behind the router network with its carry;
+    residual scaling, a tied table."""
+    return Config(
+        vocab=vocab, dim=64, n_layers=n_layers, n_heads=4, n_kv_heads=2,
+        head_dim=16, mlp_dim=32, max_seq=512, rope_theta=5e6, dtype=dtype,
+        norm_eps=1e-5, cca_time0=2, cca_time1=2, partial_rotary_factor=0.5,
+        n_experts=8, moe_top_k=1, moe_intermediate_size=32,
+        scoring_func="mlp", router_hidden_size=16, moe_dispatch="ragged",
+        residual_scaling=True, tie_word_embeddings=True,
+        expert_rank=expert_rank)
 
 
 def tiny_gdn(vocab: int = 512, pattern: str = "GD*EGEGEGE",
@@ -725,6 +839,9 @@ def _init_hybrid(rng, cfg: Config) -> dict:
     n_m, n_e, n_a, n_k = (cfg.n_of(k) for k in "ME*K")
     n_g, n_d = cfg.n_of("G"), cfg.n_of("D")
     groups = {}
+    if cfg.n_of("C"):
+        groups["cca_layers"] = cca.init(
+            jax.random.fold_in(rng, 11), D, cfg.cca, cfg.dtype, cfg.n_of("C"))
     if n_g:
         groups["gdn_layers"] = gdn.init(
             jax.random.fold_in(rng, 8), D, cfg.gdn, cfg.dtype, n_g)
@@ -765,6 +882,10 @@ def _init_hybrid(rng, cfg: Config) -> dict:
             if cfg.post_norm:
                 groups[name]["post_norm"] = _norm_weight(
                     cfg, (cfg.n_of(kind), D))
+            if cfg.residual_scaling:  # what multiplies by 1 and adds 0
+                for leaf, fill in zip(RESIDUAL_LEAVES, (1.0, 0.0, 1.0, 0.0)):
+                    groups[name][leaf] = jnp.full(
+                        (cfg.n_of(kind), D), fill, jnp.float32)
     return groups
 
 
@@ -776,8 +897,9 @@ def init(rng, cfg: Config = LLAMA3_8B):
     params = {
         "embed": _dense(ks[0], (cfg.vocab, D), cfg.dtype, scale=0.02),
         "final_norm": _norm_weight(cfg, (D,)),
-        "lm_head": _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan),
     }
+    if not cfg.tie_word_embeddings:  # tied: the head is ``embed``'s transpose
+        params["lm_head"] = _dense(ks[8], (D, cfg.vocab), cfg.dtype, fan)
     if cfg.pattern:
         params.update(_init_hybrid(rng, cfg))
         return params
@@ -788,15 +910,26 @@ def init(rng, cfg: Config = LLAMA3_8B):
     return params
 
 
+def head(params):
+    """The output table [D, vocab]: ``lm_head``, or the embedding's transpose
+    where the tables are tied (``Config.tie_word_embeddings``: one leaf; the
+    product contracts the embedding's minor dim, nothing is transposed in
+    memory)."""
+    if "lm_head" in params:
+        return params["lm_head"]
+    return params["embed"].T
+
+
 def param_logical_axes(cfg: Config = LLAMA3_8B):
     if (cfg.kv_lora_rank or cfg.n_dense_layers or cfg.n_shared_experts
-            or cfg.pattern or cfg.expert_rank):
+            or cfg.pattern or cfg.expert_rank or cfg.tie_word_embeddings):
         raise ValueError(
             "no sharding rules yet for latent attention, leading dense "
             "layers or shared experts: this block is served on one chip "
             "and not trained (ROADMAP.md, Reach); nor for a hybrid pattern's "
             "blocks (Mamba-2, KDA, GatedDeltaNet, gated attention, gated "
-            "norms) or a held share of the experts")
+            "norms, compressed convolutional attention), a held share of "
+            "the experts or a tied table")
     layers = {
         "attn_norm": (LAYER, None),
         "wq": (LAYER, EMBED, HEAD),
@@ -846,7 +979,7 @@ def _remat_policy(cfg: Config):
     return getattr(jax.checkpoint_policies, name)
 
 
-def _ffn(h, layer, cfg: Config, load: bool = False):
+def _ffn(h, layer, cfg: Config, load: bool = False, router=None):
     """FFN half of a block on the pre-normed activations; returns
     (out, aux) — aux is the f32 vector [load_balance_loss,
     dropped_token_fraction] (zeros for the dense FFN): one uniform aux
@@ -854,12 +987,13 @@ def _ffn(h, layer, cfg: Config, load: bool = False):
     telemetry without special cases. With ``load`` (the serving programs)
     it is moe.apply's ``with_load`` vector. Which FFN a layer has is read
     from its own leaves: an expert model's leading dense layers carry none
-    of ``moe``."""
+    of ``moe``. ``router`` is this block's state of a router with memory
+    (``moe.router_state``; None for the routers that read the tokens)."""
     from oim_tpu.models import moe
 
     if "moe" in layer:
         return moe.apply(layer["moe"], h, cfg.moe, with_stats=True,
-                         with_load=load)
+                         with_load=load, state=router)
     gated = moe.swiglu(h @ layer["w_gate"], h @ layer["w_up"],
                        cfg.swiglu_limit)
     width = (moe.load_width(cfg.moe, h.shape[0] * h.shape[1])
@@ -878,11 +1012,20 @@ def _norm(x, weight, cfg: Config):
     return rmsnorm(x, weight, cfg.norm_eps)
 
 
+# ``residual_scaling``: a sublayer's (scale, bias) on the stream and on its
+# output, float32 [D] each.
+RESIDUAL_LEAVES = ("res_a", "res_b", "res_c", "res_e")
+
+
 def _residual(x, out, layer, cfg: Config):
     """``x`` plus a hybrid pattern's sublayer output, normed once more
-    first where ``layernorm_type`` is "pre_post"."""
+    first where ``layernorm_type`` is "pre_post"; with ``residual_scaling``
+    ``(a x + b) + (c out + e)``, summed in float32."""
     if cfg.post_norm:
         out = _norm(out, layer["post_norm"], cfg)
+    if cfg.residual_scaling:
+        a, b, c, e = (layer[k] for k in RESIDUAL_LEAVES)
+        return ((a * x + b) + (c * out + e)).astype(x.dtype)
     return x + out
 
 
@@ -1010,12 +1153,39 @@ def run_pattern(params, cfg: Config, carry, mixers: dict):
     return carry
 
 
-def _ffn_mixer(x, layer, cfg: Config, load: bool = False):
+def _ffn_mixer(x, layer, cfg: Config, load: bool = False, router=None):
     """A hybrid's FFN block, experts ("E") or dense ("D", which its leaves
-    say): (x + ffn(norm(x)), aux)."""
+    say): (x + ffn(norm(x)), aux, router). ``router`` [B, T, R] float32 is
+    the state the expert block before left of a router with memory
+    (``Config.router_hidden_size``; None elsewhere, and in the first block):
+    this block's state is made from it, chosen from, and handed on."""
+    from oim_tpu.models import moe
+
     with jax.named_scope("blk_ffn"):
-        out, aux = _ffn(_norm(x, layer["norm"], cfg), layer, cfg, load)
-        return _residual(x, out, layer, cfg), aux
+        h = _norm(x, layer["norm"], cfg)
+        if cfg.router_hidden_size and "moe" in layer:
+            with jax.named_scope("moe_route"):
+                router = moe.router_state(layer["moe"], h, router, cfg.moe)
+        out, aux = _ffn(h, layer, cfg, load, router)
+        return _residual(x, out, layer, cfg), aux, router
+
+
+def _cca_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache,
+               tail, n_tokens=None):
+    """A hybrid's compressed-convolutional-attention layer on x [B, T, D]
+    from each row's ``tail`` [B, cca.Dims.tail]: (x + attention, cache, the
+    tail after the last real position). After the mixing K and V are final,
+    and ``attend(cache, q, k, v)`` is GQA's."""
+    B, T, _ = x.shape
+    with jax.named_scope(cca.SCOPE):
+        q, k, v, tail = cca.mix(
+            layer, _norm(x, layer["norm"], cfg), tail, cfg.cca, cos, sin,
+            positions, n_tokens, cfg.norm_eps)
+    with jax.named_scope("blk_attn"):
+        attn, cache = attend(cache, q, k, v)
+    with jax.named_scope("blk_out"):
+        out = attn.reshape(B, T, cfg.o_dim) @ layer["wo"]
+        return _residual(x, out, layer, cfg), cache, tail
 
 
 def _attn_mixer(x, layer, cfg: Config, cos, sin, positions, attend, cache):
@@ -1055,31 +1225,49 @@ def _hybrid_hidden(params, x, cfg: Config, cos, sin, attn_fn: AttentionFn):
         window_dt = leaves[dims.window_leaf][1]
 
         def mixer(carry, layer, _):
-            x, aux = carry
+            x, aux, router = carry
             y, _, _ = module.scan(
                 layer, _norm(x, layer["norm"], cfg),
                 jnp.zeros((B,) + state, dt),
                 jnp.zeros((B,) + dims.window, window_dt), T, dims,
                 cfg.norm_eps)
-            return _residual(x, y, layer, cfg), aux
+            return _residual(x, y, layer, cfg), aux, router
 
         return mixer
 
     def experts(carry, layer, _):
-        x, aux = carry
-        x, layer_aux = _ffn_mixer(x, layer, cfg)
-        return x, aux + layer_aux
+        x, aux, router = carry
+        x, layer_aux, router = _ffn_mixer(x, layer, cfg, router=router)
+        return x, aux + layer_aux, router
 
     def attention(carry, layer, _):
-        x, aux = carry
+        x, aux, router = carry
         x, _ = _attn_mixer(x, layer, cfg, cos, sin, None,
                            _full_attend(cfg, attn_fn), None)
-        return x, aux
+        return x, aux, router
 
-    return run_pattern(
-        params, cfg, (x, jnp.zeros((2,), jnp.float32)),
-        {"E": experts, "D": experts, "*": attention,
+    def conv_attention(carry, layer, _):
+        x, aux, router = carry
+        x, _, _ = _cca_mixer(
+            x, layer, cfg, cos, sin, None, _full_attend(cfg, attn_fn), None,
+            jnp.zeros((B, cfg.cca.tail), jnp.float32))
+        return x, aux, router
+
+    x, aux, _ = run_pattern(
+        params, cfg,
+        (x, jnp.zeros((2,), jnp.float32), router_carry(cfg, B, T)),
+        {"E": experts, "D": experts, "*": attention, "C": conv_attention,
          **{kind: recurrent(kind) for kind in cfg.recurrent}})
+    return x, aux
+
+
+def router_carry(cfg: Config, B: int, T: int):
+    """What the layer loop of a pattern carries beside the stream for a
+    router with memory: its state before the first expert block, zeros [B,
+    T, R] float32; None (nothing carried) for every other router."""
+    if not cfg.router_hidden_size:
+        return None
+    return jnp.zeros((B, T, cfg.router_hidden_size), jnp.float32)
 
 
 def _layer(x, layer, cfg: Config, cos, sin, attn_fn: AttentionFn):
@@ -1126,7 +1314,7 @@ def apply(params, tokens, cfg: Config = LLAMA3_8B,
     """tokens: [B, T] int32. Returns logits [B, T, vocab] float32 (and the
     summed MoE load-balance aux loss when return_aux)."""
     x, aux = hidden_states(params, tokens, cfg, attn_fn)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    logits = (x @ head(params)).astype(jnp.float32)
     if return_aux:
         return logits, aux[0]
     return logits
@@ -1157,14 +1345,14 @@ def loss_and_stats(params, tokens, cfg: Config = LLAMA3_8B,
     labels = tokens[:, 1:]
     if cfg.vocab_chunk:
         loss = chunked_softmax_cross_entropy(
-            x, params["lm_head"], labels, cfg.vocab_chunk,
+            x, head(params), labels, cfg.vocab_chunk,
             ignore_index, z_loss=cfg.z_loss,
             return_z_term=bool(cfg.z_loss),
         )
         if cfg.z_loss:
             loss, stats["z_loss_term"] = loss
     else:
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        logits = (x @ head(params)).astype(jnp.float32)
         loss = softmax_cross_entropy(logits, labels, ignore_index,
                                      z_loss=cfg.z_loss)
         if cfg.z_loss:
@@ -1611,9 +1799,12 @@ def _param_counts(cfg: Config, experts: int) -> int:
         mats = 3 if cfg.mlp_hidden_act == "silu" else 2
         shared = (cfg.moe_shared_expert_intermediate_size
                   or cfg.n_shared_experts * cfg.expert_dim)
-        ffn = (D * cfg.n_experts
+        R = cfg.router_hidden_size
+        router = (D * R + 3 * R + 2 * (R * R + R) + R * cfg.n_experts
+                  + cfg.n_experts) if R else D * cfg.n_experts
+        ffn = (router
                + mats * D * (experts * cfg.expert_dim + shared)
-               + (cfg.n_experts if cfg.scoring_func == "sigmoid" else 0))
+               + (cfg.n_experts if cfg.scoring_func != "softmax" else 0))
     else:
         ffn = dense
     if cfg.kv_lora_rank:
@@ -1629,13 +1820,19 @@ def _param_counts(cfg: Config, experts: int) -> int:
                  + m.inner + m.inner * D) if m else 0
         linear = kda.n_params(D, cfg.kda) if cfg.kda else 0
         delta = gdn.n_params(D, cfg.gdn) if cfg.gdn else 0
+        conv = cca.n_params(D, cfg.cca) if cfg.cca else 0
         attn += D * cfg.o_dim if cfg.attn_gate else 0
         layers = (cfg.n_of("M") * mamba + cfg.n_of("K") * linear
                   + cfg.n_of("G") * delta + cfg.n_of("E") * ffn
                   + cfg.n_of("D") * dense + cfg.n_of("*") * attn
-                  # a norm a block, two where one follows it too
-                  + len(cfg.pattern) * D * (2 if cfg.post_norm else 1))
-        return cfg.vocab * D + layers + D + D * cfg.vocab
+                  + cfg.n_of("C") * conv
+                  # a norm a block, two where one follows it too, and the
+                  # four vectors of a scaled residual
+                  + len(cfg.pattern) * D * (
+                      (2 if cfg.post_norm else 1)
+                      + (len(RESIDUAL_LEAVES) if cfg.residual_scaling else 0)))
+        tables = (1 if cfg.tie_word_embeddings else 2) * cfg.vocab * D
+        return tables + layers + D
     lead = cfg.n_dense_layers
     layers = L * (2 * D + attn) + lead * dense + (L - lead) * ffn
     return cfg.vocab * D + layers + D + D * cfg.vocab

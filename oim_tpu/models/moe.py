@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.lax import stop_gradient as lax_stop_gradient
 
+from oim_tpu.ops.norms import rmsnorm
 from oim_tpu.parallel.sharding import EMBED, EXPERT, LAYER, MLP
 
 
@@ -55,7 +56,17 @@ class MoEConfig:
     # top-k of score + a per-expert bias, weighed by the UNBIASED scores
     # renormalised and scaled). Sigmoid routing is dropless by
     # definition: it runs under ``dispatch="ragged"`` only.
+    # "mlp": the router is a small network with memory across layers, not
+    # ``tokens @ router`` (``router_state`` / ``route``): the tokens are
+    # projected down to ``router_dim``, the state the expert block before
+    # left is added a learned coefficient a channel, and a three-layer MLP
+    # (RMSNorm first, tanh GELU) gives the logits; softmax in float32, top-k
+    # of probability + a selection bias, the chosen probabilities the weights
+    # as they are (not renormalised: a renormalised top-1 is the constant
+    # 1). Dropless too.
     scoring: str = "softmax"
+    router_dim: int = 0
+    norm_eps: float = 1e-6  # of the "mlp" router's norm
     routed_scale: float = 1.0
     # Shared experts: a dense FFN of width ``shared_dim`` (0: n_shared *
     # mlp_dim) that every token takes beside its routed experts.
@@ -112,13 +123,16 @@ def init(rng, dim: int, mlp_dim: int, cfg: MoEConfig, dtype, n_layers: int | Non
     fan = dim**-0.5
     gated = _gated(cfg.act)
     params = {
-        "router": (jax.random.normal(ks[0], lead + (dim, e)) * fan
-                   ).astype(jnp.float32),
         "w_up": (jax.random.normal(ks[2], lead + (held, dim, mlp_dim)) * fan
                  ).astype(dtype),
         "w_down": (jax.random.normal(ks[3], lead + (held, mlp_dim, dim))
                    * mlp_dim**-0.5).astype(dtype),
     }
+    if cfg.scoring == "mlp":
+        params["router_mlp"] = _init_router_mlp(ks[0], lead, dim, cfg)
+    else:
+        params["router"] = (jax.random.normal(ks[0], lead + (dim, e)) * fan
+                            ).astype(jnp.float32)
     if gated:
         params["w_gate"] = (jax.random.normal(
             ks[1], lead + (held, dim, mlp_dim)) * fan).astype(dtype)
@@ -127,7 +141,7 @@ def init(rng, dim: int, mlp_dim: int, cfg: MoEConfig, dtype, n_layers: int | Non
             if k in params:
                 params[k] = jnp.pad(params[k], tail + (
                     ((0, pad), (0, 0)) if k == "w_down" else ((0, 0), (0, pad))))
-    if cfg.scoring == "sigmoid":
+    if cfg.scoring in ("sigmoid", "mlp"):
         # ``e_score_correction_bias``: zero in a fresh model and moved by
         # the aux-free balancing rule in training; drawn small here so
         # that a seeded tree has a bias that changes choices.
@@ -145,6 +159,30 @@ def init(rng, dim: int, mlp_dim: int, cfg: MoEConfig, dtype, n_layers: int | Non
             params["shared"]["w_gate"] = (jax.random.normal(
                 ks[5], lead + (dim, f)) * fan).astype(dtype)
     return params
+
+
+def _init_router_mlp(rng, lead: tuple, dim: int, cfg: MoEConfig) -> dict:
+    """The "mlp" router's leaves, float32: the projection down, the
+    coefficient on the state carried in (0.6: between forgetting and
+    keeping), the norm and the three layers with their biases."""
+    r, e = cfg.router_dim, cfg.n_experts
+    ks = jax.random.split(rng, 4)
+
+    def dense(key, shape):
+        return jax.random.normal(key, lead + shape, jnp.float32) \
+            * shape[0] ** -0.5
+
+    def zeros(n):
+        return jnp.zeros(lead + (n,), jnp.float32)
+
+    return {
+        "w_down": dense(ks[0], (dim, r)), "b_down": zeros(r),
+        "carry": jnp.full(lead + (r,), 0.6, jnp.float32),
+        "norm": jnp.ones(lead + (r,), jnp.float32),
+        "w_a": dense(ks[1], (r, r)), "b_a": zeros(r),
+        "w_b": dense(ks[2], (r, r)), "b_b": zeros(r),
+        "w_c": dense(ks[3], (r, e)), "b_c": zeros(e),
+    }
 
 
 def _gated(act: str) -> bool:
@@ -186,6 +224,49 @@ def capacity(n_tokens: int, cfg: MoEConfig) -> int:
     return max(1, int(cfg.top_k * n_tokens * cfg.capacity_factor / cfg.n_experts))
 
 
+_HI = jax.lax.Precision.HIGHEST  # the "mlp" router's products: a top-1
+# choice has no second expert to soften a flipped one
+
+
+def router_state(params, tokens, prev, cfg: MoEConfig):
+    """The "mlp" router's state of this expert block, float32 [..., R]:
+    ``tokens W_d + b_d + g * prev`` with ``prev`` the state the expert block
+    before left (None in the first: zeros). What the next block is handed."""
+    m = params["router_mlp"]
+    r = jnp.matmul(tokens.astype(jnp.float32), m["w_down"],
+                   precision=_HI) + m["b_down"]
+    return r if prev is None else r + m["carry"] * prev
+
+
+def _mlp_logits(m, state, eps: float):
+    """``W_c gelu(W_b gelu(W_a N(state))))``, each layer with its bias."""
+    x = rmsnorm(state, m["norm"], eps)
+    for w, b in (("w_a", "b_a"), ("w_b", "b_b")):
+        x = jax.nn.gelu(jnp.matmul(x, m[w], precision=_HI) + m[b],
+                        approximate=True)
+    return jnp.matmul(x, m["w_c"], precision=_HI) + m["b_c"]
+
+
+def route_from_state(params, state, cfg: MoEConfig):
+    """The "mlp" router: state [N, R] (``router_state``), not the tokens, ->
+    (experts [N, k] int32, weights [N, k] f32): top-k of
+    ``softmax(MLP(state)) + bias``, weighed by the probabilities of the
+    chosen as they are (not renormalised)."""
+    probs = jax.nn.softmax(
+        _mlp_logits(params["router_mlp"], state, cfg.norm_eps), axis=-1)
+    _, experts = jax.lax.top_k(probs + params["bias"], cfg.top_k)
+    w = jnp.take_along_axis(probs, experts, axis=-1)
+    return experts, w * cfg.routed_scale
+
+
+def _route(params, tokens, cfg: MoEConfig, state):
+    """A call's routing: from the router's carried state where the router
+    is a network, from the tokens otherwise."""
+    if cfg.scoring == "mlp":
+        return route_from_state(params, state, cfg)
+    return route(params, tokens, cfg)
+
+
 def route(params, tokens, cfg: MoEConfig):
     """tokens [N, D] -> (experts [N, k] int32, weights [N, k] f32): each
     token's k experts and the weight of each in its output, in float32.
@@ -193,7 +274,9 @@ def route(params, tokens, cfg: MoEConfig):
     - softmax: top-k of the probabilities, renormalised over the chosen
       (k > 1; the raw probability for k == 1);
     - sigmoid: top-k of ``sigmoid(logits) + bias``, weighed by the
-      unbiased scores of the chosen, renormalised and scaled."""
+      unbiased scores of the chosen, renormalised and scaled.
+
+    The "mlp" router reads its carried state: ``route_from_state``."""
     logits = tokens.astype(jnp.float32) @ params["router"].astype(jnp.float32)
     if cfg.scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
@@ -203,7 +286,8 @@ def route(params, tokens, cfg: MoEConfig):
         return experts, w * cfg.routed_scale
     if cfg.scoring != "softmax":
         raise ValueError(f"unknown MoE scoring {cfg.scoring!r} "
-                         "(valid: 'softmax', 'sigmoid')")
+                         "(valid: 'softmax', 'sigmoid'; 'mlp' routes from "
+                         "its state: route_from_state)")
     w, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
     if cfg.top_k > 1:
         w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
@@ -211,6 +295,7 @@ def route(params, tokens, cfg: MoEConfig):
 
 
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+SUBLANES = 8  # rows of a float32 tile: see ``grouped_ffn``
 
 
 def keep_stacked(group: dict) -> tuple[dict, dict]:
@@ -246,7 +331,19 @@ def grouped_ffn(params, rows, group_sizes, limit: float = 0.0):
     and are no part of any product. With ``params["stack"]`` = (the expert leaves of ALL L layers
     [L, E, ...], this layer's index) the products run over L * E groups, of
     which only this layer's E have rows: an empty group costs the product
-    nothing, and no layer's weights are cut out of the stack."""
+    nothing, and no layer's weights are cut out of the stack. The rows are
+    handed over in whole sublane tiles of 8 (zero rows past the groups' sum,
+    cut off again): on a v5e the grouped product in float32 at the highest
+    matmul precision gave wrong sums for EVERY row whenever the row count was
+    not a multiple of 8 (1, 2, 4, 7, 9, 15 rows: off by 4 of an rms of 1; 8,
+    16, 32 rows, and 8 or more rows of which 1 or 4 are assigned: 1.5e-6;
+    bfloat16 and float32 at the default precision are right at every count;
+    PERF.md section 6, PR 45). No serving program has such a count but a
+    decode step of fewer than 8 assignment rows."""
+    n_rows = rows.shape[0]
+    if n_rows % SUBLANES:
+        rows = jnp.pad(rows, ((0, -n_rows % SUBLANES), (0, 0)))
+        return grouped_ffn(params, rows, group_sizes, limit)[:n_rows]
     if "stack" in params:
         whole, index = params["stack"]
         n_layers, e = whole["w_up"].shape[:2]
@@ -285,7 +382,7 @@ def grouped_ffn(params, rows, group_sizes, limit: float = 0.0):
 DENSE_UP_TO_TOKENS = 64
 
 
-def _dense_held(params, x, cfg: MoEConfig):
+def _dense_held(params, x, cfg: MoEConfig, state=None):
     """``_dropless`` for a held share and few tokens: the same sum, every
     held expert computed for every token and weighed (see
     ``DENSE_UP_TO_TOKENS``). Returns (out, load) as ``_dropless``."""
@@ -293,7 +390,7 @@ def _dense_held(params, x, cfg: MoEConfig):
     n, e = b * t, cfg.n_held
     tokens = x.reshape(n, d)
     with jax.named_scope("moe_route"):
-        experts, w = route(params, tokens, cfg)                    # [N, k]
+        experts, w = _route(params, tokens, cfg, state)            # [N, k]
         chosen = (experts - cfg.held[0])[..., None] == jnp.arange(e)
         weight = jnp.sum(jnp.where(chosen, w[..., None], 0.0), axis=1)  # [N, e]
         counts = jnp.sum(chosen, axis=(0, 1))
@@ -554,7 +651,7 @@ def _routed_products(params, tokens, flat, order, counts, cfg: MoEConfig):
     return y, jnp.where(rung == len(ladder), len(RUNG_NAMES) - 1, rung)
 
 
-def _dropless(params, x, cfg: MoEConfig):
+def _dropless(params, x, cfg: MoEConfig, state=None):
     """x [B, T, D] -> (out, load f32): every token through all k of
     its experts, or through those of them that are held here (``cfg.held``:
     the others' assignments sort behind the last group and take part in no
@@ -566,11 +663,13 @@ def _dropless(params, x, cfg: MoEConfig):
     call's rung where ``load_width`` says so."""
     b, t, d = x.shape
     n, e, k = b * t, cfg.n_held, cfg.top_k
+    if state is not None:  # the "mlp" router's [B, T, R], a row a token
+        state = state.reshape(n, -1)
     if cfg.held and n <= DENSE_UP_TO_TOKENS:
-        return _dense_held(params, x, cfg)
+        return _dense_held(params, x, cfg, state)
     tokens = x.reshape(n, d)
     with jax.named_scope("moe_route"):
-        experts, w = route(params, tokens, cfg)
+        experts, w = _route(params, tokens, cfg, state)
         flat = experts.reshape(-1)                 # assignment a = token a // k
         if cfg.held:
             local = flat - cfg.held[0]
@@ -600,8 +699,12 @@ def _dropless(params, x, cfg: MoEConfig):
 
 
 def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
-          with_load: bool = False):
+          with_load: bool = False, state=None):
     """x: [B, T, D] -> (out [B, T, D], aux_loss scalar f32).
+
+    ``state`` [B, T, R]: what the "mlp" router chooses from
+    (``router_state()`` of this block, made by the caller, who carries it to
+    the next expert block); the other routers read the tokens.
 
     ``with_load`` (the serving programs): the second return is the f32
     vector [aux_loss, dropped_fraction, experts that got a row, rows of the
@@ -623,7 +726,7 @@ def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
     if cfg.dispatch == "ragged":
         # No capacity, so nothing dropped; no balance loss either (the
         # sigmoid router is balanced by its bias, outside the loss).
-        out, load = _dropless(params, x, cfg)
+        out, load = _dropless(params, x, cfg, state)
         zeros = jnp.zeros((2,), jnp.float32)
         if with_load:  # ``load_width`` entries
             return out, jnp.concatenate([zeros, load])
@@ -633,7 +736,7 @@ def apply(params, x, cfg: MoEConfig, with_stats: bool = False,
         raise ValueError(
             f"MoE dispatch {cfg.dispatch!r} runs the softmax router over "
             "gated experts all held here, without shared experts; sigmoid "
-            "scoring, a routed scale, shared experts, the squared-ReLU "
+            "or mlp scoring, a routed scale, shared experts, the squared-ReLU "
             "expert and a held share need dispatch='ragged'")
     b, t, d = x.shape
     n = b * t

@@ -51,8 +51,15 @@ def apply_rope(x, cos, sin, positions=None):
     """Rotate [B, T, H, D] by position; positions defaults to arange(T).
 
     Pair convention: (x[..., :D/2], x[..., D/2:]) — the "split-half" layout,
-    matching the frequencies above.
+    matching the frequencies above. Tables of a rotary width under the
+    head's (``partial_rotary_factor``: cos/sin [.., R/2] with R < D) rotate
+    the first R dims in split-half pairs (i, i + R/2) and pass the rest.
     """
+    width = 2 * cos.shape[-1]
+    if width < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :width], cos, sin, positions), x[..., width:]],
+            axis=-1)
     if positions is None:
         cos_t = cos[: x.shape[1]]
         sin_t = sin[: x.shape[1]]

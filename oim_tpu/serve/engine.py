@@ -490,8 +490,10 @@ class ServeEngine:
                 "(the prefill-to-decode handoff is tested over K/V pages "
                 "only); serve it as role='mixed'")
         if cfg.state_leaves:
-            # Recurrent state beside the pages (a hybrid's Mamba-2 or KDA
-            # layers): what follows cannot be right yet, one line of ROADMAP R3 each.
+            # Something a SLOT keeps beside the pages (a hybrid's Mamba-2, KDA
+            # or GatedDeltaNet state; the tail of a compressed-convolutional-
+            # attention layer, whose next position reads the one before): what
+            # follows cannot be right yet, one line of ROADMAP R3 each.
             for on, what, why in (
                     (prefix_cache_bytes > 0 and int(prefix_block) >= 1,
                      "a prefix store (prefix_cache_bytes > 0)",
@@ -1156,10 +1158,10 @@ class ServeEngine:
         would have reserved in page units)."""
         s = self._pagepool.stats()
         s["dense_equiv_pages"] = self.max_batch * self.n_blocks
-        # Recurrent state beside the pages (0 without recurrent layers):
-        # its bytes are held whole from construction, a kind of layer
-        # ("mamba", "kda"); a slot's row is live while a request decodes
-        # in it.
+        # Recurrent state beside the pages (0 without such layers): its
+        # bytes are held whole from construction, a kind of layer ("mamba",
+        # "kda", "gdn"; "cca": the tails of compressed convolutional
+        # attention); a slot's row is live while a request decodes in it.
         s["state_bytes"] = self.state_bytes
         s["state_bytes_by_kind"] = dict(self.state_bytes_by_kind)
         s["state_slots_live"] = self.active_slots if self.state_bytes else 0

@@ -1252,3 +1252,129 @@ def test_gdn_prefill_slice_carries_state_and_pool(topo, as_tpu, bucket):
     assert mem.alias_size_in_bytes >= 2.4e9
     assert mem.temp_size_in_bytes < (2.5 if bucket == 1024 else 0.5) * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- compressed convolutional attention: a TAIL beside the GQA pool (PR 45) ----
+# zaya1-8b.reasonbatch64-cca at the cell's own sizes: 14 of 40 layers, all 16
+# experts, the whole tied 262 272-row table (6.89 GB), 26 625 pages of 16
+# positions over 2 key-value heads of 128 (6.11 GB) and 64 slots' tails (9.6
+# MB) beside them.
+
+def cca_cell(sharding):
+    from benchmarks import common
+    from benchmarks.runners import serve_cca
+
+    config = common.load_json(os.path.join(
+        common.ROOT, "benchmarks", "configs", "zaya1-8b.json"))
+    model = serve_cca.model_dict(config, "serve")
+    cfg = serve_cca.program_config(model)
+    sizes = config["serve"]
+    params = shaped(jax.eval_shape(
+        lambda: llama.init(jax.random.PRNGKey(0), cfg)), sharding)
+    pool = shaped(jax.eval_shape(lambda: {
+        **gen.init_page_pool(cfg, sizes["kv_pool_tokens"] // PAGE + 1, PAGE),
+        **gen.init_state_pool(cfg, sizes["max_batch"])}), sharding)
+    return cfg, params, pool, sizes, model["max_seq"]
+
+
+def cca_program(chip, program):
+    from oim_tpu.serve.engine import _target_programs
+
+    if ("cca", program) not in _COMPILED:
+        cfg, params, pool, sizes, seq = cca_cell(chip)
+        step, prefill = _target_programs(cfg, PAGE, seq)
+        if program == "step":
+            lowered = step.lower(params, pool, *step_operands(
+                chip, sizes["max_batch"], seq))
+        else:
+            lowered = prefill.lower(
+                params, pool,
+                *prefill_operands(chip, int(program.split("-")[1]), seq),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=chip))  # the slot
+        _COMPILED["cca", program] = lowered.compile(), cfg, pool, sizes
+    return _COMPILED["cca", program]
+
+
+def test_cca_widths_are_the_published_ones(topo):
+    cfg, params, pool, sizes, seq = cca_cell(
+        SingleDeviceSharding(topo.devices[0]))
+    assert dataclasses.replace(
+        cfg, n_layers=40, max_seq=131072) == llama.ZAYA1_8B
+    assert cfg.pattern == "CE" * 14 and cfg.rope_dim == 64
+    assert set(pool) == {"k", "v", "cca_tail"}
+    assert pool["k"].shape == pool["v"].shape == (14, 26625, 16, 2, 128)
+    assert pool["cca_tail"].shape == (14, 64, 2688) \
+        and pool["cca_tail"].dtype == jnp.float32
+    assert "lm_head" not in params and params["embed"].shape == (262272, 2048)
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    held = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(pool))
+    assert 6.88e9 < weights < 6.92e9       # 3.44 B parameters, one table
+    assert 6.11e9 < held < 6.13e9          # 6.11 of pages + 9.6 MB of tails
+    assert (weights + held) / 16e9 > 0.8   # resident: 13.0 of 16 GB
+
+
+def test_cca_decode_updates_tail_and_pool_in_place(topo, as_tpu):
+    """The decode program at 64 slots: GQA's paged Pallas kernel in every
+    layer (8 query over 2 key-value heads), pages and tails aliased to the
+    donated buffers and neither copied, the 14 layers ONE scanned run that
+    carries the router's state, no copy of an expert leaf nor of the table
+    (the head contracts the embedding's minor dim where it lies: no second
+    1.07 GB), the top-1 products batched at a capacity of a step's 64 rows
+    (no grouped product), and arguments + temporaries inside the chip."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = cca_program(chip, "step")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print("CCA step", mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+          mem.alias_size_in_bytes,
+          [name for name, _, _ in mosaic_kernels(text)],
+          text.count(" while("))
+    assert [name for name, _, _ in mosaic_kernels(text)] \
+        == ["_paged_kernel"]
+    for name, leaf in pool.items():
+        assert not copies_of(text, leaf.shape), (name, copies_of(text, leaf.shape))
+    assert not copies_of(text, (262272, 2048))
+    assert not materialized(text, [(2048, 262272), (262272, 2048)])
+    assert not copies_of(text, (14, 16, 2048, 2048))
+    assert not materialized(text, [(16, 2048, 2048), (1, 16, 2048, 2048)])
+    assert "ragged-dot" not in text
+    assert moe.capacity_ladder(64, cfg.moe) == (64,)
+    state = sum(math.prod(pool[k].shape) * pool[k].dtype.itemsize
+                for k in pool)
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("bucket", [1024, 256])
+def test_cca_prefill_slice_carries_tail_and_pool(topo, as_tpu, bucket):
+    """A prefill slice (the configuration's chunk, and a short last piece):
+    the Pallas flash prefill over the slot's pages, the slot's tail cut out
+    and written back in place, pool and tails never copied, one row of
+    logits against the table where it lies, the expert leaves whole, the
+    top-1 products at ONE capacity with the rows past it through the grouped
+    product, and arguments + temporaries inside 15.75 GB at 64 slots and
+    425 984 positions (what the configuration's pool rests on)."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    compiled, cfg, pool, sizes = cca_program(chip, f"prefill-{bucket}")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    print("CCA prefill", bucket, mem.temp_size_in_bytes,
+          mem.argument_size_in_bytes, mem.alias_size_in_bytes,
+          [name for name, _, _ in mosaic_kernels(text)])
+    assert {name for name, _, _ in mosaic_kernels(text)} \
+        == {"_prefill_kernel"}
+    for name, leaf in pool.items():
+        assert not copies_of(text, leaf.shape), (name, copies_of(text, leaf.shape))
+    assert not copies_of(text, (262272, 2048))
+    assert not materialized(text, [(2048, 262272), (262272, 2048)])
+    assert not copies_of(text, (14, 16, 2048, 2048))
+    assert f"f32[{bucket},{cfg.vocab}]" not in text
+    ladder = moe.capacity_ladder(bucket, cfg.moe)
+    assert ladder == (128,)
+    assert re.search(r"= bf16\[16,128,2048\]\S* convolution\(", text)
+    assert set(re.findall(r"%ragged-dot[\w.\-]* = bf16\[(\d+),(\d+)\]", text)) \
+        == {(str(bucket), "2048")}
+    assert mem.alias_size_in_bytes >= 6.11e9
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -16,7 +16,7 @@ import pytest
 from benchmarks import common
 from oim_tpu.models import generate as gen
 from oim_tpu.models import llama, moe
-from oim_tpu.ops import gdn, kda, ssm
+from oim_tpu.ops import cca, gdn, kda, ssm
 from oim_tpu.serve import engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -49,6 +49,9 @@ FAMILIES = {
     "tiny_gdn": (llama.tiny_gdn,
                  {"prefill": EXPERTS | {"kda_scan", "mla_prefill"},
                   "step": EXPERTS | {"kda_step", "mla_decode"}}),
+    # compressed convolutional attention adds no name to the vocabulary: its
+    # mixing stands INSIDE ``blk_qkv`` (``inside`` below), its pages are GQA's
+    "tiny_cca": (llama.tiny_cca, {"prefill": EXPERTS, "step": EXPERTS}),
 }
 CASES = [(family, program) for family, (_, programs) in FAMILIES.items()
          for program in programs]
@@ -156,6 +159,19 @@ def test_program_carries_the_vocabulary(family, program):
         assert all(family_scope in path for path in paths if own in path)
         assert reader.classify("/".join(
             next(p for p in paths if own in p))) == family_scope
+    if family == "tiny_cca":
+        # the mixing's own scope under the block's: the benchmark's reader
+        # charges it to ``blk_qkv``, the sublayer's roofline reads ``cca_mix``
+        outer, own = cca.SCOPES[jitted == "prefill"].split("/")
+        assert (outer, own) == ("blk_qkv", "cca_mix") and inside(own, outer)
+        assert all(outer in path for path in paths if own in path)
+        assert reader.classify("/".join(
+            next(p for p in paths if own in p))) == outer
+        # the tail's read and write stand under it, the router's carry under
+        # the router's name
+        assert any("cca_mix" in p and "dynamic_update_slice" in p[-1]
+                   or "cca_mix" in p and "scatter" in p[-1] for p in paths)
+        assert inside("moe_route", "blk_ffn")
     # nothing of the vocabulary that the family should not have: a
     # recurrent mixer's name in a model without one would be a wrong ``with``
     assert found & set(reader.VOCABULARY) == want
